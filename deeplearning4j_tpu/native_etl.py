@@ -29,7 +29,8 @@ def _build(force: bool = False) -> bool:
     if not os.path.exists(src):
         return False
     try:
-        cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])
+        cmd = ["make", "-C", _NATIVE_DIR, os.path.basename(_LIB_PATH)] \
+            + (["-B"] if force else [])
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return os.path.exists(_LIB_PATH)
     except (subprocess.SubprocessError, OSError) as e:

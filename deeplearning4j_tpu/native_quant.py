@@ -17,6 +17,7 @@ import ctypes
 import logging
 import os
 import subprocess
+import sys
 from typing import Optional
 
 import numpy as np
@@ -26,10 +27,10 @@ log = logging.getLogger(__name__)
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libdl4jtpu_quant.so")
-_ABI = 2
+_ABI = 3
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
-_ffi_registered: Optional[bool] = None
+_ffi_registered = False
 FFI_TARGET = "dl4jtpu_int8_gemm"
 
 
@@ -38,11 +39,15 @@ def _build(force: bool = False) -> bool:
     if not os.path.exists(src):
         return False
     try:
-        cmd = ["make", "-C", _NATIVE_DIR] + (["-B"] if force else [])
+        # PYTHON: the Makefile asks THIS interpreter's jax for the XLA
+        # FFI headers the handler is compiled against.
+        cmd = ["make", "-C", _NATIVE_DIR, os.path.basename(_LIB_PATH),
+               f"PYTHON={sys.executable}"] + (["-B"] if force else [])
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return os.path.exists(_LIB_PATH)
     except (subprocess.SubprocessError, OSError) as e:
-        log.info("native quant build unavailable (%s); numpy fallback", e)
+        log.warning("native quant build unavailable (%s): %s", e,
+                    (getattr(e, "stderr", None) or b"")[-500:])
         return False
 
 
@@ -52,7 +57,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.int8_gemm.argtypes = [i8p, i8p, i32p, ctypes.c_int64,
                               ctypes.c_int64, ctypes.c_int64]
     lib.int8_gemm_vnni_available.restype = ctypes.c_int32
-    lib.int8_gemm_ffi_available.restype = ctypes.c_int32
     lib.quant_abi_version.restype = ctypes.c_int32
     return lib
 
@@ -88,34 +92,25 @@ def available() -> bool:
     return _load() is not None
 
 
-def ffi_register() -> bool:
+def ffi_register() -> None:
     """Register the library's XLA typed-FFI handler as the CPU
-    custom-call target `dl4jtpu_int8_gemm` (once per process).
-
-    This is what makes the native arm serving-fast: jax.pure_callback
-    costs ~1ms of python-trampoline + marshalling per call — an order
-    of magnitude more than the VNNI GEMM itself at serving shapes —
-    while a registered custom call hands the kernel raw XLA buffer
-    pointers in-process. Returns False (and the caller degrades to the
-    pure_callback bridge) when the .so was built without the jaxlib FFI
-    headers or the running jax lacks jax.extend.ffi."""
+    custom-call target `dl4jtpu_int8_gemm` (once per process): a
+    registered custom call hands the kernel raw XLA buffer pointers
+    in-process. Raises when the library is not available — callers
+    gate on `available()` first."""
     global _ffi_registered
-    if _ffi_registered is not None:
-        return _ffi_registered
-    _ffi_registered = False
+    if _ffi_registered:
+        return
     lib = _load()
-    if lib is None or not lib.int8_gemm_ffi_available():
-        return False
-    try:
-        from jax.extend import ffi as jffi
-        jffi.register_ffi_target(
-            FFI_TARGET, jffi.pycapsule(lib.dl4jtpu_int8_gemm_ffi),
-            platform="cpu")
-        _ffi_registered = True
-    except Exception as e:  # jax too old / duplicate registration
-        log.info("int8 FFI registration failed (%s); pure_callback "
-                 "bridge stays", e)
-    return _ffi_registered
+    if lib is None:
+        raise RuntimeError(
+            "native int8 GEMM library unavailable (native/"
+            "libdl4jtpu_quant.so missing and could not be built)")
+    import jax
+    jax.ffi.register_ffi_target(
+        FFI_TARGET, jax.ffi.pycapsule(lib.dl4jtpu_int8_gemm_ffi),
+        platform="cpu")
+    _ffi_registered = True
 
 
 def vnni() -> bool:
@@ -131,8 +126,8 @@ def int8_gemm(x: np.ndarray, w: np.ndarray) -> np.ndarray:
 
     `x` is s8 [B, K]; `w` is s8 [N, K] (weights stored transposed so
     each output channel is a unit-stride row — the layout quantize_tree
-    produces). Used from jax.pure_callback by the quant_matmul native
-    arm; also callable directly from host code and tests."""
+    produces). The host-side entry to the same kernel the quant_matmul
+    native arm reaches through its XLA custom call."""
     lib = _load()
     x = np.ascontiguousarray(x, np.int8)
     w = np.ascontiguousarray(w, np.int8)

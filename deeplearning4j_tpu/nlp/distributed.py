@@ -115,7 +115,7 @@ def _make_superstep(window: int, negative: int, chunk: int,
         # tables materializes dense [V, D] gradient buffers AND makes
         # `tables - lr*grads` a full-table pass — O(V*D) HBM traffic
         # per chunk regardless of how few rows the chunk touches, the
-        # dominant term of the 1M-vocab slowdown (BASELINE.md). The
+        # dominant term of the 1M-vocab slowdown. The
         # touched-rows form is mathematically identical to the old
         # dense count-scaling (divide each row's summed gradient by its
         # touch count): by linearity that equals scatter-adding

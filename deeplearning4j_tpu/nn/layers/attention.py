@@ -47,8 +47,8 @@ class SelfAttentionLayer(Layer):
     # through ops.attention.select_attention_impl (fused Pallas flash
     # kernel on TPU once t >= 2048, else blockwise/dense per the
     # measured rule in docs/perf_attention.md); "pallas" / "blockwise" /
-    # "dense" force a path ("pallas" falls back with a one-shot warning
-    # when the kernel is unavailable). The ring path picks its own
+    # "dense" force a path ("pallas" raises when the kernel cannot run
+    # the shape on this backend). The ring path picks its own
     # fused inner step (ring_self_attention use_flash auto).
     attention_impl: str = "auto"
     # Packed-batch mode (docs/perf_data_pipeline.md §PackToBucket): the

@@ -546,13 +546,10 @@ class LocalResponseNormalization(Layer):
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
         from ...ops import pallas_kernels as pk
         import jax as _jax
-        # The fallback decision must happen OUTSIDE the traced call: a
-        # try/except here would only see tracers (Pallas failures surface
-        # at jit-compile time), so eligibility = static shape check + a
-        # one-time eager compile probe.
+        # Eligibility is static (shape gate + backend). A kernel Mosaic
+        # refuses fails the step's compile; nothing falls back to lax.
         if self.use_pallas and pk.lrn_supported(x) and \
-                _jax.default_backend() == "tpu" and \
-                pk.tpu_kernel_available():
+                _jax.default_backend() == "tpu":
             return pk.lrn(x, self.k, self.alpha, self.beta, self.n), state
         return pk.lrn_reference(x, self.k, self.alpha, self.beta,
                                 self.n), state

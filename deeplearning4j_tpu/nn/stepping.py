@@ -10,11 +10,15 @@ straight back in. Assigning `net.iteration = n` (checkpoint restore,
 transfer learning) drops the cache; the next step re-uploads once. The
 cache is also keyed by the mesh it was produced under so ParallelWrapper's
 sharded steps never feed a foreign-sharded scalar into a single-device
-program.
+program. Under a mesh the fresh scalar is placed replicated over it — the
+placement the step's own output has — so step 2 presents the signature step
+1 compiled for (a single-device scalar there cost a second full compile of
+the train step).
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 
 class DeviceIterationMixin:
@@ -34,7 +38,10 @@ class DeviceIterationMixin:
 
     def _iteration_device(self, mesh=None):
         if self._iteration_dev is None or self._iteration_dev_mesh is not mesh:
-            return jnp.asarray(self._iteration, jnp.int32)
+            if mesh is None:
+                return jnp.asarray(self._iteration, jnp.int32)
+            from ..parallel.mesh import replicate
+            return replicate(mesh, np.asarray(self._iteration, np.int32))
         return self._iteration_dev
 
     def _commit_iteration(self, new_iter, mesh=None):

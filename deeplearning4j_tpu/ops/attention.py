@@ -97,18 +97,6 @@ def _pallas_ready(t_q: int, t_k: int, head_dim: int,
     return True if interpret else fa.flash_attention_available()
 
 
-def _warn_pallas_unavailable_once(t: int, head_dim: int) -> None:
-    if getattr(select_attention_impl, "_warned_pallas", False):
-        return
-    import logging
-    logging.getLogger(__name__).warning(
-        "attention impl 'pallas' requested but the fused kernel is "
-        "unavailable for t=%d head_dim=%d on this backend (%s); falling "
-        "back per the dispatch rule (docs/perf_attention.md)",
-        t, head_dim, jax.default_backend())
-    select_attention_impl._warned_pallas = True
-
-
 def _count_attention_impl(impl: str) -> None:
     from ..optimize.metrics import registry
     registry().counter(
@@ -129,13 +117,15 @@ def select_attention_impl(t_q: int, head_dim: int, *,
 
     Rule (measured A/B, docs/perf_attention.md): below t=2048 dense wins
     (blockwise/pallas overheads don't amortize); from 2048 up the fused
-    Pallas kernel wins everywhere it compiles (TPU probe via
-    flash_attention_available, or interpret=True for CPU tests), else
-    blockwise, else dense. An explicit user block_size (> 0) keeps the
-    blockwise path — the user asked for that shape; block_size == -1
-    forces dense (the pre-existing contract). `requested` overrides
-    ('auto'/None = the rule); a requested-but-unavailable 'pallas' warns
-    once and falls through the same rule."""
+    Pallas kernel wins wherever it exists (a TPU backend, or
+    interpret=True for CPU tests) and the geometry gate admits the
+    shape, else blockwise, else dense. An explicit user block_size (> 0)
+    keeps the blockwise path — the user asked for that shape;
+    block_size == -1 forces dense (the pre-existing contract).
+    `requested` overrides ('auto'/None = the rule). A requested 'pallas'
+    that cannot be honoured raises ValueError: the caller named a
+    kernel, and running another in its place would hide that it never
+    ran."""
     t_k = t_q if t_k is None else t_k
     req = None if requested in (None, "auto") else requested
     if req is not None and req not in ATTENTION_IMPLS:
@@ -147,8 +137,11 @@ def select_attention_impl(t_q: int, head_dim: int, *,
         blk = pick_block_size(t_q, block_size)
         if req == "pallas" and not _pallas_ready(t_q, t_k, head_dim,
                                                  interpret):
-            _warn_pallas_unavailable_once(t_q, head_dim)
-            req = None
+            raise ValueError(
+                f"attention impl 'pallas' requested but the fused kernel "
+                f"cannot run t_q={t_q} t_k={t_k} head_dim={head_dim} on "
+                f"backend {jax.default_backend()!r} "
+                "(flash_attention_supported / flash_attention_available)")
         if req == "pallas":
             choice = "pallas"
         elif req == "blockwise":
@@ -516,10 +509,10 @@ def ring_self_attention(q, k, v, mesh, *, axis: str = "seq",
     not just a forward op. See module docstring.
 
     `use_flash` selects the fused Pallas kernel as the per-hop inner
-    step (_ring_body_flash): None = auto — on when the kernel compiles
-    for the per-device geometry (TPU probe, or flash_interpret=True for
-    CPU tests), off otherwise, so CPU parity tests keep exercising the
-    legacy scan body unchanged."""
+    step (_ring_body_flash): None = auto — on when the geometry gate
+    admits the per-device shape and the kernel exists (a TPU backend, or
+    flash_interpret=True for CPU tests), off otherwise, so CPU parity
+    tests keep exercising the legacy scan body unchanged."""
     n_dev = int(mesh.shape[axis])
     t = q.shape[1]
     if t % n_dev:
@@ -554,13 +547,15 @@ def ring_self_attention(q, k, v, mesh, *, axis: str = "seq",
         _count_attention_impl("blockwise" if block_size else "dense")
         body = _ring_body(axis, n_dev, t_loc, causal, block_size)
     spec_qkv = P(batch_axis, axis, head_axis, None)
-    from ..parallel.mesh import shard_map_compat
+    # check_vma off: the ring body returns per-shard values stitched by
+    # out_specs
     if key_mask is None:
-        fn = shard_map_compat(lambda a, b, c: body(a, b, c, None), mesh,
-                              in_specs=(spec_qkv,) * 3, out_specs=spec_qkv)
+        fn = jax.shard_map(lambda a, b, c: body(a, b, c, None), mesh=mesh,
+                           in_specs=(spec_qkv,) * 3, out_specs=spec_qkv,
+                           check_vma=False)
         return fn(q, k, v)
-    fn = shard_map_compat(body, mesh,
-                          in_specs=(spec_qkv, spec_qkv, spec_qkv,
-                                    P(batch_axis, axis)),
-                          out_specs=spec_qkv)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec_qkv, spec_qkv, spec_qkv,
+                                 P(batch_axis, axis)),
+                       out_specs=spec_qkv, check_vma=False)
     return fn(q, k, v, key_mask)

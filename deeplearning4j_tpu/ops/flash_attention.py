@@ -1,7 +1,7 @@
 """Fused flash-attention Pallas kernel — forward AND backward.
 
-Why: blockwise attention (ops/attention.py) tops out at ~0.200 est-MFU
-at seq 16k (BENCH_baseline.json `attention_longctx_*`): the lax-scan
+Why: blockwise attention (ops/attention.py) topped out at ~0.200 est-MFU
+at seq 16k (docs/perf_attention.md): the lax-scan
 online softmax round-trips m/l/acc through HBM between small block
 matmuls and leaves the MXU idle. The FlashAttention formulation (Dao et
 al., 2022) keeps the whole QK^T → online softmax → PV chain for one
@@ -41,8 +41,9 @@ residual. di = rowsum(o * do) is precomputed outside the kernels.
 
 Gating mirrors lrn: `interpret=True` runs the same kernels on CPU for
 tests; the TPU fast path is guarded by flash_attention_supported
-(geometry/VMEM) + flash_attention_available (one-time eager compile
-probe via pallas_kernels.kernel_probe).
+(geometry/VMEM) + flash_attention_available (the backend is a TPU).
+There is no compile probe and no fallback: a geometry the gate admits
+and Mosaic refuses fails the caller's compile with Mosaic's message.
 """
 from __future__ import annotations
 
@@ -55,7 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .pallas_kernels import kernel_probe, pad_axis_to
+from .pallas_kernels import pad_axis_to
 
 # Cross-file trace surface (analysis/boundaries.py): decode_attention is
 # dispatched inside jitted decode steps (serving/decode.py _step_pure),
@@ -101,7 +102,7 @@ def _scores(q_ref, k_ref, qp_ref, kp_ref, km_ref, qs_ref, ks_ref, scale,
     if causal:
         s = jnp.where(kp_ref[:] <= qp_ref[:], s, NEG)
     if use_mask:
-        s = jnp.where(km_ref[:] > 0, s, NEG)
+        s = jnp.where(km_ref[0] > 0, s, NEG)
     if use_segs:
         s = jnp.where(qs_ref[0] == ks_ref[0], s, NEG)
     return s
@@ -270,12 +271,15 @@ def _bwd_dq_kernel(qp_ref, kp_ref, km_ref, qs_ref, ks_ref, q_ref, k_ref,
 # ---------------------------------------------------------------------------
 
 def _km_spec(pl, kb, use_mask, kv_axis):
-    """key-mask BlockSpec: when no mask the array is a shared [1, tk]
-    ones row — every bh grid step maps to row 0."""
-    if use_mask:
-        return pl.BlockSpec((1, kb), lambda i, j, k:
-                            (i, (j, k)[kv_axis - 1]))
-    return pl.BlockSpec((1, kb), lambda i, j, k: (0, (j, k)[kv_axis - 1]))
+    """key-mask BlockSpec over a [bh, 1, tk] row array (the kv-segment
+    layout): Mosaic wants a block's last two dims divisible by (8, 128)
+    or equal to the array's, and a (1, kb) block of a [bh, tk] array is
+    neither in its second-to-last dim — the unit axis makes it equal.
+    When no mask the array is a shared [1, 1, tk] ones row and every bh
+    grid step maps to row 0."""
+    bh = (lambda i: i) if use_mask else (lambda i: 0)
+    return pl.BlockSpec((1, 1, kb), lambda i, j, k:
+                        (bh(i), 0, (j, k)[kv_axis - 1]))
 
 
 def _seg_specs(pl, qb, kb, use_segs, q_axis, kv_axis):
@@ -506,9 +510,9 @@ def flash_attention(q, k, v, *, causal: bool = False, key_mask=None,
     use_mask = key_mask is not None
     if use_mask:
         km = jnp.broadcast_to(key_mask.astype(jnp.float32)[:, None, :],
-                              (b, hh, tk)).reshape(b * hh, tk)
+                              (b, hh, tk)).reshape(b * hh, 1, tk)
     else:
-        km = jnp.ones((1, tk), jnp.float32)
+        km = jnp.ones((1, 1, tk), jnp.float32)
     qp = (jnp.arange(tq, dtype=jnp.int32) if q_pos is None
           else q_pos.astype(jnp.int32)).reshape(tq, 1)
     kp = (jnp.arange(tk, dtype=jnp.int32) if kv_pos is None
@@ -544,30 +548,32 @@ def flash_attention(q, k, v, *, causal: bool = False, key_mask=None,
 
 def flash_attention_supported(t_q: int, t_k: int, head_dim: int, *,
                               q_block: int = 0, kv_block: int = 0) -> bool:
-    """Geometry gate: exact block tiling plus a conservative VMEM bound
-    for the worst kernel (dkv: q/k/v/do blocks + 2 [kb, d] f32 scratch +
-    the [qb, kb] score block)."""
+    """Geometry gate: exact block tiling, blocks Mosaic can lay out, and
+    a conservative VMEM bound for the worst kernel (dkv: q/k/v/do blocks
+    + 2 [kb, d] f32 scratch + the [qb, kb] score block).
+
+    Mosaic wants each block's last two dims divisible by (8, 128) or
+    equal to the array's. qb is a second-to-last dim everywhere it
+    appears ([qb, d], [qb, 1]); kb is second-to-last in the K/V blocks
+    and LAST in the kv position / mask / segment rows ([1, kb]), so it
+    must be a lane multiple unless one block spans the whole axis."""
     if t_q < 1 or t_k < 1 or head_dim < 1:
         return False
     qb = q_block or pick_kernel_block(t_q, DEFAULT_BLOCK_Q)
     kb = kv_block or pick_kernel_block(t_k, DEFAULT_BLOCK_KV)
     if t_q % qb or t_k % kb:
         return False
+    if (qb % 8 and qb != t_q) or (kb % _LANE and kb != t_k):
+        return False
     dp = head_dim + ((-head_dim) % _LANE)
     est = 4 * ((2 * qb + 4 * kb) * dp + 2 * qb * kb)
     return est <= _VMEM_BUDGET
 
 
-def _flash_probe():
-    x = jnp.ones((1, 2 * DEFAULT_BLOCK_Q, 1, _LANE), jnp.float32)
-    o = flash_attention(x, x, x, causal=True)
-    o.block_until_ready()
-
-
 def flash_attention_available() -> bool:
-    """One-time eager compile probe (kernel_probe rationale applies: a
-    traced first call must not poison the cache)."""
-    return kernel_probe("flash_attention", _flash_probe)
+    """The compiled kernels exist only for TPU (Mosaic); everywhere else
+    the answer is no, without trying to compile."""
+    return jax.default_backend() == "tpu"
 
 
 def decode_attention(q, k, v, cache_len, *, impl: str = "auto",
@@ -591,7 +597,7 @@ def decode_attention(q, k, v, cache_len, *, impl: str = "auto",
     `impl="flash"` routes through the flash kernel with q_block=1
     (pick_kernel_block(1, ·) == 1, so the tq=1 row tiles legally);
     `impl="dense"` is the einsum reference; `impl="auto"` picks flash
-    when the geometry gate and the one-time probe both pass. No
+    on a TPU when the geometry gate passes. No
     backward: decode is inference-only, and the wrapper is jit-friendly
     (cache_len is a traced operand, so one executable serves every
     fill level of a given bucket).

@@ -12,7 +12,7 @@ shifted-window accumulate → pow → divide in a single pass on the VPU.
 ROUND-5 HONESTY NOTE: the standalone-op microbench (633 µs/op Pallas vs
 1192 µs/op lax on [64,27,27,96] f32, 2026-07-30) does NOT survive
 in-workload reality. After fixing the probe bug that had silently kept
-every traced run on the lax path (see tpu_kernel_available), the full
+every traced run on the lax path, the full
 AlexNet A/B measures lax ~2x FASTER end-to-end (bench.py alexnet vs
 alexnet_pallaslrn; docs/perf_googlenet.md): the pallas_call is a
 fusion barrier, and the 128-lane channel padding doubles HBM bytes for
@@ -26,10 +26,10 @@ custom_vjp; the backward runs the Pallas backward kernel under the same
 gating (else the lax autodiff of the reference implementation) —
 parity-tested against autodiff of the lax version.
 
-The kernel path requires TPU (or interpret mode for CPU tests); any
-probe failure falls back to the lax implementation, mirroring the
-reference's "helper != null" optional-acceleration contract
-(ConvolutionLayer.java:66-77).
+The kernel path requires TPU (or interpret mode for CPU tests). Off-TPU
+the dispatch answers "no" without compiling anything; on a TPU a kernel
+Mosaic refuses fails the caller's compile with the compiler's message —
+there is no fallback to the lax reference that could hide it.
 """
 from __future__ import annotations
 
@@ -68,37 +68,6 @@ def pad_axis_to(a, axis: int, multiple: int):
     return jnp.pad(a, widths)
 
 
-_probe_results: Dict[str, bool] = {}
-
-
-def kernel_probe(name: str, probe: Callable[[], None]) -> bool:
-    """One-time compile probe for a Pallas kernel, cached per `name`.
-
-    try/except around a traced call CANNOT catch Pallas lowering failures
-    (they surface at jit-compile time), so the optional-helper fallback
-    is decided here, eagerly, once — the actual 'helper != null' check.
-
-    The first call usually happens while a layer forward is being TRACED
-    (gating runs inside jit), where a bare jnp.ones would produce a
-    tracer and the probe would throw and cache False — permanently
-    disabling the kernel for the whole process (the round-4 GoogLeNet
-    profile caught exactly this: zero Mosaic calls in a "Pallas" run).
-    ensure_compile_time_eval makes the probe eager regardless of any
-    ambient trace."""
-    cached = _probe_results.get(name)
-    if cached is not None:
-        return cached
-    try:
-        with jax.ensure_compile_time_eval():
-            probe()
-        _probe_results[name] = True
-    except Exception as e:
-        log.info("Pallas %s kernel unavailable (%s); fallback path",
-                 name, e)
-        _probe_results[name] = False
-    return _probe_results[name]
-
-
 def lrn_reference(x, k: float, alpha: float, beta: float, n: int):
     """Plain-lax LRN (the pre-Pallas implementation; also the backward)."""
     half = n // 2
@@ -114,8 +83,7 @@ def _window_sum(a, up: int, down: int):
     slices: out[:, c] = sum(a[:, c-up : c+down+1]) with zero fill.
     jnp.pad (scalar fill), NOT concatenate-with-zeros: materialized zero
     blocks become captured constants when the kernel is traced under
-    ensure_compile_time_eval (the probe context), which pallas_call
-    rejects."""
+    ensure_compile_time_eval, which pallas_call rejects."""
     acc = a
     for off in range(1, max(up, down) + 1):
         if off <= down:  # channel c sees c+off: shift left, zero-fill
@@ -213,8 +181,7 @@ def _lrn_bwd(k, alpha, beta, n, interpret, x, g):
     # The backward kernel is gated exactly like the forward (the round-4
     # profile showed the lax backward costing ~4x the Pallas forward it
     # accompanied: reduce-window + power + multiply chains over HBM).
-    if interpret or (lrn_supported(x) and jax.default_backend() == "tpu"
-                     and tpu_kernel_available()):
+    if interpret or (lrn_supported(x) and jax.default_backend() == "tpu"):
         return (_lrn_bwd_pallas(x, g, k, alpha, beta, n, interpret),)
     _, vjp = jax.vjp(lambda v: lrn_reference(v, k, alpha, beta, n), x)
     return vjp(g)
@@ -233,18 +200,6 @@ def lrn_supported(x) -> bool:
     return _ROW_BLOCK * padded_c * 4 * 4 <= 8 * 1024 * 1024  # ≤ c=2048 f32
 
 
-def _lrn_probe():
-    x = jnp.ones((1, 1, 1, 8), jnp.float32)
-    _lrn_pallas(x, 2.0, 1e-4, 0.75, 5, False).block_until_ready()
-
-
-def tpu_kernel_available() -> bool:
-    """One-time compile probe for the LRN kernel (see kernel_probe for
-    the eager-probe rationale — a traced first call once silently
-    disabled the kernel for the whole process)."""
-    return kernel_probe("lrn", _lrn_probe)
-
-
 # ---------------------------------------------------------------------------
 # int8 matmul for the quantized serving path (docs/design.md
 # "Quantized serving"). Three candidate implementations of the same
@@ -259,12 +214,12 @@ def tpu_kernel_available() -> bool:
 # the weight operand and runs ~0.2x fp32, and its bf16 dot converts the
 # weights back to f32. The native AVX512-VNNI kernel
 # (native/quant_gemm.cpp as an XLA typed-FFI custom call; ~105us at
-# [8,1024]x[1024,1024] vs ~470us fp32 — the pure_callback bridge it
-# replaced cost ~1ms/call in trampoline alone) measures 3-5x FASTER
-# than the fp32 matmul at serving shapes. On TPU the Pallas kernel
-# feeds the MXU's native int8 path with no host round-trip and the XLA
-# arm is the portable fallback. None of that is assumed: whichever arm
-# wins the probe on the running backend ships.
+# [8,1024]x[1024,1024] vs ~470us fp32) measures 3-5x FASTER than the
+# fp32 matmul at serving shapes. On TPU the Pallas kernel feeds the
+# MXU's native int8 path and the XLA arm is the portable alternative.
+# None of that is assumed: whichever arm wins the probe on the running
+# backend ships, and an arm that fails to compile or run fails the
+# probe loudly instead of dropping out of it.
 # ---------------------------------------------------------------------------
 
 _QUANT_BLOCK_N = 256  # output channels per grid step (VMEM-friendly)
@@ -317,29 +272,13 @@ def int8_matmul_native(x_q, w_q):
     """Native arm: the AVX512-VNNI GEMM as an XLA custom call. The
     typed-FFI handler (native/quant_gemm.cpp, registered once via
     native_quant.ffi_register) hands the kernel raw XLA buffer pointers
-    in-process — measured ~1ms/call cheaper than the jax.pure_callback
-    bridge, whose python trampoline + marshalling costs an order of
-    magnitude more than the GEMM itself at serving shapes. The
-    pure_callback bridge stays as the degraded path for a .so built
-    without the jaxlib FFI headers; either way the math is exact
-    integer, so trace semantics hold."""
+    in-process; the math is exact integer, so trace semantics hold.
+    CPU only, and only where the library is built (_quant_candidates
+    checks both before offering this arm)."""
     from .. import native_quant
+    native_quant.ffi_register()
     out_t = jax.ShapeDtypeStruct((x_q.shape[0], w_q.shape[0]), jnp.int32)
-    if native_quant.ffi_register():
-        from jax.extend import ffi as jffi
-        return jffi.ffi_call(native_quant.FFI_TARGET, out_t)(x_q, w_q)
-    return jax.pure_callback(native_quant.int8_gemm, out_t,
-                             x_q, w_q, vectorized=False)
-
-
-def _int8_pallas_probe():
-    x = jnp.ones((8, 128), jnp.int8)
-    w = jnp.ones((8, 128), jnp.int8)
-    int8_matmul_pallas(x, w).block_until_ready()
-
-
-def int8_pallas_available() -> bool:
-    return kernel_probe("int8_matmul", _int8_pallas_probe)
+    return jax.ffi.ffi_call(native_quant.FFI_TARGET, out_t)(x_q, w_q)
 
 
 def _quant_candidates(backend: str) -> Dict[str, Callable]:
@@ -347,7 +286,7 @@ def _quant_candidates(backend: str) -> Dict[str, Callable]:
     cands: Dict[str, Callable] = {"xla": int8_matmul_xla}
     if backend == "cpu" and native_quant.available():
         cands["native"] = int8_matmul_native
-    if backend == "tpu" and int8_pallas_available():
+    if backend == "tpu":
         cands["pallas"] = int8_matmul_pallas
     return cands
 
@@ -356,32 +295,28 @@ def _measure_quant_impl(backend: str) -> Tuple[str, Dict[str, float]]:
     """Time every candidate arm eagerly at a serving-representative
     shape and return (winner, per-arm best seconds). Eager (per-op)
     dispatch overhead is tens of µs against ms-scale GEMMs, so the
-    ordering matches the jitted steady state; the native arm's
-    pure_callback hop is included in its own timing — no arm gets its
-    overhead waived."""
+    ordering matches the jitted steady state. An arm that raises
+    propagates: a candidate offered for this backend that cannot
+    compile is a defect, not a reason to serve another arm quietly."""
     key = jax.random.PRNGKey(0)
     x = jax.random.randint(key, (8, 1024), -127, 128, jnp.int8)
     w = jax.random.randint(key, (1024, 1024), -127, 128, jnp.int8)
     timings: Dict[str, float] = {}
     for name, fn in _quant_candidates(backend).items():
-        try:
-            jax.block_until_ready(fn(x, w))  # compile/warm
-            best = float("inf")
-            for _ in range(5):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(x, w))
-                best = min(best, time.perf_counter() - t0)
-            timings[name] = best
-        except Exception as e:  # an arm failing is a fallback, not a crash
-            log.info("quant_matmul arm %s unavailable (%s)", name, e)
-    winner = min(timings, key=timings.get) if timings else "xla"
-    return winner, timings
+        jax.block_until_ready(fn(x, w))  # compile/warm
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(x, w))
+            best = min(best, time.perf_counter() - t0)
+        timings[name] = best
+    return min(timings, key=timings.get), timings
 
 
 def select_quant_impl() -> str:
     """The measured per-backend dispatch decision, cached per process.
-    Runs eagerly even when first reached during a trace (the
-    kernel_probe rationale: a traced probe would poison the cache)."""
+    Runs eagerly even when first reached during a trace (a traced
+    measurement would time tracers, not kernels)."""
     backend = jax.default_backend()
     cached = _quant_impl.get(backend)
     if cached is not None:

@@ -6,11 +6,10 @@ generates for `lax.reduce_window(max)`, running at 2.1× its byte bound.
 S&S is the one HLO in the step with no MXU/VPU-friendly lowering: it
 walks windows serially per output element. This module provides the
 alternatives and the selector that decides between them, mirroring
-`select_attention_impl` (ops/attention.py): static trace-time choice, a
-`pooling_impl_selected_total{impl=}` counter in the PR-2 registry, a
-one-shot warning when a requested impl is unavailable, and an eager
-compile probe (kernel_probe) so a lowering failure can never crash a
-traced forward.
+`select_attention_impl` (ops/attention.py): static trace-time choice
+and a `pooling_impl_selected_total{impl=}` counter in the PR-2
+registry. Every impl is portable lax; a lowering failure fails the
+step's compile rather than selecting another impl.
 
 Max pool:
   * "sns"  — `lax.reduce_window(max)`; autodiff emits select-and-scatter
@@ -203,34 +202,6 @@ def _count_pooling_impl(impl: str) -> None:
     ).labels(impl=impl).inc()
 
 
-def mask_backward_available() -> bool:
-    """One-time eager compile probe for the mask-backward formulation
-    (kernel_probe caches per name; ensure_compile_time_eval inside makes
-    it safe to first fire under an ambient trace). The formulation is
-    portable lax, so this guards against lowering regressions rather
-    than hardware support — the same 'helper != null' contract the
-    Pallas kernels use."""
-    from .pallas_kernels import kernel_probe
-
-    def probe():
-        xx = jnp.ones((1, 4, 4, 1), jnp.float32)
-        jax.grad(lambda a: _max_pool_mask(
-            a, (2, 2), (2, 2), ((0, 0), (0, 0))).sum())(xx)
-
-    return kernel_probe("pool_mask_bwd", probe)
-
-
-def _warn_unavailable_once(impl: str) -> None:
-    if getattr(select_pooling_impl, "_warned_mask", False):
-        return
-    import logging
-    logging.getLogger(__name__).warning(
-        "pooling impl %r requested but its compile probe failed on this "
-        "backend (%s); falling back per the dispatch rule "
-        "(docs/perf_googlenet.md round 6)", impl, jax.default_backend())
-    select_pooling_impl._warned_mask = True
-
-
 def select_pooling_impl(pooling_type: str, window, strides, *,
                         requested: Optional[str] = None) -> str:
     """Pick the implementation for one pooling call, increment
@@ -253,8 +224,8 @@ def select_pooling_impl(pooling_type: str, window, strides, *,
         round-6 doc) and is untested on TPU.
 
     The alternatives stay selectable per layer (pooling_impl="mask" /
-    "conv"); a requested or auto-chosen "mask" whose compile probe
-    fails warns once and falls back to "sns"."""
+    "conv"). "mask" is portable lax: if it ever fails to lower, the
+    step's compile fails — nothing swaps in "sns" behind it."""
     if pooling_type == "max":
         impls = MAX_IMPLS
         default = "mask" if jax.default_backend() == "cpu" else "sns"
@@ -268,9 +239,6 @@ def select_pooling_impl(pooling_type: str, window, strides, *,
         raise ValueError(f"pooling impl {requested!r} not in "
                          f"{impls + ('auto',)} for {pooling_type} pooling")
     choice = req or default
-    if choice == "mask" and not mask_backward_available():
-        _warn_unavailable_once("mask")
-        choice = "sns"
     _count_pooling_impl(f"{pooling_type}_{choice}")
     return choice
 

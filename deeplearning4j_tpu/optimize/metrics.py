@@ -410,14 +410,18 @@ def device_memory_stats() -> List[Dict[str, float]]:
     """Per-device {device, bytes_in_use, peak_bytes_in_use} sampled from
     jax.local_devices(); 0s where the backend exposes no memory_stats()
     (CPU). Shared by the scrape collector and StatsListener so the
-    sampling logic has exactly one implementation."""
-    try:
-        import jax
-        devices = jax.local_devices()
-    except Exception:
+    sampling logic has exactly one implementation.
+
+    Samples only where a backend is already up: a chip belongs to one
+    process at a time, so a metrics scrape must never be what
+    initialises the backend in a process that does no device work (a
+    bench parent, a federation front-end) — it would take the chip from
+    the child that needs it."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax._src.xla_bridge.backends_are_initialized():
         return []
     out = []
-    for d in devices:
+    for d in jax.local_devices():
         try:
             stats = d.memory_stats() or {}
         except Exception:

@@ -27,20 +27,6 @@ SEQ_AXIS = "seq"
 STAGE_AXIS = "stage"
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across the API move: top-level `jax.shard_map`
-    (check_vma kwarg) on recent jax, `jax.experimental.shard_map`
-    (check_rep kwarg) on 0.4.x. Replication checking is disabled either
-    way — callers here return per-shard values stitched by out_specs."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def create_mesh(shape: Optional[Sequence[int]] = None,
                 axis_names: Sequence[str] = (DATA_AXIS,),
                 devices=None) -> Mesh:

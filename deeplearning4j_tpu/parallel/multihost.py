@@ -30,6 +30,12 @@ Launch contract (one process per host, like one Spark executor per node):
 Env fallbacks: JAX_COORDINATOR_ADDRESS / JAX_NUM_PROCESSES /
 JAX_PROCESS_ID. On TPU pods, pass auto_detect=True to let jax's cluster
 detection fill everything in.
+
+Not run on the chip: every multi-process proof of this module is two OS
+processes over gloo on CPU devices (tests/test_multihost.py). On one
+host a chip belongs to one process at a time, so the one-host layout is
+one process driving all local chips (ParallelWrapper over a mesh), which
+is what chip_smoke.py --devices 4 exercises.
 """
 from __future__ import annotations
 

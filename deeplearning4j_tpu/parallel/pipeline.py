@@ -215,11 +215,12 @@ class PipelineParallelWrapper:
             # the full-batch mean)
             return jax.lax.psum(loss_acc, axis) / M
 
-        from .mesh import shard_map_compat
-        smapped = shard_map_compat(
-            spmd_loss, self.mesh,
+        # check_vma off: the body returns per-shard values stitched by
+        # out_specs
+        smapped = jax.shard_map(
+            spmd_loss, mesh=self.mesh,
             in_specs=(P(axis), P(), P(), P()),
-            out_specs=P())
+            out_specs=P(), check_vma=False)
 
         def loss_fn(body_p, out_p, x_mb, y_mb):
             loss = smapped(body_p, out_p, x_mb, y_mb)

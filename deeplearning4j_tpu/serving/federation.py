@@ -905,7 +905,12 @@ def spawn_replica(replica_id: int, frontend_url: str, *,
     stock demo builder); `env` overlays the child environment (e.g.
     JAX_PLATFORMS=cpu, DL4JTPU_REPLICA_* geometry, DL4JTPU_FAULT_*
     chaos arming). Readiness is observed through the front-end's beat
-    table (wait_for_replicas), not stdout."""
+    table (wait_for_replicas), not stdout.
+
+    Not run on the chip: a chip belongs to one process at a time, so a
+    parent that has touched jax holds it and a replica child that needs
+    it fails or hangs, and two replicas cannot share one chip. Every
+    caller so far pins the children to JAX_PLATFORMS=cpu (ROADMAP S7)."""
     import subprocess
     # -c instead of -m: the parent has usually already imported
     # serving.federation, and runpy warns when re-executing a module

@@ -30,15 +30,12 @@
 #include <omp.h>
 #endif
 
-// Optional XLA typed-FFI handler (jaxlib ships the header-only API
-// under jaxlib/include — the Makefile probes for it and defines
-// DL4JTPU_WITH_XLA_FFI when found). The ctypes int8_gemm entry costs
-// ~1ms per call through jax.pure_callback (python trampoline + operand
-// marshalling) — an order of magnitude MORE than the GEMM itself at
-// serving shapes — so the serving path registers this handler as a
-// real XLA custom call instead: XLA hands the kernel raw buffer
-// pointers in-process and the trampoline disappears. The plain ctypes
-// entry stays for probing, tests, and hosts without the headers.
+// XLA typed-FFI handler (jaxlib ships the header-only API under
+// jaxlib/include; the Makefile asks jax.ffi for the path). The serving
+// path registers it as a real XLA custom call: XLA hands the kernel raw
+// buffer pointers in-process, with no python trampoline or operand
+// marshalling in the way. The plain ctypes entry stays for host code
+// and tests.
 
 namespace {
 
@@ -120,18 +117,9 @@ void gemm_scalar(const int8_t* x, const int8_t* w, int32_t* out,
 extern "C" {
 
 // Bump on any signature change; the ctypes loader rebuilds once on
-// mismatch (same protocol as etl_abi_version). v2: XLA FFI handler.
-int32_t quant_abi_version() { return 2; }
-
-// 1 when the XLA typed-FFI handler is compiled into this .so (the
-// Python side falls back to jax.pure_callback when it is not).
-int32_t int8_gemm_ffi_available() {
-#ifdef DL4JTPU_WITH_XLA_FFI
-    return 1;
-#else
-    return 0;
-#endif
-}
+// mismatch (same protocol as etl_abi_version). v2: XLA FFI handler;
+// v3: the handler is unconditional (no build without it).
+int32_t quant_abi_version() { return 3; }
 
 // 1 when the AVX512-VNNI kernel is compiled in AND the running CPU
 // supports it; the Python probe reports which path a measurement used.
@@ -160,7 +148,6 @@ void int8_gemm(const int8_t* x, const int8_t* w, int32_t* out,
 
 }  // extern "C"
 
-#ifdef DL4JTPU_WITH_XLA_FFI
 #include "xla/ffi/api/ffi.h"
 
 namespace ffi = xla::ffi;
@@ -187,4 +174,3 @@ XLA_FFI_DEFINE_HANDLER_SYMBOL(
         .Arg<ffi::Buffer<ffi::S8>>()
         .Arg<ffi::Buffer<ffi::S8>>()
         .Ret<ffi::Buffer<ffi::S32>>());
-#endif  // DL4JTPU_WITH_XLA_FFI

@@ -27,7 +27,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_once(cache_dir: str, ceiling: float):
     env = dict(os.environ)
     env.update(JAX_PLATFORMS="cpu",
-               DL4JTPU_COMPILE_CACHE_DIR=cache_dir)
+               JAX_COMPILATION_CACHE_DIR=cache_dir)
     t0 = time.monotonic()
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "lenet_tiny",

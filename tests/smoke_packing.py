@@ -103,14 +103,22 @@ def main() -> int:
     packed_ds = next(iter(PackToBucketIterator(
         ExistingDataSetIterator([ragged]), bucket_len=8)))
     packed_score = net.score(packed_ds)
-    assert packed_score == unpacked_score, \
+    # [3, 6] ragged vs [2, 8] packed are two executables of different
+    # shape: same math, another summation order — float32 rounding, not
+    # bitwise (2 ulp of the score is the stated tolerance).
+    assert abs(packed_score - unpacked_score) <= \
+        2 * np.finfo(np.float32).eps * abs(unpacked_score), \
         f"packed {packed_score!r} != unpacked {unpacked_score!r}"
     out = np.asarray(net.output(np.asarray(packed_ds.features),
                                 features_mask=np.asarray(
                                     packed_ds.features_mask)))
     solo0 = np.asarray(net.output(feats[:1, :3]))
-    assert np.all(out[:1, :3] == solo0), "packed != solo (bitwise)"
-    print("smoke_packing: packed score/output exactness ok")
+    # the [2, 8] packed forward vs the [1, 3] solo forward: different
+    # executables again — softmax outputs agree to 2 float32 ulp of 1.0
+    np.testing.assert_allclose(out[:1, :3], solo0, rtol=0,
+                               atol=2 * np.finfo(np.float32).eps,
+                               err_msg="packed != solo")
+    print("smoke_packing: packed score/output agree to float32 rounding")
 
     # 4) metric families live
     from deeplearning4j_tpu.data.padding import register_packing_metrics

@@ -1,18 +1,15 @@
-"""Smoke: the bench scoreboard plane survives a wedged child.
+"""Smoke: the bench scoreboard plane names a wedged child and fails.
 
 Recreates the round-5 failure (a bench child that goes silent
 mid-measurement) on demand with a `delay:` fault on `bench.child`,
 then asserts the fail-safe path holds end to end:
 
-* bench.py exits 0 anyway — a wedged child must not kill the artifact
-* the artifact parses as JSON (the whole point: never `parsed: null`)
-* the row is the in-process degraded fallback: `degraded: true`,
-  `timeout: true`, a typed `"wedged"` failure string, and a real
-  (reduced-config) measurement value > 0
-* the registry snapshot is embedded with the bench families
-  pre-registered — `bench_degraded_total` fired once and the never-hit
-  statuses are present at 0, not absent
-* the ledger got one schema-valid `status: "degraded"` row
+* the watchdog kills the wedged child in seconds, not at the budget
+* bench.py exits NON-ZERO and prints no artifact line: nothing was
+  measured at the full config, and there is no reduced-config stand-in
+* stderr names the wedge
+* the ledger got exactly one schema-valid typed `status: "wedged"` row
+  with no value and backend "none" (nobody measured)
 
 Run: JAX_PLATFORMS=cpu python tests/smoke_scoreboard.py
 Run by runtests.sh as a separate step (no test_ prefix on purpose).
@@ -29,21 +26,8 @@ sys.path.insert(0, REPO)
 
 from deeplearning4j_tpu.optimize import scoreboard  # noqa: E402
 
-# Worst observed: ~6 s to wedge-kill the child + one reduced-config
-# lenet_tiny compile in-process on a cold contended CPU rig.
+# Worst observed: ~6 s to wedge-kill the child on a contended CPU rig.
 HARD_TIMEOUT_S = 420
-
-REQUIRED_FAMILIES = (
-    'bench_rows_total{status="ok"}',
-    'bench_rows_total{status="degraded"}',
-    'bench_rows_total{status="wedged"}',
-    'bench_rows_total{status="timeout"}',
-    'bench_rows_total{status="failed"}',
-    'bench_rows_total{status="dead_tunnel"}',
-    "bench_degraded_total",
-    "bench_regressions_total",
-    "bench_baseline_corrupt_total",
-)
 
 
 def _alarm(signum, frame):
@@ -76,49 +60,15 @@ def main() -> int:
             [sys.executable, os.path.join(REPO, "bench.py"), "lenet_tiny"],
             capture_output=True, text=True, env=env, cwd=REPO)
 
-        if out.returncode != 0:
-            failures.append(f"bench.py exited {out.returncode} "
-                            f"(stderr tail: {out.stderr[-400:]!r})")
-        row = None
-        try:
-            row = json.loads(out.stdout.strip().splitlines()[-1])
-        except (ValueError, IndexError) as e:
-            failures.append(f"artifact did not parse as JSON: {e} "
-                            f"(stdout tail: {out.stdout[-400:]!r})")
-
-        if row is not None:
-            if row.get("degraded") is not True:
-                failures.append(f"row.degraded is {row.get('degraded')!r},"
-                                " wanted True")
-            if row.get("timeout") is not True:
-                failures.append(f"row.timeout is {row.get('timeout')!r},"
-                                " wanted True")
-            if "wedged" not in str(row.get("failure", "")):
-                failures.append(f"row.failure {row.get('failure')!r} does"
-                                " not name the wedge")
-            if not (isinstance(row.get("value"), (int, float))
-                    and row["value"] > 0):
-                failures.append(f"row.value {row.get('value')!r} is not a"
-                                " positive measurement")
-            snap = row.get("metrics")
-            if not isinstance(snap, dict):
-                failures.append("row.metrics snapshot missing")
-                snap = {}
-            for fam in REQUIRED_FAMILIES:
-                if fam not in snap:
-                    failures.append(f"snapshot missing family {fam!r}")
-            if snap.get("bench_degraded_total") != 1.0:
-                failures.append(
-                    "bench_degraded_total is "
-                    f"{snap.get('bench_degraded_total')!r}, wanted 1.0")
-            deg_key = 'bench_rows_total{status="degraded"}'
-            if snap.get(deg_key) != 1.0:
-                failures.append(f"{deg_key} is {snap.get(deg_key)!r}, "
-                                "wanted 1.0")
-            ok_key = 'bench_rows_total{status="ok"}'
-            if snap.get(ok_key) != 0.0:
-                failures.append(f"{ok_key} is {snap.get(ok_key)!r}, "
-                                "wanted pre-registered 0.0")
+        if out.returncode == 0:
+            failures.append("bench.py exited 0 with nothing measured")
+        if out.stdout.strip():
+            failures.append("bench.py printed an artifact line with "
+                            f"nothing measured: {out.stdout[-400:]!r}")
+        if "nothing measured" not in out.stderr \
+                or "wedged" not in out.stderr:
+            failures.append("stderr does not name the wedge: "
+                            f"{out.stderr[-400:]!r}")
 
         ledger_rows = scoreboard.read_ledger(
             os.path.join(tmp, "ledger.jsonl"))
@@ -127,9 +77,12 @@ def main() -> int:
                             "wanted exactly 1")
         else:
             lrow = ledger_rows[0]
-            if lrow.get("status") != "degraded":
+            if lrow.get("status") != "wedged":
                 failures.append(f"ledger row status {lrow.get('status')!r},"
-                                " wanted 'degraded'")
+                                " wanted 'wedged'")
+            if "value" in lrow or lrow.get("backend") != "none":
+                failures.append("ledger row claims a measurement: "
+                                f"{lrow!r}")
             problems = scoreboard.validate_row(lrow)
             if problems:
                 failures.append(f"ledger row failed schema: {problems}")
@@ -140,8 +93,8 @@ def main() -> int:
         for f in failures:
             print(f"  - {f}")
         return 1
-    print("SMOKE OK: wedged bench child -> schema-valid degraded "
-          "artifact, exit 0, ledger row + registry snapshot intact")
+    print("SMOKE OK: wedged bench child -> killed, typed ledger row, "
+          "no artifact line, non-zero exit")
     return 0
 
 

@@ -259,33 +259,31 @@ class TestBenchTimeout:
                 "ok", 0, real_json + "\n", "", 3, None, False, 1.0)
 
         monkeypatch.setattr(scoreboard, "run_child", fake_run_child)
-        # the degraded fallback's in-process measurement, stubbed: this
-        # test pins the parent plumbing, not a workload
-        monkeypatch.setattr(
-            bench, "run_once",
-            lambda w, a, degraded=False: ("m", 1.0, "u",
-                                          {"degraded_config": {}}))
-        monkeypatch.setattr(bench, "host_sentinel_ms", lambda n=3: (1.0, 1.0))
+        monkeypatch.setattr(scoreboard, "host_sentinel_ms",
+                            lambda n=3: (1.0, 1.0))
         monkeypatch.setattr(bench, "_vs_baseline",
-                            lambda m, v, backend=None: 1.0)
+                            lambda m, v, backend: 1.0)
         monkeypatch.setattr(sys, "argv", ["bench.py", "lenet"])
         monkeypatch.setenv("BENCH_REPEATS", "3")
         monkeypatch.setenv("BENCH_TIME_BUDGET_S", "420")
         monkeypatch.setenv("DL4JTPU_BENCH_PROBE", "0")
         monkeypatch.setenv("DL4JTPU_BENCH_LEDGER",
                            str(tmp_path / "ledger.jsonl"))
-        bench.main()  # must NOT raise SystemExit
+        bench.main()
         return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
-    def test_first_child_timeout_falls_back_degraded(
+    def test_first_child_timeout_fails_with_typed_row(
             self, monkeypatch, capsys, tmp_path):
-        row = self._run_main(monkeypatch, capsys, tmp_path,
-                             runs_before_timeout=0)
-        assert row["timeout"] is True
-        assert row["spread"]["n"] == 0
-        assert row["degraded"] is True
-        assert row["value"] == 1.0
-        assert "metrics" in row  # registry snapshot rides the artifact
+        """Nothing measured at the full config: a typed ledger row and a
+        non-zero exit, no artifact line, no reduced-config stand-in."""
+        with pytest.raises(SystemExit) as exc:
+            self._run_main(monkeypatch, capsys, tmp_path,
+                           runs_before_timeout=0)
+        assert "nothing measured" in str(exc.value.code)
+        assert capsys.readouterr().out.strip() == ""
+        rows = [json.loads(ln) for ln in open(tmp_path / "ledger.jsonl")]
+        assert [r["status"] for r in rows] == ["timeout"]
+        assert "value" not in rows[0] and rows[0]["backend"] == "none"
 
     def test_partial_repeats_marked_timeout(self, monkeypatch, capsys,
                                             tmp_path):

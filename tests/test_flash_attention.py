@@ -189,7 +189,8 @@ class TestDispatch:
         assert att.select_attention_impl(1024, 64) == "dense"
 
     def test_rule_long_sequences_cpu(self):
-        # no TPU here: the pallas probe fails, the rule lands blockwise
+        # no TPU here: the answer is "no" without compiling anything,
+        # and the rule lands blockwise
         assert att.select_attention_impl(4096, 128) == "blockwise"
 
     def test_rule_long_sequences_interpret_pallas(self):
@@ -219,21 +220,13 @@ class TestDispatch:
         att.select_attention_impl(64, 16)
         assert self._counter("dense") == before + 1
 
-    def test_pallas_request_falls_back_with_one_shot_warning(self, caplog):
-        # off-TPU: requested pallas can't compile -> clean fallback (no
-        # crash), counter counts the impl actually used, warn ONCE
-        att.select_attention_impl._warned_pallas = False
+    def test_pallas_request_that_cannot_run_raises(self):
+        # off-TPU and not interpreted the fused kernel does not exist:
+        # a caller who NAMED it gets an error, never another impl
         before = self._counter("dense")
-        with caplog.at_level("WARNING",
-                             logger="deeplearning4j_tpu.ops.attention"):
-            assert att.select_attention_impl(
-                64, 16, requested="pallas") == "dense"
-            assert att.select_attention_impl(
-                64, 16, requested="pallas") == "dense"
-        warns = [r for r in caplog.records
-                 if "pallas" in r.getMessage()]
-        assert len(warns) == 1
-        assert self._counter("dense") == before + 2
+        with pytest.raises(ValueError, match="'pallas' requested"):
+            att.select_attention_impl(64, 16, requested="pallas")
+        assert self._counter("dense") == before
 
     def test_single_device_attention_pallas_parity(self):
         q, k, v = _qkv(B=1, T=32, H=2, D=8)
@@ -292,30 +285,3 @@ class TestSharedPlumbing:
         assert float(out[0, 5]) == 0.0
         assert pk.pad_axis_to(a, 0, 3) is a  # already aligned: no copy
 
-    def test_kernel_probe_caches_result(self):
-        calls = []
-
-        def probe():
-            calls.append(1)
-
-        name = "test_probe_ok"
-        pk._probe_results.pop(name, None)
-        assert pk.kernel_probe(name, probe) is True
-        assert pk.kernel_probe(name, probe) is True
-        assert len(calls) == 1
-        pk._probe_results.pop(name, None)
-
-    def test_kernel_probe_caches_failure(self):
-        def probe():
-            raise RuntimeError("no backend")
-
-        name = "test_probe_fail"
-        pk._probe_results.pop(name, None)
-        assert pk.kernel_probe(name, probe) is False
-        assert pk.kernel_probe(name, probe) is False
-        pk._probe_results.pop(name, None)
-
-    def test_lrn_still_routes_through_probe(self):
-        # the LRN wrapper survived the refactor: CPU probe is False
-        pk._probe_results.pop("lrn", None)
-        assert pk.tpu_kernel_available() is False

@@ -157,19 +157,6 @@ class TestDispatch:
                                     requested="mask")
         assert fam.value(impl="max_mask") == before + 1
 
-    def test_probe_failure_falls_back(self, monkeypatch):
-        monkeypatch.setattr(pooling, "mask_backward_available",
-                            lambda: False)
-        monkeypatch.setattr(pooling.select_pooling_impl, "_warned_mask",
-                            False, raising=False)
-        assert pooling.select_pooling_impl(
-            "max", (3, 3), (2, 2), requested="mask") == "sns"
-        # the auto rule degrades the same way when the probe fails
-        assert pooling.select_pooling_impl("max", (3, 3), (2, 2)) == "sns"
-
-    def test_probe_passes_on_this_backend(self):
-        assert pooling.mask_backward_available()
-
 
 class TestSubsamplingLayerKnob:
     def _fwd(self, layer, x):

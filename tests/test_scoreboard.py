@@ -161,19 +161,19 @@ class TestHeartbeats:
 
 
 class TestProbe:
-    def test_delay_wedged_probe_reports_dead_tunnel(self, monkeypatch):
+    def test_delay_wedged_probe_reports_dead_device(self, monkeypatch):
         # the fault fires before the probe subprocess touches jax, so
         # this costs ~the 2s timeout, not a backend init
         monkeypatch.setenv("DL4JTPU_FAULT_BENCH_PROBE", "delay:1@600000")
         out = sb.probe_device(timeout_s=2)
-        assert out["tunnel"] == "dead"
+        assert out["device"] == "dead"
         assert "error" in out
 
     @pytest.mark.slow
     def test_healthy_probe_reports_ok(self, monkeypatch):
         monkeypatch.delenv("DL4JTPU_FAULT_BENCH_PROBE", raising=False)
         out = sb.probe_device(timeout_s=120)
-        assert out["tunnel"] == "ok"
+        assert out["device"] == "ok"
         assert out["probe_ms"] > 0
 
 
@@ -201,13 +201,20 @@ class TestLedger:
         assert any("unknown" in p for p in sb.validate_row(unknown))
         missing = {k: v for k, v in row.items() if k != "backend"}
         assert any("backend" in p for p in sb.validate_row(missing))
-        # ok/degraded rows must carry the measurement triple
+        # ok rows must carry the measurement triple
         bare = sb.make_row("lenet", "ok")
         assert any("metric" in p for p in sb.validate_row(bare))
         # but typed failures legally have none
         wedged = sb.make_row("lenet", "wedged", failure="wedged",
                              timeout=True)
         assert sb.validate_row(wedged) == []
+        # a row nobody measured names no backend — never a guess from
+        # the JAX_PLATFORMS variable
+        assert wedged["backend"] == "none"
+        # the reduced-config stand-in row is gone from the schema
+        assert "degraded" not in row
+        assert any("status" in p for p in sb.validate_row(
+            dict(row, status="degraded")))
 
     def test_append_rejects_invalid_and_tolerates_corrupt_lines(
             self, tmp_store):
@@ -246,9 +253,11 @@ class TestBaseline:
         assert sb.load_baseline() == {"m": 7.0}
 
     def test_backend_namespacing(self):
-        assert sb.baseline_key("m", None) == "m"
-        assert sb.baseline_key("m", "tpu") == "m"  # legacy = TPU history
+        assert sb.baseline_key("m", "tpu") == "m"  # unsuffixed = TPU
         assert sb.baseline_key("m", "cpu") == "m@cpu"
+        # only a row that says "tpu" scores against TPU numbers
+        assert sb.baseline_key("m", "none") == "m@none"
+        assert sb.baseline_key("m", "unknown") == "m@unknown"
 
 
 class TestCheckRows:
@@ -272,12 +281,10 @@ class TestCheckRows:
         fails, _ = sb.check_rows([row], {"m": 100.0})
         assert fails == []
 
-    def test_degraded_rows_never_scored(self):
-        deg = self._row(1.0, status="degraded", degraded=True,
-                        timeout=True)
-        fails, lines = sb.check_rows([deg], {"m": 100.0})
-        assert fails == []
-        assert any("degraded" in ln for ln in lines)
+    def test_failure_rows_never_scored(self):
+        wedged = self._row(1.0, status="wedged", timeout=True)
+        fails, lines = sb.check_rows([wedged], {"m": 100.0})
+        assert fails == [] and lines == []
 
     def test_latest_row_wins_and_metric_filter(self):
         rows = [self._row(50.0), self._row(99.0)]
@@ -289,11 +296,10 @@ class TestCheckRows:
 
     def test_report_renders_trajectory(self):
         rows = [self._row(50.0),
-                self._row(1.0, status="degraded", degraded=True,
-                          timeout=True)]
+                self._row(1.0, status="wedged", timeout=True)]
         text = sb.render_report(rows, {"m": 100.0})
         assert "m" in text and "best 100" in text
-        assert "degraded" in text and "x0.500" in text
+        assert "wedged,timeout" in text and "x0.500" in text
 
 
 class TestMetricsFamilies:
@@ -303,7 +309,6 @@ class TestMetricsFamilies:
         snap = registry().snapshot()
         for status in sb.STATUSES:
             assert f'bench_rows_total{{status="{status}"}}' in snap
-        assert "bench_degraded_total" in snap
         assert "bench_regressions_total" in snap
         assert "bench_baseline_corrupt_total" in snap
 
@@ -318,13 +323,13 @@ class TestEndToEnd:
                    DL4JTPU_BENCH_PROBE="0",
                    DL4JTPU_BENCH_LEDGER=str(tmp_path / "ledger.jsonl"),
                    DL4JTPU_BENCH_BASELINE=str(tmp_path / "baseline.json"),
-                   DL4JTPU_COMPILE_CACHE_DIR=str(tmp_path / "cache"))
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
         return env
 
-    def test_wedged_child_yields_degraded_artifact_rc0(self, tmp_path):
-        """The acceptance criterion: a fault-wedged child still produces
-        a schema-valid artifact with degraded rows, a registry snapshot,
-        and exit 0."""
+    def test_wedged_child_fails_with_typed_ledger_row(self, tmp_path):
+        """A fault-wedged first child means nothing was measured at the
+        full config: a schema-valid typed ledger row, no artifact line,
+        and a non-zero exit — no reduced-config stand-in."""
         env = self._env(tmp_path)
         # beat 1 (the start beat) passes, every later beat wedges 600s:
         # the watchdog sees life then silence — the round-5 hang, on
@@ -335,15 +340,13 @@ class TestEndToEnd:
             [sys.executable, os.path.join(REPO, "bench.py"), "lenet_tiny"],
             capture_output=True, text=True, env=env, cwd=REPO,
             timeout=560)
-        assert out.returncode == 0, out.stderr[-2000:]
-        row = json.loads(out.stdout.strip().splitlines()[-1])
-        assert row["degraded"] is True and row["timeout"] is True
-        assert "wedged" in row["failure"]
-        assert row["value"] > 0  # the salvage measurement is real
-        assert row["metrics"]["bench_degraded_total"] == 1.0
+        assert out.returncode != 0
+        assert "nothing measured" in out.stderr and "wedged" in out.stderr
+        assert out.stdout.strip() == ""
         ledger_rows = [json.loads(ln) for ln in
                        open(tmp_path / "ledger.jsonl")]
-        assert ledger_rows[-1]["status"] == "degraded"
+        assert ledger_rows[-1]["status"] == "wedged"
+        assert "value" not in ledger_rows[-1]
         assert sb.validate_row(ledger_rows[-1]) == []
 
     def test_check_cli_exit_codes(self, tmp_path):
