@@ -17,11 +17,21 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Callable, Iterable, Iterator, List, Optional
 
 import numpy as np
 
+from ..optimize import tracing
 from .dataset import DataSet, MultiDataSet
+
+
+def register_metrics():
+    """Pre-register the prefetcher's family at 0; returns the counter."""
+    from ..optimize.metrics import registry
+    return registry().counter(
+        "etl_h2d_bytes_total",
+        "Bytes of host batches the device prefetcher handed to the device")
 
 
 class DataSetIterator:
@@ -212,7 +222,10 @@ class AsyncDataSetIterator(DataSetIterator):
             return next(it)
 
     def _producer(self, q: queue.Queue):
-        import time
+        """The producer thread. Its spans, from the readings it takes
+        anyway: `etl/produce` (the pull from the base iterator) and
+        `etl/handoff` (blocked in `q.put`: the queue is full, so the
+        consumer or the device is the slow side, not this thread)."""
         try:
             it = iter(self._base)
             while True:
@@ -221,10 +234,15 @@ class AsyncDataSetIterator(DataSetIterator):
                     ds = self._next_resilient(it)
                 except StopIteration:
                     break
-                host_ms = (time.perf_counter() - t0) * 1000.0
+                host_s = time.perf_counter() - t0
+                tracing.add_span("etl/produce", t0, host_s)
                 if self._shutdown.is_set():
                     return
-                q.put(self._produce_item(ds, host_ms))
+                item = self._produce_item(ds, host_s * 1000.0)
+                t1 = time.perf_counter()
+                q.put(item)
+                tracing.add_span("etl/handoff", t1,
+                                 time.perf_counter() - t1)
             q.put(_StreamEnd())
         except BaseException as e:  # propagate to consumer via the queue
             q.put(_StreamEnd(e))
@@ -671,7 +689,12 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
     the producer spent pulling it from the base iterator) and
     `_etl_h2d_ms` (device_put + transfer wait); fit() surfaces them as
     model.last_etl_host_ms / last_etl_h2d_ms next to the consumer-side
-    last_etl_ms stall clock."""
+    last_etl_ms stall clock. With tracing on, each staging is an
+    `etl/stage` span on the producer's thread (args `bytes`, `rows`)
+    with children `etl/stage/put` (the `device_put`s and the cast's
+    dispatch) and `etl/stage/fence` (the wait for them to land, which
+    queues behind whatever the device is running);
+    `etl_h2d_bytes_total` counts the host bytes handed over."""
 
     def __init__(self, base, depth: int = 2, sharding=None,
                  batch_divisor: int = 1, cast_dtype=None):
@@ -679,6 +702,7 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
         self._sharding = sharding
         self._divisor = max(1, int(batch_divisor))
         self._cast_dtype = cast_dtype
+        self._h2d_bytes = register_metrics()
 
     def _put(self, a, is_feature: bool):
         import jax
@@ -696,6 +720,7 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
 
     def _stage(self, ds):
         import jax
+        t0 = time.perf_counter()
         if isinstance(ds, MultiDataSet):
             out = MultiDataSet(
                 [self._put(f, True) for f in ds.features],
@@ -705,21 +730,36 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
                 None if ds.labels_masks is None
                 else [self._put(m, False) for m in ds.labels_masks])
             leaves = out.features + out.labels
+            sources = (list(ds.features) + list(ds.labels)
+                       + list(ds.features_masks or ())
+                       + list(ds.labels_masks or ()))
         elif isinstance(ds, DataSet):
             out = DataSet(self._put(ds.features, True),
                           self._put(ds.labels, False),
                           self._put(ds.features_mask, False),
                           self._put(ds.labels_mask, False))
             leaves = [out.features, out.labels]
+            sources = [ds.features, ds.labels, ds.features_mask,
+                       ds.labels_mask]
         else:
             return ds
+        t1 = time.perf_counter()
         # Fence on the producer thread: the consumer must never inherit
         # an in-flight transfer (that wait would be invisible ETL).
         jax.block_until_ready([a for a in leaves if a is not None])
+        t2 = time.perf_counter()
+        # what went over the link: the arrays that were the host's
+        nbytes = sum(int(getattr(a, "nbytes", 0)) for a in sources
+                     if not isinstance(a, jax.Array))
+        self._h2d_bytes.inc(nbytes)
+        if tracing.is_enabled():
+            sid = tracing.add_span("etl/stage", t0, t2 - t0, bytes=nbytes,
+                                   rows=ds.num_examples())
+            tracing.add_span("etl/stage/put", t0, t1 - t0, parent=sid)
+            tracing.add_span("etl/stage/fence", t1, t2 - t1, parent=sid)
         return out
 
     def _produce_item(self, ds, host_ms: float):
-        import time
         n = getattr(ds, "num_examples", lambda: 0)()
         if self._sharding is not None and n % self._divisor != 0:
             # Indivisible ragged batch: staging under the sharding would

@@ -149,37 +149,42 @@ class ComputationGraph(DeviceIterationMixin):
         new_state = {}
         head_inputs: Dict[str, Array] = {}
         for i, name in enumerate(conf.topo_order):
-            node = conf.nodes[name]
-            in_acts = [acts[n] for n in node.inputs]
-            in_masks = [masks.get(n) for n in node.inputs]
-            if node.is_layer():
-                a = in_acts[0]
-                if node.preprocessor is not None:
-                    a = node.preprocessor(a)
-                sub = None if rng is None else jax.random.fold_in(rng, i)
-                is_out = node.layer.is_output_layer()
-                if for_score and is_out:
-                    if train and node.layer.dropout_rate and sub is not None:
-                        from ..layers.core import dropout
-                        a = dropout(a, node.layer.dropout_rate, train, sub)
-                    head_inputs[name] = a
-                    new_state[name] = state[name]
-                    acts[name] = a  # not used downstream (outputs are sinks)
+            # the vertex's own name on its operations in the device's trace
+            with jax.named_scope(name):
+                node = conf.nodes[name]
+                in_acts = [acts[n] for n in node.inputs]
+                in_masks = [masks.get(n) for n in node.inputs]
+                if node.is_layer():
+                    a = in_acts[0]
+                    if node.preprocessor is not None:
+                        a = node.preprocessor(a)
+                    sub = None if rng is None \
+                        else jax.random.fold_in(rng, i)
+                    is_out = node.layer.is_output_layer()
+                    if for_score and is_out:
+                        if train and node.layer.dropout_rate \
+                                and sub is not None:
+                            from ..layers.core import dropout
+                            a = dropout(a, node.layer.dropout_rate, train,
+                                        sub)
+                        head_inputs[name] = a
+                        new_state[name] = state[name]
+                        acts[name] = a  # unused downstream (outputs are sinks)
+                    else:
+                        out, st = node.layer.forward(
+                            params[name], state[name], a, train=train,
+                            rng=sub, mask=in_masks[0])
+                        acts[name] = out
+                        new_state[name] = st
+                    masks[name] = in_masks[0]
                 else:
-                    out, st = node.layer.forward(
-                        params[name], state[name], a, train=train, rng=sub,
-                        mask=in_masks[0])
-                    acts[name] = out
-                    new_state[name] = st
-                masks[name] = in_masks[0]
-            else:
-                vertex = node.vertex
-                if isinstance(vertex, LastTimeStepVertex) and \
-                        vertex.mask_input is not None:
-                    in_masks = [masks.get(vertex.mask_input)]
-                acts[name] = vertex.forward(in_acts, train=train,
-                                            masks=in_masks)
-                masks[name] = vertex.output_mask(in_masks)
+                    vertex = node.vertex
+                    if isinstance(vertex, LastTimeStepVertex) and \
+                            vertex.mask_input is not None:
+                        in_masks = [masks.get(vertex.mask_input)]
+                    acts[name] = vertex.forward(in_acts, train=train,
+                                                masks=in_masks)
+                    masks[name] = vertex.output_mask(in_masks)
         return acts, new_state, masks, head_inputs
 
     def _loss_pure(self, params, state, inputs, labels, fmasks, lmasks, rng,
@@ -188,19 +193,21 @@ class ComputationGraph(DeviceIterationMixin):
         computeGradientAndScore :1161 sums IOutputLayer scores)."""
         _, new_state, _, head_inputs = self._walk(
             params, state, inputs, train, rng, fmasks, for_score=True)
-        total = jnp.asarray(0.0, jnp.float32)
-        for out_name, y in labels.items():
-            node = self.conf.nodes[out_name]
-            if not node.layer.is_output_layer():
-                raise ValueError(f"Output node {out_name!r} is not an output "
-                                 "layer")
-            total = total + node.layer.compute_score(
-                params[out_name], head_inputs[out_name], y,
-                lmasks.get(out_name))
-        reg = _regularization_score(
-            [self.conf.nodes[n].layer for n in self._layer_nodes],
-            [params[n] for n in self._layer_nodes])
-        return total + reg, new_state
+        with jax.named_scope("loss"):
+            total = jnp.asarray(0.0, jnp.float32)
+            for out_name, y in labels.items():
+                node = self.conf.nodes[out_name]
+                if not node.layer.is_output_layer():
+                    raise ValueError(f"Output node {out_name!r} is not an "
+                                     "output layer")
+                with jax.named_scope(out_name):     # loss/<head>
+                    total = total + node.layer.compute_score(
+                        params[out_name], head_inputs[out_name], y,
+                        lmasks.get(out_name))
+            reg = _regularization_score(
+                [self.conf.nodes[n].layer for n in self._layer_nodes],
+                [params[n] for n in self._layer_nodes])
+            return total + reg, new_state
 
     def _build_jitted(self):
         """(Re)build the inference jits and invalidate the training
@@ -244,21 +251,22 @@ class ComputationGraph(DeviceIterationMixin):
                     True)
             new_params = {}
             new_opt = {}
-            for name in layer_nodes:
-                layer = conf.nodes[name].layer
-                g = normalize_layer_gradients(
-                    grads[name], layer.gradient_normalization,
-                    layer.gradient_normalization_threshold)
-                updates, opt_i = layer.updater.update(
-                    g, opt_state[name], iteration)
-                if layer.frozen:
-                    new_params[name] = params[name]
-                    new_opt[name] = opt_state[name]
-                else:
-                    new_params[name] = jax.tree_util.tree_map(
-                        lambda p, u: p - u.astype(p.dtype), params[name],
-                        updates)
-                    new_opt[name] = opt_i
+            with jax.named_scope("updater"):
+                for name in layer_nodes:
+                    layer = conf.nodes[name].layer
+                    g = normalize_layer_gradients(
+                        grads[name], layer.gradient_normalization,
+                        layer.gradient_normalization_threshold)
+                    updates, opt_i = layer.updater.update(
+                        g, opt_state[name], iteration)
+                    if layer.frozen:
+                        new_params[name] = params[name]
+                        new_opt[name] = opt_state[name]
+                    else:
+                        new_params[name] = jax.tree_util.tree_map(
+                            lambda p, u: p - u.astype(p.dtype),
+                            params[name], updates)
+                        new_opt[name] = opt_i
             return (new_params, new_opt, new_state, iteration + 1, rng, loss)
 
         # Donate params/opt/state (see MultiLayerNetwork._build_jitted).

@@ -73,6 +73,12 @@ def _regularization_score(layers, params) -> Array:
     return total
 
 
+def _scope_name(i: int, layer) -> str:
+    """`<index>_<layer type>`: the name a layer's operations carry in the
+    device's trace (`jax.named_scope`; metadata only)."""
+    return f"{i}_{type(layer).__name__}"
+
+
 class RnnStateMismatchError(ValueError):
     """rnn_time_step was called with a batch size that does not match
     the stored recurrent carry. The carry is RESET before this raises:
@@ -158,12 +164,13 @@ class MultiLayerNetwork(DeviceIterationMixin):
         new_states = []
         activations = []
         for i, layer in enumerate(self.layers):
-            p = self.conf.preprocessor(i)
-            if p is not None:
-                a = p(a)
-            sub = None if rng is None else jax.random.fold_in(rng, i)
-            a, st = layer.forward(params[i], state[i], a, train=train, rng=sub,
-                                  mask=fmask)
+            with jax.named_scope(_scope_name(i, layer)):
+                p = self.conf.preprocessor(i)
+                if p is not None:
+                    a = p(a)
+                sub = None if rng is None else jax.random.fold_in(rng, i)
+                a, st = layer.forward(params[i], state[i], a, train=train,
+                                      rng=sub, mask=fmask)
             new_states.append(st)
             activations.append(a)
         return a, tuple(new_states), activations
@@ -175,26 +182,28 @@ class MultiLayerNetwork(DeviceIterationMixin):
         new_states = []
         n = len(self.layers)
         for i, layer in enumerate(self.layers[:-1]):
-            p = self.conf.preprocessor(i)
-            if p is not None:
-                a = p(a)
-            sub = None if rng is None else jax.random.fold_in(rng, i)
-            a, st = layer.forward(params[i], state[i], a, train=train, rng=sub,
-                                  mask=fmask)
+            with jax.named_scope(_scope_name(i, layer)):
+                p = self.conf.preprocessor(i)
+                if p is not None:
+                    a = p(a)
+                sub = None if rng is None else jax.random.fold_in(rng, i)
+                a, st = layer.forward(params[i], state[i], a, train=train,
+                                      rng=sub, mask=fmask)
             new_states.append(st)
         out_layer = self.layers[-1]
         if not out_layer.is_output_layer():
             raise ValueError("Last layer must be an output layer to compute score")
-        p = self.conf.preprocessor(n - 1)
-        if p is not None:
-            a = p(a)
-        if train and out_layer.dropout_rate and rng is not None:
-            a = core_layers.dropout(a, out_layer.dropout_rate, train,
-                                    jax.random.fold_in(rng, n - 1))
-        loss = out_layer.compute_score(params[n - 1], a, y, lmask)
-        new_states.append(state[n - 1])
-        reg = _regularization_score(self.layers, params)
-        return loss + reg, tuple(new_states)
+        with jax.named_scope("loss"):
+            p = self.conf.preprocessor(n - 1)
+            if p is not None:
+                a = p(a)
+            if train and out_layer.dropout_rate and rng is not None:
+                a = core_layers.dropout(a, out_layer.dropout_rate, train,
+                                        jax.random.fold_in(rng, n - 1))
+            loss = out_layer.compute_score(params[n - 1], a, y, lmask)
+            new_states.append(state[n - 1])
+            reg = _regularization_score(self.layers, params)
+            return loss + reg, tuple(new_states)
 
     def _build_jitted(self):
         """(Re)build the inference jits and invalidate the training
@@ -229,18 +238,21 @@ class MultiLayerNetwork(DeviceIterationMixin):
                     params, state, x, y, fmask, lmask, step_rng, True)
             new_params = []
             new_opt = []
-            for i, layer in enumerate(layers):
-                g = normalize_layer_gradients(
-                    grads[i], layer.gradient_normalization,
-                    layer.gradient_normalization_threshold)
-                updates, opt_i = layer.updater.update(g, opt_state[i], iteration)
-                if layer.frozen:
-                    new_params.append(params[i])
-                    new_opt.append(opt_state[i])
-                else:
-                    new_params.append(jax.tree_util.tree_map(
-                        lambda p, u: p - u.astype(p.dtype), params[i], updates))
-                    new_opt.append(opt_i)
+            with jax.named_scope("updater"):
+                for i, layer in enumerate(layers):
+                    g = normalize_layer_gradients(
+                        grads[i], layer.gradient_normalization,
+                        layer.gradient_normalization_threshold)
+                    updates, opt_i = layer.updater.update(
+                        g, opt_state[i], iteration)
+                    if layer.frozen:
+                        new_params.append(params[i])
+                        new_opt.append(opt_state[i])
+                    else:
+                        new_params.append(jax.tree_util.tree_map(
+                            lambda p, u: p - u.astype(p.dtype), params[i],
+                            updates))
+                        new_opt.append(opt_i)
             return (tuple(new_params), tuple(new_opt), new_state,
                     iteration + 1, rng, loss)
 

@@ -296,6 +296,7 @@ def _seg_specs(pl, qb, kb, use_segs, q_axis, kv_axis):
     return qspec, kspec
 
 
+@jax.named_scope("flash_attention_fwd")
 def _fwd_call(q3, k3, v3, km, qp, kp, qs, ks, scale, causal, use_mask,
               use_segs, qb, kb, interpret):
     from jax.experimental import pallas as pl
@@ -334,6 +335,7 @@ def _fwd_call(q3, k3, v3, km, qp, kp, qs, ks, scale, causal, use_mask,
             pltpu.VMEM((qb, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qp, kp, km, qs, ks, q3, k3, v3)
 
 
@@ -357,7 +359,7 @@ def _bwd_calls(q3, k3, v3, km, qp, kp, qs, ks, o, lse, do, dlse,
                                  causal=causal, use_mask=use_mask,
                                  use_segs=use_segs, nq=nq, acc_dtype=acc_dt)
     qs_dkv, ks_dkv = _seg_specs(pl, qb, kb, use_segs, q_axis=2, kv_axis=1)
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         dkv_kern,
         grid=(bh, nk, nq),
         in_specs=[
@@ -387,7 +389,10 @@ def _bwd_calls(q3, k3, v3, km, qp, kp, qs, ks, o, lse, do, dlse,
             pltpu.VMEM((kb, d), acc_dt),
         ],
         interpret=interpret,
-    )(qp, kp, km, qs, ks, q3, k3, v3, do, lse, di, gl)
+        name="flash_attention_bwd_dkv",
+    )
+    with jax.named_scope("flash_attention_bwd_dkv"):
+        dk, dv = dkv_call(qp, kp, km, qs, ks, q3, k3, v3, do, lse, di, gl)
 
     # dq: grid (bh, nq, nk) — Q block parallel, KV sweep innermost.
     qblk = lambda i, j, k: (i, j, 0)
@@ -395,7 +400,7 @@ def _bwd_calls(q3, k3, v3, km, qp, kp, qs, ks, o, lse, do, dlse,
                                 use_mask=use_mask, use_segs=use_segs,
                                 nk=nk, acc_dtype=acc_dt)
     qs_dq, ks_dq = _seg_specs(pl, qb, kb, use_segs, q_axis=1, kv_axis=2)
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kern,
         grid=(bh, nq, nk),
         in_specs=[
@@ -416,7 +421,10 @@ def _bwd_calls(q3, k3, v3, km, qp, kp, qs, ks, o, lse, do, dlse,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((qb, d), acc_dt)],
         interpret=interpret,
-    )(qp, kp, km, qs, ks, q3, k3, v3, do, lse, di, gl)
+        name="flash_attention_bwd_dq",
+    )
+    with jax.named_scope("flash_attention_bwd_dq"):
+        dq = dq_call(qp, kp, km, qs, ks, q3, k3, v3, do, lse, di, gl)
     return dq, dk, dv
 
 
@@ -576,6 +584,7 @@ def flash_attention_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@jax.named_scope("decode_attention")
 def decode_attention(q, k, v, cache_len, *, impl: str = "auto",
                      interpret: bool = False):
     """Single-query-row attention against a growing KV cache.

@@ -135,12 +135,13 @@ def lrn(x, k: float = 2.0, alpha: float = 1e-4, beta: float = 0.75,
     return _lrn_pallas(x, k, alpha, beta, n, interpret)
 
 
-def _run_lrn_call(kernel, arrays, k, alpha, beta, n, interpret):
+def _run_lrn_call(kernel, name, arrays, k, alpha, beta, n, interpret):
     """Shared pallas_call plumbing for the fwd/bwd LRN kernels: flatten
     NHWC to [rows, C], lane-align channels, pad rows to the block
     multiple, grid over row blocks. Zero-padding is exact: padded
     channels contribute 0 to the window sums of real channels, and
-    padded rows are sliced away."""
+    padded rows are sliced away. `name` names the kernel in the device's
+    trace."""
     from jax.experimental import pallas as pl
 
     b, h, w, c = arrays[0].shape
@@ -160,17 +161,21 @@ def _run_lrn_call(kernel, arrays, k, alpha, beta, n, interpret):
         in_specs=[spec] * len(flats),
         out_specs=spec,
         interpret=interpret,
+        name=name,
     )(*flats)
     return out[:rows, :c].reshape(b, h, w, c)
 
 
+@jax.named_scope("lrn_fwd")
 def _lrn_pallas(x, k, alpha, beta, n, interpret):
-    return _run_lrn_call(_lrn_kernel, (x,), k, alpha, beta, n, interpret)
-
-
-def _lrn_bwd_pallas(x, g, k, alpha, beta, n, interpret):
-    return _run_lrn_call(_lrn_bwd_kernel, (x, g), k, alpha, beta, n,
+    return _run_lrn_call(_lrn_kernel, "lrn_fwd", (x,), k, alpha, beta, n,
                          interpret)
+
+
+@jax.named_scope("lrn_bwd")
+def _lrn_bwd_pallas(x, g, k, alpha, beta, n, interpret):
+    return _run_lrn_call(_lrn_bwd_kernel, "lrn_bwd", (x, g), k, alpha,
+                         beta, n, interpret)
 
 
 def _lrn_fwd(x, k, alpha, beta, n, interpret):
@@ -239,6 +244,7 @@ def _int8_matmul_kernel(x_ref, w_ref, o_ref):
         preferred_element_type=jnp.int32)
 
 
+@jax.named_scope("int8_matmul")
 def int8_matmul_pallas(x_q, w_q, interpret: bool = False):
     """Pallas arm: x stays whole in VMEM (serving batches are small),
     grid over output-channel blocks. int8 pads to the (32, 128) minimum
@@ -258,6 +264,7 @@ def int8_matmul_pallas(x_q, w_q, interpret: bool = False):
                   pl.BlockSpec((_QUANT_BLOCK_N, kp), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bp, _QUANT_BLOCK_N), lambda i: (0, i)),
         interpret=interpret,
+        name="int8_matmul",
     )(xp, wp)
     return out[:b, :n]
 

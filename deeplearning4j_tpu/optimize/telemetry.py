@@ -7,7 +7,9 @@ counts backend compilations two ways:
 
 * A process-global counter fed by a `jax.monitoring` duration listener
   on the backend-compile event — every XLA compilation in the process,
-  whatever jitted function triggered it. `CompilationTracker` snapshots
+  whatever jitted function triggered it. With tracing on, each is also
+  a retroactive `compile` span whose parent is the span that caused
+  it. `CompilationTracker` snapshots
   it around a region (bench.py wraps whole workloads;
   PerformanceListener reports the delta between reports).
 * `jit_cache_size(fn)` — the per-function executable-cache size of one
@@ -24,6 +26,9 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
+
+from . import tracing
 
 log = logging.getLogger(__name__)
 
@@ -50,6 +55,12 @@ def _counter():
 def _on_event(event: str, duration: float, **_kw) -> None:
     if event.endswith(_COMPILE_EVENT_SUFFIX):
         _counter().inc()
+        # jax calls the listener on the compiling thread as the
+        # compilation ends, so the span lies inside whatever span that
+        # thread has open (a `dispatch`, a `decode/launch`) and names
+        # the step that recompiled by its parent.
+        tracing.add_span("compile", time.perf_counter() - duration,
+                         duration, cat="compile")
 
 
 def _ensure_listener() -> bool:

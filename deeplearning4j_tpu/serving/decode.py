@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from ..data.padding import next_pow2_bucket
 from ..ops.attention import dense_attention
 from ..ops.flash_attention import decode_attention
+from ..optimize import tracing
 from ..optimize.metrics import registry
 from ..parallel.inference import (DeadlineExceededError, DecodeStepError,
                                   KVCacheExhaustedError, NonFiniteOutputError,
@@ -94,6 +95,11 @@ _ITL_HELP = ("Wall time between a request's consecutive tokens "
 _KV_BLOCKS_HELP = "KV cache blocks currently allocated"
 _KV_UTIL_HELP = ("Fraction of allocated KV block capacity holding real "
                  "tokens (1.0 = no block-tail waste)")
+_H2D_HELP = ("Bytes of the host arrays the decode adapters hand to the "
+             "device (a step's operands, a prefill's packed row)")
+_D2H_HELP = ("Bytes of the device outputs the decode adapters copy back "
+             "to the host (logits, new K/V or carry)")
+_PHASES = ("step", "prefill")
 
 
 def register_metrics() -> None:
@@ -108,6 +114,24 @@ def register_metrics() -> None:
                   buckets=INTER_TOKEN_BUCKETS_MS)
     reg.gauge("serving_kv_blocks_in_use", _KV_BLOCKS_HELP)
     reg.gauge("serving_kv_utilization", _KV_UTIL_HELP)
+    _register_link_metrics()
+
+
+def _register_link_metrics() -> Dict[Tuple[str, str], Any]:
+    """The link-byte counters' children by (`h2d`|`d2h`, phase): what
+    each adapter increments as it hands arrays to the device and copies
+    the outputs back. The counts follow from shapes alone, so they
+    repeat exactly from run to run."""
+    reg = registry()
+    fams = {"h2d": reg.counter("serving_decode_h2d_bytes_total", _H2D_HELP),
+            "d2h": reg.counter("serving_decode_d2h_bytes_total", _D2H_HELP)}
+    return {(way, ph): fam.labels(phase=ph)
+            for way, fam in fams.items() for ph in _PHASES}
+
+
+def _nbytes(*arrays) -> int:
+    """Bytes of the arrays, and of the leaves of any tree among them."""
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(arrays))
 
 
 # ---------------------------------------------------------------------------
@@ -349,23 +373,29 @@ class TransformerDecoder:
     def _prefill_pure(self, params, tokens, seg, pos):
         """tokens/seg/pos [1, T] → (logits [1, T, vocab],
         k_all/v_all [1, T, layers, heads, head_dim])."""
-        x = self._embed(params, tokens, pos)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens, pos)
         ks, vs = [], []
-        for lp in params["layers"]:
-            h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
-            q = self._heads(h @ lp["wq"])
-            k = self._heads(h @ lp["wk"])
-            v = self._heads(h @ lp["wv"])
-            # Causal within the packed row is exact under global arange
-            # positions + segment equality (ops/flash_attention.py
-            # docstring); pads are segment 0 and never read.
-            a = dense_attention(q, k, v, causal=True, segment_ids=seg)
-            x = x + a.reshape(a.shape[:2] + (self.d_model,)) @ lp["wo"]
-            x = x + self._mlp(_layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
+        for li, lp in enumerate(params["layers"]):
+            with jax.named_scope(f"layer_{li}/attn"):
+                h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
+                q = self._heads(h @ lp["wq"])
+                k = self._heads(h @ lp["wk"])
+                v = self._heads(h @ lp["wv"])
+                # Causal within the packed row is exact under global
+                # arange positions + segment equality
+                # (ops/flash_attention.py docstring); pads are segment 0
+                # and never read.
+                a = dense_attention(q, k, v, causal=True, segment_ids=seg)
+                x = x + a.reshape(a.shape[:2] + (self.d_model,)) @ lp["wo"]
+            with jax.named_scope(f"layer_{li}/mlp"):
+                x = x + self._mlp(
+                    _layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
             ks.append(k)
             vs.append(v)
-        x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
-        logits = x @ params["emb"].T
+        with jax.named_scope("head"):
+            x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
+            logits = x @ params["emb"].T
         return (logits, jnp.stack(ks, axis=2), jnp.stack(vs, axis=2))
 
     def _step_pure(self, params, tok, pos, k_view, v_view, lens):
@@ -374,22 +404,27 @@ class TransformerDecoder:
         k_new/v_new [b, layers, heads, head_dim])."""
         b = tok.shape[0]
         rows = jnp.arange(b)
-        x = self._embed(params, tok, pos)[:, None, :]  # [b, 1, d]
+        with jax.named_scope("embed"):
+            x = self._embed(params, tok, pos)[:, None, :]  # [b, 1, d]
         k_toks, v_toks = [], []
         for li, lp in enumerate(params["layers"]):
-            h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
-            q = self._heads(h @ lp["wq"])                 # [b, 1, H, Dh]
-            k_new = self._heads((h @ lp["wk"])[:, 0])     # [b, H, Dh]
-            v_new = self._heads((h @ lp["wv"])[:, 0])
-            k_l = k_view[:, :, li].at[rows, lens].set(k_new)
-            v_l = v_view[:, :, li].at[rows, lens].set(v_new)
-            a = decode_attention(q, k_l, v_l, lens + 1)
-            x = x + a.reshape(b, 1, self.d_model) @ lp["wo"]
-            x = x + self._mlp(_layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
+            with jax.named_scope(f"layer_{li}/attn"):
+                h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
+                q = self._heads(h @ lp["wq"])               # [b, 1, H, Dh]
+                k_new = self._heads((h @ lp["wk"])[:, 0])   # [b, H, Dh]
+                v_new = self._heads((h @ lp["wv"])[:, 0])
+                k_l = k_view[:, :, li].at[rows, lens].set(k_new)
+                v_l = v_view[:, :, li].at[rows, lens].set(v_new)
+                a = decode_attention(q, k_l, v_l, lens + 1)
+                x = x + a.reshape(b, 1, self.d_model) @ lp["wo"]
+            with jax.named_scope(f"layer_{li}/mlp"):
+                x = x + self._mlp(
+                    _layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
             k_toks.append(k_new)
             v_toks.append(v_new)
-        x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
-        logits = (x @ params["emb"].T)[:, 0]
+        with jax.named_scope("head"):
+            x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
+            logits = (x @ params["emb"].T)[:, 0]
         return (logits, jnp.stack(k_toks, axis=1),
                 jnp.stack(v_toks, axis=1))
 
@@ -464,6 +499,7 @@ class TransformerAdapter:
         if self.pack_bucket > model.max_context:
             raise ValueError("pack_bucket exceeds model max_context")
         self.check_finite = bool(check_finite)
+        self._link = _register_link_metrics()
 
     @staticmethod
     def _greedy(logits) -> int:
@@ -527,25 +563,33 @@ class TransformerAdapter:
             pos[0, lo:hi] = np.arange(p.size)
             spans.append((rid, lo, hi))
             cur = hi
-        logits, k_all, v_all = self.model.prefill(row, seg, pos)
-        logits = np.asarray(logits)
-        k_all = np.asarray(k_all)
-        v_all = np.asarray(v_all)
+        up = _nbytes(row, seg, pos)
+        with tracing.span("decode/launch", cat="serve", bytes=up):
+            logits, k_all, v_all = self.model.prefill(row, seg, pos)
+        self._link["h2d", "prefill"].inc(up)
+        with tracing.span("decode/fetch", cat="serve"):
+            logits = np.asarray(logits)
+            k_all = np.asarray(k_all)
+            v_all = np.asarray(v_all)
+            down = _nbytes(logits, k_all, v_all)
+            tracing.annotate(bytes=down)
+        self._link["d2h", "prefill"].inc(down)
         out: Dict[int, int] = {}
         fails: Dict[int, BaseException] = {}
-        for rid, lo, hi in spans:
-            if self.check_finite and not np.isfinite(
-                    logits[0, lo:hi]).all():
-                fails[rid] = NonFiniteOutputError(
-                    "prefill produced non-finite logits")
-                continue
-            try:
-                self.cache.write_prompt(rid, k_all[0, lo:hi],
-                                        v_all[0, lo:hi])
-            except KVCacheExhaustedError as e:
-                fails[rid] = e
-                continue
-            out[rid] = self._greedy(logits[0, hi - 1])
+        with tracing.span("decode/commit", cat="serve"):
+            for rid, lo, hi in spans:
+                if self.check_finite and not np.isfinite(
+                        logits[0, lo:hi]).all():
+                    fails[rid] = NonFiniteOutputError(
+                        "prefill produced non-finite logits")
+                    continue
+                try:
+                    self.cache.write_prompt(rid, k_all[0, lo:hi],
+                                            v_all[0, lo:hi])
+                except KVCacheExhaustedError as e:
+                    fails[rid] = e
+                    continue
+                out[rid] = self._greedy(logits[0, hi - 1])
         return out, fails
 
     # ------------------------------------------------------------------- step
@@ -566,27 +610,39 @@ class TransformerAdapter:
         bucket = next_pow2_bucket(n)
         kvb = self.kv_bucket(rids)
         pad_rids = list(rids) + [rids[0]] * (bucket - n)
-        k_view, v_view, lens = self.cache.batch_view(pad_rids, kvb)
+        tracing.annotate(row_bucket=bucket, kv_bucket=kvb)
+        with tracing.span("decode/gather", cat="serve"):
+            k_view, v_view, lens = self.cache.batch_view(pad_rids, kvb)
+            tracing.annotate(bytes=_nbytes(k_view, v_view, lens))
         toks = np.asarray([int(t) for t in last_items]
                           + [int(last_items[0])] * (bucket - n), np.int32)
-        logits, k_t, v_t = self.model.step(toks, lens, k_view, v_view,
-                                           lens)
-        logits = np.asarray(logits)
-        k_t = np.asarray(k_t)
-        v_t = np.asarray(v_t)
+        # `lens` goes up twice: as the positions and as the lengths
+        up = _nbytes(toks, lens, k_view, v_view, lens)
+        with tracing.span("decode/launch", cat="serve", bytes=up):
+            logits, k_t, v_t = self.model.step(toks, lens, k_view, v_view,
+                                               lens)
+        self._link["h2d", "step"].inc(up)
+        with tracing.span("decode/fetch", cat="serve"):
+            logits = np.asarray(logits)
+            k_t = np.asarray(k_t)
+            v_t = np.asarray(v_t)
+            down = _nbytes(logits, k_t, v_t)
+            tracing.annotate(bytes=down)
+        self._link["d2h", "step"].inc(down)
         out: Dict[int, int] = {}
         fails: Dict[int, BaseException] = {}
-        for i, rid in enumerate(rids):
-            if self.check_finite and not np.isfinite(logits[i]).all():
-                fails[rid] = NonFiniteOutputError(
-                    "decode step produced non-finite logits")
-                continue
-            try:
-                self.cache.append(rid, k_t[i], v_t[i])
-            except KVCacheExhaustedError as e:
-                fails[rid] = e
-                continue
-            out[rid] = self._greedy(logits[i])
+        with tracing.span("decode/commit", cat="serve"):
+            for i, rid in enumerate(rids):
+                if self.check_finite and not np.isfinite(logits[i]).all():
+                    fails[rid] = NonFiniteOutputError(
+                        "decode step produced non-finite logits")
+                    continue
+                try:
+                    self.cache.append(rid, k_t[i], v_t[i])
+                except KVCacheExhaustedError as e:
+                    fails[rid] = e
+                    continue
+                out[rid] = self._greedy(logits[i])
         return out, fails
 
     # ------------------------------------------------------------------ admin
@@ -664,6 +720,7 @@ class RecurrentAdapter:
         self.check_finite = bool(check_finite)
         self._carries: Dict[int, tuple] = {}
         self.pack_bucket = 0  # no packed path on the stream arm
+        self._link = _register_link_metrics()
 
     # ------------------------------------------------------------- validation
     def validate_prompt(self, prompt) -> np.ndarray:
@@ -694,18 +751,26 @@ class RecurrentAdapter:
             # One token at a time at the [1, features] signature:
             # exactly ONE compiled executable serves every prompt
             # length, and no pad step ever touches the carry.
-            for t in range(prompt.shape[0]):
-                out = net.rnn_time_step(prompt[t:t + 1])
-            self._carries[rid] = tuple(
-                {k: np.asarray(v).copy() for k, v in layer.items()}
-                for layer in net._rnn_carry)
+            with tracing.span("decode/launch", cat="serve",
+                              bytes=prompt.nbytes):
+                for t in range(prompt.shape[0]):
+                    out = net.rnn_time_step(prompt[t:t + 1])
+            self._link["h2d", "prefill"].inc(prompt.nbytes)
+            with tracing.span("decode/fetch", cat="serve"):
+                carry = tuple(
+                    {k: np.asarray(v).copy() for k, v in layer.items()}
+                    for layer in net._rnn_carry)
+                out = np.asarray(out)[0]
+                down = _nbytes(out, carry)
+                tracing.annotate(bytes=down)
+            self._link["d2h", "prefill"].inc(down)
         finally:
             net.rnn_clear_previous_state()
-        out = np.asarray(out)[0]
-        if self.check_finite and not np.isfinite(out).all():
-            self._carries.pop(rid, None)
-            return {}, {rid: NonFiniteOutputError(
-                "stream prefill produced non-finite outputs")}
+        with tracing.span("decode/commit", cat="serve"):
+            if self.check_finite and not np.isfinite(out).all():
+                return {}, {rid: NonFiniteOutputError(
+                    "stream prefill produced non-finite outputs")}
+            self._carries[rid] = carry
         return {rid: out}, {}
 
     # ------------------------------------------------------------------- step
@@ -717,33 +782,45 @@ class RecurrentAdapter:
         pad_i = [min(i, n - 1) for i in range(bucket)]
         net = self.net
         layers = len(self._carries[rids[0]])
-        merged = tuple(
-            {k: jnp.asarray(np.concatenate(
-                [self._carries[rids[pad_i[j]]][li][k]
-                 for j in range(bucket)], axis=0))
-             for k in self._carries[rids[0]][li]}
-            for li in range(layers))
-        x = np.stack([np.asarray(last_items[pad_i[j]], np.float32)
-                      for j in range(bucket)], axis=0)
-        net._rnn_carry = merged
-        try:
-            out = net.rnn_time_step(x)
-            new_carry = net._rnn_carry
-        finally:
-            net.rnn_clear_previous_state()
-        out = np.asarray(out)
+        tracing.annotate(row_bucket=bucket)
+        with tracing.span("decode/launch", cat="serve"):
+            merged = tuple(
+                {k: jnp.asarray(np.concatenate(
+                    [self._carries[rids[pad_i[j]]][li][k]
+                     for j in range(bucket)], axis=0))
+                 for k in self._carries[rids[0]][li]}
+                for li in range(layers))
+            x = np.stack([np.asarray(last_items[pad_i[j]], np.float32)
+                          for j in range(bucket)], axis=0)
+            up = _nbytes(x, merged)
+            tracing.annotate(bytes=up)
+            net._rnn_carry = merged
+            try:
+                out = net.rnn_time_step(x)
+                new_carry = net._rnn_carry
+            finally:
+                net.rnn_clear_previous_state()
+        self._link["h2d", "step"].inc(up)
+        with tracing.span("decode/fetch", cat="serve"):
+            out = np.asarray(out)
+            # the carry comes back in the commit below, once a leaf (jax
+            # keeps an array's host copy) however many riders slice it
+            down = _nbytes(out, new_carry)
+            tracing.annotate(bytes=down)
+        self._link["d2h", "step"].inc(down)
         results: Dict[int, np.ndarray] = {}
         fails: Dict[int, BaseException] = {}
-        for i, rid in enumerate(rids):
-            if self.check_finite and not np.isfinite(out[i]).all():
-                fails[rid] = NonFiniteOutputError(
-                    "decode step produced non-finite outputs")
-                continue
-            self._carries[rid] = tuple(
-                {k: np.asarray(v)[i:i + 1].copy()
-                 for k, v in layer.items()}
-                for layer in new_carry)
-            results[rid] = out[i]
+        with tracing.span("decode/commit", cat="serve"):
+            for i, rid in enumerate(rids):
+                if self.check_finite and not np.isfinite(out[i]).all():
+                    fails[rid] = NonFiniteOutputError(
+                        "decode step produced non-finite outputs")
+                    continue
+                self._carries[rid] = tuple(
+                    {k: np.asarray(v)[i:i + 1].copy()
+                     for k, v in layer.items()}
+                    for layer in new_carry)
+                results[rid] = out[i]
         return results, fails
 
     # ------------------------------------------------------------------ admin
@@ -881,6 +958,7 @@ class DecodeEngine:
         self._lock = threading.RLock()   # step/execution lock (paused())
         self._shutdown = False
         self._ewma_step_s = 0.0
+        self._step_no = 0       # step attempts made; names `decode/step`
 
         reg = registry()
         self._tokens_c = reg.counter("serving_decode_tokens_total",
@@ -1086,6 +1164,14 @@ class DecodeEngine:
             live.append(req)
         if not live:
             return
+        with tracing.span("decode/admit", cat="serve"):
+            tracing.annotate(admitted=self._prefill_groups(live))
+        self._retire_done()
+
+    def _prefill_groups(self, live: List[_DecodeRequest]) -> int:
+        """Prefill `live` group by group; the number that joined the
+        active set."""
+        admitted = 0
         by_rid = {r.rid: r for r in live}
         for group in self.adapter.pack_groups(
                 [(r.rid, r.prompt) for r in live]):
@@ -1096,7 +1182,11 @@ class DecodeEngine:
             try:
                 with self._lock, self._sched_slot(cost=float(len(group))):
                     t0 = time.perf_counter()
-                    first, fails = self.adapter.prefill_group(group)
+                    with self._span("decode/prefill", reqs):
+                        if tracing.is_enabled():
+                            tracing.annotate(tokens=sum(
+                                int(p.shape[0]) for _, p in group))
+                        first, fails = self.adapter.prefill_group(group)
                     dur = time.perf_counter() - t0
             except Exception as e:  # noqa: BLE001 — typed wrapper below
                 self.total_batch_failures += 1  # jaxlint: atomic
@@ -1126,6 +1216,7 @@ class DecodeEngine:
                 if r.trace is not None:
                     r.trace.mark("prefill")
                 self._active.append(r)
+            admitted += len(ok_reqs)
             kv_starved = [r for r in reqs if isinstance(
                 fails.get(r.rid), KVCacheExhaustedError)]
             if kv_starved:
@@ -1137,7 +1228,7 @@ class DecodeEngine:
                     if self.on_batch_error is not None:
                         self.on_batch_error(err, 1)
                     self._fail(r, err)
-        self._retire_done()
+        return admitted
 
     def _requeue_or_fail(self, reqs: List[_DecodeRequest],
                          fails: Dict[int, BaseException]) -> None:
@@ -1163,11 +1254,25 @@ class DecodeEngine:
         return self.scheduler.slot(self.sched_name or self.name,
                                    cost=cost)
 
+    def _span(self, name: str, reqs: List[_DecodeRequest], **args):
+        """A `serve` span over work done for `reqs`: `rows`, and `rids`,
+        each rider under the identifier its `serve/*` spans carry (the
+        flight recorder's) or, with the recorder off, the engine's own.
+        One branch when tracing is off."""
+        if not tracing.is_enabled():
+            return tracing.span(name)
+        return tracing.span(
+            name, cat="serve", rows=len(reqs),
+            rids=[r.rid if r.trace is None else r.trace.rid for r in reqs],
+            **args)
+
     def _step_attempt(self, reqs: List[_DecodeRequest]
                       ) -> Tuple[Dict[int, Any], Dict[int, BaseException]]:
-        faults.fire("serve.decode_step")
-        return self.adapter.step([r.rid for r in reqs],
-                                 [r.last_item for r in reqs])
+        self._step_no += 1  # jaxlint: atomic (loop-thread stat)
+        with self._span("decode/step", reqs, step=self._step_no):
+            faults.fire("serve.decode_step")
+            return self.adapter.step([r.rid for r in reqs],
+                                     [r.last_item for r in reqs])
 
     def _step_once(self) -> None:
         now = time.monotonic()

@@ -258,9 +258,12 @@ class TestTracing:
             doc = json.load(f)
         names = [e["name"] for e in doc["traceEvents"]]
         assert names == ["outer", "inner"]  # sorted by start time
-        # args survive export
-        outer = [e for e in doc["traceEvents"] if e["name"] == "outer"][0]
-        assert outer["args"] == {"k": 1}
+        # args survive export, beside the span's id and its parent's
+        outer, inner = doc["traceEvents"]
+        assert outer["args"] == {"k": 1, "span_id": outer["args"]["span_id"],
+                                 "parent_id": 0}
+        assert inner["args"]["parent_id"] == outer["args"]["span_id"]
+        assert set(doc["clock"]) == {"perf_counter_s", "unix_ns"}
 
     def test_fit_records_step_metrics(self):
         reg = registry()
