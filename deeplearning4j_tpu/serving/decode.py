@@ -16,13 +16,15 @@ This module implements the two techniques that fix that:
 
 * **Block-paged KV cache** (PagedAttention, SOSP '23): per-request KV
   state lives in fixed-size token blocks from one preallocated arena
-  (:class:`PagedKVCache`), so memory scales with tokens-in-flight, not
-  ``max_seq × batch``. Block size is forced through the shared
-  ``next_pow2_bucket`` rule (data/padding.py) — the same rule buckets
-  the decode-step row count and the KV view length, so the set of
-  compiled step executables is the finite grid
-  ``{row buckets} × {kv buckets}`` and a warmed engine compiles NOTHING
-  at steady state.
+  ON THE DEVICE (:class:`PagedKVCache`), so memory scales with
+  tokens-in-flight, not ``max_seq × batch``. The arena is an operand of
+  the step and prefill executables, donated and updated in place: a
+  step sends a block table and the lengths, never K/V. Block size is
+  forced through the shared ``next_pow2_bucket`` rule
+  (data/padding.py) — the same rule buckets the decode-step row count
+  and the KV view length, so the set of compiled step executables is
+  the finite grid ``{row buckets} × {table widths}`` and a warmed
+  engine compiles NOTHING at steady state.
 
 Two model families decode through the one engine:
 
@@ -96,9 +98,11 @@ _KV_BLOCKS_HELP = "KV cache blocks currently allocated"
 _KV_UTIL_HELP = ("Fraction of allocated KV block capacity holding real "
                  "tokens (1.0 = no block-tail waste)")
 _H2D_HELP = ("Bytes of the host arrays the decode adapters hand to the "
-             "device (a step's operands, a prefill's packed row)")
+             "device (a step's tokens, positions, block table and lengths; "
+             "a prefill's packed row and its cache slots; a stream's carry)")
 _D2H_HELP = ("Bytes of the device outputs the decode adapters copy back "
-             "to the host (logits, new K/V or carry)")
+             "to the host (picked tokens and finite flags; a stream's "
+             "outputs and carry)")
 _PHASES = ("step", "prefill")
 
 
@@ -138,32 +142,53 @@ def _nbytes(*arrays) -> int:
 # Paged KV cache
 # ---------------------------------------------------------------------------
 class PagedKVCache:
-    """Bucket-allocated KV arena: fixed-size token blocks, per-request
-    block tables, all-or-nothing grabs.
+    """Bucket-allocated KV arena on the device: fixed-size token blocks,
+    per-request block tables, all-or-nothing grabs.
 
-    One preallocated pair of arenas ``[max_blocks, block_tokens,
-    layers, heads, head_dim]`` holds K and V for every in-flight
+    One preallocated pair of device arrays ``[layers, max_blocks + 1,
+    block_tokens, heads × head_dim]`` holds K and V for every in-flight
     request; a request owns ``ceil(len / block_tokens)`` blocks, so
     live memory scales with tokens-in-flight, never ``max_seq ×
-    batch``. ``block_tokens`` is forced through ``next_pow2_bucket`` —
-    the SAME rule that buckets the step's KV view length, so every view
-    shape a growing request can need is already in the compiled set.
+    batch``. The arrays are operands of the decoder's step and prefill
+    executables, which gather a row's view by its block table, scatter
+    the new K/V in place (the operands are donated) and hand the arrays
+    back: :meth:`update` lends them and takes the outputs.
+    Layer-major with a token's heads side by side, so one layer's
+    block is one contiguous ``block_tokens × (heads × head_dim)`` chunk
+    for the gather and the last axis fills the device's lanes (a
+    trailing ``head_dim`` of 64 would be laid out padded or
+    transposed).
+
+    Everything else is host integers and stays here: the free list, the
+    tables, the lengths. A length advances only at :meth:`advance`,
+    after the host has seen the step's outcome, so a step that is run
+    again (a failed row, a solo retry) writes the same slot again.
+
+    Block ``max_blocks`` (:attr:`scratch`) belongs to no request: pad
+    rows, pad positions and warm-up calls point at it, so they can
+    write nowhere else. ``max_blocks`` counts the blocks a request can
+    own; ``blocks_in_use`` never counts the scratch block.
+    ``block_tokens`` is forced through ``next_pow2_bucket`` — the SAME
+    rule that buckets the step's view length, so every table width a
+    growing request can need is already in the compiled set.
 
     Exhaustion is typed: an allocation that cannot be satisfied raises
     :class:`KVCacheExhaustedError` WITHOUT taking any blocks (the
     admission path requeues or sheds on it; a growth failure fails only
-    the growing request). Thread-safe; all methods take the one lock.
+    the growing request). The bookkeeping is thread-safe under the one
+    lock; the arrays belong to whoever holds the engine's step lock.
     """
 
     def __init__(self, *, layers: int, heads: int, head_dim: int,
                  block_tokens: int = 16, max_blocks: int = 256,
-                 dtype=np.float32):
+                 dtype=jnp.float32):
         self.block_tokens = next_pow2_bucket(block_tokens)
         self.max_blocks = int(max_blocks)
-        shape = (self.max_blocks, self.block_tokens, layers, heads,
-                 head_dim)
-        self._k = np.zeros(shape, dtype)
-        self._v = np.zeros(shape, dtype)
+        self.scratch = self.max_blocks
+        shape = (int(layers), self.max_blocks + 1, self.block_tokens,
+                 int(heads) * int(head_dim))
+        self._k = jnp.zeros(shape, dtype)
+        self._v = jnp.zeros(shape, dtype)
         self._free: List[int] = list(range(self.max_blocks - 1, -1, -1))
         self._tables: Dict[int, List[int]] = {}
         self._lens: Dict[int, int] = {}
@@ -206,78 +231,86 @@ class PagedKVCache:
                 f"(block_tokens={self.block_tokens})")
         return [self._free.pop() for _ in range(n_blocks)]
 
-    # ------------------------------------------------------------------ writes
-    def write_prompt(self, rid: int, k: np.ndarray, v: np.ndarray) -> None:
-        """Allocate and fill `rid`'s table with a prefilled prompt
-        (`k`/`v`: [tokens, layers, heads, head_dim]). All-or-nothing:
-        a raise leaves no blocks taken and no table entry."""
-        t = k.shape[0]
+    def reserve(self, rid: int, n_tokens: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
+        """Give `rid` the blocks of an `n_tokens` prompt and return where
+        each position goes: ``(block, offset)``, int32 ``[n_tokens]``
+        each, the prefill's scatter operands. The length stays 0 until
+        :meth:`advance`. All-or-nothing: a raise leaves no blocks taken
+        and no table entry."""
         with self._lock:
             if rid in self._tables:
                 raise ValueError(f"rid {rid} already cached")
-            blocks = self._grab_locked(self.blocks_needed(t))
-            self._tables[rid] = blocks
-            self._lens[rid] = t
-            bt = self.block_tokens
-            for i, b in enumerate(blocks):
-                chunk_k = k[i * bt:(i + 1) * bt]
-                chunk_v = v[i * bt:(i + 1) * bt]
-                self._k[b, :chunk_k.shape[0]] = chunk_k
-                self._v[b, :chunk_v.shape[0]] = chunk_v
+            table = self._grab_locked(self.blocks_needed(n_tokens))
+            self._tables[rid] = table
+            self._lens[rid] = 0
+        at = np.arange(int(n_tokens))
+        bt = self.block_tokens
+        return (np.asarray(table, np.int32)[at // bt],
+                (at % bt).astype(np.int32))
 
-    def append(self, rid: int, k_tok: np.ndarray, v_tok: np.ndarray) -> None:
-        """Append ONE token's KV (`[layers, heads, head_dim]`), growing
-        the table by a block when the tail block is full. A failed grow
-        raises typed and leaves the existing table intact."""
+    def advance(self, rid: int, n_tokens: int = 1) -> None:
+        """`rid`'s next `n_tokens` slots now hold what the device wrote
+        there."""
         with self._lock:
-            table = self._tables[rid]
-            n = self._lens[rid]
-            bt = self.block_tokens
-            if n >= len(table) * bt:
-                table.extend(self._grab_locked(1))
-            b, off = table[n // bt], n % bt
-            self._k[b, off] = k_tok
-            self._v[b, off] = v_tok
-            self._lens[rid] = n + 1
+            self._lens[rid] += int(n_tokens)
 
     def free(self, rid: int) -> None:
-        """Return `rid`'s blocks to the free list (idempotent)."""
+        """Return `rid`'s blocks to the free list (idempotent). What
+        they held stays in the arena; a view masks it by length."""
         with self._lock:
             blocks = self._tables.pop(rid, None)
             self._lens.pop(rid, None)
             if blocks:
                 self._free.extend(blocks)
 
-    # ------------------------------------------------------------------- reads
-    def batch_view(self, rids: Sequence[int], bucket_t: int
-                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Gather a padded dense view for one decode step: K and V as
-        ``[len(rids), bucket_t, layers, heads, head_dim]`` plus valid
-        lengths ``[len(rids)] int32``. ``bucket_t`` must be a block
-        multiple >= every row's length + 1 (room for the token the step
-        is about to scatter in)."""
+    # ---------------------------------------------------------- the step's view
+    def batch_view(self, rids: Sequence[int], bucket_t: int,
+                   rows: Optional[int] = None
+                   ) -> Tuple[np.ndarray, np.ndarray,
+                              Dict[int, BaseException]]:
+        """Describe one decode step's view: the block table int32
+        ``[rows, bucket_t / block_tokens]``, the valid lengths int32
+        ``[rows]``, and the rids that could not be given room. A row
+        whose tail block is full grows by one block here, before the
+        launch; a failed grow takes nothing, leaves the row's table
+        intact and names the rid in the third result. Such a row, every
+        row past ``len(rids)`` and every table entry past a row's own
+        blocks point at the scratch block, at length 0. ``bucket_t``
+        must be a block multiple >= every row's length + 1 (room for
+        the token the step is about to scatter in)."""
+        bt = self.block_tokens
+        if bucket_t % bt:
+            raise ValueError(
+                f"view bucket {bucket_t} not a multiple of "
+                f"block_tokens {bt}")
+        n = len(rids) if rows is None else int(rows)
+        tables = np.full((n, bucket_t // bt), self.scratch, np.int32)
+        lens = np.zeros((n,), np.int32)
+        starved: Dict[int, BaseException] = {}
         with self._lock:
-            bt = self.block_tokens
-            if bucket_t % bt:
-                raise ValueError(
-                    f"view bucket {bucket_t} not a multiple of "
-                    f"block_tokens {bt}")
-            n = len(rids)
-            shape = (n, bucket_t) + self._k.shape[2:]
-            k = np.zeros(shape, self._k.dtype)
-            v = np.zeros(shape, self._v.dtype)
-            lens = np.zeros((n,), np.int32)
             for i, rid in enumerate(rids):
                 table, ln = self._tables[rid], self._lens[rid]
+                if ln >= len(table) * bt:
+                    try:
+                        table.extend(self._grab_locked(1))
+                    except KVCacheExhaustedError as e:
+                        starved[rid] = e
+                        continue
+                tables[i, :len(table)] = table
                 lens[i] = ln
-                n_blk = self.blocks_needed(ln) if ln else 0
-                for j in range(n_blk):
-                    blk = table[j]
-                    lo = j * bt
-                    hi = min(ln, lo + bt)
-                    k[i, lo:hi] = self._k[blk, :hi - lo]
-                    v[i, lo:hi] = self._v[blk, :hi - lo]
-            return k, v, lens
+        return tables, lens, starved
+
+    def arenas(self) -> Tuple[jax.Array, jax.Array]:
+        """The K and V arrays as they stand, to read."""
+        return self._k, self._v
+
+    def update(self, fn: Callable) -> tuple:
+        """Lend the arenas to ``fn(k, v) -> (*results, k, v)``, an
+        executable that donates them, rebind to the two arrays it hands
+        back, and return the results."""
+        *results, self._k, self._v = fn(self._k, self._v)
+        return tuple(results)
 
 
 # ---------------------------------------------------------------------------
@@ -305,19 +338,35 @@ class TransformerDecoder:
     computed from explicit position operands (so a packed prefill row
     and a mid-generation step agree on every token's encoding).
 
-    Two jitted pure functions are the whole inference surface:
+    Two jitted pure functions are the serving surface. Both take the
+    paged cache's two arenas (layer-major
+    ``[layers, blocks + 1, block_tokens, heads × head_dim]``) as DONATED
+    operands, write the new K/V into them in place, pick the greedy
+    token on the device and return ``(token, finite, arena_k,
+    arena_v)`` — the cache rebinds to the two arrays
+    (``PagedKVCache.update``), and only int32 tokens and bool flags
+    cross the link:
 
-    * ``prefill(tokens[1,T], seg[1,T], pos[1,T])`` — one segment-masked
-      packed row through ``dense_attention(causal=True, segment_ids=…)``
-      returning per-position logits AND the per-layer K/V to seed the
-      paged cache. Padding is segment 0; packed prompts are 1..k.
-    * ``step(tok[b], pos[b], k_view, v_view, lens[b])`` — one token per
-      row against a bucketed KV view: the new token's K/V is scattered
-      at ``lens`` (in-executable), attention runs through
-      ``decode_attention`` over ``lens+1`` positions, and the new K/V
-      rows are ALSO returned for the host-side cache append.
+    * ``prefill(tokens[1,T], seg[1,T], pos[1,T], arena_k, arena_v,
+      blk[T], off[T], last[W])`` — one segment-masked packed row through
+      ``dense_attention(causal=True, segment_ids=…)``. Padding is
+      segment 0; packed prompts are 1..k. Position ``i``'s K/V go to
+      ``(blk[i], off[i])`` (pads point at the scratch block); the pick
+      and the finite flag are taken at the positions ``last`` (each
+      prompt's last token, padded to a fixed width).
+    * ``step(tok[b], pos[b], arena_k, arena_v, tables[b,w], lens[b])``
+      — one token per row: each layer's view ``[b, w·block_tokens,
+      heads, head_dim]`` is gathered from the arena by the block table,
+      zero past ``lens``, with the new token's K/V at ``lens``;
+      attention runs through ``decode_attention`` over ``lens+1``
+      positions; the new K/V are scattered to ``(tables[row, lens //
+      block_tokens], lens % block_tokens)``.
 
-    Both close over static geometry only; params ride as an argument,
+    ``logits(tokens, seg, pos)`` is the plain forward of the same
+    packed row with no cache: what ``naive_generate``, the tests and
+    chip_smoke.py compare the served path with.
+
+    All close over static geometry only; params ride as an argument,
     so a hot-swap is a tree assignment, never a recompile.
     """
 
@@ -356,8 +405,9 @@ class TransformerDecoder:
                 "w2": w(f, d), "b2": jnp.zeros((d,), jnp.float32),
             } for _ in range(self.n_layers)],
         }
-        self._prefill_fn = jax.jit(self._prefill_pure)
-        self._step_fn = jax.jit(self._step_pure)
+        self._logits_fn = jax.jit(self._logits_pure)
+        self._prefill_fn = jax.jit(self._prefill_pure, donate_argnums=(4, 5))
+        self._step_fn = jax.jit(self._step_pure, donate_argnums=(3, 4))
 
     # ----------------------------------------------------------- pure forward
     def _embed(self, params, tok, pos):
@@ -370,40 +420,89 @@ class TransformerDecoder:
     def _heads(self, a):  # [..., t, d_model] -> [..., t, H, Dh]
         return a.reshape(a.shape[:-1] + (self.heads, self.head_dim))
 
-    def _prefill_pure(self, params, tokens, seg, pos):
-        """tokens/seg/pos [1, T] → (logits [1, T, vocab],
-        k_all/v_all [1, T, layers, heads, head_dim])."""
+    def _head(self, params, x):
+        """Final norm and tied unembedding: [..., d_model] → logits."""
+        with jax.named_scope("head"):
+            x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
+            return x @ params["emb"].T
+
+    def _pick(self, params, x):
+        """The greedy pick of x [n, d_model] → (first index of the
+        largest logit int32 [n], all logits finite bool [n])."""
+        logits = self._head(params, x)
+        with jax.named_scope("head"):
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                    jnp.isfinite(logits).all(axis=-1))
+
+    @staticmethod
+    def _scatter(arena_k, arena_v, blk, off, ks, vs):
+        """ks/vs [layers, n, d_model] into slot (blk[i], off[i]) of each
+        layer. The layer is an index like the other two, so the three
+        indexed axes are the arena's leading ones and the update is a
+        plain row write (with the layer as a window axis the compiler
+        re-lays the whole arena out around the scatter)."""
+        with jax.named_scope("kv_scatter"):
+            layer = jnp.arange(ks.shape[0])[:, None]
+            return (arena_k.at[layer, blk, off].set(ks),
+                    arena_v.at[layer, blk, off].set(vs))
+
+    def _packed_forward(self, params, tokens, seg, pos):
+        """tokens/seg/pos [1, T] → (hidden [1, T, d_model] before the
+        final norm, K and V [layers, T, d_model])."""
         with jax.named_scope("embed"):
             x = self._embed(params, tokens, pos)
         ks, vs = [], []
         for li, lp in enumerate(params["layers"]):
             with jax.named_scope(f"layer_{li}/attn"):
                 h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
-                q = self._heads(h @ lp["wq"])
-                k = self._heads(h @ lp["wk"])
-                v = self._heads(h @ lp["wv"])
+                q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
                 # Causal within the packed row is exact under global
                 # arange positions + segment equality
                 # (ops/flash_attention.py docstring); pads are segment 0
                 # and never read.
-                a = dense_attention(q, k, v, causal=True, segment_ids=seg)
+                a = dense_attention(self._heads(q), self._heads(k),
+                                    self._heads(v), causal=True,
+                                    segment_ids=seg)
                 x = x + a.reshape(a.shape[:2] + (self.d_model,)) @ lp["wo"]
             with jax.named_scope(f"layer_{li}/mlp"):
                 x = x + self._mlp(
                     _layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
-            ks.append(k)
-            vs.append(v)
-        with jax.named_scope("head"):
-            x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
-            logits = x @ params["emb"].T
-        return (logits, jnp.stack(ks, axis=2), jnp.stack(vs, axis=2))
+            ks.append(k[0])
+            vs.append(v[0])
+        return x, jnp.stack(ks), jnp.stack(vs)
 
-    def _step_pure(self, params, tok, pos, k_view, v_view, lens):
-        """One decode step. tok/pos/lens [b]; k_view/v_view
-        [b, bucket_t, layers, heads, head_dim] → (logits [b, vocab],
-        k_new/v_new [b, layers, heads, head_dim])."""
-        b = tok.shape[0]
-        rows = jnp.arange(b)
+    def _logits_pure(self, params, tokens, seg, pos):
+        """tokens/seg/pos [1, T] → logits [1, T, vocab]."""
+        return self._head(
+            params, self._packed_forward(params, tokens, seg, pos)[0])
+
+    def _prefill_pure(self, params, tokens, seg, pos, arena_k, arena_v,
+                      blk, off, last):
+        """The packed row into the cache. blk/off [T]: the slot of each
+        position; last [W]: the positions whose next token is wanted →
+        (token int32 [W], finite bool [W], arena_k, arena_v)."""
+        x, ks, vs = self._packed_forward(params, tokens, seg, pos)
+        arena_k, arena_v = self._scatter(arena_k, arena_v, blk, off, ks, vs)
+        tok, finite = self._pick(params, x[0, last])
+        return tok, finite, arena_k, arena_v
+
+    def _step_pure(self, params, tok, pos, arena_k, arena_v, tables, lens):
+        """One decode step. tok/pos/lens [b]; tables [b, w] →
+        (token int32 [b], finite bool [b], arena_k, arena_v)."""
+        b, bt = tok.shape[0], arena_k.shape[2]
+        kv = tables.shape[1] * bt
+        at = jnp.arange(kv, dtype=jnp.int32)[None, :, None]
+        is_new = at == lens[:, None, None]
+        is_old = at < lens[:, None, None]
+
+        def view(arena, li, new):
+            # The row's blocks, then exactly the dense view a host copy
+            # would build: real tokens below `lens`, the new one at it,
+            # zero past it (a freed block keeps its last owner's data).
+            got = arena[li, tables].reshape(b, kv, self.d_model)
+            return self._heads(jnp.where(
+                is_new, new[:, None], jnp.where(is_old, got, 0)))
+
         with jax.named_scope("embed"):
             x = self._embed(params, tok, pos)[:, None, :]  # [b, 1, d]
         k_toks, v_toks = [], []
@@ -411,10 +510,11 @@ class TransformerDecoder:
             with jax.named_scope(f"layer_{li}/attn"):
                 h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
                 q = self._heads(h @ lp["wq"])               # [b, 1, H, Dh]
-                k_new = self._heads((h @ lp["wk"])[:, 0])   # [b, H, Dh]
-                v_new = self._heads((h @ lp["wv"])[:, 0])
-                k_l = k_view[:, :, li].at[rows, lens].set(k_new)
-                v_l = v_view[:, :, li].at[rows, lens].set(v_new)
+                k_new = (h @ lp["wk"])[:, 0]                # [b, d]
+                v_new = (h @ lp["wv"])[:, 0]
+                with jax.named_scope("kv_gather"):
+                    k_l = view(arena_k, li, k_new)          # [b, kv, H, Dh]
+                    v_l = view(arena_v, li, v_new)
                 a = decode_attention(q, k_l, v_l, lens + 1)
                 x = x + a.reshape(b, 1, self.d_model) @ lp["wo"]
             with jax.named_scope(f"layer_{li}/mlp"):
@@ -422,44 +522,41 @@ class TransformerDecoder:
                     _layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
             k_toks.append(k_new)
             v_toks.append(v_new)
-        with jax.named_scope("head"):
-            x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
-            logits = (x @ params["emb"].T)[:, 0]
-        return (logits, jnp.stack(k_toks, axis=1),
-                jnp.stack(v_toks, axis=1))
+        # One scatter per arena, after every layer has read it: the
+        # donated buffer's last use, so it is updated where it lies.
+        arena_k, arena_v = self._scatter(
+            arena_k, arena_v, tables[jnp.arange(b), lens // bt], lens % bt,
+            jnp.stack(k_toks), jnp.stack(v_toks))
+        picked, finite = self._pick(params, x[:, 0])
+        return picked, finite, arena_k, arena_v
 
     # ------------------------------------------------------------ conveniences
-    def prefill(self, tokens, seg, pos):
-        return self._prefill_fn(self.params_tree,
-                                jnp.asarray(tokens, jnp.int32),
-                                jnp.asarray(seg, jnp.int32),
-                                jnp.asarray(pos, jnp.int32))
+    def logits(self, tokens, seg, pos):
+        return self._logits_fn(self.params_tree,
+                               np.asarray(tokens, np.int32),
+                               np.asarray(seg, np.int32),
+                               np.asarray(pos, np.int32))
 
-    def step(self, tok, pos, k_view, v_view, lens):
-        return self._step_fn(self.params_tree, jnp.asarray(tok, jnp.int32),
-                             jnp.asarray(pos, jnp.int32),
-                             jnp.asarray(k_view), jnp.asarray(v_view),
-                             jnp.asarray(lens, jnp.int32))
+    def prefill(self, tokens, seg, pos, arena_k, arena_v, blk, off, last):
+        return self._prefill_fn(
+            self.params_tree, np.asarray(tokens, np.int32),
+            np.asarray(seg, np.int32), np.asarray(pos, np.int32),
+            arena_k, arena_v, np.asarray(blk, np.int32),
+            np.asarray(off, np.int32), np.asarray(last, np.int32))
 
-    def warmup(self, batch_size: int, *, time_steps=None
-               ) -> "TransformerDecoder":
-        """Engine-warmup compatibility shim: warm the step executable at
-        this row bucket and a minimal KV view (DecodeEngine.swap_warm
-        covers the full KV grid during swaps)."""
-        b = next_pow2_bucket(batch_size)
-        shape = (b, 2, self.n_layers, self.heads, self.head_dim)
-        self.step(np.zeros(b, np.int32), np.zeros(b, np.int32),
-                  np.zeros(shape, np.float32), np.zeros(shape, np.float32),
-                  np.zeros(b, np.int32))
-        return self
+    def step(self, tok, pos, arena_k, arena_v, tables, lens):
+        return self._step_fn(
+            self.params_tree, np.asarray(tok, np.int32),
+            np.asarray(pos, np.int32), arena_k, arena_v,
+            np.asarray(tables, np.int32), np.asarray(lens, np.int32))
 
 
 def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
                    n_tokens: int, *, pad_to: int) -> List[int]:
     """Full-recompute greedy decode — the baseline the KV-cached loop is
-    benched against: every token re-runs the WHOLE sequence through the
-    prefill executable (padded to `pad_to`, so it reuses the already
-    warmed signature and the A/B measures work, not compiles)."""
+    compared with: every token re-runs the WHOLE sequence through the
+    plain forward (padded to `pad_to`: one signature for every length)
+    and takes numpy's argmax of the last position's logits."""
     toks = [int(t) for t in prompt]
     out: List[int] = []
     for _ in range(n_tokens):
@@ -472,8 +569,7 @@ def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
         row[0, :t] = toks
         seg[0, :t] = 1
         pos[0, :t] = np.arange(t)
-        logits, _, _ = model.prefill(row, seg, pos)
-        nxt = int(np.asarray(logits[0, t - 1]).argmax())
+        nxt = int(np.asarray(model.logits(row, seg, pos)[0, t - 1]).argmax())
         toks.append(nxt)
         out.append(nxt)
     return out
@@ -482,11 +578,21 @@ def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
 class TransformerAdapter:
     """Token-family adapter: packed prefill + paged-KV decode steps.
 
+    The adapter owns nothing on the device: each call lends the cache's
+    arenas to the model's executable and rebinds the cache to what comes
+    back. What crosses the link is a step's tokens, positions, block
+    table and lengths going up and one picked token and one finite flag
+    a row coming back (``serving_decode_h2d|d2h_bytes_total`` count
+    exactly those). Every served token passes through :meth:`_greedy`
+    on the host.
+
     ``prefill_group``/``step`` report per-request outcomes as
     ``(results, failures)`` dicts instead of raising for partial
     trouble — the engine applies survivors and fails victims typed, so
     a KV-grow failure or a poisoned row never costs its batchmates a
-    token (and a solo retry can never double-append into the cache).
+    token. A row's length advances only once the host has accepted its
+    token, so a row that failed, or that is stepped again alone after a
+    failed batch step, writes the slot it wrote before.
     """
 
     kind = "token"
@@ -502,8 +608,9 @@ class TransformerAdapter:
         self._link = _register_link_metrics()
 
     @staticmethod
-    def _greedy(logits) -> int:
-        return int(np.asarray(logits).argmax())
+    def _greedy(picked) -> int:
+        """The token served for one row, from what the device picked."""
+        return int(picked)
 
     # ------------------------------------------------------------- validation
     def validate_prompt(self, prompt) -> np.ndarray:
@@ -543,53 +650,61 @@ class TransformerAdapter:
 
     def prefill_group(self, group: List[Tuple[int, np.ndarray]]
                       ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
-        """Prefill ONE packed group: splice prompts into a segment-
-        masked row, run the packed forward, seed each rid's KV blocks.
-        Returns ``({rid: first token}, {rid: error})`` — a rid whose
-        blocks cannot be granted lands in the failure dict (typed
-        KVCacheExhaustedError) without costing groupmates theirs."""
+        """Prefill ONE packed group: reserve each rid's KV blocks,
+        splice the prompts into a segment-masked row and run the packed
+        forward, which writes their K/V into those blocks. Returns
+        ``({rid: first token}, {rid: error})`` — a rid whose blocks
+        cannot be granted lands in the failure dict (typed
+        KVCacheExhaustedError) and stays out of the row, without
+        costing groupmates theirs."""
         pb = self.pack_bucket
         row = np.zeros((1, pb), np.int32)
         seg = np.zeros((1, pb), np.int32)
         pos = np.zeros((1, pb), np.int32)
-        spans: List[Tuple[int, int, int]] = []  # (rid, lo, hi)
+        blk = np.full((pb,), self.cache.scratch, np.int32)
+        off = np.zeros((pb,), np.int32)
+        last = np.zeros((pb,), np.int32)
+        out: Dict[int, int] = {}
+        fails: Dict[int, BaseException] = {}
+        placed: List[Tuple[int, int]] = []  # (rid, prompt tokens)
         cur = 0
-        for si, (rid, p) in enumerate(group, start=1):
+        for rid, p in group:
             lo, hi = cur, cur + p.size
             if hi > pb:
                 raise ValueError("pack group overflows pack_bucket")
+            try:
+                blk[lo:hi], off[lo:hi] = self.cache.reserve(rid, p.size)
+            except KVCacheExhaustedError as e:
+                fails[rid] = e
+                continue
             row[0, lo:hi] = p
-            seg[0, lo:hi] = si
+            seg[0, lo:hi] = len(placed) + 1
             pos[0, lo:hi] = np.arange(p.size)
-            spans.append((rid, lo, hi))
+            last[len(placed)] = hi - 1
+            placed.append((rid, p.size))
             cur = hi
-        up = _nbytes(row, seg, pos)
+        if not placed:
+            return out, fails
+        up = _nbytes(row, seg, pos, blk, off, last)
         with tracing.span("decode/launch", cat="serve", bytes=up):
-            logits, k_all, v_all = self.model.prefill(row, seg, pos)
+            picked, finite = self.cache.update(
+                lambda k, v: self.model.prefill(row, seg, pos, k, v, blk,
+                                                off, last))
         self._link["h2d", "prefill"].inc(up)
         with tracing.span("decode/fetch", cat="serve"):
-            logits = np.asarray(logits)
-            k_all = np.asarray(k_all)
-            v_all = np.asarray(v_all)
-            down = _nbytes(logits, k_all, v_all)
+            picked, finite = jax.device_get((picked, finite))
+            down = _nbytes(picked, finite)
             tracing.annotate(bytes=down)
         self._link["d2h", "prefill"].inc(down)
-        out: Dict[int, int] = {}
-        fails: Dict[int, BaseException] = {}
         with tracing.span("decode/commit", cat="serve"):
-            for rid, lo, hi in spans:
-                if self.check_finite and not np.isfinite(
-                        logits[0, lo:hi]).all():
+            for i, (rid, n_tok) in enumerate(placed):
+                if self.check_finite and not finite[i]:
+                    self.cache.free(rid)
                     fails[rid] = NonFiniteOutputError(
                         "prefill produced non-finite logits")
                     continue
-                try:
-                    self.cache.write_prompt(rid, k_all[0, lo:hi],
-                                            v_all[0, lo:hi])
-                except KVCacheExhaustedError as e:
-                    fails[rid] = e
-                    continue
-                out[rid] = self._greedy(logits[0, hi - 1])
+                self.cache.advance(rid, n_tok)
+                out[rid] = self._greedy(picked[i])
         return out, fails
 
     # ------------------------------------------------------------------- step
@@ -603,46 +718,40 @@ class TransformerAdapter:
     def step(self, rids: Sequence[int], last_items: Sequence[Any]
              ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
         """One iteration-level step for `rids`: rows are padded to the
-        pow2 row bucket by REPEATING row 0 (pad rows recompute a real
-        row's token — garbage never enters the cache because only real
-        rows append). Returns ``({rid: next token}, {rid: error})``."""
+        pow2 row bucket with rows of the scratch block at length 0 (a
+        pad row reads and writes nothing a request owns). Returns
+        ``({rid: next token}, {rid: error})``."""
         n = len(rids)
         bucket = next_pow2_bucket(n)
         kvb = self.kv_bucket(rids)
-        pad_rids = list(rids) + [rids[0]] * (bucket - n)
         tracing.annotate(row_bucket=bucket, kv_bucket=kvb)
         with tracing.span("decode/gather", cat="serve"):
-            k_view, v_view, lens = self.cache.batch_view(pad_rids, kvb)
-            tracing.annotate(bytes=_nbytes(k_view, v_view, lens))
-        toks = np.asarray([int(t) for t in last_items]
-                          + [int(last_items[0])] * (bucket - n), np.int32)
+            tables, lens, fails = self.cache.batch_view(rids, kvb, bucket)
+            tracing.annotate(bytes=_nbytes(tables, lens))
+        toks = np.zeros((bucket,), np.int32)
+        toks[:n] = last_items
         # `lens` goes up twice: as the positions and as the lengths
-        up = _nbytes(toks, lens, k_view, v_view, lens)
+        up = _nbytes(toks, lens, tables, lens)
         with tracing.span("decode/launch", cat="serve", bytes=up):
-            logits, k_t, v_t = self.model.step(toks, lens, k_view, v_view,
-                                               lens)
+            picked, finite = self.cache.update(
+                lambda k, v: self.model.step(toks, lens, k, v, tables, lens))
         self._link["h2d", "step"].inc(up)
         with tracing.span("decode/fetch", cat="serve"):
-            logits = np.asarray(logits)
-            k_t = np.asarray(k_t)
-            v_t = np.asarray(v_t)
-            down = _nbytes(logits, k_t, v_t)
+            picked, finite = jax.device_get((picked, finite))
+            down = _nbytes(picked, finite)
             tracing.annotate(bytes=down)
         self._link["d2h", "step"].inc(down)
         out: Dict[int, int] = {}
-        fails: Dict[int, BaseException] = {}
         with tracing.span("decode/commit", cat="serve"):
             for i, rid in enumerate(rids):
-                if self.check_finite and not np.isfinite(logits[i]).all():
+                if rid in fails:
+                    continue
+                if self.check_finite and not finite[i]:
                     fails[rid] = NonFiniteOutputError(
                         "decode step produced non-finite logits")
                     continue
-                try:
-                    self.cache.append(rid, k_t[i], v_t[i])
-                except KVCacheExhaustedError as e:
-                    fails[rid] = e
-                    continue
-                out[rid] = self._greedy(logits[i])
+                self.cache.advance(rid)
+                out[rid] = self._greedy(picked[i])
         return out, fails
 
     # ------------------------------------------------------------------ admin
@@ -658,8 +767,9 @@ class TransformerAdapter:
     def warm_signatures(self, max_rows: int, max_context: int
                         ) -> Tuple[List[int], List[int]]:
         """The finite compiled grid: pow2 row buckets × pow2 KV view
-        buckets (block-floored; one bucket past max_context so a row AT
-        max_context can still scatter its final token)."""
+        buckets (block-floored, each a table width; one bucket past
+        max_context so a row AT max_context can still scatter its final
+        token)."""
         rows, b = [], 1
         top = next_pow2_bucket(max_rows)
         while b <= top:
@@ -672,21 +782,27 @@ class TransformerAdapter:
             kv <<= 1
         return rows, kvs
 
+    def warm_steps(self, rows: int, kvs: Sequence[int]) -> None:
+        """Run the step executable of `rows` rows at each view bucket
+        on the live arena (it is the only one): every row is a pad row,
+        so only the scratch block is written."""
+        for kv in kvs:
+            tables, zeros, _ = self.cache.batch_view((), kv, rows)
+            self.cache.update(lambda k, v: self.model.step(
+                zeros, zeros, k, v, tables, zeros))
+
     def warmup(self, max_rows: int, max_context: int) -> List[int]:
         """Precompile the packed prefill signature and every step
         signature in the grid. Returns the row buckets warmed."""
         pb = self.pack_bucket
-        zeros = np.zeros((1, pb), np.int32)
-        self.model.prefill(zeros, zeros, zeros)
+        row = np.zeros((1, pb), np.int32)
+        at = np.zeros((pb,), np.int32)
+        scratch = np.full((pb,), self.cache.scratch, np.int32)
+        self.cache.update(lambda k, v: self.model.prefill(
+            row, row, row, k, v, scratch, at, at))
         rows, kvs = self.warm_signatures(max_rows, max_context)
-        L, H, D = (self.model.n_layers, self.model.heads,
-                   self.model.head_dim)
         for b in rows:
-            for kv in kvs:
-                view = np.zeros((b, kv, L, H, D), np.float32)
-                self.model.step(np.zeros(b, np.int32),
-                                np.zeros(b, np.int32), view, view,
-                                np.zeros(b, np.int32))
+            self.warm_steps(b, kvs)
         return rows
 
 
@@ -1019,8 +1135,9 @@ class DecodeEngine:
         every (row bucket × KV view bucket) step executable. After this
         a steady-state engine compiles NOTHING (the acceptance gate
         tests/smoke_decode.py asserts)."""
-        rows = self.adapter.warmup(max_bucket or self.max_decode_batch,
-                                   self.max_context)
+        with self._lock:    # the token arm's warm-up runs on the live arena
+            rows = self.adapter.warmup(
+                max_bucket or self.max_decode_batch, self.max_context)
         for b in rows:
             if b not in self.warmed_buckets:
                 self.warmed_buckets.append(b)
@@ -1033,14 +1150,7 @@ class DecodeEngine:
         ad = self.adapter
         if ad.kind == "token":
             _, kvs = ad.warm_signatures(bucket, self.max_context)
-            L, H, D = (ad.model.n_layers, ad.model.heads,
-                       ad.model.head_dim)
-            b = next_pow2_bucket(bucket)
-            for kv in kvs:
-                view = np.zeros((b, kv, L, H, D), np.float32)
-                ad.model.step(np.zeros(b, np.int32),
-                              np.zeros(b, np.int32), view, view,
-                              np.zeros(b, np.int32))
+            ad.warm_steps(next_pow2_bucket(bucket), kvs)
         else:
             net = ad.net
             net.rnn_clear_previous_state()

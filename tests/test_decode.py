@@ -3,6 +3,7 @@ arithmetic, decode_attention parity, adapter packing/validation, the
 typed rnn_time_step state-reset contract, scoreboard row-kind schema,
 and (slow) engine end-to-end parity / chaos isolation."""
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -15,8 +16,10 @@ from deeplearning4j_tpu.nn.multilayer import RnnStateMismatchError
 from deeplearning4j_tpu.ops.flash_attention import decode_attention
 from deeplearning4j_tpu.optimize.scoreboard import _validate_row_kind
 from deeplearning4j_tpu.optimize.telemetry import CompilationTracker
+from deeplearning4j_tpu.optimize.metrics import registry
 from deeplearning4j_tpu.parallel.inference import (DecodeStepError,
-                                                   KVCacheExhaustedError)
+                                                   KVCacheExhaustedError,
+                                                   NonFiniteOutputError)
 from deeplearning4j_tpu.serving.decode import (DecodeEngine, PagedKVCache,
                                                RecurrentAdapter,
                                                TransformerAdapter,
@@ -32,6 +35,18 @@ def _cache(**kw):
     return PagedKVCache(**kw)
 
 
+def _decoder(layers=2, heads=2, head_dim=4, **kw):
+    kw.setdefault("vocab", 32)
+    kw.setdefault("ff", 16)
+    kw.setdefault("max_context", 64)
+    return TransformerDecoder(layers=layers, heads=heads, head_dim=head_dim,
+                              **kw)
+
+
+def _arenas(cache):
+    return tuple(np.asarray(a) for a in cache.arenas())
+
+
 class TestPagedKVCache:
     def test_block_arithmetic(self):
         c = _cache(block_tokens=16, max_blocks=8)
@@ -41,55 +56,95 @@ class TestPagedKVCache:
         assert c.blocks_needed(17) == 2
         # non-pow2 request is snapped through the ONE bucket rule
         assert _cache(block_tokens=12, max_blocks=2).block_tokens == 16
+        # one block past max_blocks that no request can own; a token's
+        # heads lie side by side on the last axis
+        assert c.scratch == 8
+        assert [a.shape for a in c.arenas()] == [(2, 9, 16, 8)] * 2
 
-    def test_write_append_view_roundtrip(self):
+    def test_prefill_and_step_scatter_equal_a_numpy_model_of_the_arena(self):
+        """The device arena after a packed prefill and three steps, and the
+        view a step attends over, against the K/V the plain forward
+        computes for the same tokens, laid out by the block tables."""
+        m = _decoder()
         c = _cache(block_tokens=4, max_blocks=8)
+        ad = TransformerAdapter(m, c, pack_bucket=16)
         rng = np.random.default_rng(0)
-        k = rng.standard_normal((6, 2, 2, 4)).astype(np.float32)
-        v = rng.standard_normal((6, 2, 2, 4)).astype(np.float32)
-        c.write_prompt(7, k, v)  # 6 tokens -> 2 blocks
-        assert c.length(7) == 6 and c.blocks_of(7) == 2
-        kt = rng.standard_normal((2, 2, 4)).astype(np.float32)
-        vt = rng.standard_normal((2, 2, 4)).astype(np.float32)
-        c.append(7, kt, vt)  # token 7 spills into block 2
-        assert c.length(7) == 7 and c.blocks_of(7) == 2
-        kv, vv, lens = c.batch_view([7], 8)
-        assert lens.tolist() == [7]
-        np.testing.assert_array_equal(kv[0, :6], k)
-        np.testing.assert_array_equal(kv[0, 6], kt)
-        np.testing.assert_array_equal(vv[0, :6], v)
-        np.testing.assert_array_equal(vv[0, 6], vt)
-        np.testing.assert_array_equal(kv[0, 7:], 0)  # pad stays zero
+        prompts = {7: rng.integers(0, 32, 6), 9: rng.integers(0, 32, 3)}
+        first, fails = ad.prefill_group(list(prompts.items()))
+        assert not fails and c.length(7) == 6 and c.blocks_of(7) == 2
+        seqs = {r: list(p) + [first[r]] for r, p in prompts.items()}
+        for _ in range(3):       # rid 7's third step opens its third block
+            out, fails = ad.step([7, 9], [seqs[7][-1], seqs[9][-1]])
+            assert not fails
+            for r in seqs:
+                seqs[r].append(out[r])
+        assert c.length(7) == 9 and c.blocks_of(7) == 3
+        assert c.length(9) == 6 and c.blocks_of(9) == 2
+        got_k, got_v = _arenas(c)
+        want_k, want_v = np.zeros_like(got_k), np.zeros_like(got_v)
+        for r, seq in seqs.items():
+            n = c.length(r)
+            row = np.zeros((1, 16), np.int32)
+            seg = np.zeros((1, 16), np.int32)
+            pos = np.zeros((1, 16), np.int32)
+            row[0, :n], seg[0, :n], pos[0, :n] = seq[:n], 1, np.arange(n)
+            _, ks, vs = m._packed_forward(m.params_tree, row, seg, pos)
+            tables, lens, starved = c.batch_view([r], 16)
+            assert lens.tolist() == [n] and not starved
+            for t in range(n):
+                blk, off = tables[0, t // 4], t % 4
+                want_k[:, blk, off] = np.asarray(ks)[:, t]
+                want_v[:, blk, off] = np.asarray(vs)[:, t]
+        owned = sorted(b for r in seqs for b in c.batch_view([r], 16)[0][0]
+                       if b != c.scratch)
+        assert len(owned) == 5
+        # two executables of different shape agree to rounding, not bitwise
+        np.testing.assert_allclose(got_k[:, owned], want_k[:, owned],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(got_v[:, owned], want_v[:, owned],
+                                   rtol=1e-5, atol=1e-6)
+        free = [b for b in range(8) if b not in owned]
+        assert not got_k[:, free].any() and not got_v[:, free].any()
 
     def test_exhaustion_is_all_or_nothing(self):
         c = _cache(block_tokens=4, max_blocks=2)
-        z = np.zeros((12, 2, 2, 4), np.float32)  # needs 3 > 2 blocks
         with pytest.raises(KVCacheExhaustedError):
-            c.write_prompt(1, z, z)
+            c.reserve(1, 12)  # needs 3 > 2 blocks
         assert c.blocks_in_use() == 0 and c.length(1) == 0
-        # a failed GROW leaves the existing table intact
-        c.write_prompt(2, z[:8], z[:8])
+        # a failed GROW leaves the existing table intact, names the rid,
+        # and its row rides the step as a pad row
+        blk, off = c.reserve(2, 8)
+        assert blk.tolist() == [blk[0]] * 4 + [blk[4]] * 4
+        assert off.tolist() == [0, 1, 2, 3] * 2
+        c.advance(2, 8)
         assert c.free_blocks() == 0
-        tok = np.zeros((2, 2, 4), np.float32)
-        with pytest.raises(KVCacheExhaustedError):
-            c.append(2, tok, tok)
+        tables, lens, starved = c.batch_view([2], 16)
+        assert isinstance(starved[2], KVCacheExhaustedError)
         assert c.length(2) == 8 and c.blocks_of(2) == 2
+        assert (tables == c.scratch).all() and lens.tolist() == [0]
+        with pytest.raises(ValueError):
+            c.reserve(2, 1)  # already cached
 
     def test_free_is_idempotent(self):
         c = _cache(block_tokens=4, max_blocks=4)
-        z = np.zeros((5, 2, 2, 4), np.float32)
-        c.write_prompt(3, z, z)
+        c.reserve(3, 5)
         assert c.blocks_in_use() == 2
         c.free(3)
         c.free(3)  # second free is a no-op, not a double-return
         assert c.blocks_in_use() == 0 and c.free_blocks() == 4
 
-    def test_batch_view_rejects_non_block_multiple(self):
+    def test_batch_view_is_a_table_whose_width_divides_the_bucket(self):
         c = _cache(block_tokens=4, max_blocks=4)
-        z = np.zeros((2, 2, 2, 4), np.float32)
-        c.write_prompt(1, z, z)
+        c.reserve(1, 2)
+        c.advance(1, 2)
         with pytest.raises(ValueError):
             c.batch_view([1], 6)
+        tables, lens, starved = c.batch_view([1], 8, 2)
+        assert tables.dtype == lens.dtype == np.int32 and not starved
+        assert tables.shape == (2, 2) and lens.tolist() == [2, 0]
+        # entries past a row's own blocks, and pad rows, are the scratch
+        assert tables[0, 0] != c.scratch
+        assert tables[0, 1] == tables[1, 0] == tables[1, 1] == c.scratch
 
 
 class TestDecodeAttention:
@@ -169,8 +224,7 @@ class TestRnnStateReset:
 
 class TestTransformerAdapter:
     def _adapter(self, pack_bucket=16, **cache_kw):
-        model = TransformerDecoder(vocab=32, layers=1, heads=2, head_dim=4,
-                                   ff=16, max_context=64)
+        model = _decoder(layers=1)
         cache_kw.setdefault("block_tokens", 4)
         cache_kw.setdefault("max_blocks", 32)
         cache = PagedKVCache(layers=1, heads=2, head_dim=4, **cache_kw)
@@ -221,6 +275,240 @@ class TestScoreboardDecodeRow:
     def test_salvage_rows_exempt(self):
         assert _validate_row_kind(self._row(status="error")) == []
         assert _validate_row_kind(self._row(degraded=True)) == []
+
+
+# ---------------------------------------------------------------------------
+# The device arena under the adapter and the engine
+# ---------------------------------------------------------------------------
+BT, PACK = 8, 32
+
+
+def _adapter(max_blocks=32):
+    model = TransformerDecoder(vocab=64, layers=2, heads=2, head_dim=8,
+                               ff=32, max_context=64, seed=0)
+    cache = PagedKVCache(layers=2, heads=2, head_dim=8, block_tokens=BT,
+                         max_blocks=max_blocks)
+    return TransformerAdapter(model, cache, pack_bucket=PACK)
+
+
+def _prompts(lens, seed=5):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 64, n).astype(np.int32) for n in lens]
+
+
+def _link(way, phase):
+    return registry().counter(
+        f"serving_decode_{way}_bytes_total").labels(phase=phase).value()
+
+
+def _changed_slots(before, after):
+    """(block, offset) pairs at which either arena differs."""
+    diff = np.zeros(before[0].shape[1:3], bool)
+    for b, a in zip(before, after):
+        diff |= (b != a).any(axis=(0, 3))
+    return {(int(i), int(j)) for i, j in zip(*np.nonzero(diff))}
+
+
+@pytest.mark.parametrize("what", ["warmup", "swap_warm", "pad_rows"])
+def test_pad_rows_and_warm_up_write_only_the_scratch_block(what):
+    ad = _adapter()
+    c = ad.cache
+    ps = _prompts((5, 9, 10, 7))
+    first, fails = ad.prefill_group(list(enumerate(ps)))
+    assert not fails
+    before = _arenas(c)
+    in_use = c.blocks_in_use()
+    may = {(c.scratch, off) for off in range(BT)}
+    if what == "warmup":
+        ad.warmup(4, 64)
+    elif what == "swap_warm":
+        with DecodeEngine(ad, max_decode_batch=4) as eng:
+            eng.swap_warm(3)
+    else:
+        # three riders in a bucket of four: one pad row; request 3 sits out
+        out, fails = ad.step([0, 1, 2], [first[r] for r in (0, 1, 2)])
+        assert not fails and sorted(out) == [0, 1, 2]
+        for r in (0, 1, 2):
+            tables, lens, _ = c.batch_view([r], 16)
+            n = int(lens[0]) - 1           # the slot this step filled
+            may.add((int(tables[0, n // BT]), n % BT))
+    changed = _changed_slots(before, _arenas(c))
+    assert changed <= may
+    assert (what == "pad_rows") == bool(changed - {(c.scratch, 0)})
+    assert c.blocks_in_use() == in_use     # the scratch block is not counted
+
+
+def test_a_non_finite_row_fails_alone_and_its_blocks_poison_no_one():
+    def serve(poison):
+        ad = _adapter()
+        c = ad.cache
+        ps = _prompts((6, 11, 4))
+        first, fails = ad.prefill_group(list(enumerate(ps)))
+        assert not fails
+        if poison:
+            victim = c.batch_view([0], 8)[0][0, 0]
+            c.update(lambda k, v: (k.at[:, victim].set(np.nan), v))
+        toks = {r: [first[r]] for r in first}
+        errs = {}
+        for _ in range(3):
+            live = [r for r in toks if r not in errs]
+            out, fails = ad.step(live, [toks[r][-1] for r in live])
+            for r, e in fails.items():
+                errs[r] = e
+                ad.free(r)                 # as the engine does
+            for r, t in out.items():
+                toks[r].append(t)
+        return ad, toks, errs
+
+    _, clean, errs = serve(False)
+    assert not errs
+    ad, got, errs = serve(True)
+    assert list(errs) == [0] and isinstance(errs[0], NonFiniteOutputError)
+    assert got[1] == clean[1] and got[2] == clean[2]
+    # the next owner of the freed blocks attends over its own tokens only
+    newcomer = _prompts((6,), seed=8)[0]
+    first, fails = ad.prefill_group([(9, newcomer)])
+    assert not fails
+    out, fails = ad.step([9], [first[9]])
+    assert not fails
+    assert [first[9], out[9]] == naive_generate(ad.model, newcomer, 2,
+                                                pad_to=PACK)
+
+
+def test_a_step_run_again_at_the_same_lengths_rewrites_the_same_slots():
+    """What a solo retry relies on: the scatter goes where the lengths
+    point, and the lengths move only at commit."""
+    ad = _adapter()
+    c, m = ad.cache, ad.model
+    first, _ = ad.prefill_group(list(enumerate(_prompts((6, 8)))))
+    tables, lens, _ = c.batch_view([0, 1], 16)   # request 1 grows here
+    toks = np.asarray([first[0], first[1]], np.int32)
+    runs = []
+    for _ in range(2):
+        picked, finite = c.update(
+            lambda k, v: m.step(toks, lens, k, v, tables, lens))
+        runs.append((np.asarray(picked).tolist(), _arenas(c)))
+    assert runs[0][0] == runs[1][0]
+    assert not _changed_slots(runs[0][1], runs[1][1])
+    assert c.length(0) == 6 and c.length(1) == 8
+
+
+@pytest.mark.parametrize("riders", [1, 3])
+def test_link_bytes_are_the_formula_over_the_shapes(riders):
+    ad = _adapter()
+    ps = _prompts((5, 9, 12)[:riders])
+    h0, d0 = _link("h2d", "prefill"), _link("d2h", "prefill")
+    first, _ = ad.prefill_group(list(enumerate(ps)))
+    # the packed row, its segments and positions; a block, an offset and
+    # a last position a slot. Back: an int32 token and a flag a slot
+    assert _link("h2d", "prefill") - h0 == 6 * PACK * 4
+    assert _link("d2h", "prefill") - d0 == PACK * 5
+    h0, d0 = _link("h2d", "step"), _link("d2h", "step")
+    rids = list(range(riders))
+    ad.step(rids, [first[r] for r in rids])
+    rows, width = next_pow2_bucket(riders), ad.kv_bucket(rids) // BT
+    # tokens, positions, lengths and `width` table entries a row
+    assert _link("h2d", "step") - h0 == rows * (3 + width) * 4
+    assert _link("d2h", "step") - d0 == rows * 5
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_the_arenas_are_donated_and_the_modules_keep_their_names(which):
+    ad = _adapter(max_blocks=4)
+    c, m = ad.cache, ad.model
+    k, v = c.arenas()
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    if which == "step":
+        tables, lens, _ = c.batch_view((), 16, 2)
+        lowered = m._step_fn.lower(m.params_tree, lens, lens, k, v, tables,
+                                   lens)
+        out = m.step(lens, lens, k, v, tables, lens)
+    else:
+        blk = np.full((PACK,), c.scratch, np.int32)
+        lowered = m._prefill_fn.lower(
+            m.params_tree, i32(1, PACK), i32(1, PACK), i32(1, PACK), k, v,
+            blk, i32(PACK), i32(PACK))
+        out = m.prefill(i32(1, PACK), i32(1, PACK), i32(1, PACK), k, v,
+                        blk, i32(PACK), i32(PACK))
+    # the benchmark's device metrics find the runs by these names
+    assert f"module @jit__{which}_pure" in lowered.as_text()
+    assert "input_output_alias" in lowered.compile().as_text()
+    assert k.is_deleted() and v.is_deleted()
+    assert out[2].shape == k.shape and out[3].shape == v.shape
+    assert out[0].dtype == np.int32 and out[1].dtype == np.bool_
+
+
+MIXED = (3, 9, 17, 5, 15, 16)   # 20 new tokens cross blocks of 8 and the
+NEW = 20                        # KV buckets 8, 16 and 32
+
+
+@pytest.fixture(scope="module")
+def traffic():
+    """One warmed engine of four rows: six concurrent requests, then a
+    pair under `test_chaos_step_isolation`'s fault."""
+    ad = _adapter(max_blocks=64)
+    prompts = [p.tolist() for p in _prompts(MIXED, seed=2)]
+
+    def ask(eng, pool, out):
+        def run(i):
+            try:
+                out[i] = eng.generate(pool[i], max_new_tokens=NEW)
+            except DecodeStepError as e:
+                out[i] = e
+        ts = [threading.Thread(target=run, args=(i,))
+              for i in range(len(pool))]
+        # All are in before any runs. The loop takes its admits off the
+        # queue before it asks for the step lock, so the first taken is
+        # in neither count while the pause holds it.
+        with eng.paused():
+            for t in ts:
+                t.start()
+            deadline = time.monotonic() + 30.0
+            while eng.queue_depth() < len(pool) - 1 \
+                    and time.monotonic() < deadline:
+                time.sleep(0.005)
+        for t in ts:
+            t.join(timeout=300)
+        assert not any(t.is_alive() for t in ts)
+
+    with DecodeEngine(ad, max_decode_batch=4) as eng:
+        eng.warmup()
+        served = [None] * len(prompts)
+        with CompilationTracker() as trk:
+            ask(eng, prompts, served)
+            compiles = trk.count
+        left = ad.cache.blocks_in_use()
+        chaos = [None] * 2
+        # fail:3,4 = the batch attempt + the FIRST solo retry
+        with faults.injected("serve.decode_step", "fail:3,4"):
+            ask(eng, prompts[1:3], chaos)
+    want = [naive_generate(ad.model, p, NEW, pad_to=64) for p in prompts]
+    return dict(served=served, want=want, compiles=compiles, left=left,
+                chaos=chaos, left_after_chaos=ad.cache.blocks_in_use())
+
+
+@pytest.mark.parametrize("i", range(len(MIXED)),
+                         ids=[f"prompt{n}" for n in MIXED])
+def test_engine_tokens_equal_naive_generate(traffic, i):
+    assert traffic["served"][i] == traffic["want"][i]
+
+
+def test_no_compilation_after_warmup_and_the_blocks_drain(traffic):
+    assert traffic["compiles"] == 0, "steady-state decode recompiled"
+    assert traffic["left"] == 0 and traffic["left_after_chaos"] == 0
+
+
+def test_survivors_of_a_failed_batch_step_serve_the_clean_runs_tokens(
+        traffic):
+    """The batch step fails, then the first solo retry: one rider dies
+    typed; the other re-steps alone into the slot it would have written
+    and goes on to serve every token of the clean run."""
+    died = [o for o in traffic["chaos"] if isinstance(o, DecodeStepError)]
+    lived = [(i, o) for i, o in enumerate(traffic["chaos"])
+             if isinstance(o, list)]
+    assert len(died) == 1 and len(lived) == 1
+    i, tokens = lived[0]
+    assert tokens == traffic["want"][1 + i]
 
 
 # ---------------------------------------------------------------------------

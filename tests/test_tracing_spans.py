@@ -26,6 +26,7 @@ from deeplearning4j_tpu.serving.decode import (DecodeEngine, PagedKVCache,
                                                TransformerDecoder)
 
 L, H, DH = 2, 2, 8
+BT = 8      # the engine's KV block, tokens
 
 
 @pytest.fixture(autouse=True)
@@ -162,9 +163,10 @@ def test_off_costs_the_shared_null_span_and_records_nothing():
 
 # ---------------------------------------------------------------- the engine
 def _step_bytes(row_bucket, kv_bucket):
-    """What one transformer step hands to the device: the K and V views
-    and three int32 rows (tokens, positions, lengths)."""
-    return 2 * row_bucket * kv_bucket * L * H * DH * 4 + 3 * row_bucket * 4
+    """What one transformer step hands to the device: four int32 rows
+    (tokens, positions, the block table's `kv_bucket / BT` entries a row,
+    lengths). The K/V stay in the device arena."""
+    return row_bucket * (3 + kv_bucket // BT) * 4
 
 
 def _counter(name, phase):
@@ -174,7 +176,7 @@ def _counter(name, phase):
 def _engine(seed=3):
     model = TransformerDecoder(vocab=61, layers=L, heads=H, head_dim=DH,
                                ff=24, max_context=64, seed=seed)
-    cache = PagedKVCache(layers=L, heads=H, head_dim=DH, block_tokens=8,
+    cache = PagedKVCache(layers=L, heads=H, head_dim=DH, block_tokens=BT,
                          max_blocks=32)
     return DecodeEngine(TransformerAdapter(model, cache, pack_bucket=32),
                         name="spans", max_decode_batch=2)
@@ -257,12 +259,13 @@ def test_one_decode_step_span_per_step_and_its_four_children_tile_it(served):
         assert edge <= s["ts"] + s["dur"] + 1.0
         a = s["args"]
         assert a["rows"] == len(a["rids"]) <= a["row_bucket"]
-        view = 2 * a["row_bucket"] * a["kv_bucket"] * L * H * DH * 4
-        assert kids[0]["args"]["bytes"] == view + a["row_bucket"] * 4
+        # gather: the table and the lengths; launch: those, the tokens
+        # and the positions; fetch: an int32 token and a flag a row
+        assert kids[0]["args"]["bytes"] == _step_bytes(
+            a["row_bucket"], a["kv_bucket"]) - 2 * a["row_bucket"] * 4
         assert kids[1]["args"]["bytes"] == _step_bytes(a["row_bucket"],
                                                        a["kv_bucket"])
-        assert kids[2]["args"]["bytes"] == a["row_bucket"] * (
-            61 + 2 * L * H * DH) * 4
+        assert kids[2]["args"]["bytes"] == a["row_bucket"] * 5
 
 
 def test_rids_are_the_flight_recorders_where_it_is_on(served):
@@ -468,15 +471,18 @@ def test_the_decoders_programs_name_their_parts_and_decode_attention():
     m = TransformerDecoder(vocab=32, layers=L, heads=H, head_dim=DH, ff=16,
                            max_context=32)
     b = 2
-    view = jnp.zeros((b, 16, L, H, DH), jnp.float32)
+    arena = jnp.zeros((L, 5, BT, H * DH), jnp.float32)
     z = jnp.zeros((b,), jnp.int32)
-    step = _lowered_text(m._step_pure, m.params_tree, z, z, view, view,
-                         z + 1)
+    step = _lowered_text(m._step_pure, m.params_tree, z, z, arena, arena,
+                         jnp.zeros((b, 2), jnp.int32), z + 1)
     row = jnp.zeros((1, 16), jnp.int32)
-    prefill = _lowered_text(m._prefill_pure, m.params_tree, row, row, row)
+    prefill = _lowered_text(m._prefill_pure, m.params_tree, row, row, row,
+                            arena, arena, row[0], row[0], row[0])
     for text in (step, prefill):
         for name in ("embed", "layer_0/attn", "layer_0/mlp", "layer_1/attn",
-                     "layer_1/mlp", "head"):
+                     "layer_1/mlp", "head", "kv_scatter"):
             assert f"/{name}/" in text, name
     assert "/layer_1/attn/decode_attention/" in step
+    assert "/layer_1/attn/kv_gather/" in step
     assert "/decode_attention/" not in prefill
+    assert "/kv_gather/" not in prefill
