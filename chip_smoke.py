@@ -490,8 +490,6 @@ def phase_generate(cfg):
         check(worst_pick <= GEN_LOGIT_TOL,
               f"a cached-path pick trails recompute's argmax by "
               f"{worst_pick:.4f} logits, tolerance {GEN_LOGIT_TOL}")
-        return adapter.warm_signatures(cfg["lm_decode_batch"],
-                                       lm["max_context"])[1]
     finally:
         gw.pool.shutdown()
 
@@ -507,7 +505,7 @@ def _rel_err(got, ref) -> float:
     return float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-12))
 
 
-def phase_kernels(cfg, kv_buckets, interpret: bool):
+def phase_kernels(cfg, interpret: bool):
     """Every kernel is tried; each prints compiled yes/no and its error;
     the phase fails at the end if any did not compile or missed its
     tolerance."""
@@ -515,8 +513,7 @@ def phase_kernels(cfg, kv_buckets, interpret: bool):
     import jax.numpy as jnp
     from deeplearning4j_tpu.ops import pallas_kernels as pk
     from deeplearning4j_tpu.ops.attention import dense_attention
-    from deeplearning4j_tpu.ops.flash_attention import (decode_attention,
-                                                        flash_attention)
+    from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
     failed = []
 
@@ -584,27 +581,78 @@ def phase_kernels(cfg, kv_buckets, interpret: bool):
            flash_case(key_mask=(seg > 0).astype(jnp.float32),
                       segment_ids=seg))
 
-    # ---- decode attention (q_block=1) at every KV bucket the engine warms
-    lm, rows = cfg["lm"], cfg["lm_decode_batch"]
-    for kv in sorted({2, *kv_buckets}):
-        def run(kv=kv):
-            ks = jax.random.split(jax.random.PRNGKey(kv), 3)
-            dq_ = jax.random.normal(
-                ks[0], (rows, 1, lm["heads"], lm["head_dim"]), jnp.float32)
-            dk_, dv_ = (jax.random.normal(
-                kk, (rows, kv, lm["heads"], lm["head_dim"]), jnp.float32)
-                for kk in ks[1:])
-            cache_len = jnp.asarray(
-                np.linspace(1, kv, rows).astype(np.int32))
-            got = jax.jit(lambda *a: decode_attention(
-                *a, impl="flash", interpret=interpret))(
-                    dq_, dk_, dv_, cache_len)
+    # ---- the serving kernels: the paged decode kernel (the cache read
+    # through the block table; grouped KV heads; a window) and the prefill
+    # chunk over its cached context, each against its dense arm. On the
+    # chip at the widths of the two served decoders; rehearsed tiny.
+    from deeplearning4j_tpu.ops.flash_attention import (
+        paged_decode_attention, prefill_attention)
+    serving = [("f32 h2x8 bt8", jnp.float32, 2, 2, 8, 8, 4, None, 16)] \
+        if interpret else [
+        ("f32 h32x64 bt16 (decoder-at-opt-1.3b)", jnp.float32, 32, 32, 64,
+         16, 16, None, 128),
+        ("bf16 h32/4x128 bt256 full", jnp.bfloat16, 32, 4, 128, 256, 8,
+         None, 2048),
+        ("bf16 h32/4x128 bt256 window1024", jnp.bfloat16, 32, 4, 128, 256,
+         5, 1024, 2048)]
+    for what, dt, hh, kvh, d, bt, w, window, chunk in serving:
+        if window and interpret:
+            continue
+
+        def paged(dt=dt, hh=hh, kvh=kvh, d=d, bt=bt, w=w, window=window):
+            ks = jax.random.split(jax.random.PRNGKey(w), 5)
+            nrows, blocks = 8, 8 * w
+            q = jax.random.normal(ks[0], (nrows, hh, d), dt)
+            kn, vn = (jax.random.normal(k, (nrows, kvh * d), dt)
+                      for k in ks[1:3])
+            ak, av = (jax.random.normal(k, (2, blocks + 1, bt, kvh * d), dt)
+                      for k in ks[3:])
+            tables = jnp.asarray(np.random.default_rng(0).permutation(
+                blocks).reshape(nrows, w), jnp.int32)
+            lens = jnp.asarray(np.linspace(
+                1, 3 * window if window else w * bt - 1, nrows), jnp.int32)
+            starts = jnp.zeros((nrows,), jnp.int32)
+            if window:      # tables that start where the window's block does
+                starts = jnp.maximum(lens - window + 1, 0) // bt * bt
+            args = (q, kn, vn, ak, av, 1, tables, starts, lens)
+            got = jax.jit(lambda *a: paged_decode_attention(
+                *a[:5], 1, *a[5:], window=window, impl="paged",
+                interpret=interpret))(*args[:5], *args[6:])
             with jax.default_matmul_precision("highest"):
-                ref = jax.jit(lambda *a: decode_attention(
-                    *a, impl="dense"))(dq_, dk_, dv_, cache_len)
+                ref = jax.jit(lambda *a: paged_decode_attention(
+                    *a[:5], 1, *a[5:], window=window, impl="dense"))(
+                        *args[:5], *args[6:])
             return {"out": _rel_err(got, ref)}
-        kernel(f"decode_attention flash [rows{rows} kv{kv} "
-               f"h{lm['heads']}x{lm['head_dim']} f32]", KERNEL_TOL, run)
+        kernel(f"paged_decode_attention [{what}]", KERNEL_TOL, paged)
+
+        def chunked(dt=dt, hh=hh, kvh=kvh, d=d, bt=bt, w=w, window=window,
+                    chunk=chunk):
+            ks = jax.random.split(jax.random.PRNGKey(chunk), 3)
+            n_ctx, ctx_len = w * bt, (w * bt * 3) // 4
+            q = jax.random.normal(ks[0], (chunk, hh, d), dt)
+            k_, v_ = (jax.random.normal(k, (n_ctx + chunk, kvh, d), dt)
+                      for k in ks[1:])
+            line = np.arange(chunk)
+            seg = np.where(line < chunk // 2, 1, 2)
+            seg[-chunk // 8:] = 0
+            real = np.arange(n_ctx) < ctx_len
+            kw = dict(
+                q_pos=jnp.asarray(line), q_seg=jnp.asarray(seg),
+                kv_pos=jnp.asarray(np.concatenate(
+                    [np.where(real, np.arange(n_ctx) - ctx_len, 1 << 30),
+                     line])),
+                kv_seg=jnp.asarray(np.concatenate(
+                    [np.where(real, 1, -1), seg])), window=window)
+            got = jax.jit(lambda *a: prefill_attention(
+                *a, impl="flash", interpret=interpret, **kw))(q, k_, v_)
+            with jax.default_matmul_precision("highest"):
+                ref = jax.jit(lambda *a: prefill_attention(
+                    *a, impl="dense", **kw))(q, k_, v_)
+            live = seg > 0
+            return {"out": _rel_err(np.asarray(got, np.float32)[live],
+                                    np.asarray(ref, np.float32)[live])}
+        kernel(f"prefill_attention [{what} chunk{chunk}]", KERNEL_TOL,
+               chunked)
 
     # ---- int8 matmul -----------------------------------------------------
     def int8_case():
@@ -747,9 +795,8 @@ def main(argv=None) -> int:
                     args.rehearse)
     if args.devices == 1:
         run_phase("serve", clock, phase_serve, cfg, net)
-        kv_buckets = run_phase("generate", clock, phase_generate, cfg)
-        run_phase("kernels", clock, phase_kernels, cfg, kv_buckets,
-                  args.rehearse)
+        run_phase("generate", clock, phase_generate, cfg)
+        run_phase("kernels", clock, phase_kernels, cfg, args.rehearse)
         run_phase("attention-layer", clock, phase_attention_layer, cfg,
                   args.rehearse)
     st = compile_cache.status()

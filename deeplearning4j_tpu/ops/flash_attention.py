@@ -58,10 +58,11 @@ import jax.numpy as jnp
 
 from .pallas_kernels import pad_axis_to
 
-# Cross-file trace surface (analysis/boundaries.py): decode_attention is
-# dispatched inside jitted decode steps (serving/decode.py _step_pure),
-# so the JL0xx/JL2xx purity rules must treat it as a traced root here.
-__traced__ = ("decode_attention",)
+# Cross-file trace surface (analysis/boundaries.py): the serving
+# kernels are dispatched inside the decoder's jitted step and prefill
+# (serving/decode.py _step_pure, _prefill_pure), so the JL0xx/JL2xx
+# purity rules must treat them as traced roots here.
+__traced__ = ("paged_decode_attention", "prefill_attention")
 
 NEG = -1e30  # mask sentinel; matches ops/attention.py (finite: -inf NaNs grads)
 
@@ -88,7 +89,7 @@ def pick_kernel_block(t: int, want: int) -> int:
 # ---------------------------------------------------------------------------
 
 def _scores(q_ref, k_ref, qp_ref, kp_ref, km_ref, qs_ref, ks_ref, scale,
-            causal, use_mask, use_segs):
+            causal, use_mask, use_segs, window=None):
     """s = scale * q @ k^T with causal/key/segment masking applied. f32.
 
     Segment masking reuses the position-array layout: q segments are a
@@ -101,6 +102,8 @@ def _scores(q_ref, k_ref, qp_ref, kp_ref, km_ref, qs_ref, ks_ref, scale,
         preferred_element_type=jnp.float32) * scale
     if causal:
         s = jnp.where(kp_ref[:] <= qp_ref[:], s, NEG)
+    if window is not None:
+        s = jnp.where(qp_ref[:] - kp_ref[:] < window, s, NEG)
     if use_mask:
         s = jnp.where(km_ref[0] > 0, s, NEG)
     if use_segs:
@@ -109,7 +112,7 @@ def _scores(q_ref, k_ref, qp_ref, kp_ref, km_ref, qs_ref, ks_ref, scale,
 
 
 def _skip_when(causal, use_segs, qp_ref, kp_ref, qs_ref, ks_ref, q_block,
-               body):
+               body, window=None):
     """Run `body` — under a block-skip predicate when causal and/or
     segment-masked. Causal: the whole KV block is strictly above the
     diagonal iff min(kv_pos) > max(q_pos); positions are traced data, so
@@ -118,7 +121,9 @@ def _skip_when(causal, use_segs, qp_ref, kp_ref, qs_ref, ks_ref, q_block,
     when the q tile's segment-id RANGE cannot intersect the kv tile's —
     conservative for arbitrary ids, exact for the packed case (ids
     monotone within a row), and it skips every fully-cross-segment tile
-    of a packed batch."""
+    of a packed batch. Window: the whole KV block lies behind the window
+    of every query of the tile iff the tile's first query is `window` or
+    more past the block's last key (positions monotone within a block)."""
     from jax.experimental import pallas as pl
 
     pred = None
@@ -129,6 +134,9 @@ def _skip_when(causal, use_segs, qp_ref, kp_ref, qs_ref, ks_ref, q_block,
         seg_pred = (jnp.min(ks) <= jnp.max(qs)) & \
             (jnp.max(ks) >= jnp.min(qs))
         pred = seg_pred if pred is None else pred & seg_pred
+    if window is not None:
+        near = qp_ref[0, 0] - kp_ref[0, kp_ref.shape[1] - 1] < window
+        pred = near if pred is None else pred & near
     if pred is not None:
         @pl.when(pred)
         def _():
@@ -139,7 +147,7 @@ def _skip_when(causal, use_segs, qp_ref, kp_ref, qs_ref, ks_ref, q_block,
 
 def _fwd_kernel(qp_ref, kp_ref, km_ref, qs_ref, ks_ref, q_ref, k_ref, v_ref,
                 o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, causal,
-                use_mask, use_segs, nk):
+                use_mask, use_segs, nk, window=None):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(2)  # kv block index (innermost)
@@ -152,7 +160,7 @@ def _fwd_kernel(qp_ref, kp_ref, km_ref, qs_ref, ks_ref, q_ref, k_ref, v_ref,
 
     def compute():
         s = _scores(q_ref, k_ref, qp_ref, kp_ref, km_ref, qs_ref, ks_ref,
-                    scale, causal, use_mask, use_segs)
+                    scale, causal, use_mask, use_segs, window)
         m_prev, l_prev = m_ref[:], l_ref[:]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -167,7 +175,7 @@ def _fwd_kernel(qp_ref, kp_ref, km_ref, qs_ref, ks_ref, q_ref, k_ref, v_ref,
         acc_ref[:] = acc_ref[:] * alpha + pv
 
     _skip_when(causal, use_segs, qp_ref, kp_ref, qs_ref, ks_ref,
-               q_ref.shape[1], compute)
+               q_ref.shape[1], compute, window)
 
     @pl.when(j == nk - 1)
     def _():
@@ -584,53 +592,270 @@ def flash_attention_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@jax.named_scope("decode_attention")
-def decode_attention(q, k, v, cache_len, *, impl: str = "auto",
-                     interpret: bool = False):
-    """Single-query-row attention against a growing KV cache.
+# ---------------------------------------------------------------------------
+# Serving kernels: grouped KV heads and a window beside the causal bound.
+# Forward only (decode is inference); each takes the `name=` its
+# pallas_call carries into the device trace.
+# ---------------------------------------------------------------------------
 
-    The decode-loop variant of `flash_attention`: each batch row holds
-    ONE new query token attending to its first `cache_len[i]` cached
-    KV positions. Inputs:
+PREFILL_KERNEL_MIN_KEYS = 1024   # under it one XLA fusion beats the grid
 
-      q          [batch, 1, heads, head_dim]  — this step's query
-      k, v       [batch, t_kv, heads, head_dim] — bucketed cache view
-                 (t_kv is a pow2 bucket; tail rows beyond cache_len are
-                 garbage and masked out here)
-      cache_len  [batch] int32 — valid prefix length per row, >= 1
-                 (the row INCLUDING the current token, already
-                 scattered into k/v at position cache_len-1)
 
-    Returns [batch, 1, heads, head_dim].
+def _visible(q_pos, kv_pos, q_seg, kv_seg, window):
+    """[tq, tk] bool: same segment, not after the query, inside its
+    window."""
+    ok = (kv_seg[None, :] == q_seg[:, None]) & \
+        (kv_pos[None, :] <= q_pos[:, None])
+    if window is not None:
+        ok &= q_pos[:, None] - kv_pos[None, :] < window
+    return ok
 
-    `impl="flash"` routes through the flash kernel with q_block=1
-    (pick_kernel_block(1, ·) == 1, so the tq=1 row tiles legally);
-    `impl="dense"` is the einsum reference; `impl="auto"` picks flash
-    on a TPU when the geometry gate passes. No
-    backward: decode is inference-only, and the wrapper is jit-friendly
-    (cache_len is a traced operand, so one executable serves every
-    fill level of a given bucket).
-    """
-    b, tq, hh, d = q.shape
-    if tq != 1:
-        raise ValueError(f"decode_attention takes one query row, got {tq}")
-    tk = k.shape[1]
-    cache_len = jnp.asarray(cache_len, jnp.int32)
-    valid = jnp.arange(tk, dtype=jnp.int32)[None, :] < cache_len[:, None]
+
+def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
+                      window: Optional[int] = None,
+                      name: str = "prefill_attention", impl: str = "auto",
+                      interpret: bool = False, q_block: int = 0,
+                      kv_block: int = 0):
+    """Attention of one packed row of queries over keys that need not be
+    the same row: a chunk of a prompt over the cached keys before it and
+    its own.
+
+      q            [tq, heads, head_dim]
+      k, v         [tk, kv_heads, head_dim]; query head n reads KV head
+                   n // (heads / kv_heads)
+      q_pos/kv_pos int32 [tq] / [tk]: places on one line, monotone
+                   inside a block of keys (the skip tests read a block's
+                   ends)
+      q_seg/kv_seg int32: a query sees keys of its own segment only
+
+    Query i sees key j iff the segments agree, ``kv_pos[j] <=
+    q_pos[i]`` and, with `window`, ``q_pos[i] - kv_pos[j] < window``.
+    The kernel skips key blocks wholly above the diagonal, wholly behind
+    the window, or of other segments. `impl="auto"` takes the kernel on
+    a TPU from PREFILL_KERNEL_MIN_KEYS keys on; `"dense"` is the einsum
+    arm. Returns [tq, heads, head_dim]."""
+    tq, hh, d = q.shape
+    tk, kvh, _ = k.shape
+    if hh % kvh:
+        raise ValueError(f"{hh} query heads over {kvh} KV heads")
+    group = hh // kvh
     if impl not in ("auto", "flash", "dense"):
-        raise ValueError(f"unknown decode_attention impl {impl!r}")
+        raise ValueError(f"unknown prefill_attention impl {impl!r}")
+    qb = q_block or pick_kernel_block(tq, 512)
+    kb = kv_block or next((b for b in (256, 128) if tk % b == 0),
+                          pick_kernel_block(tk, 256))   # a lane multiple
     use_flash = impl == "flash" or (
-        impl == "auto" and flash_attention_supported(1, tk, d)
-        and flash_attention_available())
-    if use_flash:
-        return flash_attention(q, k, v, key_mask=valid,
-                               interpret=interpret)
-    # Dense reference arm: f32 accumulate, NEG for masked positions.
-    # A fully-masked row cannot occur (cache_len >= 1 by contract).
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(d)
-    scores = jnp.where(valid[:, None, None, :], scores, NEG)
-    w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), v)
-    return o.astype(q.dtype)
+        impl == "auto" and flash_attention_available()
+        and tk >= PREFILL_KERNEL_MIN_KEYS
+        and flash_attention_supported(tq, tk, d, q_block=qb, kv_block=kb))
+    q_pos, kv_pos = q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32)
+    q_seg, kv_seg = q_seg.astype(jnp.int32), kv_seg.astype(jnp.int32)
+    if not use_flash:
+        with jax.named_scope(name):
+            s = jnp.einsum("qkgd,tkd->kgqt", q.reshape(tq, kvh, group, d),
+                           k, preferred_element_type=jnp.float32)
+            ok = _visible(q_pos, kv_pos, q_seg, kv_seg, window)
+            p = jax.nn.softmax(
+                jnp.where(ok[None, None], s / math.sqrt(d), NEG), axis=-1)
+            o = jnp.einsum("kgqt,tkd->qkgd", p.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+            return o.reshape(tq, hh, d).astype(q.dtype)
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    fold = lambda a: pad_axis_to(a.transpose(1, 0, 2), 2, _LANE)
+    q3, k3, v3 = fold(q), fold(k), fold(v)
+    dp = q3.shape[2]
+    nk = tk // kb
+    kern = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
+                             causal=True, use_mask=False, use_segs=True,
+                             nk=nk, window=window)
+    kv_spec = pl.BlockSpec((1, kb, dp), lambda i, j, k_: (i // group, k_, 0))
+    with jax.named_scope(name):
+        o3, _ = pl.pallas_call(
+            kern,
+            grid=(hh, tq // qb, nk),
+            in_specs=[
+                pl.BlockSpec((qb, 1), lambda i, j, k_: (j, 0)),
+                pl.BlockSpec((1, kb), lambda i, j, k_: (0, k_)),
+                pl.BlockSpec((1, 1, kb), lambda i, j, k_: (0, 0, k_)),
+                pl.BlockSpec((1, qb, 1), lambda i, j, k_: (0, j, 0)),
+                pl.BlockSpec((1, 1, kb), lambda i, j, k_: (0, 0, k_)),
+                pl.BlockSpec((1, qb, dp), lambda i, j, k_: (i, j, 0)),
+                kv_spec, kv_spec,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, qb, dp), lambda i, j, k_: (i, j, 0)),
+                pl.BlockSpec((1, qb, 1), lambda i, j, k_: (i, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((hh, tq, dp), q.dtype),
+                jax.ShapeDtypeStruct((hh, tq, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((qb, 1), jnp.float32),
+                pltpu.VMEM((qb, 1), jnp.float32),
+                pltpu.VMEM((qb, dp), jnp.float32),
+            ],
+            interpret=interpret,
+            name=name,
+        )(q_pos.reshape(tq, 1), kv_pos.reshape(1, tk),
+          jnp.ones((1, 1, tk), jnp.float32), q_seg.reshape(1, tq, 1),
+          kv_seg.reshape(1, 1, tk), q3, k3, v3)
+    return o3[:, :, :d].transpose(1, 0, 2)
+
+
+def _paged_decode_kernel(tables_ref, starts_ref, lens_ref, q_ref, kn_ref,
+                         vn_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                         *, scale, bt, window, nw):
+    """Grid (row, table entry). The row's own new token opens the online
+    softmax (so it is never empty); each table entry that holds a key
+    the row may see adds its block."""
+    from jax.experimental import pallas as pl
+
+    b, j = pl.program_id(0), pl.program_id(1)
+    ln, first = lens_ref[b], starts_ref[b] + j * bt
+    lo = 0 if window is None else ln - window + 1
+
+    @pl.when(j == 0)
+    def _():
+        q = q_ref[...].astype(jnp.float32)
+        m_ref[:] = jnp.sum(q * kn_ref[...].astype(jnp.float32), axis=1,
+                           keepdims=True) * scale
+        l_ref[:] = jnp.ones(l_ref.shape, l_ref.dtype)
+        acc_ref[:] = jnp.broadcast_to(vn_ref[...].astype(jnp.float32),
+                                      acc_ref.shape)
+
+    @pl.when((first < ln) & (first + bt > lo))
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        at = first + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        s = jnp.where((at < ln) & (at >= lo), s, NEG)
+        m_prev = m_ref[:]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:] = m_next
+        # a block's tail past `ln` keeps its last owner's values: zero
+        # them, p = 0 does not silence a NaN
+        down = first + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        v = jnp.where((down < ln) & (down >= lo), v_ref[...], 0)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nw - 1)
+    def _():
+        o_ref[...] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def paged_decode_attention(q, k_new, v_new, arena_k, arena_v, layer: int,
+                           tables, starts, lens, *,
+                           window: Optional[int] = None,
+                           name: str = "decode_attention",
+                           impl: str = "auto", interpret: bool = False):
+    """One new token a row against its paged cache, read through the
+    block table inside the kernel: no view is gathered.
+
+      q            [rows, heads, head_dim], this step's queries
+      k_new, v_new [rows, kv_heads * head_dim], this step's own key and
+                   value (position ``lens[r]``), not yet in the arena
+      arena_k/v    [layers, blocks, block_tokens, kv_heads * head_dim]
+      layer        which layer of the arena (static)
+      tables       int32 [rows, w]: the row's blocks, in order
+      starts       int32 [rows]: the position of the first token of a
+                   row's first table entry (0 unless blocks behind a
+                   window were given back)
+      lens         int32 [rows]: cached tokens; positions below it hold
+                   real keys
+
+    Row r sees its own token and the cached positions p < lens[r] with,
+    under `window`, lens[r] - p < window: `window` keys with its own.
+    Query head n reads KV head n // (heads / kv_heads); the queries go
+    in block-diagonal over the KV heads' lanes, so one product a block
+    serves every head at any head size. A row reads only table entries
+    that hold a key it may see: the block index stops moving past them
+    and the copy is not issued again. Returns [rows, heads, head_dim]."""
+    rows, hh, d = q.shape
+    bt, kd = arena_k.shape[2], arena_k.shape[3]
+    kvh = kd // d
+    if kvh * d != kd or hh % kvh:
+        raise ValueError(f"{hh} heads of {d} over an arena row of {kd}")
+    group, w = hh // kvh, tables.shape[1]
+    if impl not in ("auto", "paged", "dense"):
+        raise ValueError(f"unknown paged_decode_attention impl {impl!r}")
+    tables = tables.astype(jnp.int32)
+    starts, lens = starts.astype(jnp.int32), lens.astype(jnp.int32)
+    scale = 1.0 / math.sqrt(d)
+    if not (impl == "paged"
+            or (impl == "auto" and flash_attention_available())):
+        with jax.named_scope(name):
+            at = starts[:, None] + jnp.arange(w * bt, dtype=jnp.int32)
+            ok = at < lens[:, None]
+            if window is not None:
+                ok &= lens[:, None] - at < window
+            ok = jnp.concatenate([ok, jnp.ones((rows, 1), bool)], axis=1)
+
+            def keys(arena, new):   # [rows, w*bt + 1, kvh, d]
+                got = arena[layer][tables].reshape(rows, w * bt, kd)
+                return jnp.concatenate([got, new[:, None]], axis=1) \
+                    .reshape(rows, w * bt + 1, kvh, d)
+
+            s = jnp.einsum("rkgd,rtkd->rkgt", q.reshape(rows, kvh, group, d),
+                           keys(arena_k, k_new),
+                           preferred_element_type=jnp.float32) * scale
+            p = jax.nn.softmax(jnp.where(ok[:, None, None], s, NEG), axis=-1)
+            vv = keys(arena_v, v_new)
+            o = jnp.einsum("rkgt,rtkd->rkgd", p.astype(vv.dtype),
+                           jnp.where(ok[:, :, None, None], vv, 0),
+                           preferred_element_type=jnp.float32)
+            return o.reshape(rows, hh, d).astype(q.dtype)
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    # head n's query on the lanes of KV head n // group, zero elsewhere
+    own = (jnp.arange(hh)[:, None] // group
+           == jnp.arange(kvh)[None, :]).astype(q.dtype)      # [hh, kvh]
+    q_bd = (q[:, :, None, :] * own[None, :, :, None]).reshape(rows, hh, kd)
+
+    def block(b, j, tables_ref, starts_ref, lens_ref):
+        held = lens_ref[b] - starts_ref[b]
+        hi = jnp.maximum((held + bt - 1) // bt, 1) - 1
+        lo = 0 if window is None else \
+            jnp.clip((held - window + 1) // bt, 0, hi)
+        return (layer, tables_ref[b, jnp.clip(j, lo, hi)], 0, 0)
+
+    row3 = lambda b, j, *_: (b, 0, 0)
+    kern = functools.partial(_paged_decode_kernel, scale=scale, bt=bt,
+                             window=window, nw=w)
+    with jax.named_scope(name):
+        o = pl.pallas_call(
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(rows, w),
+                in_specs=[
+                    pl.BlockSpec((None, hh, kd), row3),
+                    pl.BlockSpec((None, 1, kd), row3),
+                    pl.BlockSpec((None, 1, kd), row3),
+                    pl.BlockSpec((None, None, bt, kd), block),
+                    pl.BlockSpec((None, None, bt, kd), block),
+                ],
+                out_specs=pl.BlockSpec((None, hh, kd), row3),
+                scratch_shapes=[
+                    pltpu.VMEM((hh, 1), jnp.float32),
+                    pltpu.VMEM((hh, 1), jnp.float32),
+                    pltpu.VMEM((hh, kd), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((rows, hh, kd), q.dtype),
+            interpret=interpret,
+            name=name,
+        )(tables, starts, lens, q_bd, k_new[:, None, :], v_new[:, None, :],
+          arena_k, arena_v)
+    # back from the lanes of each head's own KV head
+    return jnp.einsum("rhkd,hk->rhd", o.reshape(rows, hh, kvh, d), own)

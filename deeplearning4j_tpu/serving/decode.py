@@ -17,7 +17,10 @@ This module implements the two techniques that fix that:
 * **Block-paged KV cache** (PagedAttention, SOSP '23): per-request KV
   state lives in fixed-size token blocks from one preallocated arena
   ON THE DEVICE (:class:`PagedKVCache`), so memory scales with
-  tokens-in-flight, not ``max_seq × batch``. The arena is an operand of
+  tokens-in-flight, not ``max_seq × batch``; a layer that attends over
+  a sliding window gives back the blocks behind it, so the cache keeps
+  one set of arenas and tables per KIND of layer (full, sliding). The
+  arenas are operands of
   the step and prefill executables, donated and updated in place: a
   step sends a block table and the lengths, never K/V. Block size is
   forced through the shared ``next_pow2_bucket`` rule
@@ -29,12 +32,16 @@ This module implements the two techniques that fix that:
 Two model families decode through the one engine:
 
 * :class:`TransformerAdapter` wraps :class:`TransformerDecoder` (a
-  self-contained causal LM): prompts are admitted through the packed
-  path — ragged prompts spliced into ONE segment-masked ``[1,
-  pack_bucket]`` prefill row (PR 12's segment semantics; causal masking
-  inside the packed row stays exact under global positions + segment
-  equality) — and each step runs single-query-row attention against the
-  paged cache via ``ops.flash_attention.decode_attention``.
+  self-contained causal LM whose block is a configuration: norm,
+  positions, grouped KV heads, dense or sparse-expert feed-forward, a
+  pattern of full and sliding-window layers): prompts are admitted
+  through the packed path in CHUNKS of ``pack_bucket`` positions —
+  ragged prompts spliced into ONE segment-masked row (PR 12's segment
+  semantics), a prompt longer than a chunk slice by slice, each slice
+  reading the ones before it from the cache, decode steps running
+  between chunks — and each step runs single-query-row attention
+  through the block tables via
+  ``ops.flash_attention.paged_decode_attention``.
 * :class:`RecurrentAdapter` wraps a streaming ``MultiLayerNetwork``
   (``rnn_time_step``): per-request carry rows are gathered into a
   pow2-bucketed batch each step, so an LSTM generates through the same
@@ -68,8 +75,8 @@ import jax
 import jax.numpy as jnp
 
 from ..data.padding import next_pow2_bucket
-from ..ops.attention import dense_attention
-from ..ops.flash_attention import decode_attention
+from ..ops import moe
+from ..ops.flash_attention import paged_decode_attention, prefill_attention
 from ..optimize import tracing
 from ..optimize.metrics import registry
 from ..parallel.inference import (DeadlineExceededError, DecodeStepError,
@@ -104,6 +111,23 @@ _D2H_HELP = ("Bytes of the device outputs the decode adapters copy back "
              "to the host (picked tokens and finite flags; a stream's "
              "outputs and carry)")
 _PHASES = ("step", "prefill")
+_CHUNKS_HELP = "Prefill chunks executed (one packed row of pack_bucket)"
+_CHUNK_TOKENS_HELP = "Prompt positions the prefill chunks held (no padding)"
+_KV_TOKENS_HELP = ("Keys the rows of the decode steps attended to in one "
+                   "layer of the kind, each row's own token included")
+_BLOCK_STEPS_HELP = ("KV blocks the cache held for one layer of the kind, "
+                     "summed over decode steps")
+_MOE_HELP = {
+    "assignments": ("serving_moe_assignments_total",
+                    "Token-to-expert assignments the decode steps' expert "
+                    "layers computed, summed over layers"),
+    "touched": ("serving_moe_experts_touched_total",
+                "Experts with at least one token in a decode step, summed "
+                "over layers and steps"),
+    "peak": ("serving_moe_expert_load_peak_total",
+             "Tokens of the fullest expert of a layer in a decode step, "
+             "summed over layers and steps"),
+}
 
 
 def register_metrics() -> None:
@@ -119,6 +143,7 @@ def register_metrics() -> None:
     reg.gauge("serving_kv_blocks_in_use", _KV_BLOCKS_HELP)
     reg.gauge("serving_kv_utilization", _KV_UTIL_HELP)
     _register_link_metrics()
+    _register_model_metrics()
 
 
 def _register_link_metrics() -> Dict[Tuple[str, str], Any]:
@@ -133,6 +158,27 @@ def _register_link_metrics() -> Dict[Tuple[str, str], Any]:
             for way, fam in fams.items() for ph in _PHASES}
 
 
+def _register_model_metrics() -> Dict[Any, Any]:
+    """The counters the token arm increments about the model's own
+    mechanisms: prefill chunks, keys attended and blocks held by kind of
+    layer, the expert layers' routing sums. Labels are bounded (the two
+    kinds)."""
+    reg = registry()
+    out: Dict[Any, Any] = {
+        "chunks": reg.counter("serving_decode_prefill_chunks_total",
+                              _CHUNKS_HELP),
+        "chunk_tokens": reg.counter("serving_decode_prefill_tokens_total",
+                                    _CHUNK_TOKENS_HELP)}
+    for key, (name, text) in _MOE_HELP.items():
+        out[key] = reg.counter(name, text)
+    kv = reg.counter("serving_decode_kv_tokens_total", _KV_TOKENS_HELP)
+    blocks = reg.counter("serving_kv_block_steps_total", _BLOCK_STEPS_HELP)
+    for k in KINDS:
+        out["kv_tokens", k] = kv.labels(kind=k)
+        out["block_steps", k] = blocks.labels(kind=k)
+    return out
+
+
 def _nbytes(*arrays) -> int:
     """Bytes of the arrays, and of the leaves of any tree among them."""
     return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(arrays))
@@ -141,185 +187,306 @@ def _nbytes(*arrays) -> int:
 # ---------------------------------------------------------------------------
 # Paged KV cache
 # ---------------------------------------------------------------------------
+KINDS = ("full", "sliding")     # a layer keeps every position, or a window
+
+
 class PagedKVCache:
-    """Bucket-allocated KV arena on the device: fixed-size token blocks,
-    per-request block tables, all-or-nothing grabs.
+    """Bucket-allocated KV arenas on the device: fixed-size token blocks,
+    per-request block tables, all-or-nothing grabs, one set of each per
+    KIND of layer.
 
-    One preallocated pair of device arrays ``[layers, max_blocks + 1,
-    block_tokens, heads × head_dim]`` holds K and V for every in-flight
-    request; a request owns ``ceil(len / block_tokens)`` blocks, so
-    live memory scales with tokens-in-flight, never ``max_seq ×
-    batch``. The arrays are operands of the decoder's step and prefill
-    executables, which gather a row's view by its block table, scatter
-    the new K/V in place (the operands are donated) and hand the arrays
-    back: :meth:`update` lends them and takes the outputs.
-    Layer-major with a token's heads side by side, so one layer's
-    block is one contiguous ``block_tokens × (heads × head_dim)`` chunk
-    for the gather and the last axis fills the device's lanes (a
-    trailing ``head_dim`` of 64 would be laid out padded or
-    transposed).
+    A ``full`` layer keeps every position of a request; a ``sliding``
+    layer needs only the last ``window`` and gives back the blocks
+    behind them. So tables, free lists and arenas are per kind: for each
+    kind present one preallocated pair of device arrays ``[layers of the
+    kind, max_blocks + 1, block_tokens, heads × head_dim]`` holds K and
+    V (``heads`` are KV heads), and all layers of a kind share a
+    request's table. A full table covers positions from 0; a sliding
+    table covers them from :meth:`held_from`, which moves up a block
+    whenever every position of the first block is ``window`` or more
+    behind the request's length (at :meth:`advance`). The arrays are
+    operands of the decoder's step and prefill executables, which read
+    a row's blocks through its table, scatter the new K/V in place (the
+    operands are donated) and hand the arrays back: :meth:`update`
+    lends them and takes the outputs. Layer-major with a token's heads
+    side by side, so one layer's block is one contiguous ``block_tokens
+    × (heads × head_dim)`` chunk and the last axis fills the device's
+    lanes.
 
-    Everything else is host integers and stays here: the free list, the
+    Everything else is host integers and stays here: the free lists, the
     tables, the lengths. A length advances only at :meth:`advance`,
     after the host has seen the step's outcome, so a step that is run
     again (a failed row, a solo retry) writes the same slot again.
 
-    Block ``max_blocks`` (:attr:`scratch`) belongs to no request: pad
-    rows, pad positions and warm-up calls point at it, so they can
-    write nowhere else. ``max_blocks`` counts the blocks a request can
-    own; ``blocks_in_use`` never counts the scratch block.
+    Block ``max_blocks`` of a kind (``scratch_of[kind]``) belongs to no
+    request: pad rows, pad positions and warm-up calls point at it, so
+    they can write nowhere else. ``blocks_in_use`` never counts it.
     ``block_tokens`` is forced through ``next_pow2_bucket`` — the SAME
     rule that buckets the step's view length, so every table width a
     growing request can need is already in the compiled set.
 
-    Exhaustion is typed: an allocation that cannot be satisfied raises
-    :class:`KVCacheExhaustedError` WITHOUT taking any blocks (the
-    admission path requeues or sheds on it; a growth failure fails only
-    the growing request). The bookkeeping is thread-safe under the one
-    lock; the arrays belong to whoever holds the engine's step lock.
+    Exhaustion is typed and all-or-nothing ACROSS kinds: an allocation
+    that any kind cannot satisfy raises :class:`KVCacheExhaustedError`
+    WITHOUT taking a block of either (the admission path requeues or
+    sheds on it; a growth failure fails only the growing request). The
+    bookkeeping is thread-safe under the one lock; the arrays belong to
+    whoever holds the engine's step lock.
     """
 
     def __init__(self, *, layers: int, heads: int, head_dim: int,
-                 block_tokens: int = 16, max_blocks: int = 256,
-                 dtype=jnp.float32):
+                 block_tokens: int = 16, max_blocks=256,
+                 dtype=jnp.float32, layer_kinds: Optional[Sequence[str]]
+                 = None, window: Optional[int] = None):
         self.block_tokens = next_pow2_bucket(block_tokens)
-        self.max_blocks = int(max_blocks)
-        self.scratch = self.max_blocks
-        shape = (int(layers), self.max_blocks + 1, self.block_tokens,
-                 int(heads) * int(head_dim))
-        self._k = jnp.zeros(shape, dtype)
-        self._v = jnp.zeros(shape, dtype)
-        self._free: List[int] = list(range(self.max_blocks - 1, -1, -1))
-        self._tables: Dict[int, List[int]] = {}
+        of_layer = ("full",) * int(layers) if layer_kinds is None \
+            else tuple(layer_kinds)
+        if len(of_layer) != int(layers) or set(of_layer) - set(KINDS):
+            raise ValueError(f"layer_kinds {of_layer!r} for {layers} layers")
+        self.kinds = tuple(k for k in KINDS if k in of_layer)
+        self.window = None if window is None else int(window)
+        if "sliding" in self.kinds and not self.window:
+            raise ValueError("sliding layers need window=")
+        per = max_blocks if isinstance(max_blocks, dict) \
+            else {k: max_blocks for k in self.kinds}
+        self.max_blocks_of = {k: int(per[k]) for k in self.kinds}
+        self.scratch_of = dict(self.max_blocks_of)
+        self.max_blocks = sum(self.max_blocks_of.values())
+        width = int(heads) * int(head_dim)
+        self._arenas: Dict[str, Tuple[jax.Array, jax.Array]] = {}
+        for k in self.kinds:
+            shape = (of_layer.count(k), self.max_blocks_of[k] + 1,
+                     self.block_tokens, width)
+            self._arenas[k] = (jnp.zeros(shape, dtype),
+                               jnp.zeros(shape, dtype))
+        self._free: Dict[str, List[int]] = {
+            k: list(range(self.max_blocks_of[k] - 1, -1, -1))
+            for k in self.kinds}
+        self._tables: Dict[str, Dict[int, List[int]]] = {
+            k: {} for k in self.kinds}
+        self._first: Dict[int, int] = {}    # sliding: first block held
         self._lens: Dict[int, int] = {}
+        self._reserved: Dict[int, int] = {}
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------ bookkeeping
     def blocks_needed(self, n_tokens: int) -> int:
         return max(1, -(-int(n_tokens) // self.block_tokens))
 
+    def table_width(self, kind: str, bucket_t: int) -> int:
+        """Entries of a `kind` table in a view of `bucket_t` positions: a
+        sliding row never holds more than the window's blocks and one."""
+        w = bucket_t // self.block_tokens
+        if kind == "sliding":
+            w = min(w, self.blocks_needed(self.window) + 1)
+        return w
+
     def blocks_of(self, rid: int) -> int:
         with self._lock:
-            return len(self._tables.get(rid, ()))
+            return sum(len(t.get(rid, ())) for t in self._tables.values())
 
     def length(self, rid: int) -> int:
         with self._lock:
             return self._lens.get(rid, 0)
 
-    def blocks_in_use(self) -> int:
+    def held_from(self, rid: int) -> int:
+        """The first position a sliding layer still holds for `rid`."""
         with self._lock:
-            return self.max_blocks - len(self._free)
+            return self._first.get(rid, 0) * self.block_tokens
+
+    def blocks_in_use(self, kind: Optional[str] = None) -> int:
+        with self._lock:
+            return sum(self.max_blocks_of[k] - len(self._free[k])
+                       for k in self.kinds if kind in (None, k))
 
     def free_blocks(self) -> int:
         with self._lock:
-            return len(self._free)
+            return sum(len(f) for f in self._free.values())
 
     def utilization(self) -> float:
         """Stored tokens / allocated block capacity (block-tail waste)."""
         with self._lock:
-            used = self.max_blocks - len(self._free)
+            used = sum(self.max_blocks_of[k] - len(self._free[k])
+                       for k in self.kinds)
             if used == 0:
                 return 0.0
-            tokens = sum(self._lens.values())
-            return tokens / float(used * self.block_tokens)
+            bt = self.block_tokens
+            tokens = sum(ln - (self._first.get(rid, 0) * bt
+                               if k == "sliding" else 0)
+                         for k in self.kinds
+                         for rid, ln in self._lens.items())
+            return tokens / float(used * bt)
 
-    def _grab_locked(self, n_blocks: int) -> List[int]:
-        if n_blocks > len(self._free):
-            raise KVCacheExhaustedError(
-                f"KV cache needs {n_blocks} block(s), "
-                f"{len(self._free)} free of {self.max_blocks} "
-                f"(block_tokens={self.block_tokens})")
-        return [self._free.pop() for _ in range(n_blocks)]
+    def _base(self, kind: str, rid: int) -> int:
+        return self._first.get(rid, 0) if kind == "sliding" else 0
+
+    def _grab_locked(self, rid: int, upto: int) -> None:
+        """Blocks so that every kind's table of `rid` covers positions
+        below `upto`; every kind's or none."""
+        want = -(-int(upto) // self.block_tokens)
+        need = {k: want - self._base(k, rid) - len(self._tables[k][rid])
+                for k in self.kinds}
+        for k, n in need.items():
+            if n > len(self._free[k]):
+                raise KVCacheExhaustedError(
+                    f"KV cache needs {n} {k} block(s), "
+                    f"{len(self._free[k])} free of {self.max_blocks_of[k]} "
+                    f"(block_tokens={self.block_tokens})")
+        for k, n in need.items():
+            self._tables[k][rid].extend(
+                self._free[k].pop() for _ in range(n))
+
+    def extend(self, rid: int, upto: int
+               ) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
+        """Give `rid` (made here if new) the blocks for positions below
+        `upto` and return, per kind, where each position from the last
+        one reserved goes: ``(block, offset)``, int32 each, the
+        prefill's scatter operands. Lengths stay until :meth:`advance`.
+        All-or-nothing: a raise leaves no block taken, and no entry of a
+        rid that was new."""
+        bt = self.block_tokens
+        with self._lock:
+            new = rid not in self._lens
+            if new:
+                for k in self.kinds:
+                    self._tables[k][rid] = []
+            try:
+                self._grab_locked(rid, upto)
+            except KVCacheExhaustedError:
+                if new:
+                    for k in self.kinds:
+                        del self._tables[k][rid]
+                raise
+            if new:
+                self._lens[rid] = self._reserved[rid] = 0
+            at = np.arange(self._reserved[rid], int(upto))
+            self._reserved[rid] = int(upto)
+            return {k: (np.asarray(self._tables[k][rid], np.int32)[
+                at // bt - self._base(k, rid)], (at % bt).astype(np.int32))
+                for k in self.kinds}
 
     def reserve(self, rid: int, n_tokens: int
-                ) -> Tuple[np.ndarray, np.ndarray]:
-        """Give `rid` the blocks of an `n_tokens` prompt and return where
-        each position goes: ``(block, offset)``, int32 ``[n_tokens]``
-        each, the prefill's scatter operands. The length stays 0 until
-        :meth:`advance`. All-or-nothing: a raise leaves no blocks taken
-        and no table entry."""
+                ) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
+        """:meth:`extend` for a request that must be new."""
         with self._lock:
-            if rid in self._tables:
+            if rid in self._lens:
                 raise ValueError(f"rid {rid} already cached")
-            table = self._grab_locked(self.blocks_needed(n_tokens))
-            self._tables[rid] = table
-            self._lens[rid] = 0
-        at = np.arange(int(n_tokens))
-        bt = self.block_tokens
-        return (np.asarray(table, np.int32)[at // bt],
-                (at % bt).astype(np.int32))
+        return self.extend(rid, n_tokens)
 
     def advance(self, rid: int, n_tokens: int = 1) -> None:
         """`rid`'s next `n_tokens` slots now hold what the device wrote
-        there."""
+        there. A sliding table gives back each leading block whose every
+        position is now `window` or more behind the length: no later
+        query can see it."""
+        bt = self.block_tokens
         with self._lock:
             self._lens[rid] += int(n_tokens)
+            if "sliding" not in self.kinds:
+                return
+            table = self._tables["sliding"][rid]
+            first = self._first.get(rid, 0)
+            while table and (first + 1) * bt - 1 <= \
+                    self._lens[rid] - self.window:
+                self._free["sliding"].append(table.pop(0))
+                first += 1
+            self._first[rid] = first
 
     def free(self, rid: int) -> None:
-        """Return `rid`'s blocks to the free list (idempotent). What
-        they held stays in the arena; a view masks it by length."""
+        """Return `rid`'s blocks to the free lists (idempotent). What
+        they held stays in the arenas; a reader masks it by length."""
         with self._lock:
-            blocks = self._tables.pop(rid, None)
-            self._lens.pop(rid, None)
-            if blocks:
-                self._free.extend(blocks)
+            for k in self.kinds:
+                self._free[k].extend(self._tables[k].pop(rid, ()))
+            for d in (self._lens, self._reserved, self._first):
+                d.pop(rid, None)
 
     # ---------------------------------------------------------- the step's view
+    def context(self, rid: int, widths: Dict[str, int]
+                ) -> Tuple[Dict[str, np.ndarray], Dict[str, int]]:
+        """What a later chunk of `rid`'s prompt reads of the chunks
+        before it: per kind the table, padded with the scratch block to
+        `widths[kind]` entries, and the position its first entry starts
+        at."""
+        with self._lock:
+            tables, starts = {}, {}
+            for k in self.kinds:
+                held = self._tables[k][rid]
+                t = np.full((widths[k],), self.scratch_of[k], np.int32)
+                t[:len(held)] = held
+                tables[k] = t
+                starts[k] = self._base(k, rid) * self.block_tokens
+        return tables, starts
+
     def batch_view(self, rids: Sequence[int], bucket_t: int,
                    rows: Optional[int] = None
-                   ) -> Tuple[np.ndarray, np.ndarray,
-                              Dict[int, BaseException]]:
-        """Describe one decode step's view: the block table int32
-        ``[rows, bucket_t / block_tokens]``, the valid lengths int32
-        ``[rows]``, and the rids that could not be given room. A row
-        whose tail block is full grows by one block here, before the
-        launch; a failed grow takes nothing, leaves the row's table
-        intact and names the rid in the third result. Such a row, every
-        row past ``len(rids)`` and every table entry past a row's own
-        blocks point at the scratch block, at length 0. ``bucket_t``
-        must be a block multiple >= every row's length + 1 (room for
-        the token the step is about to scatter in)."""
+                   ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray],
+                              np.ndarray, Dict[int, BaseException]]:
+        """Describe one decode step's view: per kind the block table
+        int32 ``[rows, table_width(kind, bucket_t)]``; for the sliding
+        kind the position each row's table starts at, int32 ``[rows]``;
+        the valid lengths int32 ``[rows]``; and the rids that could not
+        be given room. A row whose tail block is full grows by one block
+        of each kind that needs it here, before the launch; a failed
+        grow takes nothing, leaves the row's tables intact and names the
+        rid in the last result. Such a row, every row past ``len(rids)``
+        and every table entry past a row's own blocks point at the
+        scratch block, at length 0. ``bucket_t`` must be a block
+        multiple >= every row's length + 1 (room for the token the step
+        is about to scatter in)."""
         bt = self.block_tokens
         if bucket_t % bt:
             raise ValueError(
                 f"view bucket {bucket_t} not a multiple of "
                 f"block_tokens {bt}")
         n = len(rids) if rows is None else int(rows)
-        tables = np.full((n, bucket_t // bt), self.scratch, np.int32)
+        tables = {k: np.full((n, self.table_width(k, bucket_t)),
+                             self.scratch_of[k], np.int32)
+                  for k in self.kinds}
+        starts = {k: np.zeros((n,), np.int32)
+                  for k in self.kinds if k == "sliding"}
         lens = np.zeros((n,), np.int32)
         starved: Dict[int, BaseException] = {}
         with self._lock:
             for i, rid in enumerate(rids):
-                table, ln = self._tables[rid], self._lens[rid]
-                if ln >= len(table) * bt:
-                    try:
-                        table.extend(self._grab_locked(1))
-                    except KVCacheExhaustedError as e:
-                        starved[rid] = e
-                        continue
-                tables[i, :len(table)] = table
+                ln = self._lens[rid]
+                try:
+                    self._grab_locked(rid, ln + 1)
+                except KVCacheExhaustedError as e:
+                    starved[rid] = e
+                    continue
+                for k in self.kinds:
+                    held = self._tables[k][rid]
+                    tables[k][i, :len(held)] = held
+                if starts:
+                    starts["sliding"][i] = self._base("sliding", rid) * bt
                 lens[i] = ln
-        return tables, lens, starved
+        return tables, starts, lens, starved
 
-    def arenas(self) -> Tuple[jax.Array, jax.Array]:
-        """The K and V arrays as they stand, to read."""
-        return self._k, self._v
+    def arenas(self) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+        """Per kind the K and V arrays as they stand, to read."""
+        return dict(self._arenas)
 
     def update(self, fn: Callable) -> tuple:
-        """Lend the arenas to ``fn(k, v) -> (*results, k, v)``, an
-        executable that donates them, rebind to the two arrays it hands
-        back, and return the results."""
-        *results, self._k, self._v = fn(self._k, self._v)
+        """Lend the arenas to ``fn(arenas) -> (*results, arenas)``, an
+        executable that donates them, rebind to what it hands back, and
+        return the results."""
+        *results, self._arenas = fn(self._arenas)
         return tuple(results)
 
 
 # ---------------------------------------------------------------------------
-# Transformer arm: a self-contained causal LM
+# Transformer arm: a self-contained causal LM whose block is a configuration
 # ---------------------------------------------------------------------------
 def _layer_norm(x, scale, bias, eps=1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
+
+
+def _rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def _sinusoid(pos, d_model):
@@ -332,35 +499,94 @@ def _sinusoid(pos, d_model):
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
+def rope_inv_freq(head_dim: int, rope: Dict[str, Any]
+                  ) -> Tuple[np.ndarray, float]:
+    """A rotary table's inverse frequencies float32 ``[head_dim / 2]``
+    and the factor its cos and sin are multiplied by, from settings as
+    a published config gives them: ``rope_theta``, and for ``rope_type``
+    ``yarn`` also ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow`` and ``attention_factor`` (default ``0.1
+    ln factor + 1``). YaRN (Peng et al., arXiv:2309.00071) leaves the
+    fast dimensions as they are, divides the slow ones by ``factor`` and
+    blends linearly between the two correction dimensions; the table is
+    static, it does not depend on a sequence's length."""
+    theta, half = float(rope["rope_theta"]), head_dim // 2
+    i = np.arange(half, dtype=np.float64)
+    inv = theta ** (-2.0 * i / head_dim)
+    if rope.get("rope_type", "default") == "default":
+        return inv.astype(np.float32), 1.0
+    if rope["rope_type"] != "yarn":
+        raise ValueError(f"unknown rope_type {rope['rope_type']!r}")
+    s, l0 = float(rope["factor"]), rope["original_max_position_embeddings"]
+
+    def dim(rotations):   # the dimension that turns `rotations` times in l0
+        return head_dim * math.log(l0 / (2 * math.pi * rotations)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim(rope.get("beta_fast", 32))), 0)
+    high = min(math.ceil(dim(rope.get("beta_slow", 1))), head_dim - 1)
+    r = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv = inv * (1.0 - r) + inv / s * r
+    return inv.astype(np.float32), float(
+        rope.get("attention_factor") or 0.1 * math.log(s) + 1.0)
+
+
 class TransformerDecoder:
-    """Minimal pre-LN causal transformer LM sized for serving tests and
-    the decode bench: tied embedding/unembedding, sinusoidal positions
-    computed from explicit position operands (so a packed prefill row
-    and a mid-generation step agree on every token's encoding).
+    """A pre-norm causal transformer LM whose block is a configuration.
+
+    One block definition serves every setting; each has two choices and
+    the defaults are the repo's first decoder (pre-LayerNorm, ReLU,
+    sinusoidal positions, float32, as many KV heads as query heads,
+    every layer alike, tied head), which OPT's sizes are a setting of:
+
+    * ``norm``: ``"layer"`` (scale and bias) | ``"rms"`` (scale, in
+      float32, ``norm_eps``)
+    * ``position``: ``"sinusoid"`` added to the embedding | ``"rotary"``
+      on q and k (rotate-half), one table per kind of layer from
+      ``rope[kind]`` (:func:`rope_inv_freq`: plain or YaRN)
+    * ``mlp``: ``"relu"`` dense of width ``ff`` with biases | ``"moe"``:
+      ``experts`` SwiGLU experts of width ``ff``, ``experts_per_token``
+      a token, of which this model holds ``experts_held`` (default all)
+      and computes their part (ops/moe.py)
+    * ``kv_heads`` (query head n reads KV head ``n // (heads /
+      kv_heads)``), ``d_model`` (default ``heads × head_dim``), ``tied``
+      or an own ``head`` matrix, ``dtype`` of parameters, activations
+      and cache (products accumulate in float32; norms, softmax and the
+      router run in float32)
+    * ``layer_types``: the pattern of kinds, repeated over the layers:
+      ``"full"`` attention or ``"sliding"`` (position i sees j <= i with
+      i - j < ``window``)
+    * ``row_buckets``: ``"pow2"`` step signatures, or ``"full"``: every
+      step padded to the engine's row limit (a step that costs its
+      weights whatever its rows needs one signature a table width)
+
+    Weights are drawn on the device, one jitted call a layer from
+    ``fold_in(key, layer)``, normal(0, ``init_std``) in ``dtype``; with
+    ``params=`` a ready tree is taken and nothing is drawn.
 
     Two jitted pure functions are the serving surface. Both take the
-    paged cache's two arenas (layer-major
-    ``[layers, blocks + 1, block_tokens, heads × head_dim]``) as DONATED
-    operands, write the new K/V into them in place, pick the greedy
-    token on the device and return ``(token, finite, arena_k,
-    arena_v)`` — the cache rebinds to the two arrays
-    (``PagedKVCache.update``), and only int32 tokens and bool flags
-    cross the link:
+    paged cache's arenas (``{kind: (K, V)}``, layer-major ``[layers of
+    the kind, blocks + 1, block_tokens, kv_heads × head_dim]``) as
+    DONATED operands, write the new K/V into them in place, pick the
+    greedy token on the device and hand the arenas back — the cache
+    rebinds to them (``PagedKVCache.update``), and only int32 tokens,
+    bool flags and three routing sums cross the link:
 
-    * ``prefill(tokens[1,T], seg[1,T], pos[1,T], arena_k, arena_v,
-      blk[T], off[T], last[W])`` — one segment-masked packed row through
-      ``dense_attention(causal=True, segment_ids=…)``. Padding is
-      segment 0; packed prompts are 1..k. Position ``i``'s K/V go to
-      ``(blk[i], off[i])`` (pads point at the scratch block); the pick
-      and the finite flag are taken at the positions ``last`` (each
-      prompt's last token, padded to a fixed width).
-    * ``step(tok[b], pos[b], arena_k, arena_v, tables[b,w], lens[b])``
-      — one token per row: each layer's view ``[b, w·block_tokens,
-      heads, head_dim]`` is gathered from the arena by the block table,
-      zero past ``lens``, with the new token's K/V at ``lens``;
-      attention runs through ``decode_attention`` over ``lens+1``
-      positions; the new K/V are scattered to ``(tables[row, lens //
-      block_tokens], lens % block_tokens)``.
+    * ``prefill(tokens[T], seg[T], pos[T], arenas, slots, ctx_tables,
+      ctx_starts, ctx_len, last[W])`` — one CHUNK: a packed row of
+      whole prompts and at most one later slice of a long prompt, which
+      is segment 1 and sees its ``ctx_len`` cached positions through
+      ``ctx_tables[kind]`` (first entry at position
+      ``ctx_starts[kind]``). Padding is segment 0. Position ``i``'s K/V
+      go to ``slots[kind] = (blk[T], off[T])`` (pads point at the
+      scratch block); the pick and the finite flag are taken at the
+      positions ``last``. → ``(token [W], finite [W], arenas)``.
+    * ``step(tok[b], pos[b], arenas, tables, starts, lens[b])`` — one
+      token per row through ``paged_decode_attention``, which reads
+      each row's blocks through ``tables[kind][b, w]`` (a sliding
+      table's first entry at position ``starts[kind][b]``); the new K/V
+      are scattered to the slot of position ``lens``. → ``(token [b],
+      finite [b], routing sums int32 [3] or None, arenas)``.
 
     ``logits(tokens, seg, pos)`` is the plain forward of the same
     packed row with no cache: what ``naive_generate``, the tests and
@@ -372,59 +598,181 @@ class TransformerDecoder:
 
     def __init__(self, *, vocab: int = 128, layers: int = 2,
                  heads: int = 2, head_dim: int = 8, ff: int = 64,
-                 max_context: int = 128, seed: int = 0):
-        if head_dim * heads % 2:
-            raise ValueError("d_model (heads*head_dim) must be even for "
-                             "the sinusoidal position encoding")
+                 max_context: int = 128, seed: int = 0,
+                 d_model: Optional[int] = None,
+                 kv_heads: Optional[int] = None, norm: str = "layer",
+                 norm_eps: float = 1e-6, position: str = "sinusoid",
+                 rope: Optional[Dict[str, Dict[str, Any]]] = None,
+                 mlp: str = "relu", experts: int = 0,
+                 experts_per_token: int = 0,
+                 experts_held: Optional[Sequence[int]] = None,
+                 tied: bool = True, dtype=jnp.float32,
+                 layer_types: Sequence[str] = ("full",),
+                 window: Optional[int] = None, init_std: float = 0.08,
+                 row_buckets: str = "pow2", params=None):
         self.vocab, self.n_layers = int(vocab), int(layers)
         self.heads, self.head_dim = int(heads), int(head_dim)
-        self.d_model = self.heads * self.head_dim
+        self.kv_heads = self.heads if kv_heads is None else int(kv_heads)
+        self.d_model = self.heads * self.head_dim if d_model is None \
+            else int(d_model)
         self.ff = int(ff)
         self.max_context = int(max_context)
+        self.norm, self.norm_eps = norm, float(norm_eps)
+        self.position, self.mlp = position, mlp
+        self.experts, self.top_k = int(experts), int(experts_per_token)
+        self.experts_held = tuple(range(self.experts)) \
+            if experts_held is None else tuple(experts_held)
+        self.tied, self.dtype = bool(tied), jnp.dtype(dtype)
+        self.layer_types = tuple(layer_types)
+        self.window = None if window is None else int(window)
+        self.init_std, self.row_buckets = float(init_std), row_buckets
+        if norm not in ("layer", "rms") or mlp not in ("relu", "moe") \
+                or position not in ("sinusoid", "rotary") \
+                or row_buckets not in ("pow2", "full") \
+                or set(self.layer_types) - set(KINDS):
+            raise ValueError("unknown decoder setting")
+        if self.heads % self.kv_heads:
+            raise ValueError("heads must be a multiple of kv_heads")
+        if position == "sinusoid" and self.d_model % 2:
+            raise ValueError("d_model must be even for the sinusoidal "
+                             "position encoding")
+        if "sliding" in self.layer_types and not self.window:
+            raise ValueError("sliding layers need window=")
+        if mlp == "moe" and not 0 < self.top_k <= self.experts:
+            raise ValueError("moe needs experts >= experts_per_token > 0")
+        self._rope = {} if position != "rotary" else {
+            k: rope_inv_freq(self.head_dim, rope[k])
+            for k in set(self.layer_types)}
         # Pool duck-compat: swap/describe read these on every entry.
         self.iteration = 0
         self.epoch = 0
         self.state_tree: Dict[str, Any] = {}
-        rng = np.random.RandomState(seed)
-
-        def w(*shape):
-            return jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.08)
-
-        d, f = self.d_model, self.ff
-        self.params_tree: Dict[str, Any] = {
-            "emb": w(self.vocab, d),
-            "lnf_s": jnp.ones((d,), jnp.float32),
-            "lnf_b": jnp.zeros((d,), jnp.float32),
-            "layers": [{
-                "ln1_s": jnp.ones((d,), jnp.float32),
-                "ln1_b": jnp.zeros((d,), jnp.float32),
-                "wq": w(d, d), "wk": w(d, d), "wv": w(d, d), "wo": w(d, d),
-                "ln2_s": jnp.ones((d,), jnp.float32),
-                "ln2_b": jnp.zeros((d,), jnp.float32),
-                "w1": w(d, f), "b1": jnp.zeros((f,), jnp.float32),
-                "w2": w(f, d), "b2": jnp.zeros((d,), jnp.float32),
-            } for _ in range(self.n_layers)],
-        }
+        self.params_tree: Dict[str, Any] = \
+            self._draw(seed) if params is None else params
         self._logits_fn = jax.jit(self._logits_pure)
-        self._prefill_fn = jax.jit(self._prefill_pure, donate_argnums=(4, 5))
-        self._step_fn = jax.jit(self._step_pure, donate_argnums=(3, 4))
+        self._prefill_fn = jax.jit(self._prefill_pure, donate_argnums=(4,))
+        self._step_fn = jax.jit(self._step_pure, donate_argnums=(3,))
+
+    # ---------------------------------------------------------------- geometry
+    def kind_of(self, layer: int) -> str:
+        return self.layer_types[layer % len(self.layer_types)]
+
+    def layer_kinds(self) -> List[str]:
+        """Each layer's kind, as the cache wants them."""
+        return [self.kind_of(li) for li in range(self.n_layers)]
+
+    def _slot_in_kind(self, layer: int) -> int:
+        """Which layer of its kind's arena `layer` is."""
+        return self.layer_kinds()[:layer].count(self.kind_of(layer))
+
+    # ----------------------------------------------------------------- weights
+    def _leaf_shapes(self) -> Dict[str, tuple]:
+        d, f = self.d_model, self.ff
+        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        shapes = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
+        if self.mlp == "relu":
+            shapes.update(w1=(d, f), w2=(f, d))
+        else:
+            n = len(self.experts_held)
+            shapes.update(wr=(d, self.experts), wg=(n, d, f), wu=(n, d, f),
+                          wd=(n, f, d))
+        return shapes
+
+    def _draw(self, seed: int) -> Dict[str, Any]:
+        dt, std, d = self.dtype, self.init_std, self.d_model
+        normal = lambda k, shape: (
+            jax.random.normal(k, shape, jnp.float32) * std).astype(dt)
+        ones, zeros = jnp.ones((d,), dt), jnp.zeros((d,), dt)
+
+        @jax.jit
+        def layer(key):
+            lp = {name: normal(jax.random.fold_in(key, j), shape)
+                  for j, (name, shape) in
+                  enumerate(self._leaf_shapes().items())}
+            lp.update(ln1_s=ones, ln2_s=ones)
+            if self.norm == "layer":
+                lp.update(ln1_b=zeros, ln2_b=zeros)
+            if self.mlp == "relu":
+                lp.update(b1=jnp.zeros((self.ff,), dt), b2=zeros)
+            return lp
+
+        key = jax.random.PRNGKey(seed)
+        tree = {"emb": jax.jit(lambda k: normal(k, (self.vocab, d)))(
+                    jax.random.fold_in(key, 0)),
+                "lnf_s": ones,
+                "layers": [layer(jax.random.fold_in(key, li + 1))
+                           for li in range(self.n_layers)]}
+        if self.norm == "layer":
+            tree["lnf_b"] = zeros
+        if not self.tied:
+            tree["head"] = jax.jit(lambda k: normal(k, (d, self.vocab)))(
+                jax.random.fold_in(key, self.n_layers + 1))
+        return tree
 
     # ----------------------------------------------------------- pure forward
+    def _mm(self, a, w):
+        return jnp.dot(a, w, preferred_element_type=jnp.float32
+                       ).astype(self.dtype)
+
+    def _norm(self, x, p, name):
+        if self.norm == "rms":
+            return _rms_norm(x, p[name + "_s"], self.norm_eps)
+        return _layer_norm(x, p[name + "_s"], p[name + "_b"])
+
     def _embed(self, params, tok, pos):
-        return params["emb"][tok] + _sinusoid(pos, self.d_model)
+        x = params["emb"][tok]
+        if self.position == "sinusoid":
+            x = x + _sinusoid(pos, self.d_model).astype(x.dtype)
+        return x
 
-    def _mlp(self, h, lp):
-        return jnp.maximum(h @ lp["w1"] + lp["b1"], 0.0) @ lp["w2"] \
-            + lp["b2"]
+    def _rotate(self, a, pos, kind):
+        """Rotary position on a [t, heads, head_dim], rotate-half."""
+        inv, factor = self._rope[kind]
+        ang = pos[:, None].astype(jnp.float32) * inv
+        cos = jnp.tile(jnp.cos(ang), 2)[:, None, :] * factor
+        sin = jnp.tile(jnp.sin(ang), 2)[:, None, :] * factor
+        a32 = a.astype(jnp.float32)
+        lo, hi = jnp.split(a32, 2, axis=-1)
+        return (a32 * cos + jnp.concatenate([-hi, lo], -1) * sin
+                ).astype(a.dtype)
 
-    def _heads(self, a):  # [..., t, d_model] -> [..., t, H, Dh]
-        return a.reshape(a.shape[:-1] + (self.heads, self.head_dim))
+    def _block(self, x, lp, li, pos, attend, valid):
+        """One layer on x [t, d_model]. ``attend(q [t, heads, head_dim],
+        k, v [t, kv_heads × head_dim]) -> [t, heads, head_dim]`` is the
+        caller's: a chunk over itself and its context, or a step over
+        the paged cache. -> (x, the layer's routing sums or None)."""
+        kind, t = self.kind_of(li), x.shape[0]
+        with jax.named_scope(f"layer_{li}/attn_{kind}"):
+            h = self._norm(x, lp, "ln1")
+            q = self._mm(h, lp["wq"]).reshape(t, self.heads, self.head_dim)
+            k, v = self._mm(h, lp["wk"]), self._mm(h, lp["wv"])
+            if self.position == "rotary":
+                q = self._rotate(q, pos, kind)
+                k = self._rotate(k.reshape(t, self.kv_heads, self.head_dim),
+                                 pos, kind).reshape(k.shape)
+            a = attend(q, k, v)
+            x = x + self._mm(a.reshape(t, -1), lp["wo"])
+        if self.mlp == "relu":
+            with jax.named_scope(f"layer_{li}/mlp"):
+                h = self._norm(x, lp, "ln2")
+                h = jnp.maximum(self._mm(h, lp["w1"]) + lp["b1"], 0.0)
+                return x + self._mm(h, lp["w2"]) + lp["b2"], None
+        with jax.named_scope(f"layer_{li}/moe/route"):
+            h = self._norm(x, lp, "ln2")
+            w, idx = moe.route(h, lp["wr"], self.top_k)
+        with jax.named_scope(f"layer_{li}/moe/experts"):
+            y, sums = moe.expert_ffn(
+                h, w, idx, lp["wg"], lp["wu"], lp["wd"],
+                n_experts=self.experts, experts_held=self.experts_held,
+                valid=valid)
+        return x + y, sums
 
     def _head(self, params, x):
-        """Final norm and tied unembedding: [..., d_model] → logits."""
+        """Final norm and unembedding: [..., d_model] → float32 logits."""
         with jax.named_scope("head"):
-            x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
-            return x @ params["emb"].T
+            x = self._norm(x, params, "lnf")
+            w = params["emb"].T if self.tied else params["head"]
+            return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     def _pick(self, params, x):
         """The greedy pick of x [n, d_model] → (first index of the
@@ -434,101 +782,126 @@ class TransformerDecoder:
             return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                     jnp.isfinite(logits).all(axis=-1))
 
-    @staticmethod
-    def _scatter(arena_k, arena_v, blk, off, ks, vs):
-        """ks/vs [layers, n, d_model] into slot (blk[i], off[i]) of each
-        layer. The layer is an index like the other two, so the three
-        indexed axes are the arena's leading ones and the update is a
-        plain row write (with the layer as a window axis the compiler
-        re-lays the whole arena out around the scatter)."""
+    def _scatter(self, arenas, slots, new):
+        """new[kind] = (K, V lists, a layer of the kind each, [n, kv
+        width]) into slot (blk[i], off[i]) of each layer of the kind.
+        The layer is an index like the other two, so the three indexed
+        axes are the arena's leading ones and the update is a plain row
+        write (with the layer as a window axis the compiler re-lays the
+        whole arena out around the scatter)."""
+        out = {}
         with jax.named_scope("kv_scatter"):
-            layer = jnp.arange(ks.shape[0])[:, None]
-            return (arena_k.at[layer, blk, off].set(ks),
-                    arena_v.at[layer, blk, off].set(vs))
+            for kind, (ak, av) in arenas.items():
+                blk, off = slots[kind]
+                ks, vs = (jnp.stack(a) for a in new[kind])
+                layer = jnp.arange(ks.shape[0])[:, None]
+                out[kind] = (ak.at[layer, blk, off].set(ks),
+                             av.at[layer, blk, off].set(vs))
+        return out
 
-    def _packed_forward(self, params, tokens, seg, pos):
-        """tokens/seg/pos [1, T] → (hidden [1, T, d_model] before the
-        final norm, K and V [layers, T, d_model])."""
+    def _chunk_forward(self, params, tokens, seg, pos, context=None):
+        """tokens/seg/pos [T] → (hidden [T, d_model] before the final
+        norm, {kind: (K list, V list)} a layer each [T, kv width]).
+        `context` = (arenas, ctx_tables, ctx_starts, ctx_len): segment
+        1 also sees its cached positions below ctx_len.
+
+        Causality inside the packed row is exact under the row's own
+        order and segment equality; the context's keys lie before the
+        row on that line, at their true distance from segment 1, so the
+        window test holds across the seam. A cached position at or past
+        ctx_len is no key: segment -1, its value zeroed (a freed block
+        keeps its last owner's data)."""
+        t = tokens.shape[0]
+        line = jnp.arange(t, dtype=jnp.int32)
+        valid = seg > 0
         with jax.named_scope("embed"):
             x = self._embed(params, tokens, pos)
-        ks, vs = [], []
+        new = {k: ([], []) for k in set(self.layer_kinds())}
         for li, lp in enumerate(params["layers"]):
-            with jax.named_scope(f"layer_{li}/attn"):
-                h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
-                q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-                # Causal within the packed row is exact under global
-                # arange positions + segment equality
-                # (ops/flash_attention.py docstring); pads are segment 0
-                # and never read.
-                a = dense_attention(self._heads(q), self._heads(k),
-                                    self._heads(v), causal=True,
-                                    segment_ids=seg)
-                x = x + a.reshape(a.shape[:2] + (self.d_model,)) @ lp["wo"]
-            with jax.named_scope(f"layer_{li}/mlp"):
-                x = x + self._mlp(
-                    _layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
-            ks.append(k[0])
-            vs.append(v[0])
-        return x, jnp.stack(ks), jnp.stack(vs)
+            kind, at = self.kind_of(li), self._slot_in_kind(li)
+
+            def attend(q, k, v, kind=kind, at=at):
+                new[kind][0].append(k)
+                new[kind][1].append(v)
+                kv_pos, kv_seg = line, seg
+                if context is not None:
+                    arenas, tables, starts, ctx_len = context
+                    ak, av = arenas[kind]
+                    n = tables[kind].shape[0] * ak.shape[2]
+                    true = starts.get(kind, 0) + jnp.arange(n,
+                                                            dtype=jnp.int32)
+                    real = true < ctx_len
+                    with jax.named_scope("kv_context"):
+                        ck = ak[at][tables[kind]].reshape(n, -1)
+                        cv = jnp.where(real[:, None], av[at][
+                            tables[kind]].reshape(n, -1), 0)
+                    k, v = jnp.concatenate([ck, k]), jnp.concatenate([cv, v])
+                    kv_pos = jnp.concatenate([jnp.where(
+                        real, true - ctx_len, jnp.int32(1 << 30)), line])
+                    kv_seg = jnp.concatenate([jnp.where(real, 1, -1), seg])
+                heads = lambda a: a.reshape(a.shape[0], self.kv_heads,
+                                            self.head_dim)
+                return prefill_attention(
+                    q, heads(k), heads(v), q_pos=line, kv_pos=kv_pos,
+                    q_seg=seg, kv_seg=kv_seg,
+                    window=self.window if kind == "sliding" else None,
+                    name=f"prefill_attention_{kind}")
+
+            x, _ = self._block(x, lp, li, pos, attend, valid)
+        return x, new
 
     def _logits_pure(self, params, tokens, seg, pos):
         """tokens/seg/pos [1, T] → logits [1, T, vocab]."""
-        return self._head(
-            params, self._packed_forward(params, tokens, seg, pos)[0])
+        return self._head(params, self._chunk_forward(
+            params, tokens[0], seg[0], pos[0])[0])[None]
 
-    def _prefill_pure(self, params, tokens, seg, pos, arena_k, arena_v,
-                      blk, off, last):
-        """The packed row into the cache. blk/off [T]: the slot of each
-        position; last [W]: the positions whose next token is wanted →
-        (token int32 [W], finite bool [W], arena_k, arena_v)."""
-        x, ks, vs = self._packed_forward(params, tokens, seg, pos)
-        arena_k, arena_v = self._scatter(arena_k, arena_v, blk, off, ks, vs)
-        tok, finite = self._pick(params, x[0, last])
-        return tok, finite, arena_k, arena_v
+    def _prefill_pure(self, params, tokens, seg, pos, arenas, slots,
+                      ctx_tables, ctx_starts, ctx_len, last):
+        """One chunk into the cache. → (token int32 [W], finite bool
+        [W], arenas)."""
+        x, new = self._chunk_forward(
+            params, tokens, seg, pos,
+            (arenas, ctx_tables, ctx_starts, ctx_len))
+        arenas = self._scatter(arenas, slots, new)
+        tok, finite = self._pick(params, x[last])
+        return tok, finite, arenas
 
-    def _step_pure(self, params, tok, pos, arena_k, arena_v, tables, lens):
-        """One decode step. tok/pos/lens [b]; tables [b, w] →
-        (token int32 [b], finite bool [b], arena_k, arena_v)."""
-        b, bt = tok.shape[0], arena_k.shape[2]
-        kv = tables.shape[1] * bt
-        at = jnp.arange(kv, dtype=jnp.int32)[None, :, None]
-        is_new = at == lens[:, None, None]
-        is_old = at < lens[:, None, None]
-
-        def view(arena, li, new):
-            # The row's blocks, then exactly the dense view a host copy
-            # would build: real tokens below `lens`, the new one at it,
-            # zero past it (a freed block keeps its last owner's data).
-            got = arena[li, tables].reshape(b, kv, self.d_model)
-            return self._heads(jnp.where(
-                is_new, new[:, None], jnp.where(is_old, got, 0)))
-
+    def _step_pure(self, params, tok, pos, arenas, tables, starts, lens):
+        """One decode step. tok/pos/lens [b]; tables[kind] [b, w] →
+        (token int32 [b], finite bool [b], routing sums int32 [3] or
+        None, arenas)."""
+        b = tok.shape[0]
+        zeros = jnp.zeros((b,), jnp.int32)
+        valid = lens > 0            # a pad row: no expert computes it
         with jax.named_scope("embed"):
-            x = self._embed(params, tok, pos)[:, None, :]  # [b, 1, d]
-        k_toks, v_toks = [], []
+            x = self._embed(params, tok, pos)               # [b, d]
+        new = {k: ([], []) for k in arenas}
+        sums = []
         for li, lp in enumerate(params["layers"]):
-            with jax.named_scope(f"layer_{li}/attn"):
-                h = _layer_norm(x, lp["ln1_s"], lp["ln1_b"])
-                q = self._heads(h @ lp["wq"])               # [b, 1, H, Dh]
-                k_new = (h @ lp["wk"])[:, 0]                # [b, d]
-                v_new = (h @ lp["wv"])[:, 0]
-                with jax.named_scope("kv_gather"):
-                    k_l = view(arena_k, li, k_new)          # [b, kv, H, Dh]
-                    v_l = view(arena_v, li, v_new)
-                a = decode_attention(q, k_l, v_l, lens + 1)
-                x = x + a.reshape(b, 1, self.d_model) @ lp["wo"]
-            with jax.named_scope(f"layer_{li}/mlp"):
-                x = x + self._mlp(
-                    _layer_norm(x, lp["ln2_s"], lp["ln2_b"]), lp)
-            k_toks.append(k_new)
-            v_toks.append(v_new)
+            kind, at = self.kind_of(li), self._slot_in_kind(li)
+
+            def attend(q, k, v, kind=kind, at=at):
+                new[kind][0].append(k)
+                new[kind][1].append(v)
+                return paged_decode_attention(
+                    q, k, v, *arenas[kind], at, tables[kind],
+                    starts.get(kind, zeros), lens,
+                    window=self.window if kind == "sliding" else None,
+                    name=f"decode_attention_{kind}")
+
+            x, s = self._block(x, lp, li, pos, attend, valid)
+            sums.append(s)
         # One scatter per arena, after every layer has read it: the
         # donated buffer's last use, so it is updated where it lies.
-        arena_k, arena_v = self._scatter(
-            arena_k, arena_v, tables[jnp.arange(b), lens // bt], lens % bt,
-            jnp.stack(k_toks), jnp.stack(v_toks))
-        picked, finite = self._pick(params, x[:, 0])
-        return picked, finite, arena_k, arena_v
+        slots = {}
+        for kind, (ak, _) in arenas.items():
+            at = lens - starts.get(kind, zeros)
+            bt = ak.shape[2]
+            slots[kind] = (tables[kind][jnp.arange(b), at // bt], at % bt)
+        arenas = self._scatter(arenas, slots, new)
+        picked, finite = self._pick(params, x)
+        sums = None if sums[0] is None else sum(sums[1:], sums[0])
+        return picked, finite, sums, arenas
 
     # ------------------------------------------------------------ conveniences
     def logits(self, tokens, seg, pos):
@@ -537,18 +910,15 @@ class TransformerDecoder:
                                np.asarray(seg, np.int32),
                                np.asarray(pos, np.int32))
 
-    def prefill(self, tokens, seg, pos, arena_k, arena_v, blk, off, last):
-        return self._prefill_fn(
-            self.params_tree, np.asarray(tokens, np.int32),
-            np.asarray(seg, np.int32), np.asarray(pos, np.int32),
-            arena_k, arena_v, np.asarray(blk, np.int32),
-            np.asarray(off, np.int32), np.asarray(last, np.int32))
+    def prefill(self, tokens, seg, pos, arenas, slots, ctx_tables,
+                ctx_starts, ctx_len, last):
+        return self._prefill_fn(self.params_tree, tokens, seg, pos, arenas,
+                                slots, ctx_tables, ctx_starts,
+                                np.int32(ctx_len), last)
 
-    def step(self, tok, pos, arena_k, arena_v, tables, lens):
-        return self._step_fn(
-            self.params_tree, np.asarray(tok, np.int32),
-            np.asarray(pos, np.int32), arena_k, arena_v,
-            np.asarray(tables, np.int32), np.asarray(lens, np.int32))
+    def step(self, tok, pos, arenas, tables, starts, lens):
+        return self._step_fn(self.params_tree, tok, pos, arenas, tables,
+                             starts, lens)
 
 
 def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
@@ -576,15 +946,22 @@ def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
 
 
 class TransformerAdapter:
-    """Token-family adapter: packed prefill + paged-KV decode steps.
+    """Token-family adapter: chunked packed prefill + paged-KV decode
+    steps.
 
     The adapter owns nothing on the device: each call lends the cache's
     arenas to the model's executable and rebinds the cache to what comes
     back. What crosses the link is a step's tokens, positions, block
-    table and lengths going up and one picked token and one finite flag
-    a row coming back (``serving_decode_h2d|d2h_bytes_total`` count
-    exactly those). Every served token passes through :meth:`_greedy`
-    on the host.
+    tables and lengths going up and one picked token and one finite flag
+    a row (and a sparse model's three routing sums) coming back
+    (``serving_decode_h2d|d2h_bytes_total`` count exactly those). Every
+    served token passes through :meth:`_greedy` on the host.
+
+    A prompt is prefilled in CHUNKS of ``pack_bucket`` positions through
+    one signature: a chunk is a packed row of whole prompts and at most
+    one later slice of a prompt longer than a chunk, which reads the
+    slices before it from the cache (:meth:`pack_groups`). Only a
+    prompt's last slice yields its first token.
 
     ``prefill_group``/``step`` report per-request outcomes as
     ``(results, failures)`` dicts instead of raising for partial
@@ -598,14 +975,25 @@ class TransformerAdapter:
     kind = "token"
 
     def __init__(self, model: TransformerDecoder, cache: PagedKVCache,
-                 *, pack_bucket: int = 64, check_finite: bool = True):
+                 *, pack_bucket: int = 64, check_finite: bool = True,
+                 max_rows: Optional[int] = None):
         self.model = model
         self.cache = cache
         self.pack_bucket = next_pow2_bucket(pack_bucket)
         if self.pack_bucket > model.max_context:
             raise ValueError("pack_bucket exceeds model max_context")
         self.check_finite = bool(check_finite)
+        self.max_rows = None if max_rows is None else int(max_rows)
+        # a chunk finishes at most as many prompts as the engine admits
+        self.last_width = self.pack_bucket if max_rows is None \
+            else min(self.pack_bucket, next_pow2_bucket(max_rows))
+        bt = cache.block_tokens
+        self._kv_cap = -(-(model.max_context + 1) // bt) * bt
+        self._ctx_widths = {k: cache.table_width(k, self._kv_cap)
+                            for k in cache.kinds}
+        self._prefilling: Dict[int, int] = {}   # rid -> positions cached
         self._link = _register_link_metrics()
+        self._count = _register_model_metrics()
 
     @staticmethod
     def _greedy(picked) -> int:
@@ -621,10 +1009,6 @@ class TransformerAdapter:
         if p.min() < 0 or p.max() >= self.model.vocab:
             raise ValueError(
                 f"prompt tokens outside [0, {self.model.vocab})")
-        if p.size > self.pack_bucket:
-            raise ValueError(
-                f"prompt length {p.size} exceeds pack_bucket "
-                f"{self.pack_bucket}")
         return p.astype(np.int32)
 
     def max_total_len(self, prompt_len: int, max_new: int) -> int:
@@ -632,64 +1016,103 @@ class TransformerAdapter:
 
     # ---------------------------------------------------------------- prefill
     def pack_groups(self, items: List[Tuple[int, np.ndarray]]
-                    ) -> List[List[Tuple[int, np.ndarray]]]:
-        """Greedy first-fit: split (rid, prompt) pairs into groups whose
-        total token count rides one [1, pack_bucket] row."""
-        groups: List[List[Tuple[int, np.ndarray]]] = []
+                    ) -> List[List[Tuple[int, np.ndarray, int, bool]]]:
+        """Split (rid, prompt) pairs into chunks of at most
+        ``pack_bucket`` positions, in the order they must run. A piece
+        is ``(rid, tokens, position of its first token, is the prompt's
+        last)``. A prompt longer than a chunk gives whole chunks of its
+        own and a last piece; the pieces are packed greedy first-fit, a
+        piece with slices before it first in its chunk (one a chunk: it
+        alone reads the cache) and in no chunk before its own slices."""
+        pb = self.pack_bucket
+        groups: List[List[Tuple[int, np.ndarray, int, bool]]] = []
         fill: List[int] = []
         for rid, p in items:
-            for gi, g in enumerate(groups):
-                if fill[gi] + p.size <= self.pack_bucket:
-                    g.append((rid, p))
-                    fill[gi] += p.size
+            lo = 0
+            while p.size - lo > pb:
+                groups.append([(rid, p[lo:lo + pb], lo, False)])
+                fill.append(pb)
+                lo += pb
+            piece = (rid, p[lo:], lo, True)
+            after = len(groups) if lo else 0
+            for gi in range(after, len(groups)):
+                if fill[gi] + piece[1].size <= pb and not (
+                        lo and groups[gi][0][2]):
+                    groups[gi].insert(0, piece) if lo \
+                        else groups[gi].append(piece)
+                    fill[gi] += piece[1].size
                     break
             else:
-                groups.append([(rid, p)])
-                fill.append(p.size)
+                groups.append([piece])
+                fill.append(piece[1].size)
         return groups
 
-    def prefill_group(self, group: List[Tuple[int, np.ndarray]]
-                      ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
-        """Prefill ONE packed group: reserve each rid's KV blocks,
-        splice the prompts into a segment-masked row and run the packed
-        forward, which writes their K/V into those blocks. Returns
-        ``({rid: first token}, {rid: error})`` — a rid whose blocks
-        cannot be granted lands in the failure dict (typed
-        KVCacheExhaustedError) and stays out of the row, without
-        costing groupmates theirs."""
-        pb = self.pack_bucket
-        row = np.zeros((1, pb), np.int32)
-        seg = np.zeros((1, pb), np.int32)
-        pos = np.zeros((1, pb), np.int32)
-        blk = np.full((pb,), self.cache.scratch, np.int32)
-        off = np.zeros((pb,), np.int32)
-        last = np.zeros((pb,), np.int32)
+    def prefill_group(self, group) -> Tuple[Dict[int, int],
+                                            Dict[int, BaseException]]:
+        """Prefill ONE chunk: give each piece its KV blocks, splice the
+        pieces into a segment-masked row and run the chunk's forward,
+        which writes their K/V into those blocks. A ``(rid, prompt)``
+        pair stands for a whole prompt. Returns ``({rid: first token} of
+        the prompts this chunk finished, {rid: error})`` — a rid whose
+        blocks cannot be granted lands in the failure dict (typed
+        KVCacheExhaustedError), gives back what it held and stays out
+        of the row, without costing groupmates theirs; a later piece of
+        a prompt that has failed is passed over."""
+        pb, cache = self.pack_bucket, self.cache
+        row = np.zeros((pb,), np.int32)
+        seg = np.zeros((pb,), np.int32)
+        pos = np.zeros((pb,), np.int32)
+        slots = {k: (np.full((pb,), cache.scratch_of[k], np.int32),
+                     np.zeros((pb,), np.int32)) for k in cache.kinds}
+        ctx_tables = {k: np.full((w,), cache.scratch_of[k], np.int32)
+                      for k, w in self._ctx_widths.items()}
+        ctx_starts = {k: np.int32(0) for k in cache.kinds if k == "sliding"}
+        ctx_len = 0
+        last = np.zeros((self.last_width,), np.int32)
         out: Dict[int, int] = {}
         fails: Dict[int, BaseException] = {}
-        placed: List[Tuple[int, int]] = []  # (rid, prompt tokens)
-        cur = 0
-        for rid, p in group:
-            lo, hi = cur, cur + p.size
-            if hi > pb:
-                raise ValueError("pack group overflows pack_bucket")
+        placed: List[Tuple[int, int, int]] = []  # rid, tokens, `last` index
+        cur = finals = 0
+        for piece in group:
+            rid, p, lo, final = piece if len(piece) == 4 \
+                else (*piece, 0, True)
+            if lo and self._prefilling.get(rid) != lo:
+                continue            # its earlier slice failed
+            if cur + p.size > pb or (lo and cur):
+                raise ValueError("chunk overflows pack_bucket, or a later "
+                                 "slice is not first in it")
             try:
-                blk[lo:hi], off[lo:hi] = self.cache.reserve(rid, p.size)
+                if lo:
+                    before = cache.context(rid, self._ctx_widths)
+                where = cache.extend(rid, lo + p.size)
             except KVCacheExhaustedError as e:
                 fails[rid] = e
+                self.free(rid)
                 continue
-            row[0, lo:hi] = p
-            seg[0, lo:hi] = len(placed) + 1
-            pos[0, lo:hi] = np.arange(p.size)
-            last[len(placed)] = hi - 1
-            placed.append((rid, p.size))
+            if lo:
+                ctx_tables, starts = before
+                ctx_starts = {k: np.int32(starts[k]) for k in ctx_starts}
+                ctx_len = lo
+            hi = cur + p.size
+            for k in cache.kinds:
+                slots[k][0][cur:hi], slots[k][1][cur:hi] = where[k]
+            row[cur:hi] = p
+            seg[cur:hi] = len(placed) + 1
+            pos[cur:hi] = lo + np.arange(p.size)
+            if final:
+                last[finals] = hi - 1
+            placed.append((rid, p.size, finals if final else -1))
+            finals += final
             cur = hi
         if not placed:
             return out, fails
-        up = _nbytes(row, seg, pos, blk, off, last)
+        self._count["chunks"].inc()
+        self._count["chunk_tokens"].inc(cur)
+        up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last) + 4
         with tracing.span("decode/launch", cat="serve", bytes=up):
-            picked, finite = self.cache.update(
-                lambda k, v: self.model.prefill(row, seg, pos, k, v, blk,
-                                                off, last))
+            picked, finite = cache.update(lambda a: self.model.prefill(
+                row, seg, pos, a, slots, ctx_tables, ctx_starts, ctx_len,
+                last))
         self._link["h2d", "prefill"].inc(up)
         with tracing.span("decode/fetch", cat="serve"):
             picked, finite = jax.device_get((picked, finite))
@@ -697,50 +1120,72 @@ class TransformerAdapter:
             tracing.annotate(bytes=down)
         self._link["d2h", "prefill"].inc(down)
         with tracing.span("decode/commit", cat="serve"):
-            for i, (rid, n_tok) in enumerate(placed):
-                if self.check_finite and not finite[i]:
-                    self.cache.free(rid)
+            for rid, n_tok, i in placed:
+                if i >= 0 and self.check_finite and not finite[i]:
+                    self.free(rid)
                     fails[rid] = NonFiniteOutputError(
                         "prefill produced non-finite logits")
                     continue
-                self.cache.advance(rid, n_tok)
-                out[rid] = self._greedy(picked[i])
+                cache.advance(rid, n_tok)
+                if i >= 0:
+                    self._prefilling.pop(rid, None)
+                    out[rid] = self._greedy(picked[i])
+                else:
+                    self._prefilling[rid] = cache.length(rid)
         return out, fails
 
     # ------------------------------------------------------------------- step
+    def row_bucket(self, n: int) -> int:
+        """Rows of the step executable for `n` live rows."""
+        if self.model.row_buckets == "full" and self.max_rows:
+            return next_pow2_bucket(max(n, self.max_rows))
+        return next_pow2_bucket(n)
+
     def kv_bucket(self, rids: Sequence[int]) -> int:
         """View length for this step: room for every row's cache + the
-        token being scattered, snapped to the shared pow2 rule and never
-        below one block (the finite compiled grid)."""
+        token being scattered, snapped to the shared pow2 rule, never
+        below one block and never past the blocks of the model's longest
+        context (the finite compiled grid)."""
         need = max(self.cache.length(r) for r in rids) + 1
-        return max(self.cache.block_tokens, next_pow2_bucket(need))
+        return min(max(self.cache.block_tokens, next_pow2_bucket(need)),
+                   self._kv_cap)
 
     def step(self, rids: Sequence[int], last_items: Sequence[Any]
              ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
         """One iteration-level step for `rids`: rows are padded to the
-        pow2 row bucket with rows of the scratch block at length 0 (a
-        pad row reads and writes nothing a request owns). Returns
+        row bucket with rows of the scratch block at length 0 (a pad row
+        reads and writes nothing a request owns). Returns
         ``({rid: next token}, {rid: error})``."""
-        n = len(rids)
-        bucket = next_pow2_bucket(n)
+        n, cache = len(rids), self.cache
+        bucket = self.row_bucket(n)
         kvb = self.kv_bucket(rids)
         tracing.annotate(row_bucket=bucket, kv_bucket=kvb)
         with tracing.span("decode/gather", cat="serve"):
-            tables, lens, fails = self.cache.batch_view(rids, kvb, bucket)
-            tracing.annotate(bytes=_nbytes(tables, lens))
+            tables, starts, lens, fails = cache.batch_view(rids, kvb, bucket)
+            tracing.annotate(bytes=_nbytes(tables, starts, lens))
         toks = np.zeros((bucket,), np.int32)
         toks[:n] = last_items
         # `lens` goes up twice: as the positions and as the lengths
-        up = _nbytes(toks, lens, tables, lens)
+        up = _nbytes(toks, lens, tables, starts, lens)
         with tracing.span("decode/launch", cat="serve", bytes=up):
-            picked, finite = self.cache.update(
-                lambda k, v: self.model.step(toks, lens, k, v, tables, lens))
+            picked, finite, sums = cache.update(lambda a: self.model.step(
+                toks, lens, a, tables, starts, lens))
         self._link["h2d", "step"].inc(up)
         with tracing.span("decode/fetch", cat="serve"):
-            picked, finite = jax.device_get((picked, finite))
-            down = _nbytes(picked, finite)
+            picked, finite, sums = jax.device_get((picked, finite, sums))
+            down = _nbytes(picked, finite, sums)
             tracing.annotate(bytes=down)
         self._link["d2h", "step"].inc(down)
+        seen = lens[lens > 0].astype(np.int64) + 1      # with its own
+        for k in cache.kinds:
+            self._count["kv_tokens", k].inc(int(
+                (np.minimum(seen, cache.window) if k == "sliding"
+                 else seen).sum()))
+            self._count["block_steps", k].inc(cache.blocks_in_use(k))
+        if sums is not None:    # host integers since the fetch above
+            for name, v in zip(("assignments", "touched", "peak"),
+                               sums.tolist()):  # jaxlint: disable=JL102
+                self._count[name].inc(v)
         out: Dict[int, int] = {}
         with tracing.span("decode/commit", cat="serve"):
             for i, rid in enumerate(rids):
@@ -750,12 +1195,13 @@ class TransformerAdapter:
                     fails[rid] = NonFiniteOutputError(
                         "decode step produced non-finite logits")
                     continue
-                self.cache.advance(rid)
+                cache.advance(rid)
                 out[rid] = self._greedy(picked[i])
         return out, fails
 
     # ------------------------------------------------------------------ admin
     def free(self, rid: int) -> None:
+        self._prefilling.pop(rid, None)
         self.cache.free(rid)
 
     def kv_blocks(self, rid: int) -> int:
@@ -766,40 +1212,45 @@ class TransformerAdapter:
 
     def warm_signatures(self, max_rows: int, max_context: int
                         ) -> Tuple[List[int], List[int]]:
-        """The finite compiled grid: pow2 row buckets × pow2 KV view
-        buckets (block-floored, each a table width; one bucket past
-        max_context so a row AT max_context can still scatter its final
+        """The finite compiled grid: row buckets (pow2, or the one full
+        bucket) × KV view buckets (pow2 from one block up, then the
+        blocks of the model's longest context with room for its last
         token)."""
-        rows, b = [], 1
         top = next_pow2_bucket(max_rows)
+        rows, b = [], 1
         while b <= top:
             rows.append(b)
             b <<= 1
+        if self.model.row_buckets == "full":
+            rows = [self.row_bucket(max_rows)]
         kvs, kv = [], self.cache.block_tokens
-        top_kv = next_pow2_bucket(max_context + 1)
-        while kv <= top_kv:
+        while kv < self._kv_cap:
             kvs.append(kv)
             kv <<= 1
-        return rows, kvs
+        return rows, kvs + [self._kv_cap]
 
     def warm_steps(self, rows: int, kvs: Sequence[int]) -> None:
         """Run the step executable of `rows` rows at each view bucket
-        on the live arena (it is the only one): every row is a pad row,
-        so only the scratch block is written."""
+        on the live arenas (they are the only ones): every row is a pad
+        row, so only the scratch blocks are written."""
         for kv in kvs:
-            tables, zeros, _ = self.cache.batch_view((), kv, rows)
-            self.cache.update(lambda k, v: self.model.step(
-                zeros, zeros, k, v, tables, zeros))
+            tables, starts, zeros, _ = self.cache.batch_view((), kv, rows)
+            self.cache.update(lambda a: self.model.step(
+                zeros, zeros, a, tables, starts, zeros))
 
     def warmup(self, max_rows: int, max_context: int) -> List[int]:
-        """Precompile the packed prefill signature and every step
+        """Precompile the chunk's prefill signature and every step
         signature in the grid. Returns the row buckets warmed."""
-        pb = self.pack_bucket
-        row = np.zeros((1, pb), np.int32)
+        pb, cache = self.pack_bucket, self.cache
         at = np.zeros((pb,), np.int32)
-        scratch = np.full((pb,), self.cache.scratch, np.int32)
-        self.cache.update(lambda k, v: self.model.prefill(
-            row, row, row, k, v, scratch, at, at))
+        slots = {k: (np.full((pb,), cache.scratch_of[k], np.int32), at)
+                 for k in cache.kinds}
+        ctx = {k: np.full((w,), cache.scratch_of[k], np.int32)
+               for k, w in self._ctx_widths.items()}
+        starts = {k: np.int32(0) for k in cache.kinds if k == "sliding"}
+        cache.update(lambda a: self.model.prefill(
+            at, at, at, a, slots, ctx, starts, 0,
+            np.zeros((self.last_width,), np.int32)))
         rows, kvs = self.warm_signatures(max_rows, max_context)
         for b in rows:
             self.warm_steps(b, kvs)
@@ -1279,23 +1730,32 @@ class DecodeEngine:
         self._retire_done()
 
     def _prefill_groups(self, live: List[_DecodeRequest]) -> int:
-        """Prefill `live` group by group; the number that joined the
+        """Prefill `live` chunk by chunk, one decode step of the active
+        set between chunks (a row's token gap is bounded by one chunk,
+        not by a newcomer's whole prompt); the number that joined the
         active set."""
         admitted = 0
         by_rid = {r.rid: r for r in live}
-        for group in self.adapter.pack_groups(
-                [(r.rid, r.prompt) for r in live]):
-            reqs = [by_rid[rid] for rid, _ in group]
-            for r in reqs:
-                if r.trace is not None:
+        for gi, group in enumerate(self.adapter.pack_groups(
+                [(r.rid, r.prompt) for r in live])):
+            reqs = [by_rid[piece[0]] for piece in group
+                    if not by_rid[piece[0]].event.is_set()]
+            if not reqs:
+                continue        # every prompt of the chunk has failed
+            if gi and self._active:
+                self._step_once()
+            for piece in group:     # a prompt's first piece ends its wait
+                r = by_rid[piece[0]]
+                if r.trace is not None and r in reqs and not (
+                        len(piece) == 4 and piece[2]):
                     r.trace.mark("queue_wait")
             try:
                 with self._lock, self._sched_slot(cost=float(len(group))):
                     t0 = time.perf_counter()
-                    with self._span("decode/prefill", reqs):
+                    with self._span("decode/prefill", reqs, chunk=gi):
                         if tracing.is_enabled():
                             tracing.annotate(tokens=sum(
-                                int(p.shape[0]) for _, p in group))
+                                int(piece[1].shape[0]) for piece in group))
                         first, fails = self.adapter.prefill_group(group)
                     dur = time.perf_counter() - t0
             except Exception as e:  # noqa: BLE001 — typed wrapper below

@@ -395,7 +395,7 @@ class ModelPool:
                    max_context: Optional[int] = None,
                    pack_bucket: int = 64,
                    kv_block_tokens: int = 16,
-                   kv_max_blocks: int = 256,
+                   kv_max_blocks=256,
                    feature_dim: Optional[int] = None,
                    check_finite: bool = True,
                    breaker: Optional[CircuitBreaker] = None,
@@ -409,8 +409,12 @@ class ModelPool:
 
         The model family picks the adapter: a
         :class:`~.decode.TransformerDecoder` decodes through the
-        packed-prefill + paged-KV token arm (`pack_bucket`,
-        `kv_block_tokens`, `kv_max_blocks` size that plane); a streaming
+        chunked-prefill + paged-KV token arm (`pack_bucket` is the
+        prefill chunk, `kv_block_tokens` the block, `kv_max_blocks` the
+        blocks of each kind of layer's arena: one number for every
+        kind, or ``{"full": n, "sliding": m}``; the cache takes the
+        model's KV heads, dtype, pattern of layer kinds and window); a
+        streaming
         network exposing ``rnn_time_step`` decodes through the
         recurrent arm (`feature_dim` is its per-step input width —
         required, and the net's ``n_out`` must equal it, since the
@@ -431,12 +435,14 @@ class ModelPool:
             checkpoints = CheckpointManager(checkpoints)
         if isinstance(model, TransformerDecoder):
             cache = PagedKVCache(
-                layers=model.n_layers, heads=model.heads,
-                head_dim=model.head_dim,
+                layers=model.n_layers, heads=model.kv_heads,
+                head_dim=model.head_dim, dtype=model.dtype,
+                layer_kinds=model.layer_kinds(), window=model.window,
                 block_tokens=kv_block_tokens, max_blocks=kv_max_blocks)
             adapter = TransformerAdapter(model, cache,
                                          pack_bucket=pack_bucket,
-                                         check_finite=check_finite)
+                                         check_finite=check_finite,
+                                         max_rows=max_decode_batch)
         elif hasattr(model, "rnn_time_step"):
             if feature_dim is None:
                 raise ValueError(

@@ -1088,7 +1088,9 @@ class TestBoundaries:
         ("serving/decode.py", "_prefill_pure"),
         ("serving/decode.py", "_step_pure"),
         ("quantize/quantize.py", "dense_qforward"),
-        ("ops/flash_attention.py", "decode_attention"),
+        ("ops/flash_attention.py", "paged_decode_attention"),
+        ("ops/flash_attention.py", "prefill_attention"),
+        ("ops/moe.py", "expert_ffn"),
     ])
     def test_post_pr5_jit_surface_reachable(self, relpath, surface):
         """Each post-PR-5 serving jit surface is seen by boundary

@@ -114,9 +114,12 @@ class TestNoSilentKernelFallback:
         assert fa.flash_attention_available() is False
         assert att.select_attention_impl(4096, 128) == "blockwise"
         q, k, v = self._qkv()
-        out = fa.decode_attention(q[:, :1], k, v,
-                                  jnp.asarray([7], jnp.int32))
-        assert out.shape == (1, 1, 2, 32)
+        arena = k.reshape(1, 16, 16, 64)            # 16 blocks of 16
+        out = fa.paged_decode_attention(
+            q[0, :1], k[0, 7].reshape(1, 64), v[0, 7].reshape(1, 64), arena,
+            arena, 0, jnp.zeros((1, 1), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jnp.asarray([7], jnp.int32))
+        assert out.shape == (1, 2, 32)
 
     def test_flash_refusal_propagates_on_tpu(self, monkeypatch):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

@@ -1,5 +1,5 @@
 """Decode-plane tests (docs/serving.md §decode): paged KV cache
-arithmetic, decode_attention parity, adapter packing/validation, the
+arithmetic, paged_decode_attention parity, adapter packing/validation, the
 typed rnn_time_step state-reset contract, scoreboard row-kind schema,
 and (slow) engine end-to-end parity / chaos isolation."""
 import threading
@@ -13,7 +13,7 @@ from deeplearning4j_tpu import (LSTM, ComputationGraph, InputType,
                                 RnnOutputLayer, Sgd)
 from deeplearning4j_tpu.data.padding import next_pow2_bucket
 from deeplearning4j_tpu.nn.multilayer import RnnStateMismatchError
-from deeplearning4j_tpu.ops.flash_attention import decode_attention
+from deeplearning4j_tpu.ops.flash_attention import paged_decode_attention
 from deeplearning4j_tpu.optimize.scoreboard import _validate_row_kind
 from deeplearning4j_tpu.optimize.telemetry import CompilationTracker
 from deeplearning4j_tpu.optimize.metrics import registry
@@ -44,7 +44,14 @@ def _decoder(layers=2, heads=2, head_dim=4, **kw):
 
 
 def _arenas(cache):
-    return tuple(np.asarray(a) for a in cache.arenas())
+    return tuple(np.asarray(a) for a in cache.arenas()["full"])
+
+
+def _view(cache, *args):
+    """A one-kind cache's step view: (the full table, lengths, starved)."""
+    tables, starts, lens, starved = cache.batch_view(*args)
+    assert list(tables) == ["full"] and not starts
+    return tables["full"], lens, starved
 
 
 class TestPagedKVCache:
@@ -58,8 +65,8 @@ class TestPagedKVCache:
         assert _cache(block_tokens=12, max_blocks=2).block_tokens == 16
         # one block past max_blocks that no request can own; a token's
         # heads lie side by side on the last axis
-        assert c.scratch == 8
-        assert [a.shape for a in c.arenas()] == [(2, 9, 16, 8)] * 2
+        assert c.scratch_of["full"] == 8
+        assert [a.shape for a in c.arenas()["full"]] == [(2, 9, 16, 8)] * 2
 
     def test_prefill_and_step_scatter_equal_a_numpy_model_of_the_arena(self):
         """The device arena after a packed prefill and three steps, and the
@@ -88,15 +95,16 @@ class TestPagedKVCache:
             seg = np.zeros((1, 16), np.int32)
             pos = np.zeros((1, 16), np.int32)
             row[0, :n], seg[0, :n], pos[0, :n] = seq[:n], 1, np.arange(n)
-            _, ks, vs = m._packed_forward(m.params_tree, row, seg, pos)
-            tables, lens, starved = c.batch_view([r], 16)
+            _, new = m._chunk_forward(m.params_tree, row[0], seg[0], pos[0])
+            ks, vs = (np.stack(a) for a in new["full"])
+            tables, lens, starved = _view(c, [r], 16)
             assert lens.tolist() == [n] and not starved
             for t in range(n):
                 blk, off = tables[0, t // 4], t % 4
                 want_k[:, blk, off] = np.asarray(ks)[:, t]
                 want_v[:, blk, off] = np.asarray(vs)[:, t]
-        owned = sorted(b for r in seqs for b in c.batch_view([r], 16)[0][0]
-                       if b != c.scratch)
+        owned = sorted(b for r in seqs for b in _view(c, [r], 16)[0][0]
+                       if b != c.scratch_of["full"])
         assert len(owned) == 5
         # two executables of different shape agree to rounding, not bitwise
         np.testing.assert_allclose(got_k[:, owned], want_k[:, owned],
@@ -113,15 +121,15 @@ class TestPagedKVCache:
         assert c.blocks_in_use() == 0 and c.length(1) == 0
         # a failed GROW leaves the existing table intact, names the rid,
         # and its row rides the step as a pad row
-        blk, off = c.reserve(2, 8)
+        blk, off = c.reserve(2, 8)["full"]
         assert blk.tolist() == [blk[0]] * 4 + [blk[4]] * 4
         assert off.tolist() == [0, 1, 2, 3] * 2
         c.advance(2, 8)
         assert c.free_blocks() == 0
-        tables, lens, starved = c.batch_view([2], 16)
+        tables, lens, starved = _view(c, [2], 16)
         assert isinstance(starved[2], KVCacheExhaustedError)
         assert c.length(2) == 8 and c.blocks_of(2) == 2
-        assert (tables == c.scratch).all() and lens.tolist() == [0]
+        assert (tables == c.scratch_of["full"]).all() and lens.tolist() == [0]
         with pytest.raises(ValueError):
             c.reserve(2, 1)  # already cached
 
@@ -138,39 +146,57 @@ class TestPagedKVCache:
         c.reserve(1, 2)
         c.advance(1, 2)
         with pytest.raises(ValueError):
-            c.batch_view([1], 6)
-        tables, lens, starved = c.batch_view([1], 8, 2)
+            _view(c, [1], 6)
+        tables, lens, starved = _view(c, [1], 8, 2)
         assert tables.dtype == lens.dtype == np.int32 and not starved
         assert tables.shape == (2, 2) and lens.tolist() == [2, 0]
         # entries past a row's own blocks, and pad rows, are the scratch
-        assert tables[0, 0] != c.scratch
-        assert tables[0, 1] == tables[1, 0] == tables[1, 1] == c.scratch
+        assert tables[0, 0] != c.scratch_of["full"]
+        assert tables[0, 1] == tables[1, 0] == tables[1, 1] == c.scratch_of["full"]
 
 
 class TestDecodeAttention:
     @pytest.mark.parametrize("tk", [8, 16])
     def test_matches_masked_softmax_reference(self, tk):
+        """A row's cached keys, scattered over the arena's blocks, and its
+        own new token, against a numpy softmax over them in order."""
         rng = np.random.default_rng(1)
-        b, h, d = 3, 2, 8
-        q = rng.standard_normal((b, 1, h, d)).astype(np.float32)
-        k = rng.standard_normal((b, tk, h, d)).astype(np.float32)
-        v = rng.standard_normal((b, tk, h, d)).astype(np.float32)
-        lens = np.array([1, tk // 2, tk], np.int32)
-        out = np.asarray(decode_attention(q, k, v, lens))
-        assert out.shape == (b, 1, h, d)
+        b, h, d, bt = 3, 2, 8, 4
+        q = rng.standard_normal((b, h, d)).astype(np.float32)
+        k = rng.standard_normal((b, tk + 1, h, d)).astype(np.float32)
+        v = rng.standard_normal((b, tk + 1, h, d)).astype(np.float32)
+        lens = np.array([0, tk // 2, tk], np.int32)   # cached, own apart
+        w = tk // bt
+        tables = rng.permutation(b * w).astype(np.int32).reshape(b, w)
+        arena_k = np.full((1, b * w + 1, bt, h * d), np.nan, np.float32)
+        arena_v = np.full_like(arena_k, np.nan)
         for i in range(b):
-            n = lens[i]
+            for t in range(lens[i]):
+                arena_k[0, tables[i, t // bt], t % bt] = k[i, t].ravel()
+                arena_v[0, tables[i, t // bt], t % bt] = v[i, t].ravel()
+        new = lambda a: np.stack([a[i, lens[i]].ravel() for i in range(b)])
+        out = np.asarray(paged_decode_attention(
+            q, new(k), new(v), arena_k, arena_v, 0, tables,
+            np.zeros((b,), np.int32), lens))
+        assert out.shape == (b, h, d) and np.isfinite(out).all()
+        for i in range(b):
+            n = lens[i] + 1
             for hh in range(h):
-                s = q[i, 0, hh] @ k[i, :n, hh].T / np.sqrt(d)
-                w = np.exp(s - s.max())
-                w /= w.sum()
-                np.testing.assert_allclose(out[i, 0, hh], w @ v[i, :n, hh],
+                s = q[i, hh] @ k[i, :n, hh].T / np.sqrt(d)
+                p = np.exp(s - s.max())
+                p /= p.sum()
+                np.testing.assert_allclose(out[i, hh], p @ v[i, :n, hh],
                                            rtol=1e-4, atol=1e-5)
 
-    def test_rejects_multi_query_rows(self):
-        z = np.zeros((1, 2, 1, 4), np.float32)
+    def test_rejects_heads_that_do_not_share_the_arenas_row(self):
+        z = np.zeros((1, 3, 4), np.float32)         # 3 heads over 2 KV heads
+        arena = np.zeros((1, 2, 4, 8), np.float32)
         with pytest.raises(ValueError):
-            decode_attention(z, z, z, np.ones(1, np.int32))
+            paged_decode_attention(z, z[:, 0, :].repeat(2, -1),
+                                   z[:, 0, :].repeat(2, -1), arena, arena, 0,
+                                   np.zeros((1, 1), np.int32),
+                                   np.zeros(1, np.int32),
+                                   np.ones(1, np.int32))
 
 
 def _stream_net(n_in=4, seed=3):
@@ -234,19 +260,24 @@ class TestTransformerAdapter:
         a = self._adapter()
         np.testing.assert_array_equal(a.validate_prompt([1, 2, 3]),
                                       np.array([1, 2, 3], np.int32))
-        for bad in ([], [[1, 2]], [5, 99], [-1, 2], list(range(17))):
+        for bad in ([], [[1, 2]], [5, 99], [-1, 2]):
             with pytest.raises(ValueError):
                 a.validate_prompt(bad)
+        # longer than a chunk is no fault: it is prefilled in slices
+        long = a.validate_prompt(list(range(17)))
+        assert [(lo, p.size, final) for _, p, lo, final in
+                sum(a.pack_groups([(0, long)]), [])] == \
+            [(0, 16, False), (16, 1, True)]
 
     def test_pack_groups_first_fit(self):
         a = self._adapter(pack_bucket=16)
         items = [(i, np.zeros(n, np.int32))
                  for i, n in enumerate([10, 7, 5, 16, 1])]
         groups = a.pack_groups(items)
-        packed = sorted(r for g in groups for r, _ in g)
+        packed = sorted(r for g in groups for r, *_ in g)
         assert packed == [0, 1, 2, 3, 4]  # nobody dropped
         for g in groups:
-            assert sum(p.size for _, p in g) <= 16
+            assert sum(p.size for _, p, *_ in g) <= 16
         # 10+5+1 share a row, 7 and 16 ride alone -> 3 rows, not 5
         assert len(groups) == 3
 
@@ -318,7 +349,7 @@ def test_pad_rows_and_warm_up_write_only_the_scratch_block(what):
     assert not fails
     before = _arenas(c)
     in_use = c.blocks_in_use()
-    may = {(c.scratch, off) for off in range(BT)}
+    may = {(c.scratch_of["full"], off) for off in range(BT)}
     if what == "warmup":
         ad.warmup(4, 64)
     elif what == "swap_warm":
@@ -329,12 +360,12 @@ def test_pad_rows_and_warm_up_write_only_the_scratch_block(what):
         out, fails = ad.step([0, 1, 2], [first[r] for r in (0, 1, 2)])
         assert not fails and sorted(out) == [0, 1, 2]
         for r in (0, 1, 2):
-            tables, lens, _ = c.batch_view([r], 16)
+            tables, lens, _ = _view(c, [r], 16)
             n = int(lens[0]) - 1           # the slot this step filled
             may.add((int(tables[0, n // BT]), n % BT))
     changed = _changed_slots(before, _arenas(c))
     assert changed <= may
-    assert (what == "pad_rows") == bool(changed - {(c.scratch, 0)})
+    assert (what == "pad_rows") == bool(changed - {(c.scratch_of["full"], 0)})
     assert c.blocks_in_use() == in_use     # the scratch block is not counted
 
 
@@ -346,8 +377,9 @@ def test_a_non_finite_row_fails_alone_and_its_blocks_poison_no_one():
         first, fails = ad.prefill_group(list(enumerate(ps)))
         assert not fails
         if poison:
-            victim = c.batch_view([0], 8)[0][0, 0]
-            c.update(lambda k, v: (k.at[:, victim].set(np.nan), v))
+            victim = _view(c, [0], 8)[0][0, 0]
+            c.update(lambda a: ({"full": (
+                a["full"][0].at[:, victim].set(np.nan), a["full"][1])},))
         toks = {r: [first[r]] for r in first}
         errs = {}
         for _ in range(3):
@@ -381,12 +413,12 @@ def test_a_step_run_again_at_the_same_lengths_rewrites_the_same_slots():
     ad = _adapter()
     c, m = ad.cache, ad.model
     first, _ = ad.prefill_group(list(enumerate(_prompts((6, 8)))))
-    tables, lens, _ = c.batch_view([0, 1], 16)   # request 1 grows here
+    tables, lens, _ = _view(c, [0, 1], 16)   # request 1 grows here
     toks = np.asarray([first[0], first[1]], np.int32)
     runs = []
     for _ in range(2):
-        picked, finite = c.update(
-            lambda k, v: m.step(toks, lens, k, v, tables, lens))
+        picked, finite, _ = c.update(
+            lambda a: m.step(toks, lens, a, {"full": tables}, {}, lens))
         runs.append((np.asarray(picked).tolist(), _arenas(c)))
     assert runs[0][0] == runs[1][0]
     assert not _changed_slots(runs[0][1], runs[1][1])
@@ -400,8 +432,10 @@ def test_link_bytes_are_the_formula_over_the_shapes(riders):
     h0, d0 = _link("h2d", "prefill"), _link("d2h", "prefill")
     first, _ = ad.prefill_group(list(enumerate(ps)))
     # the packed row, its segments and positions; a block, an offset and
-    # a last position a slot. Back: an int32 token and a flag a slot
-    assert _link("h2d", "prefill") - h0 == 6 * PACK * 4
+    # a last position a slot; the context's table and its length. Back:
+    # an int32 token and a flag a slot
+    assert _link("h2d", "prefill") - h0 == \
+        (6 * PACK + ad._ctx_widths["full"] + 1) * 4
     assert _link("d2h", "prefill") - d0 == PACK * 5
     h0, d0 = _link("h2d", "step"), _link("d2h", "step")
     rids = list(range(riders))
@@ -416,25 +450,27 @@ def test_link_bytes_are_the_formula_over_the_shapes(riders):
 def test_the_arenas_are_donated_and_the_modules_keep_their_names(which):
     ad = _adapter(max_blocks=4)
     c, m = ad.cache, ad.model
-    k, v = c.arenas()
+    arenas = c.arenas()
+    k, v = arenas["full"]
     i32 = lambda *shape: np.zeros(shape, np.int32)
     if which == "step":
-        tables, lens, _ = c.batch_view((), 16, 2)
-        lowered = m._step_fn.lower(m.params_tree, lens, lens, k, v, tables,
-                                   lens)
-        out = m.step(lens, lens, k, v, tables, lens)
+        tables, starts, lens, _ = c.batch_view((), 16, 2)
+        args = (lens, lens, arenas, tables, starts, lens)
+        lowered = m._step_fn.lower(m.params_tree, *args)
+        out = m.step(*args)
     else:
-        blk = np.full((PACK,), c.scratch, np.int32)
-        lowered = m._prefill_fn.lower(
-            m.params_tree, i32(1, PACK), i32(1, PACK), i32(1, PACK), k, v,
-            blk, i32(PACK), i32(PACK))
-        out = m.prefill(i32(1, PACK), i32(1, PACK), i32(1, PACK), k, v,
-                        blk, i32(PACK), i32(PACK))
+        scratch = c.scratch_of["full"]
+        args = (i32(PACK), i32(PACK), i32(PACK), arenas,
+                {"full": (np.full((PACK,), scratch, np.int32), i32(PACK))},
+                {"full": np.full((ad._ctx_widths["full"],), scratch,
+                                 np.int32)}, {}, np.int32(0), i32(PACK))
+        lowered = m._prefill_fn.lower(m.params_tree, *args)
+        out = m.prefill(*args)
     # the benchmark's device metrics find the runs by these names
     assert f"module @jit__{which}_pure" in lowered.as_text()
     assert "input_output_alias" in lowered.compile().as_text()
     assert k.is_deleted() and v.is_deleted()
-    assert out[2].shape == k.shape and out[3].shape == v.shape
+    assert [a.shape for a in out[-1]["full"]] == [k.shape, v.shape]
     assert out[0].dtype == np.int32 and out[1].dtype == np.bool_
 
 

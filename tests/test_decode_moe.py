@@ -1,0 +1,270 @@
+"""The decoder set to a sparse, windowed block (RMS norm, rotary with a
+plain and a YaRN table, grouped KV heads, 8 SwiGLU experts with 2 a
+token, layers in the pattern S S S F, window 8) against the plain
+reference of `benchmark/reference/mellum2.py`, at a small size on the
+CPU with seeded random weights: chunked prefill and decoding through the
+two kinds of cache, the share of the experts a chip holds, the rotary
+tables at the published numbers, and the sliding layers' blocks."""
+import math
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import manifest
+from benchmark.models import mellum2 as builder
+from benchmark.models import seed_key
+from benchmark.reference import mellum2 as ref
+from deeplearning4j_tpu.ops import flash_attention as fa
+from deeplearning4j_tpu.ops import moe
+from deeplearning4j_tpu.parallel.inference import KVCacheExhaustedError
+from deeplearning4j_tpu.serving.decode import (DecodeEngine, PagedKVCache,
+                                               TransformerAdapter,
+                                               rope_inv_freq)
+
+PUBLISHED = manifest.data_file("configs", "mellum2-12b-a2.5b-instruct")
+WINDOW, CHUNK, BT, PAD = 8, 16, 4, 1024
+TINY = dict(PUBLISHED, hidden_size=32, head_dim=8, num_attention_heads=4,
+            num_key_value_heads=2, moe_intermediate_size=16, num_experts=8,
+            num_experts_per_tok=2, vocab_size=96, sliding_window=WINDOW,
+            num_hidden_layers=8, max_context=80, init_std=0.25,
+            dtype="float32")
+# Two float32 programs of different shape (chunks and a cache here, one
+# whole sequence there) agree to rounding: logits some units wide, sums of
+# a few hundred terms. The bfloat16 control must not pass it.
+TOL = 2e-4
+
+
+def _served(cfg, seed, prompts, new=6):
+    """Serve `prompts` through chunked prefill and the cache, all rows
+    in one batch. -> ({rid: tokens}, cache, adapter)."""
+    model = builder.build(cfg, seed)
+    cache = PagedKVCache(
+        layers=model.n_layers, heads=model.kv_heads, head_dim=model.head_dim,
+        dtype=model.dtype, layer_kinds=model.layer_kinds(),
+        window=model.window, block_tokens=BT,
+        max_blocks={"full": 96, "sliding": 24})
+    ad = TransformerAdapter(model, cache, pack_bucket=CHUNK,
+                            max_rows=len(prompts))
+    first = {}
+    for group in ad.pack_groups(list(prompts.items())):
+        got, fails = ad.prefill_group(group)
+        assert not fails
+        first.update(got)
+    out = {r: [first[r]] for r in prompts}
+    for _ in range(new - 1):
+        got, fails = ad.step(list(prompts), [out[r][-1] for r in prompts])
+        assert not fails
+        for r in prompts:
+            out[r].append(got[r])
+    return out, cache, ad
+
+
+# (a) shorter than the window; longer than it; ending on a chunk's edge and
+# off it; longer than two chunks
+@pytest.mark.parametrize("lengths", [(5, 12), (16, 21), (32, 41, 7)])
+def test_chunked_prefill_and_cached_decoding_agree_with_the_reference(
+        lengths):
+    rng = np.random.default_rng(sum(lengths))
+    prompts = {i: rng.integers(0, TINY["vocab_size"], n).astype(np.int32)
+               for i, n in enumerate(lengths)}
+    served, cache, _ = _served(TINY, 11, prompts)
+    weights = builder.make_weights(11, TINY)
+    seqs = [(prompts[r].tolist(), served[r]) for r in prompts]
+    gaps = ref.served_gaps(weights, 0, seqs, 0, lowp="bfloat16")
+    for (gap, control), (prompt, _) in zip(gaps, seqs):
+        # every served token is the reference's best, or level with it
+        assert gap.max() <= TOL, (len(prompt), gap)
+    # the nearest precision below fails the same tolerance somewhere
+    assert max(c.max() for _, c in gaps) > TOL
+    for r, p in prompts.items():           # what a sliding layer still holds
+        n = len(p) + len(served[r]) - 1
+        assert cache.length(r) == n
+        assert cache.held_from(r) == max(0, (n - WINDOW + 1) // BT) * BT
+
+
+def test_the_plain_forward_gives_the_references_logits():
+    rng = np.random.default_rng(3)
+    model = builder.build(TINY, 5)
+    t = 37
+    toks = rng.integers(0, TINY["vocab_size"], t)
+    row, seg, pos = (np.zeros((1, 48), np.int32) for _ in range(3))
+    row[0, :t], seg[0, :t], pos[0, :t] = toks, 1, np.arange(t)
+    got = np.asarray(model.logits(row, seg, pos))[0, :t]
+    padded = jnp.zeros((PAD,), jnp.int32).at[:t].set(jnp.asarray(toks))
+    want = np.asarray(ref.forward_all(seed_key(5), TINY, [padded],
+                                      [slice(0, t)])[0])
+    assert want.std() > 0.5                  # logits some units wide
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+# (b) the guide's share test: what shares of 2 of the 8 experts give adds
+# up to the uncut reference's layer
+def test_the_parts_that_shares_of_the_experts_give_add_up_to_the_layer():
+    key, rng = seed_key(9), np.random.default_rng(9)
+    x = jnp.asarray(rng.normal(size=(24, TINY["hidden_size"])), jnp.float32)
+    whole = ref.init_layer(key, 3, TINY)
+    uncut = ref.layer_forward(x, whole, TINY, "full")
+    # the layer with no expert's part: the residual after attention
+    after_attention = ref.layer_forward(
+        x, dict(whole, wd=jnp.zeros_like(whole["wd"])), TINY, "full")
+    h = ref._rms(after_attention, whole["ln2_s"].astype(jnp.float32),
+                 TINY["rms_norm_eps"])
+    w, idx = moe.route(h, whole["wr"].astype(jnp.float32), 2)
+    parts_ref, parts_prog, assigned = [], [], 0
+    for lo in range(0, 8, 2):
+        share = dict(TINY, experts_held=[lo, lo + 1])
+        lp = ref.init_layer(key, 3, share)
+        for name in ("wg", "wu", "wd"):      # a share holds the same values
+            np.testing.assert_array_equal(lp[name], whole[name][lo:lo + 2])
+        parts_ref.append(ref.layer_forward(x, lp, share, "full")
+                         - after_attention)
+        f32 = {k: lp[k].astype(jnp.float32) for k in ("wg", "wu", "wd")}
+        y, sums = moe.expert_ffn(h, w, idx, f32["wg"], f32["wu"], f32["wd"],
+                                 n_experts=8, experts_held=(lo, lo + 1))
+        parts_prog.append(y)
+        assigned += int(sums[0])
+    assert assigned == 24 * 2                # every assignment, once
+    for parts in (parts_ref, parts_prog):
+        np.testing.assert_allclose(after_attention + sum(parts), uncut,
+                                   atol=TOL, rtol=0)
+    assert float(jnp.abs(parts_prog[0]).max()) > 0.01   # no share is idle
+
+
+# (c) the tables at the published numbers, against the formulas written out
+def test_the_rotary_tables_are_the_published_ones():
+    rp = PUBLISHED["rope_parameters"]
+    i = np.arange(64, dtype=np.float64)
+    plain = 500000.0 ** (-2 * i / 128)
+    inv, factor = rope_inv_freq(128, rp["sliding_attention"])
+    np.testing.assert_allclose(inv, plain, rtol=1e-6)
+    assert factor == 1.0
+    dim = lambda b: 128 * math.log(8192 / (2 * math.pi * b)) \
+        / (2 * math.log(500000.0))
+    low, high = math.floor(dim(32)), math.ceil(dim(1))
+    assert (low, high) == (18, 35)
+    r = np.clip((i - low) / (high - low), 0, 1)
+    yarn = plain * (1 - r) + plain / 16 * r
+    inv, factor = rope_inv_freq(128, rp["full_attention"])
+    np.testing.assert_allclose(inv, yarn, rtol=1e-6)
+    assert factor == 1.2772588722239782 == 0.1 * math.log(16) + 1
+    # fast dimensions as published, slow ones a sixteenth; the reference's
+    # own table is the same one
+    np.testing.assert_allclose(inv[:19], plain[:19], rtol=1e-6)
+    np.testing.assert_allclose(inv[35:], plain[35:] / 16, rtol=1e-6)
+    for kind, want in (("sliding", plain), ("full", yarn)):
+        got, _ = ref.rope_table(PUBLISHED, kind)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-6)
+
+
+# (d) a sliding layer's blocks are given back behind the window
+def test_a_sliding_table_gives_back_the_blocks_behind_its_window():
+    c = PagedKVCache(layers=4, heads=1, head_dim=2, block_tokens=4,
+                     max_blocks={"full": 16, "sliding": 6},
+                     layer_kinds=["sliding", "sliding", "sliding", "full"],
+                     window=8)
+    assert [a.shape[0] for a, _ in c.arenas().values()] == [1, 3]
+    where = c.reserve(1, 10)
+    assert c.blocks_in_use("full") == c.blocks_in_use("sliding") == 3
+    assert where["sliding"][1].tolist() == [0, 1, 2, 3] * 2 + [0, 1]
+    c.advance(1, 10)         # positions 0-2 are 8 or more behind: not yet a
+    assert c.held_from(1) == 0 and c.blocks_in_use("sliding") == 3  # block
+    c.extend(1, 12)
+    c.advance(1, 2)          # length 12: block 0 (0-3) lies behind 12 - 8
+    assert c.held_from(1) == 4 and c.blocks_in_use("sliding") == 2
+    assert c.blocks_in_use("full") == 3
+    tables, starts, lens, starved = c.batch_view([1], 16)
+    assert not starved and lens.tolist() == [12]
+    assert starts["sliding"].tolist() == [4]
+    assert tables["full"].shape == (1, 4) and tables["sliding"].shape == (1, 3)
+    # all or nothing across kinds: the sliding arena cannot take 7 blocks,
+    # so the full one gives none either
+    with pytest.raises(KVCacheExhaustedError):
+        c.reserve(2, 28)
+    assert c.blocks_in_use("full") == 4 and c.length(2) == 0
+    c.free(1)
+    assert c.blocks_in_use() == 0 and c.free_blocks() == 22
+
+
+def test_the_engine_drains_both_kinds_and_steps_between_chunks():
+    model = builder.build(TINY, 2)
+    cache = PagedKVCache(
+        layers=8, heads=2, head_dim=8, layer_kinds=model.layer_kinds(),
+        window=WINDOW, block_tokens=BT,
+        max_blocks={"full": 96, "sliding": 24})
+    ad = TransformerAdapter(model, cache, pack_bucket=CHUNK, max_rows=2)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 96, n).tolist() for n in (6, 50, 20, 33)]
+    with DecodeEngine(ad, max_decode_batch=2) as eng:
+        eng.warmup()
+        peak = []
+        real = ad.step
+
+        def watched(rids, items):
+            peak.append(cache.blocks_in_use("sliding"))
+            return real(rids, items)
+
+        ad.step = watched
+        import threading
+        out = {}
+        ts = [threading.Thread(target=lambda i=i, p=p: out.__setitem__(
+            i, eng.generate(p, max_new_tokens=8)))
+            for i, p in enumerate(prompts)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+    assert sorted(out) == [0, 1, 2, 3] and all(len(v) == 8
+                                               for v in out.values())
+    # two rows of a window and a block of slack, and one prompt in
+    # prefill at a window and a chunk
+    assert max(peak) <= 2 * (WINDOW // BT + 1) + (WINDOW + CHUNK) // BT + 1
+    assert cache.blocks_in_use() == 0
+    weights = builder.make_weights(2, TINY)
+    gaps = ref.served_gaps(weights, 0, [(prompts[i], out[i]) for i in out],
+                           0)
+    assert max(g.max() for g, _ in gaps) <= TOL
+
+
+# the kernels themselves, interpreted: grouped heads, the window, the table
+@pytest.mark.parametrize("window", [None, 12])
+def test_the_paged_decode_kernel_agrees_with_its_dense_arm(window):
+    rng = np.random.default_rng(0)
+    rows, hh, kvh, d, bt, w, blocks = 3, 8, 2, 16, 8, 4, 12
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    ak, av = arr(2, blocks, bt, kvh * d), arr(2, blocks, bt, kvh * d)
+    ak = ak.at[:, 11].set(jnp.nan)          # a freed block's leavings
+    args = (arr(rows, hh, d), arr(rows, kvh * d), arr(rows, kvh * d), ak, av,
+            1, jnp.asarray(rng.permutation(11)[:rows * w - 1].tolist() + [11],
+                           jnp.int32).reshape(rows, w),
+            jnp.asarray([0, 8, 16], jnp.int32) if window else
+            jnp.zeros((rows,), jnp.int32),
+            # row 2's last table entry is the block of NaNs, past its length
+            jnp.asarray([5, 29, 16 + 9 if window else 23], jnp.int32))
+    got = fa.paged_decode_attention(*args, window=window, impl="paged",
+                                    interpret=True)
+    want = fa.paged_decode_attention(*args, window=window, impl="dense")
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_the_prefill_kernel_agrees_with_its_dense_arm(window):
+    rng = np.random.default_rng(1)
+    tq, n_ctx, hh, kvh, d, ctx_len, start = 16, 16, 4, 2, 8, 11, 3
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    true = start + np.arange(n_ctx)
+    real = true < ctx_len
+    seg = np.where(np.arange(tq) < 9, 1, 2)
+    seg[-2:] = 0
+    kw = dict(
+        q_pos=jnp.arange(tq), q_seg=jnp.asarray(seg), window=window,
+        kv_pos=jnp.asarray(np.concatenate([np.where(real, true - ctx_len,
+                                                    1 << 30),
+                                           np.arange(tq)])),
+        kv_seg=jnp.asarray(np.concatenate([np.where(real, 1, -1), seg])))
+    q, k, v = arr(tq, hh, d), arr(tq + n_ctx, kvh, d), arr(tq + n_ctx, kvh, d)
+    got = fa.prefill_attention(q, k, v, impl="flash", interpret=True,
+                               q_block=8, kv_block=8, **kw)
+    want = fa.prefill_attention(q, k, v, impl="dense", **kw)
+    np.testing.assert_allclose(got[:-2], want[:-2], atol=1e-5, rtol=1e-5)

@@ -1,11 +1,14 @@
-"""The decode step and the packed prefill compiled for a described TPU
+"""The decode step and the prefill chunk compiled for a described TPU
 v5e at the benchmark's widths (two layers): no chip is attached and
 nothing runs. What only the chip's compiler shows: the donated arenas
 alias their outputs, no executable re-lays an arena out (with the layer
 as a window axis of the scatter, or heads and head_dim as two trailing
-axes, it copied all of it on every step), and the Pallas kernel is in.
-One file, the topology described in a fixture: see the
-on-chip-measurement guide, section 2."""
+axes, it copied all of it on every step), the Pallas kernels are in and
+Mosaic takes them. Two settings of the one decoder: OPT-1.3B's dense
+float32 block, and a sparse bfloat16 block with grouped KV heads, a
+sliding and a full layer side by side and 64 experts. One file, the
+topology described in a fixture: see the on-chip-measurement guide,
+section 2."""
 import re
 
 import jax
@@ -15,11 +18,36 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deeplearning4j_tpu.ops import flash_attention as fa
+from deeplearning4j_tpu.ops import moe
 from deeplearning4j_tpu.serving.decode import (PagedKVCache,
+                                               TransformerAdapter,
                                                TransformerDecoder)
 
-LAYERS, HEADS, HEAD_DIM, FF, VOCAB = 2, 32, 64, 8192, 50272
-BLOCKS, BT, ROWS, KV, PACK = 256, 16, 8, 128, 128
+LAYERS = 2
+DENSE = dict(
+    model=dict(vocab=50272, layers=LAYERS, heads=32, head_dim=64, ff=8192,
+               max_context=255),
+    cache=dict(block_tokens=16, max_blocks=256), rows=8, kv=128, pack=128,
+    step_kernels=LAYERS, prefill_kernels=0)
+SPARSE = dict(
+    model=dict(vocab=98304, layers=LAYERS, heads=32, kv_heads=4,
+               head_dim=128, d_model=2304, ff=896, max_context=8320,
+               norm="rms", position="rotary", mlp="moe", experts=64,
+               experts_per_token=8, tied=False, dtype=jnp.bfloat16,
+               layer_types=("sliding", "full"), window=1024,
+               row_buckets="full",
+               rope={"sliding": {"rope_theta": 500000.0},
+                     "full": {"rope_type": "yarn", "rope_theta": 500000.0,
+                              "factor": 16.0, "beta_fast": 32,
+                              "beta_slow": 1,
+                              "original_max_position_embeddings": 8192}}),
+    cache=dict(block_tokens=256, max_blocks={"full": 1056, "sliding": 256}),
+    rows=32, kv=8448, pack=2048,
+    # a layer: the attention kernel and the grouped product's three
+    step_kernels=4 * LAYERS, prefill_kernels=4 * LAYERS,
+    # a chunk's own temporaries: 2,048 tokens x 8 experts each, their
+    # float32 products of width 2,304 gathered back into token order
+    prefill_temporaries=2048 * 8 * 2304 * 12)
 
 
 @pytest.fixture(scope="module")
@@ -40,6 +68,7 @@ def compiled(one_chip, monkeypatch):
     cannot be read back) and the kernel's dispatch steered as on a TPU."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(fa, "flash_attention_available", lambda: True)
+    monkeypatch.setattr(moe, "grouped_kernel_available", lambda: True)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -58,46 +87,66 @@ def compiled(one_chip, monkeypatch):
     compilation_cache.reset_cache()
 
 
-def _shapes():
-    """The parameter tree and an arena at the benchmark's widths, as
-    shapes: a tiny model's tree with each of its sizes put up (the
-    constructor draws its weights on the host)."""
-    up = {4: HEADS * HEAD_DIM, 6: FF, 3: VOCAB}
-    tiny = TransformerDecoder(vocab=3, layers=LAYERS, heads=2, head_dim=2,
-                              ff=6, max_context=8)
-    params = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(tuple(up[d] for d in a.shape),
-                                       a.dtype), tiny.params_tree)
-    arena = jax.eval_shape(lambda: PagedKVCache(
-        layers=LAYERS, heads=HEADS, head_dim=HEAD_DIM, block_tokens=BT,
-        max_blocks=BLOCKS).arenas()[0])
-    return params, arena
+def _shapes(setting):
+    """The model, its parameter tree, the arenas and one call's host
+    operands, all as shapes: nothing of the benchmark's size is made."""
+    m = TransformerDecoder(params={}, **setting["model"])
+    params = jax.eval_shape(lambda: m._draw(0))
+    cache = jax.eval_shape(lambda: PagedKVCache(
+        layers=m.n_layers, heads=m.kv_heads, head_dim=m.head_dim,
+        dtype=m.dtype, layer_kinds=m.layer_kinds(), window=m.window,
+        **setting["cache"]).arenas())
+    return m, params, cache
+
+
+def _host_operands(setting, m, which):
+    """What the adapter hands the executable, from a cache of one block
+    a kind (the host's side is the same at any arena size)."""
+    tiny = PagedKVCache(layers=m.n_layers, heads=1, head_dim=1,
+                        layer_kinds=m.layer_kinds(), window=m.window,
+                        block_tokens=setting["cache"]["block_tokens"],
+                        max_blocks=1)
+    ad = TransformerAdapter(m, tiny, pack_bucket=setting["pack"],
+                            max_rows=setting["rows"])
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    if which == "step":
+        tables, starts, lens, _ = tiny.batch_view((), setting["kv"],
+                                                  setting["rows"])
+        return lens, lens, tables, starts, lens
+    pb = ad.pack_bucket
+    return (i32(pb), i32(pb), i32(pb),
+            {k: (i32(pb), i32(pb)) for k in tiny.kinds},
+            {k: i32(w) for k, w in ad._ctx_widths.items()},
+            {k: np.int32(0) for k in tiny.kinds if k == "sliding"},
+            np.int32(0), i32(ad.last_width))
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
-def test_the_arena_is_updated_where_it_lies(compiled, which):
-    params, arena = _shapes()
-    m = TransformerDecoder(vocab=2, layers=0, heads=HEADS,
-                           head_dim=HEAD_DIM, ff=FF, max_context=255)
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+@pytest.mark.parametrize("setting", [DENSE, SPARSE], ids=["dense", "sparse"])
+def test_the_arenas_are_updated_where_they_lie(compiled, setting, which):
+    m, params, arenas = _shapes(setting)
+    ops = _host_operands(setting, m, which)
     if which == "step":
-        exe = compiled(m._step_pure, (3, 4), params, i32(ROWS), i32(ROWS),
-                       arena, arena, i32(ROWS, KV // BT), i32(ROWS))
+        exe = compiled(m._step_pure, (3,), params, *ops[:2], arenas,
+                       *ops[2:])
     else:
-        exe = compiled(m._prefill_pure, (4, 5), params, i32(1, PACK),
-                       i32(1, PACK), i32(1, PACK), arena, arena, i32(PACK),
-                       i32(PACK), i32(PACK))
+        exe = compiled(m._prefill_pure, (4,), params, *ops[:3], arenas,
+                       *ops[3:])
     text = exe.as_text()
+    leaves = jax.tree_util.tree_leaves(arenas)
     alias = re.search(r"input_output_alias=\{([^\n]*?)\}, entry", text)
     assert alias and alias.group(1).count("may-alias") + \
-        alias.group(1).count("must-alias") == 2, "an arena is not donated"
-    arena_bytes = int(np.prod(arena.shape)) * 4
+        alias.group(1).count("must-alias") == len(leaves), \
+        "an arena is not donated"
+    arena_bytes = [int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves]
     mem = exe.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * arena_bytes
-    assert mem.temp_size_in_bytes < arena_bytes, \
+    assert mem.alias_size_in_bytes >= sum(arena_bytes)
+    assert mem.temp_size_in_bytes < setting.get(
+        which + "_temporaries", min(arena_bytes)), \
         "the executable holds a copy of an arena"
-    dims = ",".join(str(d) for d in arena.shape)
-    layouts = set(re.findall(r"f32\[%s\]\{([\d,]+)" % dims, text))
-    assert layouts == {"3,2,1,0"}, f"an arena is re-laid out: {layouts}"
-    assert text.count("tpu_custom_call") == \
-        (LAYERS if which == "step" else 0)
+    for a in leaves:
+        dims = ",".join(str(d) for d in a.shape)
+        name = {"float32": "f32", "bfloat16": "bf16"}[a.dtype.name]
+        layouts = set(re.findall(r"%s\[%s\]\{([\d,]+)" % (name, dims), text))
+        assert layouts == {"3,2,1,0"}, f"an arena is re-laid out: {layouts}"
+    assert text.count("tpu_custom_call") == setting[which + "_kernels"]

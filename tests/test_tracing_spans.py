@@ -471,18 +471,48 @@ def test_the_decoders_programs_name_their_parts_and_decode_attention():
     m = TransformerDecoder(vocab=32, layers=L, heads=H, head_dim=DH, ff=16,
                            max_context=32)
     b = 2
-    arena = jnp.zeros((L, 5, BT, H * DH), jnp.float32)
+    arenas = {"full": (jnp.zeros((L, 5, BT, H * DH), jnp.float32),) * 2}
     z = jnp.zeros((b,), jnp.int32)
-    step = _lowered_text(m._step_pure, m.params_tree, z, z, arena, arena,
-                         jnp.zeros((b, 2), jnp.int32), z + 1)
-    row = jnp.zeros((1, 16), jnp.int32)
+    step = _lowered_text(m._step_pure, m.params_tree, z, z, arenas,
+                         {"full": jnp.zeros((b, 2), jnp.int32)}, {}, z + 1)
+    row = jnp.zeros((16,), jnp.int32)
     prefill = _lowered_text(m._prefill_pure, m.params_tree, row, row, row,
-                            arena, arena, row[0], row[0], row[0])
+                            arenas, {"full": (row, row)},
+                            {"full": jnp.zeros((4,), jnp.int32)}, {},
+                            jnp.int32(0), row)
     for text in (step, prefill):
-        for name in ("embed", "layer_0/attn", "layer_0/mlp", "layer_1/attn",
-                     "layer_1/mlp", "head", "kv_scatter"):
+        for name in ("embed", "layer_0/attn_full", "layer_0/mlp",
+                     "layer_1/attn_full", "layer_1/mlp", "head",
+                     "kv_scatter"):
             assert f"/{name}/" in text, name
-    assert "/layer_1/attn/decode_attention/" in step
-    assert "/layer_1/attn/kv_gather/" in step
-    assert "/decode_attention/" not in prefill
-    assert "/kv_gather/" not in prefill
+    # a step reads the cache through the table inside its kernel; a chunk
+    # gathers the slices before it and attends over them and itself
+    assert "/layer_1/attn_full/decode_attention_full/" in step
+    assert "/kv_context/" not in step
+    assert "/layer_1/attn_full/prefill_attention_full/" in prefill
+    assert "/layer_1/attn_full/kv_context/" in prefill
+    assert "/decode_attention_full/" not in prefill
+
+
+def test_a_sparse_windowed_decoder_names_its_layer_kinds_and_experts():
+    m = TransformerDecoder(
+        vocab=32, layers=4, heads=4, kv_heads=2, head_dim=DH, d_model=12,
+        ff=8, max_context=32, norm="rms", position="rotary", mlp="moe",
+        experts=4, experts_per_token=2, tied=False, window=4,
+        layer_types=("sliding", "full"),
+        rope={k: {"rope_theta": 10000.0} for k in ("full", "sliding")})
+    b = 2
+    arena = (jnp.zeros((2, 5, BT, 2 * DH), jnp.float32),) * 2
+    z = jnp.zeros((b,), jnp.int32)
+    tables = jnp.zeros((b, 2), jnp.int32)
+    step = _lowered_text(m._step_pure, m.params_tree, z, z,
+                         {"full": arena, "sliding": arena},
+                         {"full": tables, "sliding": tables},
+                         {"sliding": z}, z + 1)
+    for name in ("layer_0/attn_sliding/decode_attention_sliding",
+                 "layer_1/attn_full/decode_attention_full",
+                 "layer_2/attn_sliding", "layer_3/attn_full",
+                 "layer_0/moe/route", "layer_0/moe/experts",
+                 "layer_3/moe/experts", "head", "kv_scatter"):
+        assert f"/{name}/" in step, name
+    assert "/mlp/" not in step
