@@ -461,24 +461,28 @@ def phase_generate(cfg):
 
         # The cached path's picks against naive recompute's logits, step
         # by step: the adapter's own calls (prefill into the device arena
-        # -> block table -> step executable), with the reference choosing
-        # the tokens that are fed back.
+        # -> block table -> step executable, launched and then fetched).
+        # A pick stays on the device and is the next step's token, so
+        # the reference follows the picks.
         worst_pick = 0.0
         for i, prompt in enumerate(prompts[:3]):
             rid = 1_000_000 + i
-            got, fails = adapter.prefill_group(
-                [(rid, np.asarray(prompt, np.int32))])
             toks = list(prompt)
-            try:
-                for _ in range(n_new):
-                    check(not fails, f"the cached path failed: {fails}")
-                    ref = _logits(model, toks, pack)[-1]
-                    worst_pick = max(worst_pick,
-                                     float(ref.max() - ref[got[rid]]))
-                    toks.append(int(ref.argmax()))
-                    got, fails = adapter.step([rid], toks[-1:])
-            finally:
-                cache.free(rid)
+            # the arenas and the step in flight are the lock's holder's
+            with entry.engine.paused():
+                adapter.prefill_group([(rid, np.asarray(prompt, np.int32))])
+                got, fails = adapter.collect()
+                try:
+                    for _ in range(n_new):
+                        check(not fails, f"the cached path failed: {fails}")
+                        ref = _logits(model, toks, pack)[-1]
+                        worst_pick = max(worst_pick,
+                                         float(ref.max() - ref[got[rid]]))
+                        toks.append(got[rid])
+                        adapter.step([rid])
+                        got, fails = adapter.collect()
+                finally:
+                    adapter.free(rid)
         check(cache.blocks_in_use() == 0, "the direct calls left KV blocks")
         log(f"  {len(prompts)} prompts of {min(cfg['prompt_lens'])}-"
             f"{max(cfg['prompt_lens'])} tokens x {n_new} new, all 200, "

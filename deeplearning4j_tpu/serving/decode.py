@@ -53,11 +53,21 @@ a batch-tier generation between tokens. Gateway breaker / hot-swap /
 canary semantics carry over unchanged (ModelPool.add_decode wires the
 same hooks; swaps pause the loop via ``paused()`` between steps).
 
+One step is always in flight: the loop LAUNCHES step n+1 and only then
+fetches and commits step n, so the copy back and all of the host's
+bookkeeping run while the device computes. The next step's input tokens
+never leave the device (the token arm keeps them in a donated vector,
+one entry a row slot), and everything else a launch needs is known
+without the step before it: the only stop rule is ``max_new_tokens`` and
+a length grows by one. What is learned late is handled late: a row that
+turns out non-finite or expired at the commit of step n is failed typed
+there and its result in step n+1 is dropped.
+
 Chaos seam: each step attempt fires the ``serve.decode_step`` fault
-point (utils/faults.py) before dispatch. A failing step is isolated by
-solo retry — only requests whose SOLO step also fails get a typed
-:class:`~..parallel.inference.DecodeStepError`, their KV blocks are
-freed, and batchmates keep generating on the next step.
+point (utils/faults.py) before dispatch. A launch that raises is
+isolated by solo retry — only requests whose SOLO launch also raises get
+a typed :class:`~..parallel.inference.DecodeStepError`, their KV blocks
+are freed, and batchmates keep generating on the next step.
 """
 from __future__ import annotations
 
@@ -67,7 +77,8 @@ import math
 import threading
 import time
 import weakref
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -98,6 +109,9 @@ INTER_TOKEN_BUCKETS_MS = (0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
 
 _TOKENS_HELP = "Tokens generated by the decode loop"
 _STEPS_HELP = "Iteration-level decode steps executed (all riders advance)"
+_OVERLAPPED_HELP = ("Decode steps launched while earlier work (a step or "
+                    "a prefill chunk) was still unfetched (over "
+                    "serving_decode_steps_total: the run-ahead share)")
 _PREFILL_HELP = "Packed prefill forwards executed"
 _ITL_HELP = ("Wall time between a request's consecutive tokens "
              "(step + between-step scheduling)")
@@ -105,8 +119,9 @@ _KV_BLOCKS_HELP = "KV cache blocks currently allocated"
 _KV_UTIL_HELP = ("Fraction of allocated KV block capacity holding real "
                  "tokens (1.0 = no block-tail waste)")
 _H2D_HELP = ("Bytes of the host arrays the decode adapters hand to the "
-             "device (a step's tokens, positions, block table and lengths; "
-             "a prefill's packed row and its cache slots; a stream's carry)")
+             "device (a step's row slots, positions, block table and "
+             "lengths; a prefill's packed row, its cache slots and row "
+             "slots; a stream's carry)")
 _D2H_HELP = ("Bytes of the device outputs the decode adapters copy back "
              "to the host (picked tokens and finite flags; a stream's "
              "outputs and carry)")
@@ -137,6 +152,7 @@ def register_metrics() -> None:
     reg = registry()
     reg.counter("serving_decode_tokens_total", _TOKENS_HELP)
     reg.counter("serving_decode_steps_total", _STEPS_HELP)
+    reg.counter("serving_decode_steps_overlapped_total", _OVERLAPPED_HELP)
     reg.counter("serving_decode_prefills_total", _PREFILL_HELP)
     reg.histogram("serving_inter_token_ms", _ITL_HELP,
                   buckets=INTER_TOKEN_BUCKETS_MS)
@@ -214,9 +230,13 @@ class PagedKVCache:
     lanes.
 
     Everything else is host integers and stays here: the free lists, the
-    tables, the lengths. A length advances only at :meth:`advance`,
-    after the host has seen the step's outcome, so a step that is run
-    again (a failed row, a solo retry) writes the same slot again.
+    tables, the lengths. A length advances only at :meth:`advance`, which
+    the adapter calls once the work that writes the slot is LAUNCHED (a
+    length grows by one whatever the step picks), so a launch that
+    raised, made again (a solo retry), writes the same slot again. The
+    device runs work in launch order and the arenas chain by donation:
+    a block given back here and granted again is written by its new
+    owner only after everything launched before has read it.
 
     Block ``max_blocks`` of a kind (``scratch_of[kind]``) belongs to no
     request: pad rows, pad positions and warm-up calls point at it, so
@@ -373,10 +393,10 @@ class PagedKVCache:
         return self.extend(rid, n_tokens)
 
     def advance(self, rid: int, n_tokens: int = 1) -> None:
-        """`rid`'s next `n_tokens` slots now hold what the device wrote
-        there. A sliding table gives back each leading block whose every
-        position is now `window` or more behind the length: no later
-        query can see it."""
+        """`rid`'s next `n_tokens` slots hold what the work launched so
+        far writes there. A sliding table gives back each leading block
+        whose every position is now `window` or more behind the length:
+        no query launched later can see it."""
         bt = self.block_tokens
         with self._lock:
             self._lens[rid] += int(n_tokens)
@@ -570,23 +590,29 @@ class TransformerDecoder:
     DONATED operands, write the new K/V into them in place, pick the
     greedy token on the device and hand the arenas back — the cache
     rebinds to them (``PagedKVCache.update``), and only int32 tokens,
-    bool flags and three routing sums cross the link:
+    bool flags and three routing sums cross the link. The token a row's
+    NEXT step reads stays on the device: ``feed`` is an int32 vector of
+    one entry a row slot (and a last one that belongs to no request,
+    where pad rows point), donated and handed back like the arenas:
 
     * ``prefill(tokens[T], seg[T], pos[T], arenas, slots, ctx_tables,
-      ctx_starts, ctx_len, last[W])`` — one CHUNK: a packed row of
-      whole prompts and at most one later slice of a long prompt, which
-      is segment 1 and sees its ``ctx_len`` cached positions through
-      ``ctx_tables[kind]`` (first entry at position
+      ctx_starts, ctx_len, last[W], feed, feed_slots[W])`` — one CHUNK:
+      a packed row of whole prompts and at most one later slice of a
+      long prompt, which is segment 1 and sees its ``ctx_len`` cached
+      positions through ``ctx_tables[kind]`` (first entry at position
       ``ctx_starts[kind]``). Padding is segment 0. Position ``i``'s K/V
       go to ``slots[kind] = (blk[T], off[T])`` (pads point at the
       scratch block); the pick and the finite flag are taken at the
-      positions ``last``. → ``(token [W], finite [W], arenas)``.
-    * ``step(tok[b], pos[b], arenas, tables, starts, lens[b])`` — one
-      token per row through ``paged_decode_attention``, which reads
-      each row's blocks through ``tables[kind][b, w]`` (a sliding
-      table's first entry at position ``starts[kind][b]``); the new K/V
-      are scattered to the slot of position ``lens``. → ``(token [b],
-      finite [b], routing sums int32 [3] or None, arenas)``.
+      positions ``last`` and the picks written to ``feed[feed_slots]``.
+      → ``(token [W], finite [W], feed, arenas)``.
+    * ``step(slot[b], pos[b], arenas, tables, starts, lens[b], feed)`` —
+      one token per row, read from ``feed[slot]``, through
+      ``paged_decode_attention``, which reads each row's blocks through
+      ``tables[kind][b, w]`` (a sliding table's first entry at position
+      ``starts[kind][b]``); the new K/V are scattered to the slot of
+      position ``lens`` and the picks written back to ``feed[slot]``. →
+      ``(token [b], finite [b], routing sums int32 [3] or None, feed,
+      arenas)``.
 
     ``logits(tokens, seg, pos)`` is the plain forward of the same
     packed row with no cache: what ``naive_generate``, the tests and
@@ -650,8 +676,9 @@ class TransformerDecoder:
         self.params_tree: Dict[str, Any] = \
             self._draw(seed) if params is None else params
         self._logits_fn = jax.jit(self._logits_pure)
-        self._prefill_fn = jax.jit(self._prefill_pure, donate_argnums=(4,))
-        self._step_fn = jax.jit(self._step_pure, donate_argnums=(3,))
+        self._prefill_fn = jax.jit(self._prefill_pure,
+                                   donate_argnums=(4, 10))
+        self._step_fn = jax.jit(self._step_pure, donate_argnums=(3, 7))
 
     # ---------------------------------------------------------------- geometry
     def kind_of(self, layer: int) -> str:
@@ -856,25 +883,27 @@ class TransformerDecoder:
             params, tokens[0], seg[0], pos[0])[0])[None]
 
     def _prefill_pure(self, params, tokens, seg, pos, arenas, slots,
-                      ctx_tables, ctx_starts, ctx_len, last):
+                      ctx_tables, ctx_starts, ctx_len, last, feed,
+                      feed_slots):
         """One chunk into the cache. → (token int32 [W], finite bool
-        [W], arenas)."""
+        [W], feed, arenas)."""
         x, new = self._chunk_forward(
             params, tokens, seg, pos,
             (arenas, ctx_tables, ctx_starts, ctx_len))
         arenas = self._scatter(arenas, slots, new)
         tok, finite = self._pick(params, x[last])
-        return tok, finite, arenas
+        return tok, finite, feed.at[feed_slots].set(tok), arenas
 
-    def _step_pure(self, params, tok, pos, arenas, tables, starts, lens):
-        """One decode step. tok/pos/lens [b]; tables[kind] [b, w] →
+    def _step_pure(self, params, slot, pos, arenas, tables, starts, lens,
+                   feed):
+        """One decode step. slot/pos/lens [b]; tables[kind] [b, w] →
         (token int32 [b], finite bool [b], routing sums int32 [3] or
-        None, arenas)."""
-        b = tok.shape[0]
+        None, feed, arenas)."""
+        b = slot.shape[0]
         zeros = jnp.zeros((b,), jnp.int32)
         valid = lens > 0            # a pad row: no expert computes it
         with jax.named_scope("embed"):
-            x = self._embed(params, tok, pos)               # [b, d]
+            x = self._embed(params, feed[slot], pos)        # [b, d]
         new = {k: ([], []) for k in arenas}
         sums = []
         for li, lp in enumerate(params["layers"]):
@@ -901,7 +930,7 @@ class TransformerDecoder:
         arenas = self._scatter(arenas, slots, new)
         picked, finite = self._pick(params, x)
         sums = None if sums[0] is None else sum(sums[1:], sums[0])
-        return picked, finite, sums, arenas
+        return picked, finite, sums, feed.at[slot].set(picked), arenas
 
     # ------------------------------------------------------------ conveniences
     def logits(self, tokens, seg, pos):
@@ -911,14 +940,14 @@ class TransformerDecoder:
                                np.asarray(pos, np.int32))
 
     def prefill(self, tokens, seg, pos, arenas, slots, ctx_tables,
-                ctx_starts, ctx_len, last):
+                ctx_starts, ctx_len, last, feed, feed_slots):
         return self._prefill_fn(self.params_tree, tokens, seg, pos, arenas,
                                 slots, ctx_tables, ctx_starts,
-                                np.int32(ctx_len), last)
+                                np.int32(ctx_len), last, feed, feed_slots)
 
-    def step(self, tok, pos, arenas, tables, starts, lens):
-        return self._step_fn(self.params_tree, tok, pos, arenas, tables,
-                             starts, lens)
+    def step(self, slot, pos, arenas, tables, starts, lens, feed):
+        return self._step_fn(self.params_tree, slot, pos, arenas, tables,
+                             starts, lens, feed)
 
 
 def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
@@ -945,15 +974,31 @@ def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
     return out
 
 
+class _Launched(NamedTuple):
+    """One step or prefill chunk on the device whose outputs the host
+    has not fetched: which it is, its place among the adapter's step
+    launches (0 for a chunk), which request's token is at which index,
+    and the device's ``(picked, finite, sums)``."""
+    phase: str
+    seq: int
+    rows: List[Tuple[int, int]]
+    outputs: tuple
+
+
 class TransformerAdapter:
     """Token-family adapter: chunked packed prefill + paged-KV decode
-    steps.
+    steps, one step in flight.
 
-    The adapter owns nothing on the device: each call lends the cache's
-    arenas to the model's executable and rebinds the cache to what comes
-    back. What crosses the link is a step's tokens, positions, block
-    tables and lengths going up and one picked token and one finite flag
-    a row (and a sparse model's three routing sums) coming back
+    The adapter owns one thing on the device, the ``feed`` vector: the
+    token each row slot's next step reads (two slots a row of
+    ``max_rows`` and a scratch one for pad rows). A request takes a
+    slot when its prompt's last piece is prefilled and keeps it for its
+    life; the chunk writes its first token there, each step reads
+    ``feed[slot]`` and writes its pick back. Each call lends the
+    cache's arenas and the feed to the model's executable and rebinds
+    to what comes back. What crosses the link is a step's row slots, positions, block tables and
+    lengths going up and one picked token and one finite flag a row (and
+    a sparse model's three routing sums) coming back
     (``serving_decode_h2d|d2h_bytes_total`` count exactly those). Every
     served token passes through :meth:`_greedy` on the host.
 
@@ -963,13 +1008,23 @@ class TransformerAdapter:
     slices before it from the cache (:meth:`pack_groups`). Only a
     prompt's last slice yields its first token.
 
-    ``prefill_group``/``step`` report per-request outcomes as
-    ``(results, failures)`` dicts instead of raising for partial
+    :meth:`step` and :meth:`prefill_group` each LAUNCH their work and
+    then fetch and commit whatever was launched before it (a step or a
+    chunk), whose ``(results, failures)`` they return; :meth:`collect`
+    fetches and commits what is in flight and launches nothing. So the
+    copy back and the host's bookkeeping run while the device computes
+    the next thing. A request's length advances when the work that
+    writes its slots is launched; what the fetch then shows (a
+    non-finite row) fails that row alone, and whatever a later launch
+    already computed for a request that has since been freed is dropped
+    at its commit.
+
+    ``prefill_group``/``step``/``collect`` report per-request outcomes
+    as ``(results, failures)`` dicts instead of raising for partial
     trouble — the engine applies survivors and fails victims typed, so
     a KV-grow failure or a poisoned row never costs its batchmates a
-    token. A row's length advances only once the host has accepted its
-    token, so a row that failed, or that is stepped again alone after a
-    failed batch step, writes the slot it wrote before.
+    token. A launch that raises has advanced nothing: made again alone,
+    it writes the slot it would have written.
     """
 
     kind = "token"
@@ -992,6 +1047,16 @@ class TransformerAdapter:
         self._ctx_widths = {k: cache.table_width(k, self._kv_cap)
                             for k in cache.kinds}
         self._prefilling: Dict[int, int] = {}   # rid -> positions cached
+        # Row slots: entry `scratch_slot` of the feed belongs to no one.
+        # Twice the rows: a request whose last step is launched keeps its
+        # slot until that step is fetched, and a newcomer may have taken
+        # its row by then.
+        self.scratch_slot = 2 * (self.max_rows or self.pack_bucket)
+        self._feed = jnp.zeros((self.scratch_slot + 1,), jnp.int32)
+        self._slot_of: Dict[int, int] = {}
+        self._free_slots = list(range(self.scratch_slot - 1, -1, -1))
+        self._in_flight: Optional[_Launched] = None
+        self._launches = 0      # steps launched; `seq` of the spans
         self._link = _register_link_metrics()
         self._count = _register_model_metrics()
 
@@ -1051,13 +1116,17 @@ class TransformerAdapter:
                                             Dict[int, BaseException]]:
         """Prefill ONE chunk: give each piece its KV blocks, splice the
         pieces into a segment-masked row and run the chunk's forward,
-        which writes their K/V into those blocks. A ``(rid, prompt)``
-        pair stands for a whole prompt. Returns ``({rid: first token} of
-        the prompts this chunk finished, {rid: error})`` — a rid whose
-        blocks cannot be granted lands in the failure dict (typed
-        KVCacheExhaustedError), gives back what it held and stays out
-        of the row, without costing groupmates theirs; a later piece of
-        a prompt that has failed is passed over."""
+        which writes their K/V into those blocks and the first token of
+        each prompt it finishes into the feed. A ``(rid, prompt)`` pair
+        stands for a whole prompt. Like :meth:`step` it LAUNCHES and
+        then fetches and commits what was launched before: it returns
+        ``({rid: token}, {rid: error})`` of that EARLIER work, and the
+        first tokens of this chunk's prompts come back from the next
+        call. In the errors also: a rid of THIS chunk whose blocks
+        cannot be granted (typed KVCacheExhaustedError), which gives
+        back what it held and stays out of the row, without costing
+        groupmates theirs; a later piece of a prompt that has failed is
+        passed over."""
         pb, cache = self.pack_bucket, self.cache
         row = np.zeros((pb,), np.int32)
         seg = np.zeros((pb,), np.int32)
@@ -1069,8 +1138,8 @@ class TransformerAdapter:
         ctx_starts = {k: np.int32(0) for k in cache.kinds if k == "sliding"}
         ctx_len = 0
         last = np.zeros((self.last_width,), np.int32)
-        out: Dict[int, int] = {}
-        fails: Dict[int, BaseException] = {}
+        feed_slots = np.full((self.last_width,), self.scratch_slot, np.int32)
+        starved: Dict[int, BaseException] = {}
         placed: List[Tuple[int, int, int]] = []  # rid, tokens, `last` index
         cur = finals = 0
         for piece in group:
@@ -1086,7 +1155,7 @@ class TransformerAdapter:
                     before = cache.context(rid, self._ctx_widths)
                 where = cache.extend(rid, lo + p.size)
             except KVCacheExhaustedError as e:
-                fails[rid] = e
+                starved[rid] = e
                 self.free(rid)
                 continue
             if lo:
@@ -1101,38 +1170,46 @@ class TransformerAdapter:
             pos[cur:hi] = lo + np.arange(p.size)
             if final:
                 last[finals] = hi - 1
+                feed_slots[finals] = self._take_slot(rid)
             placed.append((rid, p.size, finals if final else -1))
             finals += final
             cur = hi
         if not placed:
-            return out, fails
+            return {}, starved
         self._count["chunks"].inc()
         self._count["chunk_tokens"].inc(cur)
-        up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last) + 4
+        up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last,
+                     feed_slots) + 4
         with tracing.span("decode/launch", cat="serve", bytes=up):
-            picked, finite = cache.update(lambda a: self.model.prefill(
-                row, seg, pos, a, slots, ctx_tables, ctx_starts, ctx_len,
-                last))
+            picked, finite, self._feed = cache.update(
+                lambda a: self.model.prefill(
+                    row, seg, pos, a, slots, ctx_tables, ctx_starts,
+                    ctx_len, last, self._feed, feed_slots))
         self._link["h2d", "prefill"].inc(up)
-        with tracing.span("decode/fetch", cat="serve"):
-            picked, finite = jax.device_get((picked, finite))
-            down = _nbytes(picked, finite)
-            tracing.annotate(bytes=down)
-        self._link["d2h", "prefill"].inc(down)
-        with tracing.span("decode/commit", cat="serve"):
-            for rid, n_tok, i in placed:
-                if i >= 0 and self.check_finite and not finite[i]:
-                    self.free(rid)
-                    fails[rid] = NonFiniteOutputError(
-                        "prefill produced non-finite logits")
-                    continue
-                cache.advance(rid, n_tok)
-                if i >= 0:
-                    self._prefilling.pop(rid, None)
-                    out[rid] = self._greedy(picked[i])
-                else:
-                    self._prefilling[rid] = cache.length(rid)
+        for rid, n_tok, i in placed:
+            cache.advance(rid, n_tok)
+            if i >= 0:
+                self._prefilling.pop(rid, None)
+            else:
+                self._prefilling[rid] = cache.length(rid)
+        out, fails = self._launched(_Launched(
+            "prefill", 0, [(i, rid) for rid, _, i in placed if i >= 0],
+            (picked, finite, None)))
+        fails.update(starved)
         return out, fails
+
+    def is_row(self, rid: int) -> bool:
+        """Whether `rid`'s whole prompt is prefilled or launched: it
+        holds a row slot and rides the next step."""
+        return rid in self._slot_of
+
+    def _take_slot(self, rid: int) -> int:
+        if not self._free_slots:
+            raise ValueError(
+                f"no row slot for request {rid}: {self.scratch_slot} "
+                "requests hold one (max_rows)")
+        self._slot_of[rid] = self._free_slots.pop()
+        return self._slot_of[rid]
 
     # ------------------------------------------------------------------- step
     def row_bucket(self, n: int) -> int:
@@ -1150,58 +1227,110 @@ class TransformerAdapter:
         return min(max(self.cache.block_tokens, next_pow2_bucket(need)),
                    self._kv_cap)
 
-    def step(self, rids: Sequence[int], last_items: Sequence[Any]
+    def step(self, rids: Sequence[int]
              ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
-        """One iteration-level step for `rids`: rows are padded to the
-        row bucket with rows of the scratch block at length 0 (a pad row
-        reads and writes nothing a request owns). Returns
-        ``({rid: next token}, {rid: error})``."""
+        """Launch one iteration-level step for `rids`, then fetch and
+        commit what was launched BEFORE it (a step or a chunk). Rows are
+        padded to the row bucket with rows of the scratch block at
+        length 0 and the scratch slot (a pad row reads and writes
+        nothing a request owns). Each row reads its token from the feed,
+        where the chunk or the step before left it, and its length
+        advances here, at the launch. Returns ``({rid: token}, {rid:
+        error})`` of that EARLIER work (both empty if nothing was in
+        flight), and in the errors the rows of THIS step that could not
+        be given room."""
         n, cache = len(rids), self.cache
         bucket = self.row_bucket(n)
         kvb = self.kv_bucket(rids)
         tracing.annotate(row_bucket=bucket, kv_bucket=kvb)
         with tracing.span("decode/gather", cat="serve"):
-            tables, starts, lens, fails = cache.batch_view(rids, kvb, bucket)
+            tables, starts, lens, starved = cache.batch_view(rids, kvb,
+                                                             bucket)
             tracing.annotate(bytes=_nbytes(tables, starts, lens))
-        toks = np.zeros((bucket,), np.int32)
-        toks[:n] = last_items
+        rows = [(i, rid) for i, rid in enumerate(rids) if rid not in starved]
+        slot = np.full((bucket,), self.scratch_slot, np.int32)
+        for i, rid in rows:
+            slot[i] = self._slot_of[rid]
         # `lens` goes up twice: as the positions and as the lengths
-        up = _nbytes(toks, lens, tables, starts, lens)
-        with tracing.span("decode/launch", cat="serve", bytes=up):
-            picked, finite, sums = cache.update(lambda a: self.model.step(
-                toks, lens, a, tables, starts, lens))
+        up = _nbytes(slot, lens, tables, starts, lens)
+        seq = self._launches + 1
+        with tracing.span("decode/launch", cat="serve", bytes=up, seq=seq):
+            *outputs, self._feed = cache.update(lambda a: self.model.step(
+                slot, lens, a, tables, starts, lens, self._feed))
+        self._launches = seq
         self._link["h2d", "step"].inc(up)
-        with tracing.span("decode/fetch", cat="serve"):
-            picked, finite, sums = jax.device_get((picked, finite, sums))
-            down = _nbytes(picked, finite, sums)
-            tracing.annotate(bytes=down)
-        self._link["d2h", "step"].inc(down)
+        for _, rid in rows:
+            cache.advance(rid)
         seen = lens[lens > 0].astype(np.int64) + 1      # with its own
         for k in cache.kinds:
             self._count["kv_tokens", k].inc(int(
                 (np.minimum(seen, cache.window) if k == "sliding"
                  else seen).sum()))
             self._count["block_steps", k].inc(cache.blocks_in_use(k))
+        out, fails = self._launched(_Launched("step", seq, rows,
+                                              tuple(outputs)))
+        fails.update(starved)
+        return out, fails
+
+    def _launched(self, work: _Launched
+                  ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
+        """`work` is in flight now; commit what was before it."""
+        earlier, self._in_flight = self._in_flight, work
+        return self._commit(earlier)
+
+    def collect(self) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
+        """Fetch and commit what is in flight and launch nothing:
+        ``({rid: token}, {rid: error})``, both empty if nothing was."""
+        return self._launched(None)
+
+    def in_flight(self) -> bool:
+        """Whether launched work's outputs are still unfetched."""
+        return self._in_flight is not None
+
+    def _commit(self, work: Optional[_Launched]
+                ) -> Tuple[Dict[int, int], Dict[int, BaseException]]:
+        """Copy launched work's outputs back (the wait for the work
+        itself, while whatever was launched after it runs) and sort its
+        rows into tokens and typed failures. A request freed since the
+        launch (failed, expired) gets neither. A fetch that raises fails
+        the work's rows: its outputs, and with them the arenas it was to
+        hand on, are lost, so no retry could serve them."""
+        out: Dict[int, int] = {}
+        fails: Dict[int, BaseException] = {}
+        if work is None:
+            return out, fails
+        seq = {"seq": work.seq} if work.seq else {}
+        rows = [(i, rid) for i, rid in work.rows if rid in self._slot_of]
+        try:
+            with tracing.span("decode/fetch", cat="serve", **seq):
+                picked, finite, sums = jax.device_get(work.outputs)
+                down = _nbytes(picked, finite, sums)
+                tracing.annotate(bytes=down)
+        except Exception as e:  # noqa: BLE001 — typed by the engine
+            return out, {rid: e for _, rid in rows}
+        self._link["d2h", work.phase].inc(down)
         if sums is not None:    # host integers since the fetch above
             for name, v in zip(("assignments", "touched", "peak"),
                                sums.tolist()):  # jaxlint: disable=JL102
                 self._count[name].inc(v)
-        out: Dict[int, int] = {}
-        with tracing.span("decode/commit", cat="serve"):
-            for i, rid in enumerate(rids):
-                if rid in fails:
-                    continue
+        with tracing.span("decode/commit", cat="serve", **seq):
+            for i, rid in rows:
                 if self.check_finite and not finite[i]:
                     fails[rid] = NonFiniteOutputError(
-                        "decode step produced non-finite logits")
-                    continue
-                cache.advance(rid)
-                out[rid] = self._greedy(picked[i])
+                        f"{work.phase} produced non-finite logits")
+                else:
+                    out[rid] = self._greedy(picked[i])
         return out, fails
 
     # ------------------------------------------------------------------ admin
     def free(self, rid: int) -> None:
+        """Give back `rid`'s blocks and row slot (idempotent). A step in
+        flight may still write both: the device runs work in launch
+        order, so their next owner's writes come after."""
         self._prefilling.pop(rid, None)
+        slot = self._slot_of.pop(rid, None)
+        if slot is not None:
+            self._free_slots.append(slot)
         self.cache.free(rid)
 
     def kv_blocks(self, rid: int) -> int:
@@ -1233,10 +1362,11 @@ class TransformerAdapter:
         """Run the step executable of `rows` rows at each view bucket
         on the live arenas (they are the only ones): every row is a pad
         row, so only the scratch blocks are written."""
+        slot = np.full((rows,), self.scratch_slot, np.int32)
         for kv in kvs:
             tables, starts, zeros, _ = self.cache.batch_view((), kv, rows)
-            self.cache.update(lambda a: self.model.step(
-                zeros, zeros, a, tables, starts, zeros))
+            *_, self._feed = self.cache.update(lambda a: self.model.step(
+                slot, zeros, a, tables, starts, zeros, self._feed))
 
     def warmup(self, max_rows: int, max_context: int) -> List[int]:
         """Precompile the chunk's prefill signature and every step
@@ -1248,9 +1378,10 @@ class TransformerAdapter:
         ctx = {k: np.full((w,), cache.scratch_of[k], np.int32)
                for k, w in self._ctx_widths.items()}
         starts = {k: np.int32(0) for k in cache.kinds if k == "sliding"}
-        cache.update(lambda a: self.model.prefill(
+        *_, self._feed = cache.update(lambda a: self.model.prefill(
             at, at, at, a, slots, ctx, starts, 0,
-            np.zeros((self.last_width,), np.int32)))
+            np.zeros((self.last_width,), np.int32), self._feed,
+            np.full((self.last_width,), self.scratch_slot, np.int32)))
         rows, kvs = self.warm_signatures(max_rows, max_context)
         for b in rows:
             self.warm_steps(b, kvs)
@@ -1265,12 +1396,17 @@ class RecurrentAdapter:
     same iteration-level loop via ``rnn_time_step``.
 
     There is no KV cache — recurrent state IS the cache. Per-request
-    carry rows (each layer's h/c at batch 1) live host-side in this
-    adapter; every step gathers the active rows into one pow2-bucketed
-    batch (pad rows repeat row 0), assigns it as the net's carry, runs
-    ONE ``rnn_time_step`` at the bucketed signature, and scatters the
-    advanced rows back. The model's own output feeds back as the next
-    step's input, so the net must be built with ``n_out == n_in``.
+    carry rows (each layer's h/c at batch 1) and each request's last
+    output live host-side in this adapter; every step gathers the
+    active rows into one pow2-bucketed batch (pad rows repeat row 0),
+    assigns it as the net's carry, runs ONE ``rnn_time_step`` at the
+    bucketed signature, and scatters the advanced rows back. The
+    model's own output feeds back as the next step's input, so the net
+    must be built with ``n_out == n_in``.
+
+    A step's inputs are host arrays made from the step before, so there
+    is nothing to launch ahead: :meth:`step` returns its own outcome
+    and :meth:`collect` never has anything to wait for.
     """
 
     kind = "stream"
@@ -1286,6 +1422,7 @@ class RecurrentAdapter:
         self.feature_dim = int(feature_dim)
         self.check_finite = bool(check_finite)
         self._carries: Dict[int, tuple] = {}
+        self._last_out: Dict[int, np.ndarray] = {}  # the next step's input
         self.pack_bucket = 0  # no packed path on the stream arm
         self._link = _register_link_metrics()
 
@@ -1338,12 +1475,17 @@ class RecurrentAdapter:
                 return {}, {rid: NonFiniteOutputError(
                     "stream prefill produced non-finite outputs")}
             self._carries[rid] = carry
+            self._last_out[rid] = out
         return {rid: out}, {}
 
+    def is_row(self, rid: int) -> bool:
+        return rid in self._carries
+
     # ------------------------------------------------------------------- step
-    def step(self, rids: Sequence[int], last_items: Sequence[np.ndarray]
+    def step(self, rids: Sequence[int]
              ) -> Tuple[Dict[int, np.ndarray],
                         Dict[int, BaseException]]:
+        """One step for `rids`, launched, fetched and committed here."""
         n = len(rids)
         bucket = next_pow2_bucket(n)
         pad_i = [min(i, n - 1) for i in range(bucket)]
@@ -1357,7 +1499,7 @@ class RecurrentAdapter:
                      for j in range(bucket)], axis=0))
                  for k in self._carries[rids[0]][li]}
                 for li in range(layers))
-            x = np.stack([np.asarray(last_items[pad_i[j]], np.float32)
+            x = np.stack([self._last_out[rids[pad_i[j]]]
                           for j in range(bucket)], axis=0)
             up = _nbytes(x, merged)
             tracing.annotate(bytes=up)
@@ -1387,12 +1529,20 @@ class RecurrentAdapter:
                     {k: np.asarray(v)[i:i + 1].copy()
                      for k, v in layer.items()}
                     for layer in new_carry)
-                results[rid] = out[i]
+                results[rid] = self._last_out[rid] = out[i]
         return results, fails
+
+    def collect(self) -> Tuple[Dict[int, np.ndarray],
+                               Dict[int, BaseException]]:
+        return {}, {}
+
+    def in_flight(self) -> bool:
+        return False
 
     # ------------------------------------------------------------------ admin
     def free(self, rid: int) -> None:
         self._carries.pop(rid, None)
+        self._last_out.pop(rid, None)
 
     def kv_blocks(self, rid: int) -> int:
         return 0
@@ -1425,7 +1575,7 @@ class RecurrentAdapter:
 # ---------------------------------------------------------------------------
 class _DecodeRequest:
     __slots__ = ("rid", "prompt", "max_new_tokens", "event", "result",
-                 "error", "deadline", "trace", "generated", "last_item",
+                 "error", "deadline", "trace", "generated", "launched",
                  "t_last")
 
     def __init__(self, rid: int, prompt, max_new_tokens: int,
@@ -1439,7 +1589,7 @@ class _DecodeRequest:
         self.deadline = deadline
         self.trace = trace
         self.generated: List[Any] = []
-        self.last_item = None     # feeds the next step's input
+        self.launched = 0         # tokens whose step has been launched
         self.t_last = 0.0         # last token emission (inter-token gap)
 
     def expired(self, now: Optional[float] = None) -> bool:
@@ -1448,19 +1598,33 @@ class _DecodeRequest:
 
 
 class _StepPause:
-    """Context manager holding the engine's step lock: the in-flight
-    step completes, then the loop stalls BETWEEN steps — active
-    generations wait, they are not dropped (the hot-swap window)."""
+    """Context manager holding the engine's step lock with nothing in
+    flight: the launched step completes and is committed, then the loop
+    stalls BETWEEN steps — active generations wait, they are not
+    dropped (the hot-swap window).
 
-    def __init__(self, lock):
-        self._lock = lock
+    Only the loop's own thread commits a step. A pauser announces
+    itself and waits until nothing is in flight; from then on the loop
+    ends every hold of the lock drained (``_drain_for_pausers``), so
+    the lock, once taken, is taken between steps."""
+
+    def __init__(self, engine: "DecodeEngine"):
+        self._engine = engine
 
     def __enter__(self):
-        self._lock.acquire()
+        eng = self._engine
+        with eng._cv:
+            eng._pausers += 1
+            while eng.adapter.in_flight() and eng._worker.is_alive():
+                eng._cv.wait(timeout=0.2)
+        eng._lock.acquire()
         return self
 
     def __exit__(self, *exc):
-        self._lock.release()
+        eng = self._engine
+        eng._lock.release()
+        with eng._cv:
+            eng._pausers -= 1
         return False
 
 
@@ -1476,21 +1640,46 @@ class DecodeEngine:
 
     The loop, each iteration:
 
-    1. **Admit** queued prompts up to ``max_decode_batch`` active
-       requests — packed prefill groups for the token arm, per-prompt
-       for the stream arm. A prompt whose KV blocks cannot be granted
-       requeues at the FRONT while others are still generating (their
-       completions free blocks), or fails typed when the cache could
-       never fit it right now.
-    2. **Step** every active request ONE token, under the step lock
-       (the ``paused()`` swap gate) and one scheduler slot (WFQ
-       preemption point: cost = live rows). Each attempt fires
-       ``serve.decode_step``; a failed batch step is re-run solo per
-       rider — only a request whose solo step ALSO fails gets
-       :class:`DecodeStepError`, its KV freed, batchmates untouched.
+    1. **Admit** queued prompts up to ``max_decode_batch`` requests
+       that still want a step launched (one whose last step is in
+       flight has given up its row) — packed prefill groups for the
+       token arm, per-prompt for the stream arm. A prompt whose KV
+       blocks cannot be granted requeues at the FRONT while others are
+       still generating (their completions free blocks), or fails
+       typed when the cache could never fit it right now.
+    2. **Step**: LAUNCH one token for every active request that still
+       wants one, then fetch and commit the work launched BEFORE (the
+       last step, or the chunk that admitted a newcomer), under the
+       step lock (the ``paused()`` swap gate) and one scheduler slot
+       (WFQ preemption point: cost = live rows). Who
+       rides a step is known without the step before it (the only stop
+       rule is ``max_new_tokens``), so the device computes step n+1
+       while the host copies step n back, counts it and answers. With
+       no one left to launch for, the step in flight is fetched alone:
+       an idle loop, a ``paused()`` swap and ``shutdown()`` leave
+       nothing unfetched behind. Each attempt fires
+       ``serve.decode_step``; a batch launch that raises is made again
+       solo per rider — only a request whose solo launch ALSO raises
+       gets :class:`DecodeStepError`, its KV freed, batchmates
+       untouched. A row that the fetch shows non-finite, and one whose
+       deadline passed with its step in flight, fails typed at that
+       commit; what the next step computed for it reaches no reply.
+       The pool's hooks hear of a launch there too, where its outcome
+       is known: ``on_batch`` with the rows a commit served (the
+       breaker's success), ``on_batch_error`` for each it failed.
     3. **Retire** finished requests immediately (event set between
        steps — the Orca property) with flight-recorder ctx
        ``tokens_generated`` / ``kv_blocks``.
+
+    An adapter gives the loop two halves: ``step(rids)`` and
+    ``prefill_group(group)`` launch their work and return whatever the
+    adapter has committed (the token arm: the step or chunk launched
+    before; the stream arm, whose inputs are host arrays: this one),
+    ``collect()`` commits what is in flight. A chunk rides the same
+    queue as a step: a prompt whose last piece has gone up is a row at
+    once, its first token comes back with the next launch. A request
+    counts the tokens launched for it apart from those it has; the
+    loop needs nothing else to serve both arms.
     """
 
     def __init__(self, adapter, *, name: str = "decode",
@@ -1524,6 +1713,7 @@ class DecodeEngine:
         self._cv = threading.Condition()
         self._lock = threading.RLock()   # step/execution lock (paused())
         self._shutdown = False
+        self._pausers = 0       # `paused()` holders and waiters (under _cv)
         self._ewma_step_s = 0.0
         self._step_no = 0       # step attempts made; names `decode/step`
 
@@ -1532,6 +1722,9 @@ class DecodeEngine:
                                      _TOKENS_HELP).labels(model=name)
         self._steps_c = reg.counter("serving_decode_steps_total",
                                     _STEPS_HELP).labels(model=name)
+        self._overlapped_c = reg.counter(
+            "serving_decode_steps_overlapped_total",
+            _OVERLAPPED_HELP).labels(model=name)
         self._prefills_c = reg.counter("serving_decode_prefills_total",
                                        _PREFILL_HELP).labels(model=name)
         self._itl_h = reg.histogram(
@@ -1578,7 +1771,7 @@ class DecodeEngine:
         return (ahead + 1) * svc
 
     def paused(self) -> _StepPause:
-        return _StepPause(self._lock)
+        return _StepPause(self)
 
     def warmup(self, max_bucket: Optional[int] = None, **_kw
                ) -> "DecodeEngine":
@@ -1659,17 +1852,18 @@ class DecodeEngine:
     def _loop(self):
         while True:
             with self._cv:
-                while (not self._queue and not self._active
-                       and not self._shutdown):
+                # (a step whose riders have all failed is still to fetch)
+                while not (self._queue or self._active or self._shutdown
+                           or self.adapter.in_flight()):
                     self._cv.wait(timeout=0.2)
-                if self._shutdown and not self._active and not self._queue:
+                if self._shutdown and not (self._active or self._queue
+                                           or self.adapter.in_flight()):
                     return
                 admits = self._take_admits_locked()
             try:
                 if admits:
                     self._admit(admits)
-                if self._active:
-                    self._step_once()
+                self._step_once()
             except Exception as e:  # noqa: BLE001 — loop must survive
                 # A bug past the per-step isolation would otherwise hang
                 # every caller: fail the in-flight set typed and keep
@@ -1683,7 +1877,12 @@ class DecodeEngine:
                                 if not r.event.is_set()]
 
     def _take_admits_locked(self) -> List[_DecodeRequest]:
-        room = self.max_decode_batch - len(self._active)
+        """Queued prompts for the rows that are free. A row is free once
+        its request's LAST step is launched: the request only waits for
+        that step's fetch, and a newcomer prefilled now rides the very
+        next step, as it would had the loop waited for the fetch."""
+        room = self.max_decode_batch - sum(
+            r.launched < r.max_new_tokens for r in self._active)
         admits: List[_DecodeRequest] = []
         while room > 0 and self._queue:
             admits.append(self._queue.popleft())
@@ -1730,10 +1929,10 @@ class DecodeEngine:
         self._retire_done()
 
     def _prefill_groups(self, live: List[_DecodeRequest]) -> int:
-        """Prefill `live` chunk by chunk, one decode step of the active
-        set between chunks (a row's token gap is bounded by one chunk,
-        not by a newcomer's whole prompt); the number that joined the
-        active set."""
+        """Launch `live`'s prefill chunk by chunk, one decode step of
+        the active set between chunks (a row's token gap is bounded by
+        one chunk, not by a newcomer's whole prompt); the number that
+        joined the active set."""
         admitted = 0
         by_rid = {r.rid: r for r in live}
         for gi, group in enumerate(self.adapter.pack_groups(
@@ -1756,7 +1955,8 @@ class DecodeEngine:
                         if tracing.is_enabled():
                             tracing.annotate(tokens=sum(
                                 int(piece[1].shape[0]) for piece in group))
-                        first, fails = self.adapter.prefill_group(group)
+                        done = [self.adapter.prefill_group(group)]
+                    self._drain_for_pausers(done)
                     dur = time.perf_counter() - t0
             except Exception as e:  # noqa: BLE001 — typed wrapper below
                 self.total_batch_failures += 1  # jaxlint: atomic
@@ -1771,37 +1971,36 @@ class DecodeEngine:
                 continue
             self.total_forwards += 1  # jaxlint: atomic (loop-thread stat)
             self._prefills_c.inc()
-            ok_reqs = [r for r in reqs if r.rid in first]
-            if self.on_batch is not None and ok_reqs:
-                self.on_batch(ok_reqs, len(ok_reqs),
-                              getattr(self.adapter, "pack_bucket", 0),
-                              dur)
-            now2 = time.perf_counter()
-            for r in ok_reqs:
-                item = first[r.rid]
-                r.generated.append(item)
-                r.last_item = item
-                r.t_last = now2
-                self._tokens_c.inc()
-                if r.trace is not None:
-                    r.trace.mark("prefill")
+            # A prompt whose last piece went up is a row from here on:
+            # its first token is in the adapter's keeping and comes
+            # back with what the loop launches next (the stream arm:
+            # with this very call).
+            rows = [r for r in reqs if self.adapter.is_row(r.rid)]
+            for r in rows:
+                r.launched = 1
                 self._active.append(r)
-            admitted += len(ok_reqs)
-            kv_starved = [r for r in reqs if isinstance(
-                fails.get(r.rid), KVCacheExhaustedError)]
+            admitted += len(rows)
+            # What the call says of a prompt of THIS chunk is the
+            # chunk's own trouble (no room, or the stream arm's check);
+            # the rest is the outcome of the work launched before.
+            fails = done[0][1]
+            own = {r: fails.pop(r.rid) for r in reqs if r.rid in fails}
+            kv_starved = [r for r, e in own.items()
+                          if isinstance(e, KVCacheExhaustedError)]
             if kv_starved:
-                self._requeue_or_fail(kv_starved, fails)
-            for r in reqs:
-                err = fails.get(r.rid)
-                if err is not None and r not in kv_starved:
+                self._requeue_or_fail(kv_starved, own)
+            for r, err in own.items():
+                if r not in kv_starved:
                     self.total_batch_failures += 1  # jaxlint: atomic
                     if self.on_batch_error is not None:
                         self.on_batch_error(err, 1)
-                    self._fail(r, err)
+                    self._fail_step(r, err)
+            self._settle(done, dur)
         return admitted
 
     def _requeue_or_fail(self, reqs: List[_DecodeRequest],
-                         fails: Dict[int, BaseException]) -> None:
+                         fails: Dict[_DecodeRequest, BaseException]
+                         ) -> None:
         """KV admission backpressure: while anything is generating its
         completion will free blocks — park the starved prompts at the
         FRONT of the queue. With nothing active the cache cannot free
@@ -1815,7 +2014,7 @@ class DecodeEngine:
             for r in reqs:
                 if self.on_shed is not None:
                     self.on_shed(r, "kv_exhausted")
-                self._fail(r, fails[r.rid])
+                self._fail(r, fails[r])
 
     # ------------------------------------------------------------------- step
     def _sched_slot(self, cost: float = 1.0):
@@ -1838,11 +2037,20 @@ class DecodeEngine:
 
     def _step_attempt(self, reqs: List[_DecodeRequest]
                       ) -> Tuple[Dict[int, Any], Dict[int, BaseException]]:
+        """Launch one step for `reqs`; what the adapter committed
+        meanwhile (earlier work's outcome, or this step's)."""
         self._step_no += 1  # jaxlint: atomic (loop-thread stat)
         with self._span("decode/step", reqs, step=self._step_no):
             faults.fire("serve.decode_step")
-            return self.adapter.step([r.rid for r in reqs],
-                                     [r.last_item for r in reqs])
+            ahead = self.adapter.in_flight()
+            done = self.adapter.step([r.rid for r in reqs])
+        for r in reqs:
+            r.launched += 1
+        self.total_forwards += 1  # jaxlint: atomic (loop-thread stat)
+        self._steps_c.inc()
+        if ahead:
+            self._overlapped_c.inc()
+        return done
 
     def _step_once(self) -> None:
         now = time.monotonic()
@@ -1855,63 +2063,83 @@ class DecodeEngine:
                 self._fail(req, DeadlineExceededError(
                     f"deadline passed after {len(req.generated)} "
                     "token(s)"))
-        if not self._active:
+        reqs = [r for r in self._active if r.launched < r.max_new_tokens]
+        if not reqs and not self.adapter.in_flight():
             return
-        reqs = list(self._active)
         t0 = time.perf_counter()
-        with self._lock, self._sched_slot(cost=float(len(reqs))):
+        with self._lock, self._sched_slot(cost=float(max(1, len(reqs)))):
             try:
-                out, fails = self._step_attempt(reqs)
+                done = [self._step_attempt(reqs) if reqs
+                        else self.adapter.collect()]
             except Exception as e:  # noqa: BLE001 — isolated below
                 self.total_batch_failures += 1  # jaxlint: atomic
                 if self.on_batch_error is not None:
                     self.on_batch_error(e, len(reqs))
-                out, fails = self._isolate(reqs, e)
+                done = self._isolate(reqs, e)
+            self._drain_for_pausers(done)
         dur = time.perf_counter() - t0
         # loop thread is the only writer; estimate_wait_s reads a
         # torn-free float snapshot
         self._ewma_step_s = (dur if self._ewma_step_s == 0.0  # jaxlint: atomic
                              else 0.8 * self._ewma_step_s + 0.2 * dur)
-        for rid, err in fails.items():
-            req = next((r for r in self._active if r.rid == rid), None)
-            if req is None:
-                continue
-            self.total_batch_failures += 1  # jaxlint: atomic
-            if self.on_batch_error is not None:
-                self.on_batch_error(err, 1)
-            self._fail_step(req, err)
-        if out:
-            self.total_forwards += 1  # jaxlint: atomic (loop-thread stat)
-            self._steps_c.inc()
-            if self.on_batch is not None:
-                survivors = [r for r in reqs if r.rid in out]
-                self.on_batch(survivors, len(survivors),
-                              next_pow2_bucket(len(reqs)), dur)
-            self._apply(out)
+        self._settle(done, dur)
         self._retire_done()
+
+    def _drain_for_pausers(self, done: List[Tuple[Dict[int, Any],
+                                                  Dict[int, BaseException]]]
+                           ) -> None:
+        """Under the step lock, as the loop's last act of a hold: with a
+        ``paused()`` announced, commit what is in flight too, so that
+        the lock is never let go with work unfetched while a pauser
+        waits for it."""
+        if self._pausers and self.adapter.in_flight():
+            done.append(self.adapter.collect())
+
+    def _settle(self, done: List[Tuple[Dict[int, Any],
+                                       Dict[int, BaseException]]],
+                dur: float) -> None:
+        """What the adapter committed, in the order it did: the failed
+        fail typed, the others get their tokens. Here, where a launch's
+        outcome is known, the pool's hooks hear of it: ``on_batch_error``
+        for each failed row, ``on_batch`` for the rows served (`dur`:
+        the seconds of the hold that committed them)."""
+        for out, fails in done:
+            for rid, err in fails.items():
+                req = next((r for r in self._active if r.rid == rid), None)
+                if req is None:
+                    continue
+                self.total_batch_failures += 1  # jaxlint: atomic
+                if self.on_batch_error is not None:
+                    self.on_batch_error(err, 1)
+                self._fail_step(req, err)
+            self._apply(out, dur)
+        if self._pausers:
+            with self._cv:
+                self._cv.notify_all()
 
     def _isolate(self, reqs: List[_DecodeRequest],
                  batch_err: BaseException
-                 ) -> Tuple[Dict[int, Any], Dict[int, BaseException]]:
-        """Solo-retry isolation after a failed batch step: each rider
-        re-steps ALONE (its own fault fire — mirrors ParallelInference's
-        per-attempt semantics); a request whose solo step also fails is
-        failed typed with its KV freed, survivors' results merge."""
+                 ) -> List[Tuple[Dict[int, Any], Dict[int, BaseException]]]:
+        """Solo-retry isolation after a batch launch that raised: each
+        rider is launched ALONE (its own fault fire — mirrors
+        ParallelInference's per-attempt semantics); a request whose solo
+        launch also raises is failed typed with its KV freed. Nothing
+        was advanced by the launch that raised, and the step before it
+        is still in flight: its outcome and the solo steps' come back in
+        order, the last of them at the loop's next step."""
         if len(reqs) == 1:
-            return {}, {reqs[0].rid: batch_err}
-        merged: Dict[int, Any] = {}
-        fails: Dict[int, BaseException] = {}
+            return [({}, {reqs[0].rid: batch_err})]
+        done: List[Tuple[Dict[int, Any], Dict[int, BaseException]]] = []
+        failed: Dict[int, BaseException] = {}
         for r in reqs:
-            if r not in self._active:
-                continue
+            if r.rid in failed:
+                continue        # an earlier step's commit failed it since
             try:
-                out_r, fails_r = self._step_attempt([r])
+                done.append(self._step_attempt([r]))
             except Exception as e:  # noqa: BLE001
-                fails[r.rid] = e
-                continue
-            merged.update(out_r)
-            fails.update(fails_r)
-        return merged, fails
+                done.append(({}, {r.rid: e}))
+            failed.update(done[-1][1])
+        return done
 
     def _fail_step(self, req: _DecodeRequest, cause: BaseException) -> None:
         if req in self._active:
@@ -1926,20 +2154,26 @@ class DecodeEngine:
             err.__cause__ = cause
         self._fail(req, err)
 
-    def _apply(self, out: Dict[int, Any]) -> None:
+    def _apply(self, out: Dict[int, Any], dur: float) -> None:
+        served = [r for r in self._active if r.rid in out]
+        if not served:
+            return
+        if self.on_batch is not None:
+            # one commit is one chunk's first tokens or one step's
+            chunk = not served[0].generated
+            self.on_batch(served, len(served),
+                          getattr(self.adapter, "pack_bucket", 0) if chunk
+                          else next_pow2_bucket(len(served)), dur)
         now = time.perf_counter()
-        for req in list(self._active):
-            if req.rid not in out:
-                continue
-            item = out[req.rid]
-            req.generated.append(item)
-            req.last_item = item
+        for req in served:
+            first = not req.generated       # the prefill's token
+            req.generated.append(out[req.rid])
             self._tokens_c.inc()
-            if req.t_last:
+            if not first:
                 self._itl_h.observe((now - req.t_last) * 1000.0)
             req.t_last = now
             if req.trace is not None:
-                req.trace.mark("decode_step")
+                req.trace.mark("prefill" if first else "decode_step")
 
     def _retire_done(self) -> None:
         for req in list(self._active):

@@ -5,6 +5,7 @@ and (slow) engine end-to-end parity / chaos isolation."""
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -47,6 +48,23 @@ def _arenas(cache):
     return tuple(np.asarray(a) for a in cache.arenas()["full"])
 
 
+def _own(ad, launch, *args):
+    """A launch's own outcome through the adapter's two halves: launch
+    it with nothing in flight, then fetch and commit it."""
+    earlier, fails = launch(*args)
+    assert not earlier
+    out, late = ad.collect()
+    return out, {**fails, **late}
+
+
+def _step(ad, rids):
+    return _own(ad, ad.step, rids)
+
+
+def _prefill(ad, group):
+    return _own(ad, ad.prefill_group, group)
+
+
 def _view(cache, *args):
     """A one-kind cache's step view: (the full table, lengths, starved)."""
     tables, starts, lens, starved = cache.batch_view(*args)
@@ -77,11 +95,11 @@ class TestPagedKVCache:
         ad = TransformerAdapter(m, c, pack_bucket=16)
         rng = np.random.default_rng(0)
         prompts = {7: rng.integers(0, 32, 6), 9: rng.integers(0, 32, 3)}
-        first, fails = ad.prefill_group(list(prompts.items()))
+        first, fails = _prefill(ad, list(prompts.items()))
         assert not fails and c.length(7) == 6 and c.blocks_of(7) == 2
         seqs = {r: list(p) + [first[r]] for r, p in prompts.items()}
         for _ in range(3):       # rid 7's third step opens its third block
-            out, fails = ad.step([7, 9], [seqs[7][-1], seqs[9][-1]])
+            out, fails = _step(ad, [7, 9])
             assert not fails
             for r in seqs:
                 seqs[r].append(out[r])
@@ -345,7 +363,7 @@ def test_pad_rows_and_warm_up_write_only_the_scratch_block(what):
     ad = _adapter()
     c = ad.cache
     ps = _prompts((5, 9, 10, 7))
-    first, fails = ad.prefill_group(list(enumerate(ps)))
+    first, fails = _prefill(ad, list(enumerate(ps)))
     assert not fails
     before = _arenas(c)
     in_use = c.blocks_in_use()
@@ -357,7 +375,7 @@ def test_pad_rows_and_warm_up_write_only_the_scratch_block(what):
             eng.swap_warm(3)
     else:
         # three riders in a bucket of four: one pad row; request 3 sits out
-        out, fails = ad.step([0, 1, 2], [first[r] for r in (0, 1, 2)])
+        out, fails = _step(ad, [0, 1, 2])
         assert not fails and sorted(out) == [0, 1, 2]
         for r in (0, 1, 2):
             tables, lens, _ = _view(c, [r], 16)
@@ -374,7 +392,7 @@ def test_a_non_finite_row_fails_alone_and_its_blocks_poison_no_one():
         ad = _adapter()
         c = ad.cache
         ps = _prompts((6, 11, 4))
-        first, fails = ad.prefill_group(list(enumerate(ps)))
+        first, fails = _prefill(ad, list(enumerate(ps)))
         assert not fails
         if poison:
             victim = _view(c, [0], 8)[0][0, 0]
@@ -384,7 +402,7 @@ def test_a_non_finite_row_fails_alone_and_its_blocks_poison_no_one():
         errs = {}
         for _ in range(3):
             live = [r for r in toks if r not in errs]
-            out, fails = ad.step(live, [toks[r][-1] for r in live])
+            out, fails = _step(ad, live)
             for r, e in fails.items():
                 errs[r] = e
                 ad.free(r)                 # as the engine does
@@ -399,9 +417,9 @@ def test_a_non_finite_row_fails_alone_and_its_blocks_poison_no_one():
     assert got[1] == clean[1] and got[2] == clean[2]
     # the next owner of the freed blocks attends over its own tokens only
     newcomer = _prompts((6,), seed=8)[0]
-    first, fails = ad.prefill_group([(9, newcomer)])
+    first, fails = _prefill(ad, [(9, newcomer)])
     assert not fails
-    out, fails = ad.step([9], [first[9]])
+    out, fails = _step(ad, [9])
     assert not fails
     assert [first[9], out[9]] == naive_generate(ad.model, newcomer, 2,
                                                 pad_to=PACK)
@@ -409,16 +427,21 @@ def test_a_non_finite_row_fails_alone_and_its_blocks_poison_no_one():
 
 def test_a_step_run_again_at_the_same_lengths_rewrites_the_same_slots():
     """What a solo retry relies on: the scatter goes where the lengths
-    point, and the lengths move only at commit."""
+    point and the token comes from where the slots point, and lengths
+    and feed move only with a launch that went through."""
     ad = _adapter()
     c, m = ad.cache, ad.model
-    first, _ = ad.prefill_group(list(enumerate(_prompts((6, 8)))))
+    first, _ = _prefill(ad, list(enumerate(_prompts((6, 8)))))
     tables, lens, _ = _view(c, [0, 1], 16)   # request 1 grows here
-    toks = np.asarray([first[0], first[1]], np.int32)
+    slot = np.asarray([ad._slot_of[0], ad._slot_of[1]], np.int32)
+    feed = np.asarray(ad._feed)
+    assert feed[slot].tolist() == [first[0], first[1]]
     runs = []
     for _ in range(2):
-        picked, finite, _ = c.update(
-            lambda a: m.step(toks, lens, a, {"full": tables}, {}, lens))
+        picked, finite, _, after = c.update(lambda a: m.step(
+            slot, lens, a, {"full": tables}, {}, lens, jnp.asarray(feed)))
+        assert np.asarray(after)[slot].tolist() == \
+            np.asarray(picked).tolist()
         runs.append((np.asarray(picked).tolist(), _arenas(c)))
     assert runs[0][0] == runs[1][0]
     assert not _changed_slots(runs[0][1], runs[1][1])
@@ -430,19 +453,22 @@ def test_link_bytes_are_the_formula_over_the_shapes(riders):
     ad = _adapter()
     ps = _prompts((5, 9, 12)[:riders])
     h0, d0 = _link("h2d", "prefill"), _link("d2h", "prefill")
-    first, _ = ad.prefill_group(list(enumerate(ps)))
-    # the packed row, its segments and positions; a block, an offset and
-    # a last position a slot; the context's table and its length. Back:
-    # an int32 token and a flag a slot
+    first, _ = _prefill(ad, list(enumerate(ps)))
+    # the packed row, its segments and positions; a block, an offset, a
+    # last position and a row slot a slot; the context's table and its
+    # length. Back: an int32 token and a flag a slot
     assert _link("h2d", "prefill") - h0 == \
-        (6 * PACK + ad._ctx_widths["full"] + 1) * 4
+        (7 * PACK + ad._ctx_widths["full"] + 1) * 4
     assert _link("d2h", "prefill") - d0 == PACK * 5
     h0, d0 = _link("h2d", "step"), _link("d2h", "step")
     rids = list(range(riders))
-    ad.step(rids, [first[r] for r in rids])
     rows, width = next_pow2_bucket(riders), ad.kv_bucket(rids) // BT
-    # tokens, positions, lengths and `width` table entries a row
+    ad.step(rids)
+    # row slots, positions, lengths and `width` table entries a row go
+    # up with the launch; the tokens come down with the fetch
     assert _link("h2d", "step") - h0 == rows * (3 + width) * 4
+    assert _link("d2h", "step") - d0 == 0
+    ad.collect()
     assert _link("d2h", "step") - d0 == rows * 5
 
 
@@ -453,9 +479,10 @@ def test_the_arenas_are_donated_and_the_modules_keep_their_names(which):
     arenas = c.arenas()
     k, v = arenas["full"]
     i32 = lambda *shape: np.zeros(shape, np.int32)
+    feed = ad._feed
     if which == "step":
         tables, starts, lens, _ = c.batch_view((), 16, 2)
-        args = (lens, lens, arenas, tables, starts, lens)
+        args = (lens, lens, arenas, tables, starts, lens, feed)
         lowered = m._step_fn.lower(m.params_tree, *args)
         out = m.step(*args)
     else:
@@ -463,14 +490,16 @@ def test_the_arenas_are_donated_and_the_modules_keep_their_names(which):
         args = (i32(PACK), i32(PACK), i32(PACK), arenas,
                 {"full": (np.full((PACK,), scratch, np.int32), i32(PACK))},
                 {"full": np.full((ad._ctx_widths["full"],), scratch,
-                                 np.int32)}, {}, np.int32(0), i32(PACK))
+                                 np.int32)}, {}, np.int32(0), i32(PACK),
+                feed, i32(PACK))
         lowered = m._prefill_fn.lower(m.params_tree, *args)
         out = m.prefill(*args)
     # the benchmark's device metrics find the runs by these names
     assert f"module @jit__{which}_pure" in lowered.as_text()
     assert "input_output_alias" in lowered.compile().as_text()
-    assert k.is_deleted() and v.is_deleted()
+    assert k.is_deleted() and v.is_deleted() and feed.is_deleted()
     assert [a.shape for a in out[-1]["full"]] == [k.shape, v.shape]
+    assert out[-2].shape == feed.shape and out[-2].dtype == np.int32
     assert out[0].dtype == np.int32 and out[1].dtype == np.bool_
 
 

@@ -46,16 +46,17 @@ def _served(cfg, seed, prompts, new=6):
         max_blocks={"full": 96, "sliding": 24})
     ad = TransformerAdapter(model, cache, pack_bucket=CHUNK,
                             max_rows=len(prompts))
-    first = {}
-    for group in ad.pack_groups(list(prompts.items())):
-        got, fails = ad.prefill_group(group)
-        assert not fails
-        first.update(got)
-    out = {r: [first[r]] for r in prompts}
-    for _ in range(new - 1):
-        got, fails = ad.step(list(prompts), [out[r][-1] for r in prompts])
-        assert not fails
-        for r in prompts:
+    out = {r: [] for r in prompts}
+    # as the engine runs them: each chunk and step is launched before
+    # the one before it is fetched, the tokens read from the device's
+    # feed; what comes back is the work before's
+    launches = [(ad.prefill_group, g)
+                for g in ad.pack_groups(list(prompts.items()))] \
+        + [(ad.step, list(prompts))] * (new - 1) + [(ad.collect,)]
+    for n, (launch, *args) in enumerate(launches):
+        got, fails = launch(*args)
+        assert not fails and (n or not got)
+        for r in got:
             out[r].append(got[r])
     return out, cache, ad
 
@@ -200,9 +201,9 @@ def test_the_engine_drains_both_kinds_and_steps_between_chunks():
         peak = []
         real = ad.step
 
-        def watched(rids, items):
+        def watched(rids):
             peak.append(cache.blocks_in_use("sliding"))
-            return real(rids, items)
+            return real(rids)
 
         ad.step = watched
         import threading

@@ -112,13 +112,13 @@ def _host_operands(setting, m, which):
     if which == "step":
         tables, starts, lens, _ = tiny.batch_view((), setting["kv"],
                                                   setting["rows"])
-        return lens, lens, tables, starts, lens
+        return lens, lens, tables, starts, lens, ad._feed
     pb = ad.pack_bucket
     return (i32(pb), i32(pb), i32(pb),
             {k: (i32(pb), i32(pb)) for k in tiny.kinds},
             {k: i32(w) for k, w in ad._ctx_widths.items()},
             {k: np.int32(0) for k in tiny.kinds if k == "sliding"},
-            np.int32(0), i32(ad.last_width))
+            np.int32(0), i32(ad.last_width), ad._feed, i32(ad.last_width))
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
@@ -127,17 +127,18 @@ def test_the_arenas_are_updated_where_they_lie(compiled, setting, which):
     m, params, arenas = _shapes(setting)
     ops = _host_operands(setting, m, which)
     if which == "step":
-        exe = compiled(m._step_pure, (3,), params, *ops[:2], arenas,
+        exe = compiled(m._step_pure, (3, 7), params, *ops[:2], arenas,
                        *ops[2:])
     else:
-        exe = compiled(m._prefill_pure, (4,), params, *ops[:3], arenas,
+        exe = compiled(m._prefill_pure, (4, 10), params, *ops[:3], arenas,
                        *ops[3:])
     text = exe.as_text()
     leaves = jax.tree_util.tree_leaves(arenas)
     alias = re.search(r"input_output_alias=\{([^\n]*?)\}, entry", text)
+    # every arena, and the feed of the rows' next tokens
     assert alias and alias.group(1).count("may-alias") + \
-        alias.group(1).count("must-alias") == len(leaves), \
-        "an arena is not donated"
+        alias.group(1).count("must-alias") == len(leaves) + 1, \
+        "an arena or the feed is not donated"
     arena_bytes = [int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves]
     mem = exe.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(arena_bytes)

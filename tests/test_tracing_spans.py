@@ -193,6 +193,9 @@ def _serve(traced: bool):
     telemetry.compilation_count()           # the listener is attached
     h0 = _counter("serving_decode_h2d_bytes_total", "step")
     d0 = _counter("serving_decode_d2h_bytes_total", "step")
+    ahead = registry().counter(
+        "serving_decode_steps_overlapped_total").labels(model="spans")
+    o0 = ahead.value()
     with _engine() as eng:
         tokens = [eng.generate([5, 9, 2, 40, 7], max_new_tokens=6),
                   eng.generate(list(range(1, 12)), max_new_tokens=8)]
@@ -225,7 +228,7 @@ def _serve(traced: bool):
     tracing.disable()
     tracing.clear()
     return dict(tokens=tokens + out, first=h1 - h0, whole=h2 - h0,
-                down=d2 - d0, events=events,
+                down=d2 - d0, overlapped=ahead.value() - o0, events=events,
                 rids=[t.rid for t in traces if t is not None])
 
 
@@ -236,7 +239,12 @@ def served():
     return _serve(True), _serve(True), _serve(False)
 
 
-def test_one_decode_step_span_per_step_and_its_four_children_tile_it(served):
+def test_a_step_span_launches_its_step_and_fetches_the_work_before(served):
+    """Each `decode/step` gathers and launches its own step and then
+    fetches and commits what was launched before it (a step, or the
+    chunk that admitted its rows), while its own runs; a step nothing
+    was launched after is fetched alone. Every step is fetched and
+    committed once, under its `seq`."""
     ev = served[0]["events"]
     steps = _named(ev, "decode/step")
     assert [s["args"]["step"] for s in steps] == \
@@ -244,14 +252,16 @@ def test_one_decode_step_span_per_step_and_its_four_children_tile_it(served):
     # 5 + 7 steps alone (the first token comes from the prefill), then
     # the pair: 4 steps if they ran together, up to 8 if not
     assert 12 + 4 <= len(steps) <= 12 + 8
+    rows_of, overlapped = {}, 0
     for s in steps:
         kids = sorted((e for e in ev
                        if e["args"]["parent_id"] == s["args"]["span_id"]
                        and e["name"].startswith("decode/")),
                       key=lambda e: e["ts"])
-        assert [k["name"] for k in kids] == [
-            "decode/gather", "decode/launch", "decode/fetch",
-            "decode/commit"]
+        assert [k["name"] for k in kids] in (
+            ["decode/gather", "decode/launch"],
+            ["decode/gather", "decode/launch", "decode/fetch",
+             "decode/commit"])
         edge = s["ts"]
         for k in kids:
             assert k["ts"] >= edge - 1.0 and k["cat"] == "serve"
@@ -259,13 +269,35 @@ def test_one_decode_step_span_per_step_and_its_four_children_tile_it(served):
         assert edge <= s["ts"] + s["dur"] + 1.0
         a = s["args"]
         assert a["rows"] == len(a["rids"]) <= a["row_bucket"]
-        # gather: the table and the lengths; launch: those, the tokens
-        # and the positions; fetch: an int32 token and a flag a row
+        # gather: the table and the lengths; launch: those, the row
+        # slots and the positions
         assert kids[0]["args"]["bytes"] == _step_bytes(
             a["row_bucket"], a["kv_bucket"]) - 2 * a["row_bucket"] * 4
         assert kids[1]["args"]["bytes"] == _step_bytes(a["row_bucket"],
                                                        a["kv_bucket"])
-        assert kids[2]["args"]["bytes"] == a["row_bucket"] * 5
+        seq = kids[1]["args"]["seq"]
+        rows_of[seq] = a["row_bucket"]
+        if len(kids) == 4:      # the work before, begun before this launch
+            assert kids[2]["args"].get("seq", seq - 1) == \
+                kids[3]["args"].get("seq", seq - 1) == seq - 1
+            assert kids[1]["ts"] < kids[2]["ts"] + kids[2]["dur"]
+            overlapped += 1
+    assert sorted(rows_of) == list(range(1, len(steps) + 1))
+    fetches = _named(ev, "decode/fetch")
+    by_seq = {f["args"]["seq"]: f for f in fetches if "seq" in f["args"]}
+    assert sorted(by_seq) == sorted(rows_of)        # once each
+    assert sorted(c["args"]["seq"] for c in _named(ev, "decode/commit")
+                  if "seq" in c["args"]) == sorted(rows_of)
+    launches = {e["args"]["seq"]: e for e in _named(ev, "decode/launch")
+                if "seq" in e["args"]}
+    for seq, f in by_seq.items():
+        # fetch: an int32 token and a flag a row of the step it fetches
+        assert f["args"]["bytes"] == rows_of[seq] * 5
+        assert f["ts"] >= launches[seq]["ts"] + launches[seq]["dur"] - 1.0
+    # a request alone runs ahead from its first step (launched before its
+    # chunk is fetched) to its last
+    assert overlapped >= 5 + 7 + 4
+    assert served[0]["overlapped"] == overlapped
 
 
 def test_rids_are_the_flight_recorders_where_it_is_on(served):
@@ -290,7 +322,10 @@ def test_rids_are_the_flight_recorders_where_it_is_on(served):
                                           key=lambda e: e["ts"])
                 if e["args"]["parent_id"] == p["args"]["span_id"]
                 and e["name"].startswith("decode/")]
-        assert kids == ["decode/launch", "decode/fetch", "decode/commit"]
+        # its own launch, then the fetch and commit of the step that was
+        # in flight when it was launched, if one was
+        assert kids in (["decode/launch"],
+                        ["decode/launch", "decode/fetch", "decode/commit"])
     admits = _named(run["events"], "decode/admit")
     assert sum(a["args"]["admitted"] for a in admits) == 4
 
@@ -473,13 +508,15 @@ def test_the_decoders_programs_name_their_parts_and_decode_attention():
     b = 2
     arenas = {"full": (jnp.zeros((L, 5, BT, H * DH), jnp.float32),) * 2}
     z = jnp.zeros((b,), jnp.int32)
+    feed = jnp.zeros((b + 1,), jnp.int32)
     step = _lowered_text(m._step_pure, m.params_tree, z, z, arenas,
-                         {"full": jnp.zeros((b, 2), jnp.int32)}, {}, z + 1)
+                         {"full": jnp.zeros((b, 2), jnp.int32)}, {}, z + 1,
+                         feed)
     row = jnp.zeros((16,), jnp.int32)
     prefill = _lowered_text(m._prefill_pure, m.params_tree, row, row, row,
                             arenas, {"full": (row, row)},
                             {"full": jnp.zeros((4,), jnp.int32)}, {},
-                            jnp.int32(0), row)
+                            jnp.int32(0), row, feed, row)
     for text in (step, prefill):
         for name in ("embed", "layer_0/attn_full", "layer_0/mlp",
                      "layer_1/attn_full", "layer_1/mlp", "head",
@@ -508,7 +545,7 @@ def test_a_sparse_windowed_decoder_names_its_layer_kinds_and_experts():
     step = _lowered_text(m._step_pure, m.params_tree, z, z,
                          {"full": arena, "sliding": arena},
                          {"full": tables, "sliding": tables},
-                         {"sliding": z}, z + 1)
+                         {"sliding": z}, z + 1, jnp.zeros((b + 1,), jnp.int32))
     for name in ("layer_0/attn_sliding/decode_attention_sliding",
                  "layer_1/attn_full/decode_attention_full",
                  "layer_2/attn_sliding", "layer_3/attn_full",
