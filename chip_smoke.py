@@ -77,12 +77,13 @@ REAL = dict(
     distinct_batches=4, epochs=8,
     serve_sizes=(1, 2, 3, 5, 8, 1, 4, 2, 3, 1, 1, 1), serve_clients=3,
     serve_batch_limit=8,
-    # bench.py bench_serving_decode's geometry
+    # a small causal LM: 4 layers of 4 heads x 32, 8 decode rows, 128-token
+    # prefill chunks, 16-token KV blocks
     lm=dict(vocab=256, layers=4, heads=4, head_dim=32, ff=512,
             max_context=256),
     lm_decode_batch=8, lm_pack=128, lm_kv_block=16,
     prompt_lens=(4, 9, 17, 32, 12, 25), new_tokens=24,
-    # bench.py bench_attention_longctx / bench_attention_packed geometry
+    # long context: 4,096 positions, 4 heads x 128 (one MXU tile a head)
     attn_seq=4096, attn_heads=4, attn_head_dim=128, attn_batch=2,
     attn_layer_batch=8,
     int8=(8, 1024, 1024),
@@ -695,7 +696,7 @@ def phase_kernels(cfg, interpret: bool):
 
 def phase_attention_layer(cfg, rehearse: bool):
     """One SelfAttentionLayer training step through the framework's fit at
-    the long-context bench's geometry: on a TPU the dispatch rule must
+    the long-context geometry (`attn_seq`, `attn_heads`): on a TPU the dispatch rule must
     pick the fused kernel by itself."""
     import jax.numpy as jnp
     from deeplearning4j_tpu import (InputType, MultiLayerNetwork,

@@ -44,8 +44,8 @@ _BARE_OK = {"jit", "pjit", "pmap", "shard_map", "PrecompiledDispatch"}
 def build_alias_map(tree: ast.AST) -> Dict[str, str]:
     """Import-alias resolution (``import numpy as np`` → np: numpy;
     ``from jax import numpy as jnp`` → jnp: jax.numpy), collected from
-    every import statement in the file (function-local ones included —
-    the fit loops import ``time as _time`` locally)."""
+    every import statement in the file (function-local ones
+    included)."""
     aliases: Dict[str, str] = {}
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):

@@ -23,8 +23,7 @@ Rule families
   written outside it).
 * JL5xx  serving discipline: JL501 typed-error taxonomy at HTTP route
   handlers, JL502 metrics-family discipline (hot-path construction,
-  unbounded label cardinality, missing ``bench --once``
-  pre-registration), JL503 fault-point chaos coverage (every
+  unbounded label cardinality, missing pre-registration), JL503 fault-point chaos coverage (every
   ``faults.fire`` literal must be exercised by a test and documented).
 
 Hotness is lexical: a function is *hot* if its name looks like a
@@ -1215,16 +1214,12 @@ _PREREG_CACHE: Dict[str, frozenset] = {}
 
 def _preregistered_families(pkg_root: str) -> frozenset:
     """Every string constant inside a ``register*metrics`` function in
-    the package or the repo-root ``bench.py`` — the families a
-    ``bench --once`` scrape pre-registers before any traffic."""
+    the package — the families a scrape sees at 0 before any traffic."""
     cached = _PREREG_CACHE.get(pkg_root)
     if cached is not None:
         return cached
     names: Set[str] = set()
     files = [f for f in _tree_files(pkg_root) if f.endswith(".py")]
-    bench = os.path.join(os.path.dirname(pkg_root), "bench.py")
-    if os.path.isfile(bench):
-        files.append(bench)
     for fname in files:
         try:
             with open(fname, "r", encoding="utf-8") as fh:
@@ -1274,7 +1269,7 @@ def _check_metrics_discipline(ctx):
                     f"metric label '{kw.arg}' is fed from "
                     f"'{_name_of(kw.value.func)}()' — unbounded "
                     f"cardinality mints a new series per value")
-    # (c) serving families absent from bench --once pre-registration
+    # (c) serving families absent from every pre-registration
     if "serving" not in os.path.normpath(ctx.path).split(os.sep):
         return
     pkg = _package_root(ctx.path)
@@ -1293,8 +1288,8 @@ def _check_metrics_discipline(ctx):
             continue
         yield node, (
             f"metric family '{fam}' used in serving/ but absent from "
-            f"every register_metrics() pre-registration — a bench "
-            f"--once scrape misses it until first use")
+            f"every register_metrics() pre-registration — a scrape "
+            f"before the first request misses it")
 
 
 # --- JL503: fault-point coverage ------------------------------------------
@@ -1431,8 +1426,8 @@ RULES: Tuple[Rule, ...] = (
          _check_route_typed_errors),
     Rule("JL502", "warning", "metrics-discipline",
          "Construct metric families once in register_metrics(), keep "
-         "label sets bounded, and pre-register serving families so "
-         "bench --once scrapes see them.",
+         "label sets bounded, and pre-register serving families so a "
+         "scrape before traffic sees them.",
          _check_metrics_discipline),
     Rule("JL503", "error", "fault-coverage",
          "Add a test that arms the point (faults.inject/injected) and a "

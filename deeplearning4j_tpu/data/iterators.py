@@ -291,7 +291,7 @@ class AsyncDataSetIterator(DataSetIterator):
 class AsyncMultiDataSetIterator(AsyncDataSetIterator):
     """Background prefetch over MultiDataSet streams (reference
     datasets/iterator/AsyncMultiDataSetIterator.java) — same bounded-queue
-    machinery; ComputationGraph.fit wraps with this (reference
+    machinery; a ComputationGraph's fit wraps with this (reference
     ComputationGraph.java:867)."""
 
     def __init__(self, base, queue_size: int = 8):

@@ -190,9 +190,8 @@ _pack_totals = {}  # source -> [real_tokens, padded_tokens]
 
 
 def register_packing_metrics() -> None:
-    """Pre-register the packing families at zero (bench --once calls
-    this so a scrape before any packed traffic still shows the
-    families)."""
+    """Pre-register the packing families at zero, so a scrape before
+    any packed traffic still shows the families."""
     from ..optimize.metrics import registry
     reg = registry()
     for source in ("fit", "serve"):

@@ -115,8 +115,8 @@ def ffi_register() -> None:
 
 def vnni() -> bool:
     """True when the loaded library will actually run the VNNI kernel
-    (compiled in AND the CPU supports it) — surfaced in the bench row so
-    a ledger verdict records which hardware path it measured."""
+    (compiled in AND the CPU supports it), so a measurement can record
+    which hardware path it ran."""
     lib = _load()
     return bool(lib is not None and lib.int8_gemm_vnni_available())
 

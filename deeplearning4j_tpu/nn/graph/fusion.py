@@ -85,7 +85,7 @@ def _count_fusion(outcome: str, n: int = 1) -> None:
 
 
 def register_metrics() -> None:
-    """Pre-register the fusion counter family (bench --once pattern)."""
+    """Pre-register the fusion counter family at 0."""
     from ...optimize.metrics import registry
     fam = registry().counter(
         "sibling_conv_fusion_total",

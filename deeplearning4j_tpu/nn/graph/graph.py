@@ -23,28 +23,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...data.dataset import DataSet, MultiDataSet
+from ...data.iterators import AsyncMultiDataSetIterator
 from ...optimize import compile_cache as compile_cache_mod
 from ...optimize import metrics as metrics_mod
 from ...optimize import telemetry as telemetry_mod
-from ...optimize import tracing
 from ...utils import params as param_utils
 from ..conf.builders import BackpropType
 from ..conf.graph_conf import ComputationGraphConfiguration
 from ..graph.vertices import LastTimeStepVertex
 from ..multilayer import RnnStateMismatchError, _regularization_score
 from ..updaters import normalize_layer_gradients
-from ..stepping import DeviceIterationMixin
+from ..stepping import Trainer
 from ..layers.recurrent import RECURRENT_CARRY_KEYS
 
 Array = jax.Array
-
-# Training-only jit attributes, built lazily on first touch (the MLN
-# _TRAIN_JIT_ATTRS analog; inference-only graphs never pay their
-# compiles).
-_TRAIN_JIT_ATTRS = (
-    "_train_step_fn", "_train_step_raw",
-    "_multi_step_stacked_fn", "_multi_step_repeat_fn",
-)
 
 
 class _SlicingMultiIterator:
@@ -69,7 +61,16 @@ class _SlicingMultiIterator:
                 [None if m is None else m[sl] for m in mds.labels_masks])
 
 
-class ComputationGraph(DeviceIterationMixin):
+class ComputationGraph(Trainer):
+    # What Trainer asks of a front end (nn/stepping.py).
+    _TRAIN_JIT_ATTRS = (
+        "_train_step_fn", "_train_step_raw",
+        "_multi_step_stacked_fn", "_multi_step_repeat_fn",
+    )
+    _STEP_LABEL = "graph_train_step"
+    _ASYNC_ITERATOR = AsyncMultiDataSetIterator
+    _FUSES_TBPTT = False
+
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
         self.params_tree: Optional[Dict[str, dict]] = None
@@ -94,14 +95,6 @@ class ComputationGraph(DeviceIterationMixin):
         # Streaming/tBPTT recurrent carry, keyed by node name (the MLN
         # _rnn_carry analog; reference ComputationGraph rnn state maps).
         self._rnn_carry: Optional[Dict[str, dict]] = None
-
-    def __getattr__(self, name):
-        # Lazy training jits (see MultiLayerNetwork.__getattr__).
-        if name in _TRAIN_JIT_ATTRS and self.__dict__.get("_initialized"):
-            self._build_training_jits()
-            return self.__dict__[name]
-        raise AttributeError(
-            f"{type(self).__name__!r} object has no attribute {name!r}")
 
     # ------------------------------------------------------------------ init
     def init(self, seed: Optional[int] = None, dtype=jnp.float32
@@ -211,10 +204,10 @@ class ComputationGraph(DeviceIterationMixin):
 
     def _build_jitted(self):
         """(Re)build the inference jits and invalidate the training
-        jits (rebuilt lazily via __getattr__ — see
+        jits (rebuilt lazily via Trainer.__getattr__ — see
         MultiLayerNetwork._build_jitted)."""
         conf = self.conf
-        for name in _TRAIN_JIT_ATTRS:
+        for name in self._TRAIN_JIT_ATTRS:
             self.__dict__.pop(name, None)
         self._output_fn = compile_cache_mod.PrecompiledDispatch(
             jax.jit(lambda params, state, inputs, fmasks:
@@ -409,19 +402,6 @@ class ComputationGraph(DeviceIterationMixin):
                 labels_s, {}, {}, int(repeat_steps))
         return self
 
-    def warmup(self, batch_size: int = 1, *,
-               time_steps: Optional[int] = None) -> "ComputationGraph":
-        """Serving cold-start eliminator (see MultiLayerNetwork.warmup):
-        AOT-compile inference and push one concrete zero batch — host
-        float32, as a request delivers it, so a bf16 graph's per-shape
-        input cast is warmed too — through outputs()."""
-        self._check_init()
-        self.precompile(batch_size, time_steps=time_steps, train=False)
-        inputs_s = self._input_structs(batch_size, time_steps)
-        self.outputs(*[np.zeros(s.shape, np.float32)
-                       for s in inputs_s.values()])
-        return self
-
     # ----------------------------------------------------------------- data
     def _coerce(self, data, labels=None) -> MultiDataSet:
         if isinstance(data, MultiDataSet):
@@ -472,191 +452,29 @@ class ComputationGraph(DeviceIterationMixin):
         return inputs, fmasks
 
     # ------------------------------------------------------------------- fit
-    def fit(self, data, labels=None, *, epochs: int = 1,
-            batch_size: int = 32, step_fn=None, use_async: bool = True,
-            async_queue_size: int = 8, steps_per_dispatch: int = 1,
-            pad_to_bucket: bool = True, prefetch_to_device: bool = True,
-            prefetch_depth: int = 2, prefetch_sharding=None,
-            prefetch_divisor: int = 1,
-            checkpoint=None, resume: bool = False, sentinel=None
-            ) -> "ComputationGraph":
-        """Train (reference fit(MultiDataSetIterator):867). Accepts a
-        MultiDataSet, DataSet, (features, labels) arrays, or an iterator of
-        either. `step_fn` lets ParallelWrapper substitute a sharded step.
-        Batches prefetch on a background thread (the reference wraps with
-        AsyncMultiDataSetIterator at :867) unless use_async=False;
-        `prefetch_to_device` upgrades that thread to stage batches onto
-        the device, and `pad_to_bucket` pads ragged batches to the
-        epoch's canonical shape under the zero-weight mask contract so
-        one compiled step serves the whole epoch
-        (docs/perf_data_pipeline.md — both mirror MultiLayerNetwork.fit).
-        `steps_per_dispatch > 1` groups same-shaped batches into one
-        fused lax.scan dispatch (see MultiLayerNetwork.fit).
-        `checkpoint`/`resume`/`sentinel` attach the fault-tolerance
-        control plane exactly as in MultiLayerNetwork.fit
-        (docs/robustness.md)."""
-        from ...data.iterators import (AsyncMultiDataSetIterator,
-                                       DevicePrefetchIterator,
-                                       PadToBucketIterator)
-        self._check_init()
-        spd = int(steps_per_dispatch)
-        if spd > 1 and step_fn is not None:
-            raise ValueError("steps_per_dispatch cannot combine with a "
-                             "custom step_fn")
-        if spd > 1 and (checkpoint is not None or sentinel is not None):
-            raise ValueError("checkpoint=/sentinel= need per-step hooks; "
-                             "use steps_per_dispatch=1")
-        if resume and checkpoint is None:
-            raise ValueError("resume=True requires checkpoint=a "
-                             "CheckpointManager to resume from")
-        skip_batches = 0
-        if resume:
-            rec = checkpoint.restore_into(self)
-            if rec is not None:
-                epochs = max(0, int(epochs) - int(self.epoch))
-                skip_batches = int(rec.get("batches_into_epoch", 0) or 0)
-                logging.getLogger(__name__).info(
-                    "auto-resume: restored %s (iteration %d, %d epoch(s) "
-                    "done, %d batch(es) into the next); %d epoch(s) "
-                    "remain", rec.get("file"), self.iteration, self.epoch,
-                    skip_batches, epochs)
-        if spd > 1 and self.conf.backprop_type == \
-                BackpropType.TRUNCATED_BPTT:
-            raise NotImplementedError(
-                "steps_per_dispatch > 1 does not support truncated BPTT "
-                "iterators; use fit_batch_repeated for resident batches")
-        step = step_fn or self.fit_batch
+    # `fit` itself is Trainer's (nn/stepping.py); these are its hooks.
+    def _batches(self, data, labels, batch_size, epochs):
         if hasattr(data, "__iter__") and not isinstance(
                 data, (DataSet, MultiDataSet, list, tuple, np.ndarray)):
-            iterator = data
-            if epochs > 1 and not hasattr(iterator, "reset"):
+            if epochs > 1 and not hasattr(data, "reset"):
                 # Plain generator: materialize so later epochs see data.
-                iterator = list(iterator)
-        else:
-            mds = self._coerce(data, labels)
-            iterator = _SlicingMultiIterator(mds, batch_size)
-        if pad_to_bucket and \
-                self.conf.backprop_type != BackpropType.TRUNCATED_BPTT:
-            # Same tBPTT gate as MultiLayerNetwork.fit: the synthesized
-            # (n,1) zero-weight mask cannot be time-windowed.
-            iterator = PadToBucketIterator(iterator)
-        async_ok = getattr(iterator, "async_supported", lambda: True)()
-        if use_async and async_ok:
-            wrapped = DevicePrefetchIterator(
-                iterator, depth=max(1, int(prefetch_depth)),
-                sharding=prefetch_sharding,
-                batch_divisor=prefetch_divisor,
-                cast_dtype=self._dtype) if prefetch_to_device \
-                else AsyncMultiDataSetIterator(iterator, async_queue_size)
-        else:
-            wrapped = iterator
-        group: List[MultiDataSet] = []
+                return list(data)
+            return data
+        return _SlicingMultiIterator(self._coerce(data, labels), batch_size)
 
-        def group_sig(m):
-            # .shape directly — np.asarray on device-resident arrays
-            # would force d2h copies per batch in the hot loop
-            def _shape(a):
-                return a.shape if hasattr(a, "shape") else np.asarray(a).shape
-            return (tuple(_shape(f) for f in m.features),
-                    tuple(_shape(l) for l in m.labels),
-                    m.features_masks is None, m.labels_masks is None)
+    @staticmethod
+    def _batch_signature(m: MultiDataSet):
+        # .shape directly — np.asarray on device-resident arrays
+        # would force d2h copies per batch in the hot loop
+        def _shape(a):
+            return a.shape if hasattr(a, "shape") else np.asarray(a).shape
+        return (tuple(_shape(f) for f in m.features),
+                tuple(_shape(l) for l in m.labels),
+                m.features_masks is None, m.labels_masks is None)
 
-        def flush_group():
-            if not group:
-                return
-            if len(group) == 1:
-                step(group[0])
-            else:
-                self.fit_batches(group)
-            group.clear()
-
-        import time as _time
-        reg = metrics_mod.registry()
-        fit_sp = tracing.begin("fit", epochs=epochs)
-        try:
-            for _ in range(epochs):
-                epoch_sp = tracing.begin("epoch", epoch=self.epoch)
-                # Resumed run: re-consume (and discard) the batches the
-                # restored checkpoint already covers — first epoch only.
-                to_skip, skip_batches = skip_batches, 0
-                batches_done = to_skip
-                it_epoch = iter(wrapped)
-                while True:
-                    # Step span opens before the iterator poll so the
-                    # etl child nests inside it (see MultiLayerNetwork).
-                    step_sp = tracing.begin("step",
-                                            step_num=self.iteration)
-                    # Track time blocked on the data pipeline (reference
-                    # lastEtlTime); PerformanceListener reports it, with
-                    # the producer-side host/h2d split when device
-                    # prefetch is active.
-                    t0 = _time.perf_counter()
-                    try:
-                        ds = next(it_epoch)
-                    except StopIteration:
-                        step_sp.cancel()
-                        break
-                    if to_skip > 0:
-                        to_skip -= 1
-                        step_sp.cancel()
-                        continue
-                    etl_s = _time.perf_counter() - t0
-                    self.last_etl_ms = etl_s * 1000.0
-                    self.last_etl_host_ms = getattr(
-                        ds, "_etl_host_ms", self.last_etl_ms)
-                    self.last_etl_h2d_ms = getattr(ds, "_etl_h2d_ms", 0.0)
-                    tracing.add_span("etl", t0, etl_s)
-                    mds = self._coerce(ds)
-                    metrics_mod.record_etl(
-                        reg, self.last_etl_ms, self.last_etl_host_ms,
-                        self.last_etl_h2d_ms, metrics_mod.batch_rows(mds))
-                    t1 = _time.perf_counter()
-                    if sentinel is not None:
-                        sentinel.before_step(self)
-                    with tracing.span("dispatch"):
-                        if spd <= 1:
-                            step(mds)
-                        else:
-                            if group and \
-                                    group_sig(mds) != group_sig(group[0]):
-                                flush_group()
-                            group.append(mds)
-                            if len(group) >= spd:
-                                flush_group()
-                    reg.histogram(
-                        "train_step_dispatch_ms",
-                        "Host-side enqueue time per fit-loop batch "
-                        "(async: device time needs the fence)").observe(
-                            (_time.perf_counter() - t1) * 1000.0)
-                    w = tracing.fence(self.iteration, self.score_value)
-                    if w is not None:
-                        reg.gauge(
-                            "device_fence_wait_ms",
-                            "Dispatch-queue drain at the last sampled "
-                            "fence (device-compute backlog)").set(w)
-                    if sentinel is not None:
-                        sentinel.after_step(self)
-                    batches_done += 1
-                    if checkpoint is not None:
-                        checkpoint.on_batch(self, batches_done)
-                    step_sp.end()
-                if group:
-                    with tracing.span("dispatch", flush="epoch_tail"):
-                        flush_group()
-                self.epoch += 1
-                reg.counter("train_epochs_total",
-                            "Completed fit epochs").inc()
-                for lst in self.listeners:
-                    if hasattr(lst, "on_epoch_end"):
-                        lst.on_epoch_end(self, self.epoch)
-                if checkpoint is not None:
-                    checkpoint.on_epoch(self)
-                epoch_sp.end()
-        finally:
-            fit_sp.end()
-            if wrapped is not iterator:
-                wrapped.shutdown()
-        return self
+    def _input_shapes(self, batch_size, time_steps):
+        return [s.shape for s in
+                self._input_structs(batch_size, time_steps).values()]
 
     def fit_batch(self, mds: MultiDataSet, do_step=None):
         """One training batch. `do_step(inputs, labels, fmasks, lmasks)`
@@ -676,13 +494,14 @@ class ComputationGraph(DeviceIterationMixin):
                 self._fit_tbptt(mds, do_step)
                 return
             if not getattr(self, "_warned_tbptt_labels", False):
-                import logging
                 logging.getLogger(__name__).warning(
                     "Truncated BPTT requires rank-3 features and labels; "
                     "using standard BPTT")
                 self._warned_tbptt_labels = True
         self._rnn_carry = None  # standard BPTT: every batch starts fresh
         do_step(*self._pack(mds))
+
+    _fit_batch = fit_batch      # the name Trainer.fit steps through
 
     def fit_batches(self, batches: Sequence) -> "ComputationGraph":
         """K optimizer steps over K minibatches in ONE device dispatch
@@ -719,22 +538,6 @@ class ComputationGraph(DeviceIterationMixin):
             self._iteration_device(None), self._rng, *packed, int(steps))
         self._commit_multi(out, int(steps))
         return self
-
-    def _commit_multi(self, out, steps: int):
-        (self.params_tree, self.opt_state, self.state_tree, it, self._rng,
-         losses) = out
-        self._iteration += steps
-        metrics_mod.record_train_step(steps)
-        self._iteration_dev = it
-        self._iteration_dev_mesh = None
-        self.score_value = losses[-1]
-        if self.listeners:
-            for k in range(steps):
-                self.score_value = losses[k]
-                for lst in self.listeners:
-                    lst.iteration_done(
-                        self, self._iteration - steps + k + 1)
-            self.score_value = losses[-1]
 
     def _fit_tbptt(self, mds: MultiDataSet, do_step=None):
         """Truncated BPTT over the graph: slide tbptt_fwd_length windows
@@ -828,35 +631,6 @@ class ComputationGraph(DeviceIterationMixin):
             self.params_tree, self._merged_state(), inputs)
         self._commit_state(new_state)
         return [np.asarray(o) for o in outs]
-
-    def _run_and_commit(self, inputs, labels, fmasks, lmasks, mesh=None):
-        """Invoke the jitted step and commit results + listeners (shared by
-        the single-device path and ParallelWrapper's sharded path)."""
-        import contextlib
-        telemetry_mod.note_step_signature(
-            f"graph_train_step#{self._probe_tag}",
-            telemetry_mod.shape_signature(
-                *inputs.values(), *labels.values(),
-                *fmasks.values(), *lmasks.values()))
-        step = self._train_step_fn
-        if mesh is not None:
-            # Mesh-sharded inputs bypass the AOT executables (lowered
-            # for single-device placement) — see MultiLayerNetwork.
-            step = getattr(step, "jit", step)
-        with (mesh if mesh is not None else contextlib.nullcontext()):
-            out = step(
-                self.params_tree, self.opt_state, self._merged_state(),
-                self._iteration_device(mesh), self._rng,
-                inputs, labels, fmasks, lmasks)
-        (self.params_tree, self.opt_state, new_state, new_iter, self._rng,
-         loss) = out
-        self._commit_state(new_state)
-        self._commit_iteration(new_iter, mesh)
-        self.score_value = loss
-        # samples are counted at the fit-loop seam (record_etl)
-        metrics_mod.record_train_step(1)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration)
 
     # ------------------------------------------------------------- inference
     def outputs(self, *features, features_masks=None) -> List[np.ndarray]:

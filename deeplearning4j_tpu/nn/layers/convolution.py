@@ -534,7 +534,7 @@ class LocalResponseNormalization(Layer):
 
     # Pallas kernel toggle (the optional-helper contract, reference
     # ConvolutionLayer.java:66-77). OFF by default: the round-5
-    # in-workload A/B (bench.py alexnet vs alexnet_pallaslrn, after
+    # in-workload A/B (AlexNet with and without the kernel, after
     # fixing the probe bug that had silently disabled the kernel in
     # every traced run) measured XLA's fused lax chain FASTER than the
     # VMEM kernel — the pallas_call is a fusion barrier and its

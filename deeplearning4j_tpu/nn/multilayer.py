@@ -37,27 +37,16 @@ from ..data.iterators import (AsyncDataSetIterator, DataSetIterator,
 from ..optimize import compile_cache as compile_cache_mod
 from ..optimize import metrics as metrics_mod
 from ..optimize import telemetry as telemetry_mod
-from ..optimize import tracing
 from ..utils import params as param_utils
 from .conf.builders import BackpropType, MultiLayerConfiguration
 from .layers import core as core_layers
 from .updaters import normalize_layer_gradients
-from .stepping import DeviceIterationMixin
+from .stepping import Trainer
 from .layers.recurrent import RECURRENT_CARRY_KEYS
 
 Array = jax.Array
 
 log = logging.getLogger(__name__)
-
-# Training-only jit attributes, built lazily on first touch (the
-# ParallelInference serving path never trains, so it must never pay
-# these compiles — the compile-cost control plane's "lazy" leg).
-_TRAIN_JIT_ATTRS = (
-    "_train_step_fn", "_train_step_raw",
-    "_multi_step_stacked_fn", "_multi_step_repeat_fn",
-    "_multi_step_repeat_tbptt_fn", "_multi_step_stacked_tbptt_fn",
-)
-
 
 def _regularization_score(layers, params) -> Array:
     """L1 + 0.5*L2 penalty over all parameters (reference
@@ -87,7 +76,17 @@ class RnnStateMismatchError(ValueError):
     behind, silently corrupting the following sequence)."""
 
 
-class MultiLayerNetwork(DeviceIterationMixin):
+class MultiLayerNetwork(Trainer):
+    # What Trainer asks of a front end (nn/stepping.py).
+    _TRAIN_JIT_ATTRS = (
+        "_train_step_fn", "_train_step_raw",
+        "_multi_step_stacked_fn", "_multi_step_repeat_fn",
+        "_multi_step_repeat_tbptt_fn", "_multi_step_stacked_tbptt_fn",
+    )
+    _STEP_LABEL = "mln_train_step"
+    _ASYNC_ITERATOR = AsyncDataSetIterator
+    _FUSES_TBPTT = True
+
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
         self.layers = list(conf.layers)
@@ -112,21 +111,11 @@ class MultiLayerNetwork(DeviceIterationMixin):
         self._dtype = jnp.float32
         self._rng: Optional[Array] = None
         # Training jits are NOT listed here: they are lazy attributes
-        # (see __getattr__) so inference-only nets skip their compiles.
+        # (Trainer.__getattr__) so inference-only nets skip their compiles.
         self._output_fn = None
         self._loss_fn_jit = None
         self._probe_tag = f"{id(self) & 0xffff:04x}"
         self._initialized = False
-
-    def __getattr__(self, name):
-        # Lazy training jits: first touch of any train-path jit builds
-        # them all (they share one traced train_step closure). Guarded
-        # on _initialized so pre-init access still raises cleanly.
-        if name in _TRAIN_JIT_ATTRS and self.__dict__.get("_initialized"):
-            self._build_training_jits()
-            return self.__dict__[name]
-        raise AttributeError(
-            f"{type(self).__name__!r} object has no attribute {name!r}")
 
     # ------------------------------------------------------------------ init
     def init(self, seed: Optional[int] = None, dtype=jnp.float32) -> "MultiLayerNetwork":
@@ -208,11 +197,11 @@ class MultiLayerNetwork(DeviceIterationMixin):
     def _build_jitted(self):
         """(Re)build the inference jits and invalidate the training
         jits. Training jits rebuild lazily on first touch
-        (__getattr__ → _build_training_jits) so inference-only nets —
-        the ParallelInference serving path — never pay their compiles,
-        and a post-init retrace (bench's Pallas toggle) stays cheap
-        until training actually resumes."""
-        for name in _TRAIN_JIT_ATTRS:
+        (Trainer.__getattr__ → _build_training_jits) so inference-only
+        nets — the ParallelInference serving path — never pay their
+        compiles, and a post-init retrace stays cheap until training
+        actually resumes."""
+        for name in self._TRAIN_JIT_ATTRS:
             self.__dict__.pop(name, None)
         self._output_fn = compile_cache_mod.PrecompiledDispatch(
             jax.jit(lambda params, state, x, fmask:
@@ -464,7 +453,7 @@ class MultiLayerNetwork(DeviceIterationMixin):
                                          y_s.dtype),
                     None, None)
         else:
-            # Two signatures: maskless (direct _do_step / bench), and
+            # Two signatures: maskless (direct _do_step), and
             # the ones-(b,1) labels mask the default fit loop's
             # pad-to-bucket iterator synthesizes on EVERY batch (see
             # data/iterators.py: uniform mask structure across the
@@ -481,213 +470,25 @@ class MultiLayerNetwork(DeviceIterationMixin):
                     None, None, int(repeat_steps))
         return self
 
-    def warmup(self, batch_size: int = 1, *,
-               time_steps: Optional[int] = None) -> "MultiLayerNetwork":
-        """Serving cold-start eliminator: AOT-compile the inference path
-        for `batch_size` and push one concrete zero batch through
-        `output()` so the first real request pays neither compile nor
-        first-dispatch cost. The batch is host float32, as a request
-        delivers it: on a bf16 net that also warms the per-shape input
-        cast, which is an XLA compilation of its own."""
-        self._check_init()
-        self.precompile(batch_size, time_steps=time_steps, train=False)
-        x_s = self._feature_struct(batch_size, time_steps)
-        self.output(np.zeros(x_s.shape, np.float32))
-        return self
-
     # ------------------------------------------------------------------- fit
-    def fit(self, data, labels=None, *, epochs: int = 1, batch_size: int = 32,
-            use_async: bool = True, async_queue_size: int = 8,
-            step_fn=None, steps_per_dispatch: int = 1,
-            pad_to_bucket: bool = True, prefetch_to_device: bool = True,
-            prefetch_depth: int = 2, prefetch_sharding=None,
-            prefetch_divisor: int = 1,
-            checkpoint=None, resume: bool = False, sentinel=None
-            ) -> "MultiLayerNetwork":
-        """Train (reference fit(DataSetIterator):1019). Accepts a
-        DataSetIterator, a DataSet, or (features, labels) arrays. `step_fn`
-        lets ParallelWrapper reuse this loop with a sharded step.
+    # `fit` itself is Trainer's (nn/stepping.py); these are its hooks.
+    def _batches(self, data, labels, batch_size, epochs):
+        return as_iterator(data, labels, batch_size)
 
-        Fault tolerance (docs/robustness.md): `checkpoint` attaches a
-        resilience.CheckpointManager (periodic atomic saves at its
-        configured cadence); with `resume=True` the newest valid
-        checkpoint is restored first and the loop fast-forwards past the
-        epochs/batches it already covers — on a deterministic,
-        unshuffled pipeline the resumed run is bitwise-identical to an
-        uninterrupted one (`epochs` counts TOTAL epochs for the run, not
-        additional ones). `sentinel` attaches a DivergenceSentinel
-        checking each step for non-finite loss/params. Both require
-        steps_per_dispatch=1 (per-step hook cadence).
+    def _coerce(self, ds: DataSet) -> DataSet:
+        return ds
 
-        Input pipeline (docs/perf_data_pipeline.md): `pad_to_bucket`
-        pads ragged batches (the short final batch) up to the epoch's
-        canonical shape under the zero-weight mask contract — loss and
-        gradients match the unpadded batch exactly, and the whole epoch
-        reuses ONE compiled train step. `prefetch_to_device` upgrades
-        the async prefetch thread to stage batches onto the device
-        (`jax.device_put` + transfer fence off the training thread);
-        `prefetch_sharding`/`prefetch_divisor` let ParallelWrapper stage
-        mesh-sharded batches. Both honor use_async=False (no threads)
-        and AsyncShield iterators.
+    @staticmethod
+    def _batch_signature(ds: DataSet):
+        # .shape directly — np.asarray on a device-resident array
+        # would force a d2h copy per batch in the hot loop
+        f, l = ds.features, ds.labels
+        return (f.shape if hasattr(f, "shape") else np.asarray(f).shape,
+                l.shape if hasattr(l, "shape") else np.asarray(l).shape,
+                ds.features_mask is None, ds.labels_mask is None)
 
-        `steps_per_dispatch > 1` groups that many same-shaped minibatches
-        into ONE fused device dispatch (fit_batches' lax.scan —
-        bit-identical math, amortized dispatch latency; truncated-BPTT
-        batches fuse their whole window schedules). Odd-shaped batches
-        (e.g. a short final batch) flush the group and run singly;
-        incompatible with step_fn. Listener cadence under tBPTT
-        grouping: one iteration_done per BATCH (iteration advancing by
-        the window count), not one per window — the same coalescing
-        fit_batch_repeated does; per-window listener events require
-        steps_per_dispatch=1."""
-        from ..data.iterators import DevicePrefetchIterator, PadToBucketIterator
-        self._check_init()
-        spd = int(steps_per_dispatch)
-        if spd > 1 and step_fn is not None:
-            raise ValueError("steps_per_dispatch cannot combine with a "
-                             "custom step_fn")
-        if spd > 1 and (checkpoint is not None or sentinel is not None):
-            raise ValueError("checkpoint=/sentinel= need per-step hooks; "
-                             "use steps_per_dispatch=1")
-        if resume and checkpoint is None:
-            raise ValueError("resume=True requires checkpoint=a "
-                             "CheckpointManager to resume from")
-        skip_batches = 0
-        if resume:
-            rec = checkpoint.restore_into(self)
-            if rec is not None:
-                epochs = max(0, int(epochs) - int(self.epoch))
-                skip_batches = int(rec.get("batches_into_epoch", 0) or 0)
-                log.info("auto-resume: restored %s (iteration %d, %d "
-                         "epoch(s) done, %d batch(es) into the next); "
-                         "%d epoch(s) remain", rec.get("file"),
-                         self.iteration, self.epoch, skip_batches, epochs)
-        it = as_iterator(data, labels, batch_size)
-        if pad_to_bucket and \
-                self.conf.backprop_type != BackpropType.TRUNCATED_BPTT:
-            # tBPTT slices the labels mask on the time axis; the (n,1)
-            # zero-weight mask cannot window — ragged tBPTT batches keep
-            # the flush-and-recompile path (loudly documented).
-            it = PadToBucketIterator(it)
-        if use_async and it.async_supported():
-            wrapped = DevicePrefetchIterator(
-                it, depth=max(1, int(prefetch_depth)),
-                sharding=prefetch_sharding,
-                batch_divisor=prefetch_divisor,
-                cast_dtype=self._dtype) if prefetch_to_device \
-                else AsyncDataSetIterator(it, async_queue_size)
-        else:
-            wrapped = it
-        step = step_fn or self._fit_batch
-        group: List[DataSet] = []
-
-        def group_sig(ds):
-            # .shape directly — np.asarray on a device-resident array
-            # would force a d2h copy per batch in the hot loop
-            f, l = ds.features, ds.labels
-            return (f.shape if hasattr(f, "shape") else np.asarray(f).shape,
-                    l.shape if hasattr(l, "shape") else np.asarray(l).shape,
-                    ds.features_mask is None, ds.labels_mask is None)
-
-        def flush_group():
-            if not group:
-                return
-            if len(group) == 1:
-                step(group[0])
-            else:
-                self.fit_batches(group)
-            group.clear()
-
-        import time as _time
-        reg = metrics_mod.registry()
-        fit_sp = tracing.begin("fit", epochs=epochs)
-        try:
-            for _ in range(epochs):
-                epoch_sp = tracing.begin("epoch", epoch=self.epoch)
-                # Resumed run: re-consume (and discard) the batches the
-                # restored checkpoint already covers — first epoch only.
-                to_skip, skip_batches = skip_batches, 0
-                batches_done = to_skip
-                it_epoch = iter(wrapped)
-                while True:
-                    # The step span opens BEFORE the iterator is polled
-                    # so its etl child nests inside it; an exhausted
-                    # iterator cancels the empty span.
-                    step_sp = tracing.begin("step",
-                                            step_num=self.iteration)
-                    # Track time blocked on the data pipeline (reference
-                    # lastEtlTime, MultiLayerNetwork.java:1063-1065);
-                    # PerformanceListener reports it.
-                    t0 = _time.perf_counter()
-                    try:
-                        ds = next(it_epoch)
-                    except StopIteration:
-                        step_sp.cancel()
-                        break
-                    if to_skip > 0:
-                        to_skip -= 1
-                        step_sp.cancel()
-                        continue
-                    etl_s = _time.perf_counter() - t0
-                    self.last_etl_ms = etl_s * 1000.0
-                    # Device-prefetched batches carry the producer-side
-                    # split: host-wait (base iterator) vs h2d-wait
-                    # (device_put + transfer fence). Host-fed batches
-                    # attribute the whole wait to the host side.
-                    self.last_etl_host_ms = getattr(
-                        ds, "_etl_host_ms", self.last_etl_ms)
-                    self.last_etl_h2d_ms = getattr(ds, "_etl_h2d_ms", 0.0)
-                    tracing.add_span("etl", t0, etl_s)
-                    metrics_mod.record_etl(
-                        reg, self.last_etl_ms, self.last_etl_host_ms,
-                        self.last_etl_h2d_ms, metrics_mod.batch_rows(ds))
-                    t1 = _time.perf_counter()
-                    if sentinel is not None:
-                        sentinel.before_step(self)
-                    with tracing.span("dispatch"):
-                        if spd <= 1:
-                            step(ds)
-                        else:
-                            if group and \
-                                    group_sig(ds) != group_sig(group[0]):
-                                flush_group()
-                            group.append(ds)
-                            if len(group) >= spd:
-                                flush_group()
-                    reg.histogram(
-                        "train_step_dispatch_ms",
-                        "Host-side enqueue time per fit-loop batch "
-                        "(async: device time needs the fence)").observe(
-                            (_time.perf_counter() - t1) * 1000.0)
-                    w = tracing.fence(self.iteration, self.score_value)
-                    if w is not None:
-                        reg.gauge(
-                            "device_fence_wait_ms",
-                            "Dispatch-queue drain at the last sampled "
-                            "fence (device-compute backlog)").set(w)
-                    if sentinel is not None:
-                        sentinel.after_step(self)
-                    batches_done += 1
-                    if checkpoint is not None:
-                        checkpoint.on_batch(self, batches_done)
-                    step_sp.end()
-                if group:  # end of epoch: run the partial group
-                    with tracing.span("dispatch", flush="epoch_tail"):
-                        flush_group()
-                self.epoch += 1
-                reg.counter("train_epochs_total",
-                            "Completed fit epochs").inc()
-                for lst in self.listeners:
-                    if hasattr(lst, "on_epoch_end"):
-                        lst.on_epoch_end(self, self.epoch)
-                if checkpoint is not None:
-                    checkpoint.on_epoch(self)
-                epoch_sp.end()
-        finally:
-            fit_sp.end()
-            if isinstance(wrapped, AsyncDataSetIterator):
-                wrapped.shutdown()
-        return self
+    def _input_shapes(self, batch_size, time_steps):
+        return [self._feature_struct(batch_size, time_steps).shape]
 
     def fit_batches(self, batches: Sequence) -> "MultiLayerNetwork":
         """K optimizer steps over K same-shaped DataSets in ONE device
@@ -769,27 +570,6 @@ class MultiLayerNetwork(DeviceIterationMixin):
             self._iteration_device(None), self._rng, *args, int(steps))
         self._commit_multi(out, int(steps))
         return self
-
-    def _commit_multi(self, out, steps: int, listener_events=None):
-        """`steps` = optimizer iterations taken; `listener_events` = how
-        many per-scan losses exist (tBPTT repeats record one loss per
-        REPEAT while taking several window steps)."""
-        (self.params_tree, self.opt_state, self.state_tree, it, self._rng,
-         losses) = out
-        events = steps if listener_events is None else listener_events
-        self._iteration += steps
-        metrics_mod.record_train_step(steps)
-        self._iteration_dev = it
-        self._iteration_dev_mesh = None
-        self.score_value = losses[-1]
-        if self.listeners:
-            per = steps // max(events, 1)
-            for k in range(events):
-                self.score_value = losses[k]
-                for lst in self.listeners:
-                    lst.iteration_done(
-                        self, self._iteration - steps + (k + 1) * per)
-            self.score_value = losses[-1]
 
     def fit_solver(self, x, y, *, max_iterations: int = 100,
                    tolerance: float = 1e-6, fmask=None, lmask=None) -> float:
@@ -911,35 +691,6 @@ class MultiLayerNetwork(DeviceIterationMixin):
             self._cast_features(x), jnp.asarray(y),
             None if fmask is None else jnp.asarray(fmask),
             None if lmask is None else jnp.asarray(lmask))
-
-    def _run_and_commit(self, x, y, fmask, lmask, mesh=None):
-        """Invoke the jitted step and commit results + listeners. Shared by
-        the single-device path and ParallelWrapper's sharded path."""
-        import contextlib
-        telemetry_mod.note_step_signature(
-            f"mln_train_step#{self._probe_tag}",
-            telemetry_mod.shape_signature(x, y, fmask, lmask))
-        step = self._train_step_fn
-        if mesh is not None:
-            # Mesh-sharded inputs must not hit an AOT executable lowered
-            # for single-device placement — take the jit path, which
-            # reshards freely.
-            step = getattr(step, "jit", step)
-        with (mesh if mesh is not None else contextlib.nullcontext()):
-            out = step(
-                self.params_tree, self.opt_state, self._merged_state(),
-                self._iteration_device(mesh), self._rng,
-                x, y, fmask, lmask)
-        (self.params_tree, self.opt_state, new_state, new_iter, self._rng,
-         loss) = out
-        self._commit_state(new_state)
-        self._commit_iteration(new_iter, mesh)
-        self.score_value = loss
-        # samples are counted at the fit-loop seam (record_etl), never
-        # here — the wrapper's sharded path funnels through both
-        metrics_mod.record_train_step(1)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration)
 
     # The recurrent carry is merged into the state only on stateful paths
     # (tbptt windows, rnn_time_step) and split back out on commit, so the

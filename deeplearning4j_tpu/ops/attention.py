@@ -65,7 +65,7 @@ def active_sequence_parallel():
 # --------------------------------------------------------------------------
 # Single-device dispatch: pallas (fused flash kernel) / blockwise / dense.
 # The rule is MEASURED, not aspirational — docs/perf_attention.md holds the
-# standing A/B (bench.py attention_ab) behind it.
+# A/B behind it.
 # --------------------------------------------------------------------------
 
 ATTENTION_IMPLS = ("pallas", "blockwise", "dense")

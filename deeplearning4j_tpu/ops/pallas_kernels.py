@@ -13,8 +13,8 @@ ROUND-5 HONESTY NOTE: the standalone-op microbench (633 µs/op Pallas vs
 1192 µs/op lax on [64,27,27,96] f32, 2026-07-30) does NOT survive
 in-workload reality. After fixing the probe bug that had silently kept
 every traced run on the lax path, the full
-AlexNet A/B measures lax ~2x FASTER end-to-end (bench.py alexnet vs
-alexnet_pallaslrn; docs/perf_googlenet.md): the pallas_call is a
+AlexNet A/B measures lax ~2x FASTER end-to-end (the same net with and
+without the kernel; docs/perf_googlenet.md): the pallas_call is a
 fusion barrier, and the 128-lane channel padding doubles HBM bytes for
 64-channel LRN layers. The kernels (fwd AND bwd) therefore ship
 default-OFF (LocalResponseNormalization.use_pallas=False) as the
@@ -212,7 +212,7 @@ def lrn_supported(x) -> bool:
 # output channel is one contiguous row — and a MEASURED per-backend
 # dispatch under the LRN honesty rule: a one-time timed probe at a
 # serving-representative shape picks the winner, the losers stay
-# standing for the bench.py quant_matmul_ab A/B row.
+# standing as the probe's other arms.
 #
 # Why three arms exist at all (CPU rig, 2026-08): XLA's CPU backend has
 # no int8 dot emitter — an s8 dot_general materializes an s32 copy of
@@ -229,7 +229,7 @@ def lrn_supported(x) -> bool:
 
 _QUANT_BLOCK_N = 256  # output channels per grid step (VMEM-friendly)
 
-#: force the dispatch (tests / bench A/B arms): native | pallas | xla
+#: force the dispatch (tests / A/B arms): native | pallas | xla
 QUANT_MATMUL_ENV = "DL4JTPU_QUANT_MATMUL"
 
 _quant_impl: Dict[str, str] = {}  # backend -> winning arm

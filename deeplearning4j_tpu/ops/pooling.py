@@ -209,16 +209,15 @@ def select_pooling_impl(pooling_type: str, window, strides, *,
     TRACE time (static shapes), so the counter counts selections, not
     per-step executions — same contract as select_attention_impl.
 
-    Rule (measured A/B, docs/perf_googlenet.md round 6 + the standing
-    `bench.py googlenet_pool_ab` row), per backend like the attention
-    rule:
+    Rule (measured A/B, docs/perf_googlenet.md round 6), per backend
+    like the attention rule:
 
       * max on CPU → "mask": 3.4-4x faster than the S&S expansion at
         GoogLeNet's pool geometries op-level, +5% whole-model
         (85.7 -> 81.5 s/step, b8 bf16, 2026-08-05).
       * max on TPU → "sns": the round-5 profiled baseline; "mask" is
-        UNMEASURED on TPU this round (no chip) — the standing bench row
-        flips this default if/when it measures a win there.
+        UNMEASURED on TPU: a chip run that measures a win there flips
+        this default.
       * avg → "window" everywhere: the depthwise-conv formulation lost
         its CPU A/B by 270x (XLA:CPU's grouped conv; numbers in the
         round-6 doc) and is untested on TPU.
@@ -245,8 +244,7 @@ def select_pooling_impl(pooling_type: str, window, strides, *,
 
 def register_metrics() -> None:
     """Pre-register the pooling dispatch counter family so a scrape
-    BEFORE the first trace already exposes every label at 0 (the PR-8/9
-    bench --once pattern)."""
+    BEFORE the first trace already exposes every label at 0."""
     from ..optimize.metrics import registry
     fam = registry().counter(
         "pooling_impl_selected_total",

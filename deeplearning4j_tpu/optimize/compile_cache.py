@@ -10,7 +10,7 @@ runs. This module attacks that cost on two fronts:
   dropped to zero so every executable is cached, and mirrors jax's
   cache-hit/miss monitoring events into the MetricsRegistry
   (`compile_cache_hits_total` / `_misses_total`) so warm vs cold
-  compiles are visible in `/metrics` and in bench JSON. A warm cache
+  compiles are visible in `/metrics`. A warm cache
   turns a minutes-long cold compile into a sub-second deserialize.
 
 * **AOT precompile dispatch** — `PrecompiledDispatch` wraps one

@@ -9,9 +9,8 @@ Counter / Gauge / Histogram families, exported two ways:
 
 * `registry().prometheus_text()` — Prometheus text exposition format
   (`GET /metrics` on the UI server scrapes this).
-* `registry().snapshot()` — a flat {name{labels}: value} dict, embedded
-  in bench.py's BENCH JSON so a timed-out run still leaves telemetry
-  behind.
+* `registry().snapshot()` — a flat {name{labels}: value} dict, for
+  embedding in a run's own JSON output.
 
 Device visibility: a runtime collector samples
 `jax.local_devices()[i].memory_stats()` at scrape time into per-device
@@ -125,7 +124,7 @@ class _Family:
 
     def total(self, **labels) -> float:
         """Sum of this family's value across every label set (the
-        label-blind aggregate bench extras and health summaries want:
+        label-blind aggregate health summaries want:
         e.g. breaker transitions regardless of target state).
         Histograms aggregate their observation counts. A label filter
         (`total(outcome="canary_rejected")`) sums only the children
@@ -378,7 +377,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict[str, float]:
         """Flat {name{labels}: value}; histograms contribute _count and
-        _sum. The bench-JSON embedding format."""
+        _sum. The JSON embedding format."""
         self.collect()
         out: Dict[str, float] = {}
         with self._lock:
@@ -415,8 +414,8 @@ def device_memory_stats() -> List[Dict[str, float]]:
     Samples only where a backend is already up: a chip belongs to one
     process at a time, so a metrics scrape must never be what
     initialises the backend in a process that does no device work (a
-    bench parent, a federation front-end) — it would take the chip from
-    the child that needs it."""
+    federation front-end) — it would take the chip from the child that
+    needs it."""
     jax = sys.modules.get("jax")
     if jax is None or not jax._src.xla_bridge.backends_are_initialized():
         return []
@@ -526,7 +525,7 @@ def record_train_step(steps: int = 1, samples: int = 0) -> None:
 
 def record_etl(reg: MetricsRegistry, etl_ms: float, host_ms: float,
                h2d_ms: float, samples: int = 0) -> None:
-    """Per-batch data-pipeline wait (the fit loops' lastEtlTime signal),
+    """Per-batch data-pipeline wait (the fit loop's lastEtlTime signal),
     host/h2d split included."""
     reg.gauge("etl_ms", "Data-pipeline wait for the last batch"
               ).set(etl_ms)

@@ -22,8 +22,8 @@ Four pillars on top of the serializer/metrics/tracing stack:
   ``retries_total{edge}`` and emits a span.
 
 All recovery actions are observable: counters registered by
-:func:`register_metrics` (surfaced by ``bench.py --once`` and the
-``/metrics`` endpoint) and spans in the trace ring.
+:func:`register_metrics` (surfaced by the ``/metrics`` endpoint) and
+spans in the trace ring.
 """
 from __future__ import annotations
 
@@ -394,7 +394,7 @@ class DivergenceError(RuntimeError):
 
 
 class DivergenceSentinel:
-    """Per-step non-finite watchdog for the fit loops.
+    """Per-step non-finite watchdog for the fit loop.
 
     After each (checked) step, one fused jitted reduction computes a
     single all-finite flag over the step loss and every floating-point

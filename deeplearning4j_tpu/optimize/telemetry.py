@@ -10,8 +10,8 @@ counts backend compilations two ways:
   whatever jitted function triggered it. With tracing on, each is also
   a retroactive `compile` span whose parent is the span that caused
   it. `CompilationTracker` snapshots
-  it around a region (bench.py wraps whole workloads;
-  PerformanceListener reports the delta between reports).
+  it around a region (PerformanceListener reports the delta between
+  reports).
 * `jit_cache_size(fn)` — the per-function executable-cache size of one
   `jax.jit` callable (e.g. `net._train_step_fn`), the precise "how many
   distinct shapes did THIS step compile for" probe the regression tests
@@ -145,7 +145,7 @@ def jit_cache_size(fn) -> int:
 # warning plus a labeled counter — when a step crosses the threshold:
 # the canonical symptom is a data pipeline emitting ragged batches
 # (every epoch tail a fresh compile) or unbucketed variable-length
-# sequences. bench.py surfaces the offenders in its JSON.
+# sequences. `churn_offenders()` names them.
 # ---------------------------------------------------------------------------
 ENV_CHURN_THRESHOLD = "DL4JTPU_RECOMPILE_CHURN_THRESHOLD"
 DEFAULT_CHURN_THRESHOLD = 5

@@ -1,8 +1,9 @@
 """Nestable span tracing over the training loop, with a Chrome
 trace-event exporter.
 
-The fit loops emit the span taxonomy `fit / epoch / step /
-{etl, dispatch, device}` (docs/observability.md). Spans are
+The fit loop (`Trainer.fit`, nn/stepping.py) emits the span taxonomy
+`fit / epoch / step / {etl, dispatch, device}`
+(docs/observability.md). Spans are
 `time.perf_counter` intervals recorded into a bounded ring buffer —
 O(1) memory however long training runs — and export as Chrome
 trace-event-format JSON (`ph:"X"` complete events; load in
@@ -238,7 +239,7 @@ def add_span(name: str, start: float, dur_s: float,
              cat: Optional[str] = None, parent: Optional[int] = None,
              **args) -> int:
     """Record a retroactive span from an already-measured interval
-    (`start` in time.perf_counter seconds): the fit loops time ETL with
+    (`start` in time.perf_counter seconds): the fit loop times ETL with
     perf_counter anyway, so the span costs nothing extra. `cat` tags the
     event category in the Chrome export ("train" when omitted) — the
     serving flight recorder uses "serve" so a serving incident and a

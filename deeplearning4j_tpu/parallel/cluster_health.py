@@ -187,8 +187,8 @@ _HELP = {
 
 
 def register_metrics(reg=None):
-    """Pre-register every cluster-health family so MULTICHIP/BENCH
-    snapshots carry them even before the first beat."""
+    """Pre-register every cluster-health family so a snapshot carries
+    them even before the first beat."""
     reg = reg or metrics_mod.registry()
     for name, help_ in _HELP.items():
         if name.endswith("_total"):

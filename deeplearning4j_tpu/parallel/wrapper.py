@@ -190,14 +190,9 @@ class ParallelWrapper:
                         prefetch_sharding=None if self.multiprocess
                         else mesh_lib.batch_sharded(self.mesh),
                         prefetch_divisor=self.data_shards)
-        if hasattr(self.model, "_pack"):  # ComputationGraph
-            self.model.fit(data, labels, epochs=epochs,
-                           batch_size=batch_size, step_fn=self.fit_batch,
-                           **prefetch)
-        else:
-            self.model.fit(data, labels, epochs=epochs, batch_size=batch_size,
-                           async_queue_size=self.prefetch_buffer,
-                           step_fn=self.fit_batch, **prefetch)
+        self.model.fit(data, labels, epochs=epochs, batch_size=batch_size,
+                       async_queue_size=self.prefetch_buffer,
+                       step_fn=self.fit_batch, **prefetch)
         self.finalize()
         return self
 

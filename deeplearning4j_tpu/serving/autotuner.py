@@ -17,7 +17,7 @@ telemetry was read by nobody. This module closes the loop:
   existing actuators (``ModelPool.reconfigure`` /
   ``reconfigure_scheduler`` — the same seam ``POST /config`` drives)
   inside hard per-knob guardrails. Every decision is appended to
-  ``autotune_ledger.jsonl`` with a scoreboard-style strict schema
+  ``autotune_ledger.jsonl`` under a strict, versioned row schema
   (unknown fields and kinds REJECTED): the knob, old→new, the windowed
   evidence that motivated the move, the observed outcome after a settle
   window, and the revert when the move regressed. The tuner FREEZES —
@@ -32,8 +32,8 @@ A gateway without a tuner attached runs today's serving path bitwise:
 nothing here touches admission or dispatch — the monitor reads the
 scrape surface, the tuner writes through the reconfigure seam.
 
-Metric families (pre-registered by ``register_metrics()``, bench
-``--once`` pattern): ``serving_tuner_moves_total{knob,outcome}``
+Metric families (pre-registered at 0 by ``register_metrics()``, so a
+scrape sees them): ``serving_tuner_moves_total{knob,outcome}``
 (applied/kept/reverted/neutral/refused), ``serving_tuner_frozen``,
 ``serving_tuner_state`` (0=watching, 1=settling, 2=frozen),
 ``serving_tuner_reverts_total``,
@@ -61,7 +61,7 @@ __all__ = [
 ]
 
 # ---------------------------------------------------------------------------
-# Ledger: append-only jsonl, strict schema (optimize/scoreboard.py idiom)
+# Ledger: append-only jsonl, every row validated against its kind's fields
 # ---------------------------------------------------------------------------
 LEDGER_SCHEMA_VERSION = 1
 LEDGER_ENV = "DL4JTPU_AUTOTUNE_LEDGER"
@@ -74,7 +74,7 @@ FREEZE_REASONS = ("breaker_open", "canary_rejected", "slo_breach", "manual")
 
 _NUM = (int, float)
 # Required fields per row, common first. Unknown kinds and unknown
-# fields are REJECTED (scoreboard strictness): the ledger is an audit
+# fields are REJECTED on write and on read: the ledger is an audit
 # artifact — a row that doesn't parse against the schema is a bug, not
 # a forward-compat extension point.
 _COMMON_FIELDS: Dict[str, Any] = {
@@ -94,8 +94,8 @@ _KIND_FIELDS: Dict[str, Dict[str, Any]] = {
 
 def default_ledger_path() -> str:
     """$DL4JTPU_AUTOTUNE_LEDGER, else <repo root>/autotune_ledger.jsonl
-    (beside BENCH_ledger.jsonl — the serving counterpart of the bench
-    scoreboard's audit trail)."""
+    (a deployment path: git-ignored, created on the first append; the
+    tuner's audit trail)."""
     env = os.environ.get(LEDGER_ENV)
     if env:
         return env
@@ -161,7 +161,7 @@ def append_entry(entry: Dict[str, Any],
 
 def read_ledger(path: Optional[str] = None) -> List[Dict[str, Any]]:
     """All parseable rows, in file order. Torn/corrupt lines (a crash
-    mid-append) are skipped, never fatal — scoreboard semantics."""
+    mid-append) are skipped, never fatal: an append-only log's tail."""
     path = path or default_ledger_path()
     rows: List[Dict[str, Any]] = []
     try:
@@ -292,7 +292,7 @@ class SLOMonitor:
         self._clock = clock
         # Window floor: the registry rings are process-global but this
         # monitor is not — observations stamped before it existed (an
-        # earlier gateway/bench arm in the same process) never count.
+        # earlier gateway or A/B arm in the same process) never count.
         self._born = float(clock())
         self._lock = threading.Lock()
         self._last: Optional[Dict[str, float]] = None
@@ -527,7 +527,7 @@ _STATE_VALUES = {WATCHING: 0, SETTLING: 1, FROZEN: 2}
 
 
 def register_metrics() -> None:
-    """Pre-register the tuner families at 0 (bench --once pattern) so a
+    """Pre-register the tuner families at 0 so a scrape or a
     snapshot distinguishes 'tuner never moved' from 'tuner never ran'."""
     reg = registry()
     reg.counter("serving_tuner_moves_total",

@@ -54,7 +54,7 @@ class BreakerOpenError(RuntimeError):
 
 def register_metrics() -> None:
     """Pre-register the breaker/chaos metric families so a snapshot
-    (bench.py --once) records serving resilience activity — including
+    or a scrape records serving resilience activity — including
     its absence — before any breaker exists."""
     reg = registry()
     reg.gauge("serving_breaker_state",

@@ -146,8 +146,8 @@ _MOE_HELP = {
 
 
 def register_metrics() -> None:
-    """Pre-register the decode/KV-cache families at 0 (bench --once
-    pattern: a scrape must distinguish 'no decode traffic yet' from
+    """Pre-register the decode/KV-cache families at 0 (before any
+    traffic: a scrape must distinguish 'no decode traffic yet' from
     'families absent')."""
     reg = registry()
     reg.counter("serving_decode_tokens_total", _TOKENS_HELP)

@@ -147,8 +147,8 @@ _HELP = {
 
 
 def register_metrics() -> None:
-    """Pre-register the federation families at 0 (bench --once
-    pattern) so scrapes and the scoreboard distinguish 'no federation
+    """Pre-register the federation families at 0, so a scrape taken
+    before any traffic can distinguish 'no federation
     activity' from 'no federation'. The population gauge is touched at
     every state so a snapshot always carries the full state axis."""
     reg = registry()
@@ -409,7 +409,7 @@ class FederationFrontEnd(JsonHttpServer):
             self._pop_g.labels(state=state).set(float(n))
 
     def wait_for_replicas(self, n: int, timeout: float = 60.0) -> bool:
-        """Block until `n` replicas are HEALTHY (bench/test
+        """Block until `n` replicas are HEALTHY (smoke/test
         convenience). Wall-clock bound, not fake-clock driven."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
@@ -833,7 +833,7 @@ def default_builder(gateway) -> None:
     every replica of a fleet built this way serves bitwise-identical
     params, the homogeneity least-loaded routing assumes). Geometry
     and engine knobs come from the environment so a PARENT process
-    (bench, smoke, tests) shapes the fleet without a custom builder:
+    (a smoke, a test) shapes the fleet without a custom builder:
 
         DL4JTPU_REPLICA_N_IN / _HIDDEN / _N_OUT   model geometry
         DL4JTPU_REPLICA_BATCH_LIMIT               rows per forward (the
@@ -900,7 +900,7 @@ def spawn_replica(replica_id: int, frontend_url: str, *,
                   builder: Optional[str] = None, port: int = 0,
                   interval_s: float = 0.5, env: Optional[dict] = None):
     """Spawn a replica SUBPROCESS running this module's main (the
-    multihost harness pattern — tests/bench SIGKILL the handle for
+    multihost harness pattern — tests and smokes SIGKILL the handle for
     chaos). `builder` is a ``pkg.mod:fn`` import path (default: the
     stock demo builder); `env` overlays the child environment (e.g.
     JAX_PLATFORMS=cpu, DL4JTPU_REPLICA_* geometry, DL4JTPU_FAULT_*

@@ -112,8 +112,8 @@ def _percentile(sorted_vals, q: float) -> float:
 
 
 def register_metrics() -> None:
-    """Pre-register the gateway's request/latency families (bench
-    --once): a scrape taken before any traffic must already show them."""
+    """Pre-register the gateway's request/latency families at 0:
+    a scrape taken before any traffic must already show them."""
     reg = registry()
     reg.counter("serving_requests_total",
                 "Gateway requests by terminal status (ok/shed/error)")
@@ -509,8 +509,8 @@ class ServingGateway(JsonHttpServer):
 
     def stats(self) -> Dict[str, Any]:
         """Per-model {p50_ms, p99_ms, count} over the windowed latency
-        ring plus the pool description (bench.py's serving row reads
-        this)."""
+        ring plus the pool description (what `GET /stats` answers
+        with)."""
         out: Dict[str, Any] = {"models": self.pool.describe()}
         items, titems = self._windowed_latencies()
         out["latency"] = {

@@ -109,7 +109,7 @@ def _fused_fallback_counter(reason: str, n: int = 1):
 
 
 def register_metrics() -> None:
-    """Pre-register every pool-owned family (bench --once): a scrape
+    """Pre-register every pool-owned family at 0: a scrape
     taken before the first request must already show them at zero."""
     reg = registry()
     fam = reg.counter(

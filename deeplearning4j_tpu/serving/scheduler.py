@@ -83,7 +83,7 @@ class TierShedError(RuntimeError):
 
 
 def register_metrics() -> None:
-    """Pre-register the scheduler families (bench --once pattern) and
+    """Pre-register the scheduler families at 0 for a first scrape and
     the per-tier SLO gauges at their defaults."""
     reg = registry()
     reg.counter("serving_starvation_total",

@@ -36,18 +36,6 @@ Points used by the cluster health plane (docs/robustness.md):
                        keeps beating but looks frozen (the deterministic
                        stand-in for a wedged main thread)
 
-Points used by the bench scoreboard plane (docs/observability.md):
-
-    bench.child        each heartbeat publish inside a bench --once
-                       child (only when the parent armed the side
-                       channel) — ``delay:SEL@MS`` with a huge MS wedges
-                       the child mid-measurement, the deterministic
-                       stand-in for the round-5 hung bench subprocess;
-                       ``fail:`` silences the beat thread instead
-    bench.probe        inside the device-liveness probe subprocess,
-                       before it touches jax — ``delay:`` wedges the
-                       probe into a ``"device": "dead"`` verdict
-
 Points used by the serving stack (docs/serving.md):
 
     serve.forward      each coalesced forward in ParallelInference (and

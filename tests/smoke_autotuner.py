@@ -10,8 +10,7 @@ mid-run. Asserts the loop actually closed:
   schema-valid (the auditable-trail contract)
 * NO move ever left its knob's [lo, hi] guardrails, and the live
   config agrees with the ledger's final word for each knob
-* the tuner measurably tightened the mis-tuned linger (the standing
-  bench row's win, in miniature)
+* the tuner measurably tightened the mis-tuned linger
 * GET /debug/tuner renders the state + knob table + decision trail
   over live HTTP, and GET /metrics carries the tuner families
 * a clean run never froze: serving_tuner_frozen == 0

@@ -1,11 +1,12 @@
 """Compile-cache smoke: the warm-start acceptance check, end to end.
 
-Runs the tiny lenet bench workload TWICE as fresh subprocesses sharing
-one temporary persistent-cache directory. The cold run populates the
-cache (misses); the warm run must report cache HITS > 0 — proving a new
-process deserializes XLA executables from disk instead of recompiling —
-and both runs must finish under a wall-clock ceiling and emit valid
-JSON (the bench-survivability contract).
+Runs a tiny `fit` (LeNet-shaped MLP, two batches) TWICE as fresh
+`python -c` subprocesses sharing one temporary persistent-cache
+directory. The cold run populates the cache (misses); the warm run must
+report cache HITS > 0 — proving a new process deserializes XLA
+executables from disk instead of recompiling — and both runs must
+finish under a wall-clock ceiling and print `compile_cache.status()`
+with the final score as valid JSON.
 
 Run by runtests.sh as a separate step (no test_ prefix on purpose —
 this is a cross-process end-to-end smoke, not a pytest unit). Exits
@@ -23,6 +24,27 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+CHILD = """
+import json
+import numpy as np
+from deeplearning4j_tpu import (Adam, DenseLayer, InputType,
+                                MultiLayerNetwork, NeuralNetConfiguration,
+                                OutputLayer)
+from deeplearning4j_tpu.optimize import compile_cache
+compile_cache.enable()
+conf = (NeuralNetConfiguration.builder().seed(1).updater(Adam(0.01)).list()
+        .layer(DenseLayer(n_out=32, activation="relu"))
+        .layer(OutputLayer(n_out=10, activation="softmax", loss="mcxent"))
+        .set_input_type(InputType.feed_forward(64)).build())
+rng = np.random.default_rng(0)
+x = rng.standard_normal((32, 64)).astype(np.float32)
+y = np.eye(10, dtype=np.float32)[rng.integers(0, 10, 32)]
+net = MultiLayerNetwork(conf).init()
+net.fit(x, y, epochs=1, batch_size=16)
+print(json.dumps({"value": float(net.score_value),
+                  "compile_cache": compile_cache.status()}))
+"""
+
 
 def run_once(cache_dir: str, ceiling: float):
     env = dict(os.environ)
@@ -30,19 +52,18 @@ def run_once(cache_dir: str, ceiling: float):
                JAX_COMPILATION_CACHE_DIR=cache_dir)
     t0 = time.monotonic()
     out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "lenet_tiny",
-         "--once"],
+        [sys.executable, "-c", CHILD],
         capture_output=True, text=True, env=env, cwd=REPO,
         timeout=ceiling + 60)
     wall = time.monotonic() - t0
     if out.returncode != 0:
-        print(f"SMOKE FAIL: bench rc={out.returncode}\n"
+        print(f"SMOKE FAIL: child rc={out.returncode}\n"
               f"{out.stderr[-3000:]}", file=sys.stderr)
         sys.exit(1)
     try:
         row = json.loads(out.stdout.strip().splitlines()[-1])
     except (ValueError, IndexError):
-        print(f"SMOKE FAIL: bench stdout is not JSON:\n"
+        print(f"SMOKE FAIL: child stdout is not JSON:\n"
               f"{out.stdout[-2000:]}", file=sys.stderr)
         sys.exit(1)
     return row, wall

@@ -1534,7 +1534,7 @@ class TestTriageDefectRegressions:
     def test_serving_families_preregistered_for_bench_once(self):
         """serving/gateway.py + model_pool.py defect: gateway latency /
         shed / tier families and pool swap/precision/queue-depth gauges
-        were constructed lazily on first request, so a bench --once
+        were constructed lazily on first request, so a
         scrape before traffic missed them. register_metrics() now
         pre-registers every family; pre-fix source fires JL502 here."""
         assert not _real_findings("serving/gateway.py", "JL502")

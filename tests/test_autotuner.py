@@ -2,7 +2,7 @@
 control loop").
 
 Covers the PR-17 tentpole legs with NO devices and NO sleeps on the
-fast paths: the autotune ledger's scoreboard-strict schema (unknown
+fast paths: the autotune ledger's strict schema (unknown
 field/kind, wrong type, out-of-vocab outcome all reject; torn tail
 lines never do), windowed histogram quantiles with explicit ``t=``
 stamps, fake-clock SLOMonitor verdicts (aging, born-floor, shed-rate

@@ -74,7 +74,7 @@ class TestCacheRule:
     def test_no_other_code_places_the_cache(self):
         """Only compile_cache.py names the cache directory, and it builds
         the path from nothing that changes between runs."""
-        sources = [os.path.join(REPO, "bench.py"), SMOKE]
+        sources = [SMOKE]
         for root, _, files in os.walk(os.path.join(REPO,
                                                    "deeplearning4j_tpu")):
             sources += [os.path.join(root, f) for f in files
@@ -161,10 +161,10 @@ class TestNoSilentKernelFallback:
         """Found by the smoke's four-device rehearsal: a single-device
         iteration scalar on step 1 cost a second full compile of the
         train step on step 2."""
-        from deeplearning4j_tpu.nn.stepping import DeviceIterationMixin
+        from deeplearning4j_tpu.nn.stepping import Trainer
         from deeplearning4j_tpu.parallel import data_parallel_mesh
         mesh = data_parallel_mesh(4)
-        it = DeviceIterationMixin()._iteration_device(mesh)
+        it = Trainer()._iteration_device(mesh)
         assert len(it.sharding.device_set) == 4
         assert it.sharding.is_fully_replicated
         assert np.asarray(it) == 0 and it.dtype == jnp.int32

@@ -2,18 +2,11 @@
 
 Covers the four tentpole legs: the persistent XLA cache round trip,
 AOT precompile leaving the fit path compile-silent, lazy training-jit
-construction for inference-only nets, the recompile-churn guard, and
-bench.py's deadline-aware partial JSON.
+construction for inference-only nets, and the recompile-churn guard.
 """
-import json
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from deeplearning4j_tpu import (Adam, DataSet, DenseLayer, InputType,
                                 MultiLayerNetwork, NeuralNetConfiguration,
@@ -21,7 +14,6 @@ from deeplearning4j_tpu import (Adam, DataSet, DenseLayer, InputType,
 from deeplearning4j_tpu.optimize import compile_cache, telemetry
 from deeplearning4j_tpu.optimize.metrics import registry
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def mlp_conf(seed=42):
@@ -223,7 +215,7 @@ class TestLazyTrainingJits:
         net = MultiLayerNetwork(mlp_conf()).init()
         x, y = small_batch(8)
         net._fit_batch(DataSet(x, y))
-        net._build_jitted()  # the bench retrace path
+        net._build_jitted()  # the retrace path
         assert "_train_step_fn" not in net.__dict__
         net._fit_batch(DataSet(x, y))  # lazily rebuilt, still trains
         assert np.isfinite(float(net.score_value))
@@ -293,48 +285,3 @@ class TestChurnGuard:
             assert dict(telemetry.churn_offenders(100)).get(label) == 1
         finally:
             telemetry.reset_churn()
-
-
-class TestBenchSurvivability:
-    @pytest.mark.slow
-    def test_partial_json_under_tiny_budget(self, tmp_path):
-        """A 1-second global budget still yields valid JSON: the first
-        child completes under its floor, the loop stops before child 2,
-        and spread.n reports what actually ran — never `parsed: null`."""
-        env = dict(os.environ)
-        env.update(JAX_PLATFORMS="cpu", BENCH_TIME_BUDGET_S="1",
-                   DL4JTPU_BENCH_PROBE="0",
-                   DL4JTPU_BENCH_LEDGER=str(tmp_path / "ledger.jsonl"),
-                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "lenet_tiny"],
-            capture_output=True, text=True, env=env, cwd=REPO, timeout=500)
-        assert out.returncode == 0, out.stderr[-2000:]
-        row = json.loads(out.stdout.strip().splitlines()[-1])
-        assert row["spread"]["n"] == 1
-        assert row["metric"] == "lenet_tiny_images_per_sec"
-        assert row["value"] > 0
-        assert row["compile_cache"]["enabled"] is True
-
-    @pytest.mark.slow
-    def test_timeout_child_fails_with_typed_ledger_row(self, tmp_path):
-        """A child that blows its wall limit with zero completed repeats
-        means nothing was measured at the full config: the parent
-        writes its typed ledger row and exits non-zero — there is no
-        reduced-config stand-in row."""
-        ledger = tmp_path / "ledger.jsonl"
-        env = dict(os.environ)
-        env.update(JAX_PLATFORMS="cpu", BENCH_TIME_BUDGET_S="1",
-                   BENCH_CHILD_MIN_S="2",  # far below jax startup time
-                   DL4JTPU_BENCH_PROBE="0",
-                   DL4JTPU_BENCH_LEDGER=str(ledger),
-                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-        out = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "lenet_tiny"],
-            capture_output=True, text=True, env=env, cwd=REPO, timeout=500)
-        assert out.returncode != 0
-        assert "nothing measured" in out.stderr
-        assert out.stdout.strip() == ""
-        row = json.loads(ledger.read_text().strip().splitlines()[-1])
-        assert row["status"] == "timeout" and row["timeout"] is True
-        assert row["backend"] == "none" and "value" not in row

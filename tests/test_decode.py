@@ -1,7 +1,6 @@
 """Decode-plane tests (docs/serving.md §decode): paged KV cache
 arithmetic, paged_decode_attention parity, adapter packing/validation, the
-typed rnn_time_step state-reset contract, scoreboard row-kind schema,
-and (slow) engine end-to-end parity / chaos isolation."""
+typed rnn_time_step state-reset contract, and (slow) engine end-to-end parity / chaos isolation."""
 import threading
 import time
 
@@ -15,7 +14,6 @@ from deeplearning4j_tpu import (LSTM, ComputationGraph, InputType,
 from deeplearning4j_tpu.data.padding import next_pow2_bucket
 from deeplearning4j_tpu.nn.multilayer import RnnStateMismatchError
 from deeplearning4j_tpu.ops.flash_attention import paged_decode_attention
-from deeplearning4j_tpu.optimize.scoreboard import _validate_row_kind
 from deeplearning4j_tpu.optimize.telemetry import CompilationTracker
 from deeplearning4j_tpu.optimize.metrics import registry
 from deeplearning4j_tpu.parallel.inference import (DecodeStepError,
@@ -298,32 +296,6 @@ class TestTransformerAdapter:
             assert sum(p.size for _, p, *_ in g) <= 16
         # 10+5+1 share a row, 7 and 16 ride alone -> 3 rows, not 5
         assert len(groups) == 3
-
-
-class TestScoreboardDecodeRow:
-    _EXTRAS = {"tokens_per_sec": 100.0, "naive_tokens_per_sec": 40.0,
-               "kv_cache_speedup": 2.5, "inter_token_p99_ms": 3.0,
-               "kv_utilization": 0.8}
-
-    def _row(self, **kw):
-        row = {"workload": "serving_decode", "status": "ok",
-               "extras": dict(self._EXTRAS)}
-        row.update(kw)
-        return row
-
-    def test_complete_extras_pass(self):
-        assert _validate_row_kind(self._row()) == []
-
-    def test_missing_extra_is_schema_violation(self):
-        extras = dict(self._EXTRAS)
-        del extras["kv_cache_speedup"]
-        probs = _validate_row_kind(self._row(extras=extras))
-        assert probs and "kv_cache_speedup" in probs[0]
-        assert _validate_row_kind(self._row(extras=None))
-
-    def test_salvage_rows_exempt(self):
-        assert _validate_row_kind(self._row(status="error")) == []
-        assert _validate_row_kind(self._row(degraded=True)) == []
 
 
 # ---------------------------------------------------------------------------

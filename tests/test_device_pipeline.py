@@ -1,11 +1,8 @@
 """Device-resident input pipeline (docs/perf_data_pipeline.md):
 pad-to-bucket ragged batches (one compiled train step per epoch, loss
 normalization by REAL rows), DevicePrefetchIterator staging/lifecycle,
-sharded prefetch on the virtual mesh, compile/ETL telemetry, and the
-bench driver's partial-JSON timeout contract."""
-import json
+sharded prefetch on the virtual mesh, and compile/ETL telemetry."""
 import queue
-import sys
 import threading
 import time
 
@@ -230,65 +227,12 @@ class TestTelemetry:
         f(jnp.ones((3,))).block_until_ready()  # cached: no new compile
         assert compilation_count() == before
 
-    def test_performance_listener_reports_breakdown(self):
+    def test_performance_listener_reports_breakdown(self, front):
         from deeplearning4j_tpu.optimize.listeners import PerformanceListener
         lines = []
         lst = PerformanceListener(frequency=1, printer=lines.append)
-        net = _net()
-        net.add_listener(lst) if hasattr(net, "add_listener") else \
-            net.listeners.append(lst)
+        net = front.net(n_in=12)
+        net.listeners.append(lst)
         x, y = _xy(96)
         net.fit(x, y, epochs=1, batch_size=32)
         assert any("host" in ln and "h2d" in ln for ln in lines)
-
-
-class TestBenchTimeout:
-    def _run_main(self, monkeypatch, capsys, tmp_path,
-                  runs_before_timeout):
-        import bench
-        from deeplearning4j_tpu.optimize import scoreboard
-        calls = {"n": 0}
-        real_json = json.dumps({"metric": "m", "value": 1.0, "unit": "u"})
-
-        def fake_run_child(cmd, **kw):
-            calls["n"] += 1
-            if calls["n"] > runs_before_timeout:
-                return scoreboard.ChildResult(
-                    "timeout", None, "", "", 0, None, False, 1.0)
-            return scoreboard.ChildResult(
-                "ok", 0, real_json + "\n", "", 3, None, False, 1.0)
-
-        monkeypatch.setattr(scoreboard, "run_child", fake_run_child)
-        monkeypatch.setattr(scoreboard, "host_sentinel_ms",
-                            lambda n=3: (1.0, 1.0))
-        monkeypatch.setattr(bench, "_vs_baseline",
-                            lambda m, v, backend: 1.0)
-        monkeypatch.setattr(sys, "argv", ["bench.py", "lenet"])
-        monkeypatch.setenv("BENCH_REPEATS", "3")
-        monkeypatch.setenv("BENCH_TIME_BUDGET_S", "420")
-        monkeypatch.setenv("DL4JTPU_BENCH_PROBE", "0")
-        monkeypatch.setenv("DL4JTPU_BENCH_LEDGER",
-                           str(tmp_path / "ledger.jsonl"))
-        bench.main()
-        return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-
-    def test_first_child_timeout_fails_with_typed_row(
-            self, monkeypatch, capsys, tmp_path):
-        """Nothing measured at the full config: a typed ledger row and a
-        non-zero exit, no artifact line, no reduced-config stand-in."""
-        with pytest.raises(SystemExit) as exc:
-            self._run_main(monkeypatch, capsys, tmp_path,
-                           runs_before_timeout=0)
-        assert "nothing measured" in str(exc.value.code)
-        assert capsys.readouterr().out.strip() == ""
-        rows = [json.loads(ln) for ln in open(tmp_path / "ledger.jsonl")]
-        assert [r["status"] for r in rows] == ["timeout"]
-        assert "value" not in rows[0] and rows[0]["backend"] == "none"
-
-    def test_partial_repeats_marked_timeout(self, monkeypatch, capsys,
-                                            tmp_path):
-        row = self._run_main(monkeypatch, capsys, tmp_path,
-                             runs_before_timeout=2)
-        assert row["timeout"] is True
-        assert row["spread"]["n"] == 2
-        assert row["value"] == 1.0

@@ -4,8 +4,8 @@ sum-to-wall by construction), phase ATTRIBUTION correctness under
 `delay:` chaos (a delay at serve.schedule must land in sched_wait, at
 serve.forward in device — not just "some phase got slower"), the
 bounded exemplar ring (capture rules + eviction), the gateway surfaces
-(/debug/requests + /trace gating, response-embedded timelines, the
-always-on SLO burn counter), and the `bench.py report` tier extras.
+(/debug/requests + /trace gating, response-embedded timelines, and
+the always-on SLO burn counter).
 
 Everything here runs against stub models — no jax device work — so the
 whole file stays tier-1 fast (ROADMAP budget note)."""
@@ -14,7 +14,7 @@ import time
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.optimize import scoreboard, tracing
+from deeplearning4j_tpu.optimize import tracing
 from deeplearning4j_tpu.optimize.metrics import registry
 from deeplearning4j_tpu.parallel.inference import (BatchExecutionError,
                                                    InferenceMode,
@@ -311,33 +311,3 @@ class TestGatewaySurfaces:
             assert seen["params"] == {"model": "a", "tier": "b"}
             json_request(srv.url + "/q")
             assert seen["params"] is None
-
-
-# ---------------------------------------------------------------------------
-# bench.py report tier extras
-# ---------------------------------------------------------------------------
-class TestReportTierExtras:
-    def test_render_report_renders_tier_lines(self):
-        row = {"metric": "serving_multimodel_requests_per_sec",
-               "value": 5000.0, "unit": "requests/sec", "ts": 0,
-               "git_sha": "abc1234", "backend": "cpu", "status": "ok",
-               "workload": "serving_multimodel",
-               "extras": {"tier_latency_ms": {
-                              "batch": {"p50": 9.0, "p99": 30.0},
-                              "critical": {"p50": 1.2, "p99": 4.5}},
-                          "tier_sheds": 3, "starvation_total": 1,
-                          "fused_speedup": 2.1}}
-        out = scoreboard.render_report([row], {})
-        assert "tier critical: p50 1.2ms  p99 4.5ms" in out
-        assert "tier batch: p50 9ms  p99 30ms" in out
-        assert "sheds 3" in out and "starvation 1" in out
-        assert "fused x2.1" in out
-        # tiers render in priority order
-        assert out.index("tier critical") < out.index("tier batch")
-
-    def test_rows_without_extras_render_unchanged(self):
-        row = {"metric": "x_images_per_sec", "value": 10.0, "unit": "i/s",
-               "ts": 0, "git_sha": "abc", "backend": "cpu",
-               "status": "ok", "extras": {"raw_times_s": []}}
-        out = scoreboard.render_report([row], {})
-        assert "tier " not in out

@@ -218,9 +218,9 @@ class TestTracing:
         tracing.enable(fence_every=0)
         assert tracing.fence(4, val) is None
 
-    def test_fit_emits_nested_taxonomy(self):
+    def test_fit_emits_nested_taxonomy(self, front):
         tracing.enable(fence_every=2)
-        _net().fit(_data(), epochs=2, batch_size=16)
+        front.net(n_in=6).fit(_data(), epochs=2, batch_size=16)
         doc = tracing.export_trace_events()
         json.loads(json.dumps(doc))  # serializable
         events = doc["traceEvents"]
@@ -265,11 +265,11 @@ class TestTracing:
         assert inner["args"]["parent_id"] == outer["args"]["span_id"]
         assert set(doc["clock"]) == {"perf_counter_s", "unix_ns"}
 
-    def test_fit_records_step_metrics(self):
+    def test_fit_records_step_metrics(self, front):
         reg = registry()
         before = reg.counter("train_iterations_total").value()
         ep_before = reg.counter("train_epochs_total").value()
-        _net().fit(_data(), epochs=2, batch_size=16)
+        front.net(n_in=6).fit(_data(), epochs=2, batch_size=16)
         assert reg.counter("train_iterations_total").value() - before == 6
         assert reg.counter("train_epochs_total").value() - ep_before == 2
         snap = reg.snapshot()

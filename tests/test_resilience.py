@@ -237,12 +237,12 @@ class TestCheckpointManager:
         rec = CheckpointManager(str(tmp_path)).latest_valid()
         assert rec["file"] == "checkpoint-00000002.zip"
 
-    def test_restore_into_roundtrip(self, tmp_path):
-        net = _mknet()
+    def test_restore_into_roundtrip(self, front, tmp_path):
+        net = front.net()
         net.fit(_data(32), epochs=1, batch_size=16)
         mgr = CheckpointManager(str(tmp_path))
         mgr.save(net)
-        other = _mknet(seed=99)
+        other = front.net(seed=99)
         rec = mgr.restore_into(other)
         assert rec["iteration"] == net.iteration
         assert other.iteration == net.iteration
@@ -254,8 +254,8 @@ class TestCheckpointManager:
         assert mgr.restore_into(_mknet()) is None
         assert mgr.restore_latest() == (None, None)
 
-    def test_listener_adapter_drives_manager(self, tmp_path):
-        net = _mknet()
+    def test_listener_adapter_drives_manager(self, front, tmp_path):
+        net = front.net()
         mgr = CheckpointManager(str(tmp_path), save_every_n_iterations=2,
                                 keep_last=10)
         lst = mgr.listener()
@@ -273,52 +273,56 @@ class TestCheckpointManager:
 # ---------------------------------------------------------------------------
 
 class TestAutoResume:
-    def test_resume_after_corruption_is_bitwise_identical(self, tmp_path):
+    def test_resume_after_corruption_is_bitwise_identical(self, front,
+                                                          tmp_path):
         ds = _data()
         # "interrupted" run: 2 of 3 epochs with per-iteration checkpoints
-        part = _mknet()
+        part = front.net()
         part.fit(ds, epochs=2, batch_size=8,
                  checkpoint=CheckpointManager(
                      str(tmp_path), save_every_n_iterations=1, keep_last=5))
         # tear the newest two checkpoints (mid-write crash analog)
         for f in ("checkpoint-00000016.zip", "checkpoint-00000015.zip"):
             _truncate(str(tmp_path / f))
-        resumed = _mknet()
+        resumed = front.net()
         resumed.fit(ds, epochs=3, batch_size=8,
                     checkpoint=CheckpointManager(
                         str(tmp_path), save_every_n_iterations=1,
                         keep_last=5),
                     resume=True)
-        straight = _mknet()
+        straight = front.net()
         straight.fit(ds, epochs=3, batch_size=8)
         assert resumed.iteration == straight.iteration == 24
         assert resumed.epoch == straight.epoch == 3
         np.testing.assert_array_equal(resumed.params(), straight.params())
 
-    def test_resume_with_no_checkpoint_trains_from_scratch(self, tmp_path):
+    def test_resume_with_no_checkpoint_trains_from_scratch(self, front,
+                                                           tmp_path):
         ds = _data(32)
-        net = _mknet()
+        net = front.net()
         net.fit(ds, epochs=1, batch_size=16,
                 checkpoint=CheckpointManager(str(tmp_path)), resume=True)
         assert net.iteration == 2 and net.epoch == 1
 
-    def test_resume_of_finished_run_is_noop(self, tmp_path):
+    def test_resume_of_finished_run_is_noop(self, front, tmp_path):
         ds = _data(32)
         mgr = CheckpointManager(str(tmp_path))
-        net = _mknet()
+        net = front.net()
         net.fit(ds, epochs=2, batch_size=16, checkpoint=mgr)
         p_done = np.asarray(net.params())
-        again = _mknet()
+        again = front.net()
         again.fit(ds, epochs=2, batch_size=16,
                   checkpoint=CheckpointManager(str(tmp_path)), resume=True)
         assert again.epoch == 2
         np.testing.assert_array_equal(again.params(), p_done)
 
-    def test_arg_validation(self, tmp_path):
-        net = _mknet()
+    def test_arg_validation(self, front, tmp_path):
+        net = front.net()
         ds = _data(32)
         with pytest.raises(ValueError, match="resume"):
             net.fit(ds, resume=True)
+        with pytest.raises(ValueError, match="step_fn"):
+            net.fit(ds, steps_per_dispatch=2, step_fn=lambda batch: None)
         with pytest.raises(ValueError, match="steps_per_dispatch"):
             net.fit(ds, steps_per_dispatch=2,
                     checkpoint=CheckpointManager(str(tmp_path)))
@@ -340,8 +344,8 @@ class TestDivergenceSentinel:
         with pytest.raises(ValueError):
             DivergenceSentinel("skip_step", check_every=4)
 
-    def test_warn_counts_and_continues(self):
-        net = _mknet()
+    def test_warn_counts_and_continues(self, front):
+        net = front.net()
         sent = DivergenceSentinel("warn")
         with faults.injected("step.nonfinite", "fail:2,4"):
             net.fit(_data(), epochs=1, batch_size=8, sentinel=sent)
@@ -356,8 +360,8 @@ class TestDivergenceSentinel:
         net.score_value = 0.5
         assert not sent.after_step(net)
 
-    def test_skip_step_drops_update(self):
-        net = _mknet()
+    def test_skip_step_drops_update(self, front):
+        net = front.net()
         sent = DivergenceSentinel("skip_step")
         with faults.injected("step.nonfinite", "fail:3"):
             net.fit(_data(), epochs=1, batch_size=8, sentinel=sent)
@@ -365,24 +369,25 @@ class TestDivergenceSentinel:
         # 8 batches, one update dropped and iteration rolled back
         assert net.iteration == 7
 
-    def test_rollback_restores_and_backs_off_lr(self, tmp_path):
-        net = _mknet()
+    def test_rollback_restores_and_backs_off_lr(self, front, tmp_path):
+        net = front.net()
         mgr = CheckpointManager(str(tmp_path), save_every_n_iterations=1,
                                 keep_last=3)
-        lr0 = net.layers[0].updater.learning_rate
+        lr0 = front.first_layer(net).updater.learning_rate
         sent = DivergenceSentinel("rollback", checkpoint=mgr,
                                   lr_backoff=0.5, max_rollbacks=2)
         with faults.injected("step.nonfinite", "fail:5"):
             net.fit(_data(), epochs=1, batch_size=8,
                     checkpoint=mgr, sentinel=sent)
         assert sent.rollbacks == 1
-        assert net.layers[0].updater.learning_rate == pytest.approx(lr0 / 2)
+        assert front.first_layer(net).updater.learning_rate == \
+            pytest.approx(lr0 / 2)
         snap = metrics_mod.registry().snapshot()
         assert snap.get("rollbacks_total", 0) >= 1
         assert snap.get('nonfinite_steps_total{policy="rollback"}', 0) >= 1
 
-    def test_rollback_budget_exhausted_raises(self, tmp_path):
-        net = _mknet()
+    def test_rollback_budget_exhausted_raises(self, front, tmp_path):
+        net = front.net()
         mgr = CheckpointManager(str(tmp_path), save_every_n_iterations=1)
         sent = DivergenceSentinel("rollback", checkpoint=mgr,
                                   max_rollbacks=1)
@@ -391,8 +396,9 @@ class TestDivergenceSentinel:
                 net.fit(_data(), epochs=1, batch_size=8,
                         checkpoint=mgr, sentinel=sent)
 
-    def test_rollback_without_checkpoint_on_disk_raises(self, tmp_path):
-        net = _mknet()
+    def test_rollback_without_checkpoint_on_disk_raises(self, front,
+                                                        tmp_path):
+        net = front.net()
         mgr = CheckpointManager(str(tmp_path))  # never saved into
         sent = DivergenceSentinel("rollback", checkpoint=mgr)
         net.score_value = float("nan")
