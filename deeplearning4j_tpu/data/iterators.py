@@ -676,14 +676,20 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
     for this framework. Shutdown/reset/error semantics are inherited
     from AsyncDataSetIterator (same bounded queue + sentinel protocol).
 
+    The producer issues TRANSFERS AND NOTHING ELSE: an array is staged
+    as the host holds it, dtype and all, and no executable is dispatched
+    from this thread. The device runs executables in the order they are
+    enqueued, so a cast enqueued here could get in ahead of the fit
+    thread's next train step and park that step behind a transfer; the
+    cast to the network's dtype is the front end's (`_cast_features`,
+    `_pack_inputs`), on the fit thread, on a batch that has landed.
+
     `depth` bounds how many staged batches may be device-resident at
-    once (HBM cost: depth x batch bytes). `sharding` places every
-    staged array under that sharding; batches whose leading dimension
-    is not divisible by `batch_divisor` (the mesh's data-axis size)
-    skip device staging and pass through as host arrays, letting the
-    wrapper's zero-weight pad path handle them. `cast_dtype` pre-casts
-    floating FEATURE arrays to the network dtype on the producer thread
-    (the step-time `_cast_features` then no-ops).
+    once (HBM cost: depth x batch bytes, in the host's dtype).
+    `sharding` places every staged array under that sharding; batches
+    whose leading dimension is not divisible by `batch_divisor` (the
+    mesh's data-axis size) skip device staging and pass through as host
+    arrays, letting the wrapper's zero-weight pad path handle them.
 
     Each staged batch carries its ETL breakdown as `_etl_host_ms` (time
     the producer spent pulling it from the base iterator) and
@@ -691,61 +697,53 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
     model.last_etl_host_ms / last_etl_h2d_ms next to the consumer-side
     last_etl_ms stall clock. With tracing on, each staging is an
     `etl/stage` span on the producer's thread (args `bytes`, `rows`)
-    with children `etl/stage/put` (the `device_put`s and the cast's
-    dispatch) and `etl/stage/fence` (the wait for them to land, which
-    queues behind whatever the device is running);
+    with children `etl/stage/put` (the `device_put`s, nothing else) and
+    `etl/stage/fence` (the wait for the link to deliver them; it does
+    not queue behind what the device is computing);
     `etl_h2d_bytes_total` counts the host bytes handed over."""
 
     def __init__(self, base, depth: int = 2, sharding=None,
-                 batch_divisor: int = 1, cast_dtype=None):
+                 batch_divisor: int = 1):
         super().__init__(base, queue_size=depth)
         self._sharding = sharding
         self._divisor = max(1, int(batch_divisor))
-        self._cast_dtype = cast_dtype
         self._h2d_bytes = register_metrics()
 
-    def _put(self, a, is_feature: bool):
+    def _put(self, a):
         import jax
-        import jax.numpy as jnp
-        if a is None:
-            return None
-        if is_feature and self._cast_dtype is not None:
-            dt = np.asarray(a).dtype if not isinstance(a, jax.Array) \
-                else a.dtype
-            if jnp.issubdtype(dt, jnp.floating):
-                a = jnp.asarray(a).astype(self._cast_dtype)
-        if self._sharding is not None:
-            return jax.device_put(a, self._sharding)
-        return jax.device_put(a)
+        # an absent mask (None) is an empty tree and comes back as None
+        return jax.device_put(a, self._sharding)
 
     def _stage(self, ds):
         import jax
         t0 = time.perf_counter()
         if isinstance(ds, MultiDataSet):
             out = MultiDataSet(
-                [self._put(f, True) for f in ds.features],
-                [self._put(l, False) for l in ds.labels],
+                [self._put(f) for f in ds.features],
+                [self._put(l) for l in ds.labels],
                 None if ds.features_masks is None
-                else [self._put(m, False) for m in ds.features_masks],
+                else [self._put(m) for m in ds.features_masks],
                 None if ds.labels_masks is None
-                else [self._put(m, False) for m in ds.labels_masks])
+                else [self._put(m) for m in ds.labels_masks])
             leaves = out.features + out.labels
             sources = (list(ds.features) + list(ds.labels)
                        + list(ds.features_masks or ())
                        + list(ds.labels_masks or ()))
         elif isinstance(ds, DataSet):
-            out = DataSet(self._put(ds.features, True),
-                          self._put(ds.labels, False),
-                          self._put(ds.features_mask, False),
-                          self._put(ds.labels_mask, False))
+            out = DataSet(self._put(ds.features), self._put(ds.labels),
+                          self._put(ds.features_mask),
+                          self._put(ds.labels_mask))
             leaves = [out.features, out.labels]
             sources = [ds.features, ds.labels, ds.features_mask,
                        ds.labels_mask]
         else:
             return ds
         t1 = time.perf_counter()
-        # Fence on the producer thread: the consumer must never inherit
-        # an in-flight transfer (that wait would be invisible ETL).
+        # Fence on the producer thread, and on the link alone (nothing
+        # above is an executable). It stays: a step launched on a batch
+        # that has not landed parks the device's compute stream behind
+        # the link, a whole cycle idle; and it is what keeps at most
+        # `depth` staged batches resident.
         jax.block_until_ready([a for a in leaves if a is not None])
         t2 = time.perf_counter()
         # what went over the link: the arrays that were the host's

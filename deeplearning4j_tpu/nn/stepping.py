@@ -34,6 +34,7 @@ scalar there cost a second full compile of the train step).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import time
@@ -49,6 +50,17 @@ from ..optimize import tracing
 from .conf.builders import BackpropType
 
 log = logging.getLogger(__name__)
+
+
+# How many launches (a step, or a fused group of `steps_per_dispatch`) `fit`
+# leaves unfinished behind it. A producer that stages faster than the device
+# steps would otherwise let the loop run ahead until the runtime's own
+# limits stop it, every queued launch holding its batches. One already
+# hides the host's part of a cycle behind the running launch, and read the
+# same rate on the chip; two and not one for slack alone: a hiccup of the
+# host then has to outlast a whole launch more before the device runs dry
+# (PERF.md section 6, PR 32, has the numbers).
+_STEPS_IN_FLIGHT = 2
 
 
 def _is_none(a) -> bool:
@@ -145,7 +157,12 @@ class Trainer:
         reuses ONE compiled train step. Batches prefetch on a background
         thread (`async_queue_size` deep); `prefetch_to_device` upgrades
         that thread to stage batches onto the device (`jax.device_put` +
-        transfer fence off the training thread, `prefetch_depth` deep);
+        transfer fence off the training thread, `prefetch_depth` deep).
+        That thread moves bytes and dispatches nothing: a batch is staged
+        in the host's dtype, and the cast to the network's dtype is
+        enqueued here, on the fit thread, immediately before the step
+        that reads it, so nothing can stand between two steps in the
+        device's queue.
         `prefetch_sharding`/`prefetch_divisor` let ParallelWrapper stage
         mesh-sharded batches. Both honor use_async=False (no threads)
         and AsyncShield iterators.
@@ -195,8 +212,7 @@ class Trainer:
             wrapped = DevicePrefetchIterator(
                 it, depth=max(1, int(prefetch_depth)),
                 sharding=prefetch_sharding,
-                batch_divisor=prefetch_divisor,
-                cast_dtype=self._dtype) if prefetch_to_device \
+                batch_divisor=prefetch_divisor) if prefetch_to_device \
                 else self._ASYNC_ITERATOR(it, async_queue_size)
         else:
             wrapped = it
@@ -215,6 +231,7 @@ class Trainer:
             group.clear()
 
         reg = metrics_mod.registry()
+        unfinished = collections.deque()    # losses of the newest launches
         fit_sp = tracing.begin("fit", epochs=epochs)
         try:
             for _ in range(epochs):
@@ -283,6 +300,16 @@ class Trainer:
                             "fence (device-compute backlog)").set(w)
                     if sentinel is not None:
                         sentinel.after_step(self)
+                    if not unfinished or \
+                            self.score_value is not unfinished[-1]:
+                        # a launch put a new loss there; a batch that
+                        # only joined its group launched nothing, and
+                        # waiting then would hold the next group back
+                        unfinished.append(self.score_value)
+                        if len(unfinished) > _STEPS_IN_FLIGHT:
+                            # outside `dispatch`: this is the device's
+                            # time, not the host's enqueue
+                            jax.block_until_ready(unfinished.popleft())
                     batches_done += 1
                     if checkpoint is not None:
                         checkpoint.on_batch(self, batches_done)

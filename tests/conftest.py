@@ -45,11 +45,19 @@ class _Front:
         from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
         return DataSet if self.kind == "mln" else MultiDataSet
 
+    @property
+    def cast_name(self):
+        """The method that casts a batch's features to the network's dtype
+        on the way into a step."""
+        return "_cast_features" if self.kind == "mln" else "_pack_inputs"
+
     def net(self, seed=7, n_in=4, hidden=16, classes=3, updater=None,
-            activation="relu"):
+            activation="relu", dtype=None):
+        import jax.numpy as jnp
         from deeplearning4j_tpu import (Adam, ComputationGraph, DenseLayer,
                                         InputType, MultiLayerNetwork,
                                         NeuralNetConfiguration, OutputLayer)
+        how = {} if dtype is None else {"dtype": jnp.dtype(dtype)}
         b = (NeuralNetConfiguration.builder().seed(seed)
              .updater(updater or Adam(0.05)))
         dense = DenseLayer(n_out=hidden, activation=activation)
@@ -57,12 +65,14 @@ class _Front:
         if self.kind == "mln":
             return MultiLayerNetwork(
                 b.list().layer(dense).layer(out)
-                .set_input_type(InputType.feed_forward(n_in)).build()).init()
+                .set_input_type(InputType.feed_forward(n_in)).build()
+                ).init(**how)
         return ComputationGraph(
             b.graph_builder().add_inputs("in")
             .add_layer("dense", dense, "in").add_layer("out", out, "dense")
             .set_outputs("out")
-            .set_input_types(InputType.feed_forward(n_in)).build()).init()
+            .set_input_types(InputType.feed_forward(n_in)).build()
+            ).init(**how)
 
     def tbptt_net(self, seed=5, n_in=4, window=5):
         """The same pair under truncated BPTT: an LSTM and a

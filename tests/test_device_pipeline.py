@@ -132,16 +132,100 @@ class TestPadToBucket:
         assert m.sum() == 20  # denominator unchanged by pad rows
 
 
+class _CastCalls:
+    """Wraps a front end's cast of the features (`front.cast_name`): which
+    thread called it, what it was given, what it returned."""
+
+    def __init__(self, net, name):
+        self.seen = []
+        inner = getattr(net, name)
+
+        def wrapped(features, *rest):
+            out = inner(features, *rest)
+            given = features if name == "_cast_features" else features[0]
+            got = out if name == "_cast_features" \
+                else next(iter(out[0].values()))
+            self.seen.append((threading.current_thread(), given, got))
+            return out
+
+        setattr(net, name, wrapped)
+
+
+def _step_features(net):
+    """Spy on `_run_and_commit`: (calling thread, the step's first feature
+    operand) of every step."""
+    seen = []
+    run = net._run_and_commit
+    net._run_and_commit = lambda *ops, **kw: (
+        seen.append((threading.current_thread(),
+                     jax.tree_util.tree_leaves(ops[0])[0])),
+        run(*ops, **kw))
+    return seen
+
+
 class TestDevicePrefetchIterator:
     def test_stages_on_device_with_etl_breakdown(self):
+        """A staged batch is the host's batch moved, nothing else: the
+        host's dtype, on the device, landed when it is handed over."""
+        x, y = _xy(64)
         it = DevicePrefetchIterator(
-            ListDataSetIterator(DataSet(*_xy(64)), batch_size=32))
+            ListDataSetIterator(DataSet(x, y), batch_size=32))
         batches = list(it)
         assert len(batches) == 2
         for b in batches:
             assert isinstance(b.features, jax.Array)
             assert isinstance(b.labels, jax.Array)
+            assert b.features.is_ready() and b.labels.is_ready()
+            assert b.features.dtype == b.labels.dtype == np.float32
             assert b._etl_host_ms >= 0.0 and b._etl_h2d_ms >= 0.0
+        np.testing.assert_array_equal(np.asarray(batches[0].features),
+                                      x[:32])
+
+    @pytest.mark.parametrize("host_dtype", ["float16", "int32"])
+    def test_a_staged_batch_keeps_the_hosts_dtype(self, host_dtype):
+        x, y = _xy(64)
+        it = DevicePrefetchIterator(
+            ListDataSetIterator(DataSet(x.astype(host_dtype), y),
+                                batch_size=32))
+        for b in it:
+            assert isinstance(b.features, jax.Array)
+            assert b.features.dtype == np.dtype(host_dtype)
+
+    def test_cast_dtype_is_no_argument(self):
+        """The producer casts nothing, so it takes no dtype to cast to."""
+        import jax.numpy as jnp
+        base = ListDataSetIterator(DataSet(*_xy(64)), batch_size=32)
+        with pytest.raises(TypeError, match="cast_dtype"):
+            DevicePrefetchIterator(base, cast_dtype=jnp.bfloat16)
+
+    def test_staging_a_new_shape_compiles_nothing(self):
+        """`device_put` is a transfer; a cast would be an executable, and
+        the first of its shape a compilation."""
+        x, y = _xy(74, n_in=37)       # a shape no other test stages
+        it = DevicePrefetchIterator(
+            ListDataSetIterator(DataSet(x, y), batch_size=37))
+        with CompilationTracker() as trk:
+            batches = list(it)
+        assert len(batches) == 2
+        assert trk.count == 0
+
+    def test_the_cast_runs_on_the_fit_thread_before_its_step(self, front):
+        """A bfloat16 network fed float32 host batches: the producer
+        stages float32, the front end's cast is called by the thread that
+        called `fit` and by no other, and the step gets bfloat16."""
+        import jax.numpy as jnp
+        net = front.net(n_in=12, dtype="bfloat16")
+        calls = _CastCalls(net, front.cast_name)
+        operands = _step_features(net)
+        x, y = _xy(96)
+        net.fit(x, y, epochs=1, batch_size=32)
+        assert len(calls.seen) == 3 and len(operands) == 3
+        for thread, given, got in calls.seen:
+            assert thread is threading.current_thread()
+            assert isinstance(given, jax.Array)       # staged ...
+            assert given.dtype == np.float32          # ... as the host's
+            assert got.dtype == jnp.bfloat16
+        assert all(f.dtype == jnp.bfloat16 for _, f in operands)
 
     def test_shutdown_mid_epoch(self):
         it = DevicePrefetchIterator(
@@ -182,6 +266,14 @@ class TestDevicePrefetchIterator:
         assert [b.features.shape[0] for b in batches] == [32, 32, 16]
         for b in batches:
             assert b.features.sharding.is_equivalent_to(sh, b.features.ndim)
+            # the host's array went straight under the sharding: committed
+            # there, an eighth of the rows a device, the host's dtype
+            assert b.features.committed and b.features.dtype == np.float32
+            rows = b.features.shape[0] // 8
+            assert [s.data.shape for s in b.features.addressable_shards] \
+                == [(rows, 12)] * 8
+            assert {s.data.dtype for s in b.features.addressable_shards} \
+                == {np.dtype("float32")}
         # indivisible tail (30 % 8 != 0) passes through as host arrays
         it2 = DevicePrefetchIterator(
             ListDataSetIterator(DataSet(*_xy(94)), batch_size=32),
@@ -210,6 +302,36 @@ class TestParallelWrapperPrefetch:
                           jax.tree_util.tree_leaves(ref.params_tree)):
             np.testing.assert_allclose(np.asarray(pa), np.asarray(pb),
                                        rtol=2e-5, atol=2e-6)
+
+    def test_a_sharded_step_casts_each_shard_of_a_float32_staging(
+            self, front):
+        """Under the wrapper the host's float32 batch is staged straight
+        under the mesh's sharding and the wrapper's step casts it there:
+        the step's features are bfloat16, sharded 8 ways, and the cast is
+        the fit thread's."""
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.parallel import (ParallelWrapper,
+                                                 data_parallel_mesh)
+        from deeplearning4j_tpu.parallel.mesh import batch_sharded
+        net = front.net(n_in=12, dtype="bfloat16")
+        mesh = data_parallel_mesh(8)
+        staged, operands = [], _step_features(net)
+        pw = ParallelWrapper(net, mesh=mesh)
+        step = pw.fit_batch
+        pw.fit_batch = lambda ds: (staged.append(ds), step(ds))
+        x, y = _xy(64)
+        pw.fit(x, y, epochs=1, batch_size=32)
+        assert len(staged) == 2 and len(operands) == 2
+        sh = batch_sharded(mesh)
+        for ds in staged:
+            f = ds.features[0] if isinstance(ds.features, list) \
+                else ds.features
+            assert f.dtype == np.float32 and f.sharding.is_equivalent_to(sh, 2)
+        for thread, f in operands:
+            assert thread is threading.current_thread()
+            assert f.dtype == jnp.bfloat16
+            assert f.sharding.is_equivalent_to(sh, 2)
+            assert {s.data.shape for s in f.addressable_shards} == {(4, 12)}
 
 
 class TestTelemetry:

@@ -116,6 +116,34 @@ class TestShutdown:
         assert any(during)
 
 
+class TestRunAhead:
+    def test_the_loop_leaves_two_steps_unfinished_behind_it_and_no_more(
+            self, front):
+        """A producer that stages faster than the device steps must not
+        let the host queue steps without bound (each holds its batch on
+        the device): after dispatching step n the loop waits for the
+        loss of step n - 2, in order, once each."""
+        dispatched, waited = [], []
+
+        class Loss:
+            def __init__(self, n):
+                self.n = n
+
+            def block_until_ready(self):
+                waited.append((self.n, len(dispatched)))
+                return self
+
+        net = front.net()
+
+        def step(batch):
+            dispatched.append(batch)
+            net.score_value = Loss(len(dispatched))
+
+        net.fit(_data(), batch_size=8, step_fn=step)
+        assert len(dispatched) == 8
+        assert waited == [(n, n + 2) for n in range(1, 7)]
+
+
 class TestGrouping:
     def test_flushes_at_a_change_of_signature_and_at_the_epoch_tail(
             self, front):
@@ -141,6 +169,32 @@ class TestGrouping:
                  if e["name"] == "dispatch"
                  and e["args"].get("flush") == "epoch_tail"]
         assert len(tails) == 1
+
+    def test_a_group_is_waited_for_once_two_more_are_launched_not_before(
+            self, front, monkeypatch):
+        """The bound on run-ahead counts launches, not batches. A batch
+        that joins a filling group launches nothing; a wait there, for
+        the group just flushed, would leave the device idle while the
+        host gathers, stacks and dispatches the next group: the latency
+        `steps_per_dispatch` exists to hide."""
+        net = front.net()
+        launched, waited = [], []
+        fit_batches, ready = net.fit_batches, jax.block_until_ready
+
+        def launch(group):
+            fit_batches(group)
+            launched.append(net.score_value)
+
+        def wait(x):
+            waited.extend((n, len(launched))
+                          for n, loss in enumerate(launched, 1) if x is loss)
+            return ready(x)
+
+        net.fit_batches = launch
+        monkeypatch.setattr(jax, "block_until_ready", wait)
+        net.fit(_data(192), batch_size=8, steps_per_dispatch=4)
+        assert len(launched) == 6
+        assert waited == [(n, n + 2) for n in range(1, 5)]
 
     def test_truncated_bptt_batches_fuse_on_the_list_network_only(
             self, front):
@@ -218,15 +272,21 @@ class TestFeeding:
         assert sum(b.num_examples() for b in got) == 64
         assert net.iteration == 0       # the loop itself steps nothing
 
-    def test_three_ways_of_feeding_end_in_the_same_parameters(self, front):
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_three_ways_of_feeding_end_in_the_same_parameters(self, front,
+                                                              dtype):
+        """Host batches are float32 either way: a bfloat16 network's cast
+        is the same `convert` on the fit thread whether the batch came
+        from the host or was staged on the device by the producer."""
         ds = _data(60)                  # a ragged tail: 7 x 8 + 4
         ends = []
         for how in (dict(use_async=False), dict(prefetch_to_device=False),
                     dict()):
-            net = front.net()
+            net = front.net(dtype=dtype)
             net.fit(ds, batch_size=8, **how)
             assert net.iteration == 8
             ends.append(_leaves(net))
+            assert {str(a.dtype) for a in ends[-1]} == {dtype}
         for other in ends[1:]:
             for a, b in zip(ends[0], other):
                 np.testing.assert_array_equal(a, b)
