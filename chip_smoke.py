@@ -600,6 +600,20 @@ def phase_kernels(cfg, interpret: bool):
          None, 2048),
         ("bf16 h32/4x128 bt256 window1024", jnp.bfloat16, 32, 4, 128, 256,
          5, 1024, 2048)]
+    def chunk_line(chunk, n_ctx, ctx_len):
+        """A chunk of two segments and a padded tail after `n_ctx` cached
+        positions of which `ctx_len` are real: (the live queries, the
+        positions and segments `prefill_attention` takes)."""
+        line = np.arange(chunk)
+        seg = np.where(line < chunk // 2, 1, 2)
+        seg[-chunk // 8:] = 0
+        real = np.arange(n_ctx) < ctx_len
+        return seg > 0, dict(
+            q_pos=jnp.asarray(line), q_seg=jnp.asarray(seg),
+            kv_pos=jnp.asarray(np.concatenate(
+                [np.where(real, np.arange(n_ctx) - ctx_len, 1 << 30), line])),
+            kv_seg=jnp.asarray(np.concatenate([np.where(real, 1, -1), seg])))
+
     for what, dt, hh, kvh, d, bt, w, window, chunk in serving:
         if window and interpret:
             continue
@@ -637,27 +651,67 @@ def phase_kernels(cfg, interpret: bool):
             q = jax.random.normal(ks[0], (chunk, hh, d), dt)
             k_, v_ = (jax.random.normal(k, (n_ctx + chunk, kvh, d), dt)
                       for k in ks[1:])
-            line = np.arange(chunk)
-            seg = np.where(line < chunk // 2, 1, 2)
-            seg[-chunk // 8:] = 0
-            real = np.arange(n_ctx) < ctx_len
-            kw = dict(
-                q_pos=jnp.asarray(line), q_seg=jnp.asarray(seg),
-                kv_pos=jnp.asarray(np.concatenate(
-                    [np.where(real, np.arange(n_ctx) - ctx_len, 1 << 30),
-                     line])),
-                kv_seg=jnp.asarray(np.concatenate(
-                    [np.where(real, 1, -1), seg])), window=window)
+            live, where = chunk_line(chunk, n_ctx, ctx_len)
+            kw = dict(where, window=window)
             got = jax.jit(lambda *a: prefill_attention(
                 *a, impl="flash", interpret=interpret, **kw))(q, k_, v_)
             with jax.default_matmul_precision("highest"):
                 ref = jax.jit(lambda *a: prefill_attention(
                     *a, impl="dense", **kw))(q, k_, v_)
-            live = seg > 0
             return {"out": _rel_err(np.asarray(got, np.float32)[live],
                                     np.asarray(ref, np.float32)[live])}
         kernel(f"prefill_attention [{what} chunk{chunk}]", KERNEL_TOL,
                chunked)
+
+    # ---- the latent-attention kernels: the absorbed decode kernel over
+    # the paged latents (one entry is key and value) and the prefill kernel
+    # with a value head of its own size, each against its dense arm. On the
+    # chip at the served decoder's widths (32 heads, an entry of 576 on 640
+    # lanes, keys of 192 and values of 128); rehearsed tiny.
+    from deeplearning4j_tpu.ops.flash_attention import latent_decode_attention
+    hh, width, dv, dq, dvh, bt, w, chunk = (2, 24, 16, 12, 8, 8, 4, 16) \
+        if interpret else (32, 640, 512, 192, 128, 256, 8, 2048)
+    dt = jnp.float32 if interpret else jnp.bfloat16
+
+    def latent_decode():
+        ks = jax.random.split(jax.random.PRNGKey(33), 3)
+        nrows, blocks = 8, 8 * w
+        q = jax.random.normal(ks[0], (nrows, hh, width), dt) * 0.2
+        new = jax.random.normal(ks[1], (nrows, width), dt)
+        arena = jax.random.normal(ks[2], (2, blocks + 1, bt, width), dt)
+        tables = jnp.asarray(np.random.default_rng(0).permutation(
+            blocks).reshape(nrows, w), jnp.int32)
+        lens = jnp.asarray(np.linspace(0, w * bt - 1, nrows), jnp.int32)
+        kw = dict(v_width=dv, scale=dq ** -0.5)
+        got = jax.jit(lambda *a: latent_decode_attention(
+            *a[:3], 1, *a[3:], impl="paged", interpret=interpret, **kw))(
+                q, new, arena, tables, lens)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a: latent_decode_attention(
+                *a[:3], 1, *a[3:], impl="dense", **kw))(
+                    q, new, arena, tables, lens)
+        return {"out": _rel_err(got, ref)}
+    kernel(f"latent_decode_attention [h{hh} entry{width} v{dv} bt{bt}]",
+           KERNEL_TOL, latent_decode)
+
+    def latent_prefill():
+        ks = jax.random.split(jax.random.PRNGKey(34), 3)
+        n_ctx, ctx_len = w * bt, (w * bt * 3) // 4
+        q = jax.random.normal(ks[0], (hh, chunk, dq), dt)
+        k_ = jax.random.normal(ks[1], (hh, n_ctx + chunk, dq), dt)
+        v_ = jax.random.normal(ks[2], (hh, n_ctx + chunk, dvh), dt)
+        live, where = chunk_line(chunk, n_ctx, ctx_len)
+        kw = dict(where, scale=dq ** -0.5, heads_first=True)
+        got = jax.jit(lambda *a: prefill_attention(
+            *a, impl="flash", interpret=interpret,
+            name="prefill_attention_latent", **kw))(q, k_, v_)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a: prefill_attention(
+                *a, impl="dense", **kw))(q, k_, v_)
+        return {"out": _rel_err(np.asarray(got, np.float32)[:, live],
+                                np.asarray(ref, np.float32)[:, live])}
+    kernel(f"prefill_attention [latent h{hh} qk{dq} v{dvh} chunk{chunk}]",
+           KERNEL_TOL, latent_prefill)
 
     # ---- int8 matmul -----------------------------------------------------
     def int8_case():

@@ -62,7 +62,8 @@ from .pallas_kernels import pad_axis_to
 # kernels are dispatched inside the decoder's jitted step and prefill
 # (serving/decode.py _step_pure, _prefill_pure), so the JL0xx/JL2xx
 # purity rules must treat them as traced roots here.
-__traced__ = ("paged_decode_attention", "prefill_attention")
+__traced__ = ("paged_decode_attention", "prefill_attention",
+              "latent_decode_attention")
 
 NEG = -1e30  # mask sentinel; matches ops/attention.py (finite: -inf NaNs grads)
 
@@ -613,6 +614,8 @@ def _visible(q_pos, kv_pos, q_seg, kv_seg, window):
 
 def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                       window: Optional[int] = None,
+                      scale: Optional[float] = None,
+                      heads_first: bool = False,
                       name: str = "prefill_attention", impl: str = "auto",
                       interpret: bool = False, q_block: int = 0,
                       kv_block: int = 0):
@@ -621,8 +624,14 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
     its own.
 
       q            [tq, heads, head_dim]
-      k, v         [tk, kv_heads, head_dim]; query head n reads KV head
+      k            [tk, kv_heads, head_dim]; query head n reads KV head
                    n // (heads / kv_heads)
+      v            [tk, kv_heads, v_dim]: the value's head size is its
+                   own (latent attention: keys of 192, values of 128)
+      scale        on the scores (default ``head_dim ** -0.5``)
+      heads_first  q, k, v and the result are ``[heads, t, dim]``, the
+                   kernel's own order: a caller whose products can give
+                   that order spares a transposed copy of each
       q_pos/kv_pos int32 [tq] / [tk]: places on one line, monotone
                    inside a block of keys (the skip tests read a block's
                    ends)
@@ -633,9 +642,13 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
     The kernel skips key blocks wholly above the diagonal, wholly behind
     the window, or of other segments. `impl="auto"` takes the kernel on
     a TPU from PREFILL_KERNEL_MIN_KEYS keys on; `"dense"` is the einsum
-    arm. Returns [tq, heads, head_dim]."""
-    tq, hh, d = q.shape
-    tk, kvh, _ = k.shape
+    arm. Returns [tq, heads, v_dim]."""
+    turn = lambda a: a.transpose(1, 0, 2)
+    same = lambda a: a
+    # to [t, heads, dim] (the dense arm's order) and to the kernel's
+    by_t, by_head = (turn, same) if heads_first else (same, turn)
+    tq, hh, d = by_t(q).shape
+    tk, kvh, dv = by_t(v).shape
     if hh % kvh:
         raise ValueError(f"{hh} query heads over {kvh} KV heads")
     group = hh // kvh
@@ -647,31 +660,32 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
     use_flash = impl == "flash" or (
         impl == "auto" and flash_attention_available()
         and tk >= PREFILL_KERNEL_MIN_KEYS
-        and flash_attention_supported(tq, tk, d, q_block=qb, kv_block=kb))
+        and flash_attention_supported(tq, tk, max(d, dv), q_block=qb,
+                                      kv_block=kb))
     q_pos, kv_pos = q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32)
     q_seg, kv_seg = q_seg.astype(jnp.int32), kv_seg.astype(jnp.int32)
     if not use_flash:
         with jax.named_scope(name):
+            q, k, v = by_t(q), by_t(k), by_t(v)
             s = jnp.einsum("qkgd,tkd->kgqt", q.reshape(tq, kvh, group, d),
                            k, preferred_element_type=jnp.float32)
             ok = _visible(q_pos, kv_pos, q_seg, kv_seg, window)
-            p = jax.nn.softmax(
-                jnp.where(ok[None, None], s / math.sqrt(d), NEG), axis=-1)
+            s = s / math.sqrt(d) if scale is None else s * scale
+            p = jax.nn.softmax(jnp.where(ok[None, None], s, NEG), axis=-1)
             o = jnp.einsum("kgqt,tkd->qkgd", p.astype(v.dtype), v,
                            preferred_element_type=jnp.float32)
-            return o.reshape(tq, hh, d).astype(q.dtype)
+            return by_t(o.reshape(tq, hh, dv).astype(q.dtype))
 
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    fold = lambda a: pad_axis_to(a.transpose(1, 0, 2), 2, _LANE)
-    q3, k3, v3 = fold(q), fold(k), fold(v)
-    dp = q3.shape[2]
+    q3, k3, v3 = (pad_axis_to(by_head(a), 2, _LANE) for a in (q, k, v))
+    dp, dvp = q3.shape[2], v3.shape[2]
     nk = tk // kb
-    kern = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
-                             causal=True, use_mask=False, use_segs=True,
-                             nk=nk, window=window)
-    kv_spec = pl.BlockSpec((1, kb, dp), lambda i, j, k_: (i // group, k_, 0))
+    kern = functools.partial(
+        _fwd_kernel, scale=1.0 / math.sqrt(d) if scale is None else scale,
+        causal=True, use_mask=False, use_segs=True, nk=nk, window=window)
+    kv_at = lambda i, j, k_: (i // group, k_, 0)
     with jax.named_scope(name):
         o3, _ = pl.pallas_call(
             kern,
@@ -683,27 +697,43 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                 pl.BlockSpec((1, qb, 1), lambda i, j, k_: (0, j, 0)),
                 pl.BlockSpec((1, 1, kb), lambda i, j, k_: (0, 0, k_)),
                 pl.BlockSpec((1, qb, dp), lambda i, j, k_: (i, j, 0)),
-                kv_spec, kv_spec,
+                pl.BlockSpec((1, kb, dp), kv_at),
+                pl.BlockSpec((1, kb, dvp), kv_at),
             ],
             out_specs=[
-                pl.BlockSpec((1, qb, dp), lambda i, j, k_: (i, j, 0)),
+                pl.BlockSpec((1, qb, dvp), lambda i, j, k_: (i, j, 0)),
                 pl.BlockSpec((1, qb, 1), lambda i, j, k_: (i, j, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((hh, tq, dp), q.dtype),
+                jax.ShapeDtypeStruct((hh, tq, dvp), q.dtype),
                 jax.ShapeDtypeStruct((hh, tq, 1), jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((qb, 1), jnp.float32),
                 pltpu.VMEM((qb, 1), jnp.float32),
-                pltpu.VMEM((qb, dp), jnp.float32),
+                pltpu.VMEM((qb, dvp), jnp.float32),
             ],
             interpret=interpret,
             name=name,
         )(q_pos.reshape(tq, 1), kv_pos.reshape(1, tk),
           jnp.ones((1, 1, tk), jnp.float32), q_seg.reshape(1, tq, 1),
           kv_seg.reshape(1, 1, tk), q3, k3, v3)
-    return o3[:, :, :d].transpose(1, 0, 2)
+    return by_head(o3[:, :, :dv])
+
+
+def _online_softmax_add(s, v, m_ref, l_ref, acc_ref):
+    """One block into a decode kernel's running softmax: s [heads, bt]
+    masked scores, v [bt, dv] with the rows no query sees zeroed (p = 0
+    does not silence a NaN that a block's last owner left)."""
+    m_prev = m_ref[:]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next)
+    l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[:] = m_next
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _paged_decode_kernel(tables_ref, starts_ref, lens_ref, q_ref, kn_ref,
@@ -734,19 +764,10 @@ def _paged_decode_kernel(tables_ref, starts_ref, lens_ref, q_ref, kn_ref,
             preferred_element_type=jnp.float32) * scale
         at = first + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
         s = jnp.where((at < ln) & (at >= lo), s, NEG)
-        m_prev = m_ref[:]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_next
-        # a block's tail past `ln` keeps its last owner's values: zero
-        # them, p = 0 does not silence a NaN
+        # a block's tail past `ln` keeps its last owner's values
         down = first + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
         v = jnp.where((down < ln) & (down >= lo), v_ref[...], 0)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _online_softmax_add(s, v, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nw - 1)
     def _():
@@ -859,3 +880,119 @@ def paged_decode_attention(q, k_new, v_new, arena_k, arena_v, layer: int,
           arena_k, arena_v)
     # back from the lanes of each head's own KV head
     return jnp.einsum("rhkd,hk->rhd", o.reshape(rows, hh, kvh, d), own)
+
+
+def _latent_decode_kernel(tables_ref, lens_ref, q_ref, new_ref, c_ref, o_ref,
+                          m_ref, l_ref, acc_ref, *, scale, bt, dv, nw):
+    """Grid (row, table entry), as `_paged_decode_kernel`, over a cache
+    whose entry is key and value at once: every head's query meets the
+    one block, and the value is the block's first `dv` lanes."""
+    from jax.experimental import pallas as pl
+
+    b, j = pl.program_id(0), pl.program_id(1)
+    ln, first = lens_ref[b], j * bt
+
+    @pl.when(j == 0)
+    def _():
+        new = new_ref[...].astype(jnp.float32)
+        m_ref[:] = jnp.sum(q_ref[...].astype(jnp.float32) * new, axis=1,
+                           keepdims=True) * scale
+        l_ref[:] = jnp.ones(l_ref.shape, l_ref.dtype)
+        acc_ref[:] = jnp.broadcast_to(new[:, :dv], acc_ref.shape)
+
+    @pl.when(first < ln)
+    def _():
+        # a block's tail past `ln` keeps its last owner's values
+        down = first + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        c = jnp.where(down < ln, c_ref[...], 0)
+        s = jax.lax.dot_general(
+            q_ref[...], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        at = first + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        _online_softmax_add(jnp.where(at < ln, s, NEG), c[:, :dv], m_ref,
+                            l_ref, acc_ref)
+
+    @pl.when(j == nw - 1)
+    def _():
+        o_ref[...] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, new, arena, layer: int, tables, lens, *,
+                            v_width: int, scale: float,
+                            name: str = "decode_attention_latent",
+                            impl: str = "auto", interpret: bool = False):
+    """Latent attention in the ABSORBED form, one new token a row
+    against its paged cache of latents, read through the block table
+    inside the kernel. A cached entry is one vector a token that every
+    head shares: it is the key whole, and its first `v_width` values are
+    the value, so a block is read once and serves both products.
+
+      q        [rows, heads, width]: each head's query in the entry's
+               own space (the caller has multiplied the up-projection of
+               the keys into it), zero on lanes the entry pads
+      new      [rows, width], this step's own entry (position
+               ``lens[r]``), not yet in the arena
+      arena    [layers, blocks, block_tokens, width]
+      layer    which layer of the arena (static)
+      tables   int32 [rows, w]: the row's blocks, from position 0
+      lens     int32 [rows]: cached tokens
+
+    Row r sees its own token and the cached positions below lens[r]; a
+    row reads only table entries that hold one. Returns the weighted
+    entries [rows, heads, v_width] (the caller's up-projection of the
+    values turns them into heads' outputs)."""
+    rows, hh, width = q.shape
+    bt, w = arena.shape[2], tables.shape[1]
+    if arena.shape[3] != width or not 0 < v_width <= width:
+        raise ValueError(f"queries of {width} and values of {v_width} over "
+                         f"an arena entry of {arena.shape[3]}")
+    if impl not in ("auto", "paged", "dense"):
+        raise ValueError(f"unknown latent_decode_attention impl {impl!r}")
+    tables, lens = tables.astype(jnp.int32), lens.astype(jnp.int32)
+    if not (impl == "paged"
+            or (impl == "auto" and flash_attention_available())):
+        with jax.named_scope(name):
+            ok = jnp.arange(w * bt, dtype=jnp.int32) < lens[:, None]
+            ok = jnp.concatenate([ok, jnp.ones((rows, 1), bool)], axis=1)
+            c = jnp.concatenate([arena[layer][tables].reshape(
+                rows, w * bt, width), new[:, None]], axis=1)
+            c = jnp.where(ok[:, :, None], c, 0)
+            s = jnp.einsum("rhc,rtc->rht", q, c,
+                           preferred_element_type=jnp.float32) * scale
+            p = jax.nn.softmax(jnp.where(ok[:, None], s, NEG), axis=-1)
+            o = jnp.einsum("rht,rtc->rhc", p.astype(c.dtype),
+                           c[:, :, :v_width],
+                           preferred_element_type=jnp.float32)
+            return o.astype(q.dtype)
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def block(b, j, tables_ref, lens_ref):
+        hi = jnp.maximum((lens_ref[b] + bt - 1) // bt, 1) - 1
+        return (layer, tables_ref[b, jnp.minimum(j, hi)], 0, 0)
+
+    row3 = lambda b, j, *_: (b, 0, 0)
+    kern = functools.partial(_latent_decode_kernel, scale=scale, bt=bt,
+                             dv=v_width, nw=w)
+    with jax.named_scope(name):
+        return pl.pallas_call(
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(rows, w),
+                in_specs=[
+                    pl.BlockSpec((None, hh, width), row3),
+                    pl.BlockSpec((None, 1, width), row3),
+                    pl.BlockSpec((None, None, bt, width), block),
+                ],
+                out_specs=pl.BlockSpec((None, hh, v_width), row3),
+                scratch_shapes=[
+                    pltpu.VMEM((hh, 1), jnp.float32),
+                    pltpu.VMEM((hh, 1), jnp.float32),
+                    pltpu.VMEM((hh, v_width), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((rows, hh, v_width), q.dtype),
+            interpret=interpret,
+            name=name,
+        )(tables, lens, q, new[:, None, :], arena)

@@ -1,8 +1,13 @@
 """A layer of sparse experts that is told which experts it holds.
 
-Routing is over every expert of the layer (softmax over all router
-outputs in float32, the `top_k` largest, their weights renormalised);
-the product is over the experts held here only. Tokens are grouped by
+Routing is over every expert of the layer, in float32, by one of two
+rules: softmax over all router outputs, the `top_k` largest, their
+weights renormalised; or a sigmoid an expert, the `top_k` largest of
+score + a selection-only bias, weighted by the unbiased scores,
+renormalised and scaled. A shared expert that every token passes is no
+part of this layer: the caller computes it once, whatever share of the
+experts is held here.
+The product is over the experts held here only. Tokens are grouped by
 expert with a stable sort, with no capacity, so no token is ever
 dropped, and multiplied by a grouped product that reads an expert's
 weights only if the expert has tokens: on a TPU jax's own Pallas
@@ -54,13 +59,29 @@ def grouped_dot(a, w, sizes):
                tiling=(tm, _tile(w.shape[1]), _tile(w.shape[2])))
 
 
-def route(h, wr, top_k: int) -> Tuple[jax.Array, jax.Array]:
+def route(h, wr, top_k: int, *, scoring: str = "softmax", select_bias=None,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """h [t, d], wr [d, experts] -> (weights float32 [t, top_k], expert
-    ids int32 [t, top_k]): softmax over all experts in float32, the
-    `top_k` largest (ties to the lower index), renormalised to sum 1."""
+    ids int32 [t, top_k]), all in float32, ties to the lower index.
+    `scoring="softmax"`: softmax over all experts, the `top_k` largest,
+    renormalised to sum 1. `"sigmoid"`: each expert's own sigmoid; the
+    `top_k` largest of ``score + select_bias`` (float32 [experts]: it
+    moves the choice and never the weight) weighted by their unbiased
+    scores over their sum + 1e-20. Either way times `scale`."""
     logits = jnp.dot(h, wr, preferred_element_type=jnp.float32)
-    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    return w / jnp.sum(w, axis=-1, keepdims=True), idx.astype(jnp.int32)
+    if scoring == "softmax":
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    elif scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            s if select_bias is None
+            else s + select_bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    return w * scale if scale != 1.0 else w, idx.astype(jnp.int32)
 
 
 def expert_ffn(h, weights, experts, wg, wu, wd, *, n_experts: int,

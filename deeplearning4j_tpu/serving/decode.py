@@ -87,7 +87,9 @@ import jax.numpy as jnp
 
 from ..data.padding import next_pow2_bucket
 from ..ops import moe
-from ..ops.flash_attention import paged_decode_attention, prefill_attention
+from ..ops.flash_attention import (latent_decode_attention,
+                                   paged_decode_attention, prefill_attention)
+from ..ops.pallas_kernels import pad_axis_to
 from ..optimize import tracing
 from ..optimize.metrics import registry
 from ..parallel.inference import (DeadlineExceededError, DecodeStepError,
@@ -128,6 +130,9 @@ _D2H_HELP = ("Bytes of the device outputs the decode adapters copy back "
 _PHASES = ("step", "prefill")
 _CHUNKS_HELP = "Prefill chunks executed (one packed row of pack_bucket)"
 _CHUNK_TOKENS_HELP = "Prompt positions the prefill chunks held (no padding)"
+_CTX_TOKENS_HELP = ("Cached positions of their own prompts that the prefill "
+                    "chunks read back (a later slice of a long prompt reads "
+                    "every slice before it, in every layer)")
 _KV_TOKENS_HELP = ("Keys the rows of the decode steps attended to in one "
                    "layer of the kind, each row's own token included")
 _BLOCK_STEPS_HELP = ("KV blocks the cache held for one layer of the kind, "
@@ -177,14 +182,16 @@ def _register_link_metrics() -> Dict[Tuple[str, str], Any]:
 def _register_model_metrics() -> Dict[Any, Any]:
     """The counters the token arm increments about the model's own
     mechanisms: prefill chunks, keys attended and blocks held by kind of
-    layer, the expert layers' routing sums. Labels are bounded (the two
+    layer, the expert layers' routing sums. Labels are bounded (the
     kinds)."""
     reg = registry()
     out: Dict[Any, Any] = {
         "chunks": reg.counter("serving_decode_prefill_chunks_total",
                               _CHUNKS_HELP),
         "chunk_tokens": reg.counter("serving_decode_prefill_tokens_total",
-                                    _CHUNK_TOKENS_HELP)}
+                                    _CHUNK_TOKENS_HELP),
+        "ctx_tokens": reg.counter(
+            "serving_decode_prefill_context_tokens_total", _CTX_TOKENS_HELP)}
     for key, (name, text) in _MOE_HELP.items():
         out[key] = reg.counter(name, text)
     kv = reg.counter("serving_decode_kv_tokens_total", _KV_TOKENS_HELP)
@@ -203,7 +210,9 @@ def _nbytes(*arrays) -> int:
 # ---------------------------------------------------------------------------
 # Paged KV cache
 # ---------------------------------------------------------------------------
-KINDS = ("full", "sliding")     # a layer keeps every position, or a window
+# a layer keeps every position, a window of them, or of every position
+# one latent vector that is key and value at once
+KINDS = ("full", "sliding", "latent")
 
 
 class PagedKVCache:
@@ -213,11 +222,15 @@ class PagedKVCache:
 
     A ``full`` layer keeps every position of a request; a ``sliding``
     layer needs only the last ``window`` and gives back the blocks
-    behind them. So tables, free lists and arenas are per kind: for each
-    kind present one preallocated pair of device arrays ``[layers of the
-    kind, max_blocks + 1, block_tokens, heads × head_dim]`` holds K and
-    V (``heads`` are KV heads), and all layers of a kind share a
-    request's table. A full table covers positions from 0; a sliding
+    behind them; a ``latent`` layer keeps every position too, as ONE
+    vector a token that all heads share and that is key and value at
+    once. So tables, free lists and arenas are per kind, and so is what
+    an entry is: for each kind present a tuple of preallocated device
+    arrays ``[layers of the kind, max_blocks + 1, block_tokens, width]``,
+    by default the pair K and V of ``heads × head_dim`` (``heads`` are
+    KV heads), or the arrays of the widths ``entry[kind]`` names (a
+    latent kind: one). All layers of a kind share a request's table.
+    A full or latent table covers positions from 0; a sliding
     table covers them from :meth:`held_from`, which moves up a block
     whenever every position of the first block is ``window`` or more
     behind the request's length (at :meth:`advance`). The arrays are
@@ -256,7 +269,8 @@ class PagedKVCache:
     def __init__(self, *, layers: int, heads: int, head_dim: int,
                  block_tokens: int = 16, max_blocks=256,
                  dtype=jnp.float32, layer_kinds: Optional[Sequence[str]]
-                 = None, window: Optional[int] = None):
+                 = None, window: Optional[int] = None,
+                 entry: Optional[Dict[str, Sequence[int]]] = None):
         self.block_tokens = next_pow2_bucket(block_tokens)
         of_layer = ("full",) * int(layers) if layer_kinds is None \
             else tuple(layer_kinds)
@@ -271,13 +285,15 @@ class PagedKVCache:
         self.max_blocks_of = {k: int(per[k]) for k in self.kinds}
         self.scratch_of = dict(self.max_blocks_of)
         self.max_blocks = sum(self.max_blocks_of.values())
-        width = int(heads) * int(head_dim)
-        self._arenas: Dict[str, Tuple[jax.Array, jax.Array]] = {}
+        pair = (int(heads) * int(head_dim),) * 2
+        self.entry_of = {k: tuple(int(w) for w in (entry or {}).get(k, pair))
+                         for k in self.kinds}
+        self._arenas: Dict[str, Tuple[jax.Array, ...]] = {}
         for k in self.kinds:
-            shape = (of_layer.count(k), self.max_blocks_of[k] + 1,
-                     self.block_tokens, width)
-            self._arenas[k] = (jnp.zeros(shape, dtype),
-                               jnp.zeros(shape, dtype))
+            lead = (of_layer.count(k), self.max_blocks_of[k] + 1,
+                    self.block_tokens)
+            self._arenas[k] = tuple(jnp.zeros(lead + (w,), dtype)
+                                    for w in self.entry_of[k])
         self._free: Dict[str, List[int]] = {
             k: list(range(self.max_blocks_of[k] - 1, -1, -1))
             for k in self.kinds}
@@ -299,6 +315,10 @@ class PagedKVCache:
         if kind == "sliding":
             w = min(w, self.blocks_needed(self.window) + 1)
         return w
+
+    def token_bytes(self, kind: str) -> int:
+        """Bytes one cached position costs in one layer of `kind`."""
+        return sum(a.shape[3] * a.dtype.itemsize for a in self._arenas[kind])
 
     def blocks_of(self, rid: int) -> int:
         with self._lock:
@@ -481,8 +501,9 @@ class PagedKVCache:
                 lens[i] = ln
         return tables, starts, lens, starved
 
-    def arenas(self) -> Dict[str, Tuple[jax.Array, jax.Array]]:
-        """Per kind the K and V arrays as they stand, to read."""
+    def arenas(self) -> Dict[str, Tuple[jax.Array, ...]]:
+        """Per kind its arrays (K and V, or the one of latents) as they
+        stand, to read."""
         return dict(self._arenas)
 
     def update(self, fn: Callable) -> tuple:
@@ -564,10 +585,28 @@ class TransformerDecoder:
     * ``position``: ``"sinusoid"`` added to the embedding | ``"rotary"``
       on q and k (rotate-half), one table per kind of layer from
       ``rope[kind]`` (:func:`rope_inv_freq`: plain or YaRN)
+    * ``attention``: ``"heads"`` (K and V per KV head, cached as they
+      are) | ``"latent"`` (MLA, DeepSeek-V2, arXiv:2405.04434): a key is
+      ``[k_nope | k_pe]`` of ``qk_nope_head_dim + qk_rope_head_dim``
+      and a value ``v_head_dim`` wide, both up-projections ``wkv_b`` of
+      ONE normed latent of ``kv_lora_rank`` a token, beside one rotated
+      ``k_pe`` that all heads share; rotary turns the rope part only
+      and scores are scaled by ``(qk_nope + qk_rope) ** -0.5``. Every
+      layer is of kind ``"latent"``: the cache holds ``[latent | k_pe]``
+      a token (on ``latent_width`` lanes), a chunk expands the entries
+      it sees into keys and values, a step attends in the absorbed form
+      (``wkv_b``'s key half multiplied into the query, its value half
+      into the result) and expands nothing
     * ``mlp``: ``"relu"`` dense of width ``ff`` with biases | ``"moe"``:
       ``experts`` SwiGLU experts of width ``ff``, ``experts_per_token``
       a token, of which this model holds ``experts_held`` (default all)
-      and computes their part (ops/moe.py)
+      and computes their part (ops/moe.py), routed by ``router``
+      (``"softmax"`` | ``"sigmoid"`` with a selection-only bias ``rb``
+      and ``route_scale``), and beside them, where ``shared_ff`` is
+      set, a SwiGLU of that width that every token passes, added once
+      | ``"dense"``: one SwiGLU of width ``dense_ff``, no biases.
+      ``mlp_types`` names the feed-forward of each layer (default:
+      ``mlp`` in all)
     * ``kv_heads`` (query head n reads KV head ``n // (heads /
       kv_heads)``), ``d_model`` (default ``heads × head_dim``), ``tied``
       or an own ``head`` matrix, ``dtype`` of parameters, activations
@@ -586,8 +625,9 @@ class TransformerDecoder:
 
     Two jitted pure functions are the serving surface. Both take the
     paged cache's arenas (``{kind: (K, V)}``, layer-major ``[layers of
-    the kind, blocks + 1, block_tokens, kv_heads × head_dim]``) as
-    DONATED operands, write the new K/V into them in place, pick the
+    the kind, blocks + 1, block_tokens, kv_heads × head_dim]``; a
+    latent kind ``(C,)`` of ``latent_width``) as DONATED operands,
+    write the new K/V into them in place, pick the
     greedy token on the device and hand the arenas back — the cache
     rebinds to them (``PagedKVCache.update``), and only int32 tokens,
     bool flags and three routing sums cross the link. The token a row's
@@ -635,7 +675,13 @@ class TransformerDecoder:
                  tied: bool = True, dtype=jnp.float32,
                  layer_types: Sequence[str] = ("full",),
                  window: Optional[int] = None, init_std: float = 0.08,
-                 row_buckets: str = "pow2", params=None):
+                 row_buckets: str = "pow2", attention: str = "heads",
+                 kv_lora_rank: int = 0, qk_nope_head_dim: int = 0,
+                 qk_rope_head_dim: int = 0, v_head_dim: int = 0,
+                 mlp_types: Optional[Sequence[str]] = None,
+                 dense_ff: int = 0, shared_ff: int = 0,
+                 router: str = "softmax", route_scale: float = 1.0,
+                 params=None):
         self.vocab, self.n_layers = int(vocab), int(layers)
         self.heads, self.head_dim = int(heads), int(head_dim)
         self.kv_heads = self.heads if kv_heads is None else int(kv_heads)
@@ -652,11 +698,41 @@ class TransformerDecoder:
         self.layer_types = tuple(layer_types)
         self.window = None if window is None else int(window)
         self.init_std, self.row_buckets = float(init_std), row_buckets
-        if norm not in ("layer", "rms") or mlp not in ("relu", "moe") \
+        self.attention = attention
+        self.rank, self.nope = int(kv_lora_rank), int(qk_nope_head_dim)
+        self.rope_dim, self.v_dim = int(qk_rope_head_dim), int(v_head_dim)
+        self.mlp_types = None if mlp_types is None else tuple(mlp_types)
+        self.dense_ff, self.shared_ff = int(dense_ff), int(shared_ff)
+        self.router, self.route_scale = router, float(route_scale)
+        if norm not in ("layer", "rms") \
                 or position not in ("sinusoid", "rotary") \
                 or row_buckets not in ("pow2", "full") \
+                or attention not in ("heads", "latent") \
+                or router not in ("softmax", "sigmoid") \
+                or {mlp, *(self.mlp_types or ())} - {"relu", "dense", "moe"} \
+                or len(self.mlp_types or ()) not in (0, self.n_layers) \
                 or set(self.layer_types) - set(KINDS):
             raise ValueError("unknown decoder setting")
+        kinds = set(self.layer_types)
+        if (attention == "latent") != (kinds == {"latent"}) \
+                or kinds > {"latent"}:
+            raise ValueError("latent attention is every layer's or none's "
+                             "(layer_types ('latent',))")
+        if attention == "latent":
+            if position != "rotary" or min(self.rank, self.nope,
+                                           self.rope_dim, self.v_dim) < 1:
+                raise ValueError("latent attention needs rotary positions, "
+                                 "kv_lora_rank and the three head sizes")
+            # a query-key head; every head reads the one latent
+            self.head_dim, self.kv_heads = self.nope + self.rope_dim, \
+                self.heads
+        else:
+            self.rope_dim = self.head_dim
+        # lanes a cached latent entry takes: [latent | k_pe] and zeros up
+        # to the lane tile of 128 (a 576-wide array is re-laid out
+        # around the kernel)
+        self.latent_width = -(-(self.rank + self.rope_dim) // 128) * 128
+        self.attn_scale = self.head_dim ** -0.5
         if self.heads % self.kv_heads:
             raise ValueError("heads must be a multiple of kv_heads")
         if position == "sinusoid" and self.d_model % 2:
@@ -664,10 +740,13 @@ class TransformerDecoder:
                              "position encoding")
         if "sliding" in self.layer_types and not self.window:
             raise ValueError("sliding layers need window=")
-        if mlp == "moe" and not 0 < self.top_k <= self.experts:
+        ffs = {mlp, *(self.mlp_types or ())}
+        if "moe" in ffs and not 0 < self.top_k <= self.experts:
             raise ValueError("moe needs experts >= experts_per_token > 0")
+        if "dense" in ffs and self.dense_ff < 1:
+            raise ValueError("a dense SwiGLU layer needs dense_ff")
         self._rope = {} if position != "rotary" else {
-            k: rope_inv_freq(self.head_dim, rope[k])
+            k: rope_inv_freq(self.rope_dim, rope[k])
             for k in set(self.layer_types)}
         # Pool duck-compat: swap/describe read these on every entry.
         self.iteration = 0
@@ -688,21 +767,46 @@ class TransformerDecoder:
         """Each layer's kind, as the cache wants them."""
         return [self.kind_of(li) for li in range(self.n_layers)]
 
+    def mlp_of(self, layer: int) -> str:
+        """The layer's feed-forward: ``mlp_types``' entry, or ``mlp``."""
+        return self.mlp if self.mlp_types is None else self.mlp_types[layer]
+
     def _slot_in_kind(self, layer: int) -> int:
         """Which layer of its kind's arena `layer` is."""
         return self.layer_kinds()[:layer].count(self.kind_of(layer))
 
+    def cache_entry(self) -> Dict[str, Tuple[int, ...]]:
+        """What the paged cache holds a token a layer, by kind, where it
+        is not the pair of ``kv_heads × head_dim`` (`PagedKVCache`'s
+        ``entry``)."""
+        return {"latent": (self.latent_width,)} \
+            if self.attention == "latent" else {}
+
     # ----------------------------------------------------------------- weights
-    def _leaf_shapes(self) -> Dict[str, tuple]:
+    def _leaf_shapes(self, layer: int = 0) -> Dict[str, tuple]:
         d, f = self.d_model, self.ff
         q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
-        shapes = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
-        if self.mlp == "relu":
+        if self.attention == "latent":
+            shapes = {"wq": (d, q), "wkv_a": (d, self.rank + self.rope_dim),
+                      "wkv_b": (self.rank,
+                                self.heads * (self.nope + self.v_dim)),
+                      "wo": (self.heads * self.v_dim, d)}
+        else:
+            shapes = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv),
+                      "wo": (q, d)}
+        mlp = self.mlp_of(layer)
+        if mlp == "relu":
             shapes.update(w1=(d, f), w2=(f, d))
+        elif mlp == "dense":
+            shapes.update(wg=(d, self.dense_ff), wu=(d, self.dense_ff),
+                          wd=(self.dense_ff, d))
         else:
             n = len(self.experts_held)
             shapes.update(wr=(d, self.experts), wg=(n, d, f), wu=(n, d, f),
                           wd=(n, f, d))
+            if self.shared_ff:
+                shapes.update(sg=(d, self.shared_ff), su=(d, self.shared_ff),
+                              sd=(self.shared_ff, d))
         return shapes
 
     def _draw(self, seed: int) -> Dict[str, Any]:
@@ -711,23 +815,30 @@ class TransformerDecoder:
             jax.random.normal(k, shape, jnp.float32) * std).astype(dt)
         ones, zeros = jnp.ones((d,), dt), jnp.zeros((d,), dt)
 
-        @jax.jit
-        def layer(key):
+        def layer(key, li):
             lp = {name: normal(jax.random.fold_in(key, j), shape)
                   for j, (name, shape) in
-                  enumerate(self._leaf_shapes().items())}
+                  enumerate(self._leaf_shapes(li).items())}
             lp.update(ln1_s=ones, ln2_s=ones)
             if self.norm == "layer":
                 lp.update(ln1_b=zeros, ln2_b=zeros)
-            if self.mlp == "relu":
+            if self.mlp_of(li) == "relu":
                 lp.update(b1=jnp.zeros((self.ff,), dt), b2=zeros)
+            if self.attention == "latent":
+                lp.update(kv_ln_s=jnp.ones((self.rank,), dt))
+            if self.mlp_of(li) == "moe" and self.router == "sigmoid":
+                lp.update(rb=jax.random.normal(
+                    jax.random.fold_in(key, 99), (self.experts,),
+                    jnp.float32) * std)
             return lp
+
+        layer = jax.jit(layer, static_argnums=1)
 
         key = jax.random.PRNGKey(seed)
         tree = {"emb": jax.jit(lambda k: normal(k, (self.vocab, d)))(
                     jax.random.fold_in(key, 0)),
                 "lnf_s": ones,
-                "layers": [layer(jax.random.fold_in(key, li + 1))
+                "layers": [layer(jax.random.fold_in(key, li + 1), li)
                            for li in range(self.n_layers)]}
         if self.norm == "layer":
             tree["lnf_b"] = zeros
@@ -753,7 +864,7 @@ class TransformerDecoder:
         return x
 
     def _rotate(self, a, pos, kind):
-        """Rotary position on a [t, heads, head_dim], rotate-half."""
+        """Rotary position on a [t, heads, rotated width], rotate-half."""
         inv, factor = self._rope[kind]
         ang = pos[:, None].astype(jnp.float32) * inv
         cos = jnp.tile(jnp.cos(ang), 2)[:, None, :] * factor
@@ -763,35 +874,118 @@ class TransformerDecoder:
         return (a32 * cos + jnp.concatenate([-hi, lo], -1) * sin
                 ).astype(a.dtype)
 
+    def _latent_q_entry(self, h, lp, pos):
+        """Latent attention's two products of the normed input h [t,
+        d_model]: the queries [t, heads, nope + rope] and the token's
+        cache entry [t, latent_width] = [normed latent | k_pe | zeros],
+        rotary on each rope part."""
+        t, nope, rank = h.shape[0], self.nope, self.rank
+        q = self._mm(h, lp["wq"]).reshape(t, self.heads, self.head_dim)
+        q = jnp.concatenate([q[..., :nope], self._rotate(
+            q[..., nope:], pos, "latent")], axis=-1)
+        ckv = self._mm(h, lp["wkv_a"])
+        c = _rms_norm(ckv[:, :rank], lp["kv_ln_s"], self.norm_eps)
+        k_pe = self._rotate(ckv[:, None, rank:], pos, "latent")[:, 0]
+        return q, pad_axis_to(jnp.concatenate([c, k_pe], axis=-1), 1, 128)
+
+    def _up_projections(self, lp):
+        """``wkv_b`` as the keys' and the values' up-projection, each
+        [rank, heads, width]."""
+        w = lp["wkv_b"].reshape(self.rank, self.heads, self.nope + self.v_dim)
+        return w[..., :self.nope], w[..., self.nope:]
+
+    def _attend_expanded(self, q, entries, lp, **where):
+        """A chunk's latent attention in the published form: the entries
+        [tk, latent_width] it sees (its own and its cached context)
+        expanded to keys and values per head. q [t, heads, nope + rope]
+        -> [t, heads, v_dim]."""
+        w_uk, w_uv = self._up_projections(lp)
+        rank = self.rank
+        with jax.named_scope("kv_expand"):
+            expand = lambda w: jnp.einsum(
+                "tc,chd->htd", entries[:, :rank], w,
+                preferred_element_type=jnp.float32).astype(self.dtype)
+            k_pe = entries[None, :, rank:rank + self.rope_dim]
+            k = jnp.concatenate([expand(w_uk), jnp.broadcast_to(
+                k_pe, (self.heads,) + k_pe.shape[1:])], axis=-1)
+            v = expand(w_uv)
+        return prefill_attention(
+            q.transpose(1, 0, 2), k, v, scale=self.attn_scale,
+            heads_first=True, name="prefill_attention_latent",
+            **where).transpose(1, 0, 2)
+
+    def _attend_absorbed(self, q, entry, lp, arena, at, table, lens):
+        """A step's latent attention from the cache as it lies: the
+        keys' up-projection goes into the query, every head meets the
+        latents themselves, and the values' up-projection turns the
+        weighted latents into the heads' outputs. q [b, heads, nope +
+        rope], entry [b, latent_width] -> [b, heads, v_dim]."""
+        w_uk, w_uv = self._up_projections(lp)
+        nope = self.nope
+        q_lat = jnp.einsum("bhd,chd->bhc", q[..., :nope], w_uk,
+                           preferred_element_type=jnp.float32
+                           ).astype(self.dtype)
+        o_lat = latent_decode_attention(
+            pad_axis_to(jnp.concatenate([q_lat, q[..., nope:]], axis=-1),
+                        2, 128),
+            entry, arena, at, table, lens, v_width=self.rank,
+            scale=self.attn_scale, name="decode_attention_latent")
+        return jnp.einsum("bhc,chd->bhd", o_lat, w_uv,
+                          preferred_element_type=jnp.float32
+                          ).astype(self.dtype)
+
+    def _swiglu(self, h, wg, wu, wd):
+        act = jax.nn.silu(jnp.dot(h, wg, preferred_element_type=jnp.float32)) \
+            * jnp.dot(h, wu, preferred_element_type=jnp.float32)
+        return self._mm(act.astype(self.dtype), wd)
+
     def _block(self, x, lp, li, pos, attend, valid):
         """One layer on x [t, d_model]. ``attend(q [t, heads, head_dim],
         k, v [t, kv_heads × head_dim]) -> [t, heads, head_dim]`` is the
         caller's: a chunk over itself and its context, or a step over
-        the paged cache. -> (x, the layer's routing sums or None)."""
+        the paged cache (latent attention: ``attend(q, the tokens' cache
+        entries) -> [t, heads, v_dim]``). -> (x, the layer's routing
+        sums or None)."""
         kind, t = self.kind_of(li), x.shape[0]
         with jax.named_scope(f"layer_{li}/attn_{kind}"):
             h = self._norm(x, lp, "ln1")
-            q = self._mm(h, lp["wq"]).reshape(t, self.heads, self.head_dim)
-            k, v = self._mm(h, lp["wk"]), self._mm(h, lp["wv"])
-            if self.position == "rotary":
-                q = self._rotate(q, pos, kind)
-                k = self._rotate(k.reshape(t, self.kv_heads, self.head_dim),
-                                 pos, kind).reshape(k.shape)
-            a = attend(q, k, v)
+            if self.attention == "latent":
+                a = attend(*self._latent_q_entry(h, lp, pos))
+            else:
+                q = self._mm(h, lp["wq"]).reshape(t, self.heads,
+                                                  self.head_dim)
+                k, v = self._mm(h, lp["wk"]), self._mm(h, lp["wv"])
+                if self.position == "rotary":
+                    q = self._rotate(q, pos, kind)
+                    k = self._rotate(
+                        k.reshape(t, self.kv_heads, self.head_dim),
+                        pos, kind).reshape(k.shape)
+                a = attend(q, k, v)
             x = x + self._mm(a.reshape(t, -1), lp["wo"])
-        if self.mlp == "relu":
+        mlp = self.mlp_of(li)
+        if mlp == "relu":
             with jax.named_scope(f"layer_{li}/mlp"):
                 h = self._norm(x, lp, "ln2")
                 h = jnp.maximum(self._mm(h, lp["w1"]) + lp["b1"], 0.0)
                 return x + self._mm(h, lp["w2"]) + lp["b2"], None
+        if mlp == "dense":
+            with jax.named_scope(f"layer_{li}/mlp_dense"):
+                h = self._norm(x, lp, "ln2")
+                return x + self._swiglu(h, lp["wg"], lp["wu"], lp["wd"]), \
+                    None
         with jax.named_scope(f"layer_{li}/moe/route"):
             h = self._norm(x, lp, "ln2")
-            w, idx = moe.route(h, lp["wr"], self.top_k)
+            w, idx = moe.route(h, lp["wr"], self.top_k, scoring=self.router,
+                               select_bias=lp.get("rb"),
+                               scale=self.route_scale)
         with jax.named_scope(f"layer_{li}/moe/experts"):
             y, sums = moe.expert_ffn(
                 h, w, idx, lp["wg"], lp["wu"], lp["wd"],
                 n_experts=self.experts, experts_held=self.experts_held,
                 valid=valid)
+        if self.shared_ff:      # every token's, whatever experts are held
+            with jax.named_scope(f"layer_{li}/moe/shared"):
+                y = y + self._swiglu(h, lp["sg"], lp["su"], lp["sd"])
         return x + y, sums
 
     def _head(self, params, x):
@@ -810,25 +1004,27 @@ class TransformerDecoder:
                     jnp.isfinite(logits).all(axis=-1))
 
     def _scatter(self, arenas, slots, new):
-        """new[kind] = (K, V lists, a layer of the kind each, [n, kv
-        width]) into slot (blk[i], off[i]) of each layer of the kind.
+        """new[kind] = a list for each array of the kind's entry (K and
+        V, or the latents), a layer of the kind each, [n, width], into
+        slot (blk[i], off[i]) of each layer of the kind.
         The layer is an index like the other two, so the three indexed
         axes are the arena's leading ones and the update is a plain row
         write (with the layer as a window axis the compiler re-lays the
         whole arena out around the scatter)."""
         out = {}
         with jax.named_scope("kv_scatter"):
-            for kind, (ak, av) in arenas.items():
+            for kind, arrays in arenas.items():
                 blk, off = slots[kind]
-                ks, vs = (jnp.stack(a) for a in new[kind])
-                layer = jnp.arange(ks.shape[0])[:, None]
-                out[kind] = (ak.at[layer, blk, off].set(ks),
-                             av.at[layer, blk, off].set(vs))
+                stacked = [jnp.stack(a) for a in new[kind]]
+                layer = jnp.arange(stacked[0].shape[0])[:, None]
+                out[kind] = tuple(a.at[layer, blk, off].set(n)
+                                  for a, n in zip(arrays, stacked))
         return out
 
     def _chunk_forward(self, params, tokens, seg, pos, context=None):
         """tokens/seg/pos [T] → (hidden [T, d_model] before the final
-        norm, {kind: (K list, V list)} a layer each [T, kv width]).
+        norm, {kind: (K list, V list)} a layer each [T, kv width]; a
+        latent kind: one list of entries).
         `context` = (arenas, ctx_tables, ctx_starts, ctx_len): segment
         1 also sees its cached positions below ctx_len.
 
@@ -843,7 +1039,17 @@ class TransformerDecoder:
         valid = seg > 0
         with jax.named_scope("embed"):
             x = self._embed(params, tokens, pos)
-        new = {k: ([], []) for k in set(self.layer_kinds())}
+        new = {k: ([],) if k == "latent" else ([], [])
+               for k in set(self.layer_kinds())}
+
+        def cached_line(n, start, ctx_len):
+            """Where n cached positions from `start` lie for the chunk:
+            (which are real, their places on its line, their segment)."""
+            true = start + jnp.arange(n, dtype=jnp.int32)
+            real = true < ctx_len
+            return real, jnp.where(real, true - ctx_len,
+                                   jnp.int32(1 << 30)), jnp.where(real, 1, -1)
+
         for li, lp in enumerate(params["layers"]):
             kind, at = self.kind_of(li), self._slot_in_kind(li)
 
@@ -855,17 +1061,15 @@ class TransformerDecoder:
                     arenas, tables, starts, ctx_len = context
                     ak, av = arenas[kind]
                     n = tables[kind].shape[0] * ak.shape[2]
-                    true = starts.get(kind, 0) + jnp.arange(n,
-                                                            dtype=jnp.int32)
-                    real = true < ctx_len
+                    real, at_pos, at_seg = cached_line(
+                        n, starts.get(kind, 0), ctx_len)
                     with jax.named_scope("kv_context"):
                         ck = ak[at][tables[kind]].reshape(n, -1)
                         cv = jnp.where(real[:, None], av[at][
                             tables[kind]].reshape(n, -1), 0)
                     k, v = jnp.concatenate([ck, k]), jnp.concatenate([cv, v])
-                    kv_pos = jnp.concatenate([jnp.where(
-                        real, true - ctx_len, jnp.int32(1 << 30)), line])
-                    kv_seg = jnp.concatenate([jnp.where(real, 1, -1), seg])
+                    kv_pos = jnp.concatenate([at_pos, line])
+                    kv_seg = jnp.concatenate([at_seg, seg])
                 heads = lambda a: a.reshape(a.shape[0], self.kv_heads,
                                             self.head_dim)
                 return prefill_attention(
@@ -874,7 +1078,29 @@ class TransformerDecoder:
                     window=self.window if kind == "sliding" else None,
                     name=f"prefill_attention_{kind}")
 
-            x, _ = self._block(x, lp, li, pos, attend, valid)
+            def attend_latent(q, entry, lp=lp, at=at):
+                new["latent"][0].append(entry)
+                kv_pos, kv_seg = line, seg
+                if context is not None:
+                    arenas, tables, _, ctx_len = context
+                    (ac,) = arenas["latent"]
+                    n = tables["latent"].shape[0] * ac.shape[2]
+                    real, at_pos, at_seg = cached_line(n, 0, ctx_len)
+                    with jax.named_scope("kv_context"):     # key AND value
+                        # one gather of the arena itself: a layer's
+                        # slice first would be copied out whole
+                        cached = jnp.where(real[:, None], ac[
+                            at, tables["latent"]].reshape(n, -1), 0)
+                    entry = jnp.concatenate([cached, entry])
+                    kv_pos = jnp.concatenate([at_pos, line])
+                    kv_seg = jnp.concatenate([at_seg, seg])
+                return self._attend_expanded(
+                    q, entry, lp, q_pos=line, kv_pos=kv_pos, q_seg=seg,
+                    kv_seg=kv_seg)
+
+            x, _ = self._block(
+                x, lp, li, pos,
+                attend_latent if kind == "latent" else attend, valid)
         return x, new
 
     def _logits_pure(self, params, tokens, seg, pos):
@@ -904,7 +1130,7 @@ class TransformerDecoder:
         valid = lens > 0            # a pad row: no expert computes it
         with jax.named_scope("embed"):
             x = self._embed(params, feed[slot], pos)        # [b, d]
-        new = {k: ([], []) for k in arenas}
+        new = {k: tuple([] for _ in a) for k, a in arenas.items()}
         sums = []
         for li, lp in enumerate(params["layers"]):
             kind, at = self.kind_of(li), self._slot_in_kind(li)
@@ -918,18 +1144,27 @@ class TransformerDecoder:
                     window=self.window if kind == "sliding" else None,
                     name=f"decode_attention_{kind}")
 
-            x, s = self._block(x, lp, li, pos, attend, valid)
+            def attend_latent(q, entry, lp=lp, at=at):
+                new["latent"][0].append(entry)
+                return self._attend_absorbed(
+                    q, entry, lp, arenas["latent"][0], at, tables["latent"],
+                    lens)
+
+            x, s = self._block(
+                x, lp, li, pos,
+                attend_latent if kind == "latent" else attend, valid)
             sums.append(s)
         # One scatter per arena, after every layer has read it: the
         # donated buffer's last use, so it is updated where it lies.
         slots = {}
-        for kind, (ak, _) in arenas.items():
+        for kind, arrays in arenas.items():
             at = lens - starts.get(kind, zeros)
-            bt = ak.shape[2]
+            bt = arrays[0].shape[2]
             slots[kind] = (tables[kind][jnp.arange(b), at // bt], at % bt)
         arenas = self._scatter(arenas, slots, new)
         picked, finite = self._pick(params, x)
-        sums = None if sums[0] is None else sum(sums[1:], sums[0])
+        sums = [s for s in sums if s is not None]   # the sparse layers'
+        sums = sum(sums[1:], sums[0]) if sums else None
         return picked, finite, sums, feed.at[slot].set(picked), arenas
 
     # ------------------------------------------------------------ conveniences
@@ -1178,6 +1413,7 @@ class TransformerAdapter:
             return {}, starved
         self._count["chunks"].inc()
         self._count["chunk_tokens"].inc(cur)
+        self._count["ctx_tokens"].inc(ctx_len)
         up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last,
                      feed_slots) + 4
         with tracing.span("decode/launch", cat="serve", bytes=up):

@@ -81,6 +81,7 @@ from __future__ import annotations
 
 import json
 import time
+import weakref
 from typing import Any, Dict, Optional
 
 import numpy as np
@@ -196,7 +197,18 @@ class ServingGateway(JsonHttpServer):
             "serving_slo_breach_total",
             "Requests whose wall latency exceeded their tier's "
             "serving_tier_slo_ms, counted at response time")
-        reg.register_collector(self._collect_percentiles)
+        # Weakly referenced, like the pool's and the engines' collectors:
+        # the registry outlives every gateway, and a stopped gateway that
+        # it kept alive would keep its models' weights and KV arenas on
+        # the device.
+        wr = weakref.WeakMethod(self._collect_percentiles)
+
+        def _collect(reg, _wr=wr):
+            sample = _wr()
+            if sample is not None:
+                sample(reg)
+
+        reg.register_collector(_collect)
 
     # ------------------------------------------------------------ model mgmt
     def add_model(self, name: str, model, **kw):

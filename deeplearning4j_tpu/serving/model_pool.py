@@ -413,7 +413,8 @@ class ModelPool:
         prefill chunk, `kv_block_tokens` the block, `kv_max_blocks` the
         blocks of each kind of layer's arena: one number for every
         kind, or ``{"full": n, "sliding": m}``; the cache takes the
-        model's KV heads, dtype, pattern of layer kinds and window); a
+        model's KV heads, dtype, pattern of layer kinds, window and, for
+        a latent kind, its entry's layout); a
         streaming
         network exposing ``rnn_time_step`` decodes through the
         recurrent arm (`feature_dim` is its per-step input width —
@@ -438,6 +439,7 @@ class ModelPool:
                 layers=model.n_layers, heads=model.kv_heads,
                 head_dim=model.head_dim, dtype=model.dtype,
                 layer_kinds=model.layer_kinds(), window=model.window,
+                entry=model.cache_entry(),
                 block_tokens=kv_block_tokens, max_blocks=kv_max_blocks)
             adapter = TransformerAdapter(model, cache,
                                          pack_bucket=pack_bucket,

@@ -6,7 +6,9 @@ as a window axis of the scatter, or heads and head_dim as two trailing
 axes, it copied all of it on every step), the Pallas kernels are in and
 Mosaic takes them. Two settings of the one decoder: OPT-1.3B's dense
 float32 block, and a sparse bfloat16 block with grouped KV heads, a
-sliding and a full layer side by side and 64 experts. One file, the
+sliding and a full layer side by side and 64 experts; and a latent
+bfloat16 block (a dense layer and a sparse one with a shared expert)
+whose one arena a kind holds [latent | k_pe] on 640 lanes. One file, the
 topology described in a fixture: see the on-chip-measurement guide,
 section 2."""
 import re
@@ -48,6 +50,28 @@ SPARSE = dict(
     # a chunk's own temporaries: 2,048 tokens x 8 experts each, their
     # float32 products of width 2,304 gathered back into token order
     prefill_temporaries=2048 * 8 * 2304 * 12)
+LATENT = dict(
+    model=dict(vocab=128256, layers=LAYERS, heads=32, d_model=2048, ff=768,
+               max_context=32768, norm="rms", position="rotary",
+               attention="latent", kv_lora_rank=512, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128,
+               layer_types=("latent",), mlp_types=("dense", "moe"),
+               dense_ff=6144, shared_ff=1536, experts=128,
+               experts_per_token=6, router="sigmoid", route_scale=2.448,
+               tied=False, dtype=jnp.bfloat16, row_buckets="full",
+               rope={"latent": {"rope_theta": 1000000.0}}),
+    cache=dict(block_tokens=256, max_blocks={"latent": 4160}),
+    rows=32, kv=33024, pack=2048,
+    # the attention kernel a layer, and the sparse layer's grouped
+    # product's three
+    step_kernels=LAYERS + 3, prefill_kernels=LAYERS + 3,
+    # a chunk's own temporaries: the 33,024 cached and 2,048 own entries
+    # expanded for 32 heads a layer at a time (k_nope 128 and the shared
+    # k_pe 64 broadcast, joined to keys on 256 lanes, values on 128:
+    # 1.29 GB), beside the chunk's routed products (2,048 x 6 rows of
+    # 2,048 in float32, gathered back: 302 MB)
+    prefill_temporaries=35072 * 32 * (128 + 64 + 256 + 128) * 2
+    + 2048 * 6 * 2048 * 12 + (64 << 20))
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +119,7 @@ def _shapes(setting):
     cache = jax.eval_shape(lambda: PagedKVCache(
         layers=m.n_layers, heads=m.kv_heads, head_dim=m.head_dim,
         dtype=m.dtype, layer_kinds=m.layer_kinds(), window=m.window,
-        **setting["cache"]).arenas())
+        entry=m.cache_entry(), **setting["cache"]).arenas())
     return m, params, cache
 
 
@@ -122,7 +146,8 @@ def _host_operands(setting, m, which):
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
-@pytest.mark.parametrize("setting", [DENSE, SPARSE], ids=["dense", "sparse"])
+@pytest.mark.parametrize("setting", [DENSE, SPARSE, LATENT],
+                         ids=["dense", "sparse", "latent"])
 def test_the_arenas_are_updated_where_they_lie(compiled, setting, which):
     m, params, arenas = _shapes(setting)
     ops = _host_operands(setting, m, which)
