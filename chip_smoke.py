@@ -591,7 +591,7 @@ def phase_kernels(cfg, interpret: bool):
     # chunk over its cached context, each against its dense arm. On the
     # chip at the widths of the two served decoders; rehearsed tiny.
     from deeplearning4j_tpu.ops.flash_attention import (
-        paged_decode_attention, prefill_attention)
+        merge_attention, paged_decode_attention, prefill_attention)
     serving = [("f32 h2x8 bt8", jnp.float32, 2, 2, 8, 8, 4, None, 16)] \
         if interpret else [
         ("f32 h32x64 bt16 (decoder-at-opt-1.3b)", jnp.float32, 32, 32, 64,
@@ -613,6 +613,18 @@ def phase_kernels(cfg, interpret: bool):
             kv_pos=jnp.asarray(np.concatenate(
                 [np.where(real, np.arange(n_ctx) - ctx_len, 1 << 30), line])),
             kv_seg=jnp.asarray(np.concatenate([np.where(real, 1, -1), seg])))
+
+    def in_parts(q, k_, v_, n_ctx, axis, kw):
+        """The same attention as the served chunk takes it: the kernel
+        over the cached keys and over the chunk's own, each with its
+        rows' log-sum-exp, joined in float32."""
+        cut = lambda a, lo, hi: jax.lax.slice_in_dim(a, lo, hi, axis=axis)
+        part = lambda lo, hi: prefill_attention(
+            q, cut(k_, lo, hi), cut(v_, lo, hi), impl="flash",
+            interpret=interpret, return_lse=True, **dict(
+                kw, kv_pos=kw["kv_pos"][lo:hi], kv_seg=kw["kv_seg"][lo:hi]))
+        end = k_.shape[axis]
+        return merge_attention(*part(n_ctx, end), *part(0, n_ctx))[0]
 
     for what, dt, hh, kvh, d, bt, w, window, chunk in serving:
         if window and interpret:
@@ -655,11 +667,13 @@ def phase_kernels(cfg, interpret: bool):
             kw = dict(where, window=window)
             got = jax.jit(lambda *a: prefill_attention(
                 *a, impl="flash", interpret=interpret, **kw))(q, k_, v_)
+            parts = jax.jit(lambda *a: in_parts(*a, n_ctx, 0, kw))(q, k_, v_)
             with jax.default_matmul_precision("highest"):
                 ref = jax.jit(lambda *a: prefill_attention(
                     *a, impl="dense", **kw))(q, k_, v_)
-            return {"out": _rel_err(np.asarray(got, np.float32)[live],
-                                    np.asarray(ref, np.float32)[live])}
+            ref = np.asarray(ref, np.float32)[live]
+            return {"out": _rel_err(np.asarray(got, np.float32)[live], ref),
+                    "parts": _rel_err(np.asarray(parts)[live], ref)}
         kernel(f"prefill_attention [{what} chunk{chunk}]", KERNEL_TOL,
                chunked)
 
@@ -705,11 +719,15 @@ def phase_kernels(cfg, interpret: bool):
         got = jax.jit(lambda *a: prefill_attention(
             *a, impl="flash", interpret=interpret,
             name="prefill_attention_latent", **kw))(q, k_, v_)
+        parts = jax.jit(lambda *a: in_parts(
+            *a, n_ctx, 1, dict(kw, name="prefill_attention_latent")))(
+                q, k_, v_)
         with jax.default_matmul_precision("highest"):
             ref = jax.jit(lambda *a: prefill_attention(
                 *a, impl="dense", **kw))(q, k_, v_)
-        return {"out": _rel_err(np.asarray(got, np.float32)[:, live],
-                                np.asarray(ref, np.float32)[:, live])}
+        ref = np.asarray(ref, np.float32)[:, live]
+        return {"out": _rel_err(np.asarray(got, np.float32)[:, live], ref),
+                "parts": _rel_err(np.asarray(parts)[:, live], ref)}
     kernel(f"prefill_attention [latent h{hh} qk{dq} v{dvh} chunk{chunk}]",
            KERNEL_TOL, latent_prefill)
 

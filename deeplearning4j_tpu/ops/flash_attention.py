@@ -615,7 +615,7 @@ def _visible(q_pos, kv_pos, q_seg, kv_seg, window):
 def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                       window: Optional[int] = None,
                       scale: Optional[float] = None,
-                      heads_first: bool = False,
+                      heads_first: bool = False, return_lse: bool = False,
                       name: str = "prefill_attention", impl: str = "auto",
                       interpret: bool = False, q_block: int = 0,
                       kv_block: int = 0):
@@ -632,6 +632,11 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
       heads_first  q, k, v and the result are ``[heads, t, dim]``, the
                    kernel's own order: a caller whose products can give
                    that order spares a transposed copy of each
+      return_lse   also the rows' log-sum-exp of their visible scores:
+                   ``(result in float32, lse float32 [tq, heads])``
+                   (heads first alike), a row that sees no key giving
+                   zeros and ``NEG``: one part of a key range, to be
+                   joined with the others by :func:`merge_attention`
       q_pos/kv_pos int32 [tq] / [tk]: places on one line, monotone
                    inside a block of keys (the skip tests read a block's
                    ends)
@@ -671,10 +676,18 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                            k, preferred_element_type=jnp.float32)
             ok = _visible(q_pos, kv_pos, q_seg, kv_seg, window)
             s = s / math.sqrt(d) if scale is None else s * scale
-            p = jax.nn.softmax(jnp.where(ok[None, None], s, NEG), axis=-1)
+            s = jnp.where(ok[None, None], s, NEG)
+            p = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum("kgqt,tkd->qkgd", p.astype(v.dtype), v,
-                           preferred_element_type=jnp.float32)
-            return by_t(o.reshape(tq, hh, dv).astype(q.dtype))
+                           preferred_element_type=jnp.float32
+                           ).reshape(tq, hh, dv)
+            if not return_lse:
+                return by_t(o.astype(q.dtype))
+            seen = ok.any(axis=1)       # the kernel's rule for a blind row
+            lse = jnp.where(seen, jax.nn.logsumexp(s, axis=-1).reshape(
+                hh, tq), NEG)
+            return (by_t(jnp.where(seen[:, None, None], o, 0.0)),
+                    lse if heads_first else lse.T)
 
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -687,7 +700,7 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
         causal=True, use_mask=False, use_segs=True, nk=nk, window=window)
     kv_at = lambda i, j, k_: (i // group, k_, 0)
     with jax.named_scope(name):
-        o3, _ = pl.pallas_call(
+        o3, lse = pl.pallas_call(
             kern,
             grid=(hh, tq // qb, nk),
             in_specs=[
@@ -705,7 +718,8 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                 pl.BlockSpec((1, qb, 1), lambda i, j, k_: (i, j, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((hh, tq, dvp), q.dtype),
+                jax.ShapeDtypeStruct((hh, tq, dvp),
+                                     jnp.float32 if return_lse else q.dtype),
                 jax.ShapeDtypeStruct((hh, tq, 1), jnp.float32),
             ],
             scratch_shapes=[
@@ -718,7 +732,18 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
         )(q_pos.reshape(tq, 1), kv_pos.reshape(1, tk),
           jnp.ones((1, 1, tk), jnp.float32), q_seg.reshape(1, tq, 1),
           kv_seg.reshape(1, 1, tk), q3, k3, v3)
-    return by_head(o3[:, :, :dv])
+    o = by_head(o3[:, :, :dv])
+    return (o, by_head(lse)[..., 0]) if return_lse else o
+
+
+def merge_attention(o, lse, o_part, lse_part):
+    """Join two parts of one softmax over disjoint keys: each an
+    attention result in float32 [..., dim] with its rows' log-sum-exp
+    [...] (`prefill_attention`'s ``return_lse``). A part whose row saw
+    no key (lse ``NEG``, result zeros) weighs nothing. -> (o, lse)."""
+    both = jnp.logaddexp(lse, lse_part)
+    share = lambda part: jnp.exp(part - both)[..., None]
+    return o * share(lse) + o_part * share(lse_part), both
 
 
 def _online_softmax_add(s, v, m_ref, l_ref, acc_ref):

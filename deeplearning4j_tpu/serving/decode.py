@@ -87,7 +87,7 @@ import jax.numpy as jnp
 
 from ..data.padding import next_pow2_bucket
 from ..ops import moe
-from ..ops.flash_attention import (latent_decode_attention,
+from ..ops.flash_attention import (latent_decode_attention, merge_attention,
                                    paged_decode_attention, prefill_attention)
 from ..ops.pallas_kernels import pad_axis_to
 from ..optimize import tracing
@@ -133,6 +133,10 @@ _CHUNK_TOKENS_HELP = "Prompt positions the prefill chunks held (no padding)"
 _CTX_TOKENS_HELP = ("Cached positions of their own prompts that the prefill "
                     "chunks read back (a later slice of a long prompt reads "
                     "every slice before it, in every layer)")
+_CTX_READ_HELP = ("Cached positions the prefill chunks gathered and attended "
+                  "in a layer of each kind: whole slabs, as many as the "
+                  "context reaches (over ..._context_tokens_total: what a "
+                  "chunk pays for what it needs)")
 _KV_TOKENS_HELP = ("Keys the rows of the decode steps attended to in one "
                    "layer of the kind, each row's own token included")
 _BLOCK_STEPS_HELP = ("KV blocks the cache held for one layer of the kind, "
@@ -191,7 +195,10 @@ def _register_model_metrics() -> Dict[Any, Any]:
         "chunk_tokens": reg.counter("serving_decode_prefill_tokens_total",
                                     _CHUNK_TOKENS_HELP),
         "ctx_tokens": reg.counter(
-            "serving_decode_prefill_context_tokens_total", _CTX_TOKENS_HELP)}
+            "serving_decode_prefill_context_tokens_total", _CTX_TOKENS_HELP),
+        "ctx_read": reg.counter(
+            "serving_decode_prefill_context_read_tokens_total",
+            _CTX_READ_HELP)}
     for key, (name, text) in _MOE_HELP.items():
         out[key] = reg.counter(name, text)
     kv = reg.counter("serving_decode_kv_tokens_total", _KV_TOKENS_HELP)
@@ -213,6 +220,19 @@ def _nbytes(*arrays) -> int:
 # a layer keeps every position, a window of them, or of every position
 # one latent vector that is key and value at once
 KINDS = ("full", "sliding", "latent")
+
+# Cached positions one trip of a chunk's loop over its context gathers
+# and attends (`TransformerDecoder._attend_chunk`). Smaller wastes less
+# in a context's last slab, larger pays fewer kernel launches and
+# merges; read on the chip at 2,048 to 8,192 (PERF.md, PR 34).
+CONTEXT_SLAB = 4096
+
+
+def context_slab(width: int, block_tokens: int) -> int:
+    """Entries of a context table `width` entries wide that one trip
+    takes: CONTEXT_SLAB positions' blocks, or the whole table where it
+    is narrower (a sliding kind's)."""
+    return min(int(width), max(1, CONTEXT_SLAB // int(block_tokens)))
 
 
 class PagedKVCache:
@@ -640,7 +660,9 @@ class TransformerDecoder:
       a packed row of whole prompts and at most one later slice of a
       long prompt, which is segment 1 and sees its ``ctx_len`` cached
       positions through ``ctx_tables[kind]`` (first entry at position
-      ``ctx_starts[kind]``). Padding is segment 0. Position ``i``'s K/V
+      ``ctx_starts[kind]``; whole slabs wide, and read a slab a trip of
+      a loop that ends where the context does: `_attend_chunk`).
+      Padding is segment 0. Position ``i``'s K/V
       go to ``slots[kind] = (blk[T], off[T])`` (pads point at the
       scratch block); the pick and the finite flag are taken at the
       positions ``last`` and the picks written to ``feed[feed_slots]``.
@@ -894,11 +916,14 @@ class TransformerDecoder:
         w = lp["wkv_b"].reshape(self.rank, self.heads, self.nope + self.v_dim)
         return w[..., :self.nope], w[..., self.nope:]
 
-    def _attend_expanded(self, q, entries, lp, **where):
-        """A chunk's latent attention in the published form: the entries
-        [tk, latent_width] it sees (its own and its cached context)
-        expanded to keys and values per head. q [t, heads, nope + rope]
-        -> [t, heads, v_dim]."""
+    def _attend_expanded(self, q, entries, lp, heads_first=False, **where):
+        """Latent attention in the published form over ONE range of
+        entries [tk, latent_width], expanded to keys and values per
+        head: the whole of what a query sees, or (``return_lse=True``:
+        float32 and the rows' log-sum-exp beside it) a part of it, as a
+        chunk takes its own entries and each slab of its context. q [t,
+        heads, nope + rope] -> [t, heads, v_dim]; with `heads_first` q
+        and the results are [heads, t, .], the kernel's order."""
         w_uk, w_uv = self._up_projections(lp)
         rank = self.rank
         with jax.named_scope("kv_expand"):
@@ -909,10 +934,11 @@ class TransformerDecoder:
             k = jnp.concatenate([expand(w_uk), jnp.broadcast_to(
                 k_pe, (self.heads,) + k_pe.shape[1:])], axis=-1)
             v = expand(w_uv)
-        return prefill_attention(
-            q.transpose(1, 0, 2), k, v, scale=self.attn_scale,
-            heads_first=True, name="prefill_attention_latent",
-            **where).transpose(1, 0, 2)
+        turn = (lambda a: a) if heads_first \
+            else (lambda a: jnp.swapaxes(a, 0, 1))
+        return jax.tree_util.tree_map(turn, prefill_attention(
+            turn(q), k, v, scale=self.attn_scale, heads_first=True,
+            name="prefill_attention_latent", **where))
 
     def _attend_absorbed(self, q, entry, lp, arena, at, table, lens):
         """A step's latent attention from the cache as it lies: the
@@ -1021,6 +1047,50 @@ class TransformerDecoder:
                                   for a, n in zip(arrays, stacked))
         return out
 
+    def _attend_chunk(self, part, own, kind, at, line, seg, context):
+        """A chunk's attention in a layer of `kind`, as parts of one
+        softmax joined by log-sum-exp: its own entries, then its cached
+        context a SLAB at a time, as many slabs as ``ctx_len`` reaches
+        (none without a later slice), so a chunk pays for the context
+        it has and not for the longest a row may have.
+        ``part(entries, **where)`` is `prefill_attention` of the layer's
+        queries over `entries`, the kind's arrays [n, width] as the
+        cache holds them (`own`: the chunk's). A trip takes
+        ``context_slab`` entries of the kind's table and gathers those
+        blocks from the arena where it lies (the loop only reads it). A
+        cached position at or past ctx_len is no key: segment -1, its
+        entry zeroed (a freed block keeps its last owner's data); the
+        others lie before the row on its line, at their true distance
+        from segment 1."""
+        where = dict(q_pos=line, q_seg=seg)
+        if context is None:
+            return part(own, kv_pos=line, kv_seg=seg, **where)
+        arenas, tables, starts, ctx_len = context
+        arrays, table = arenas[kind], tables[kind]
+        start, bt = starts.get(kind, 0), arrays[0].shape[2]
+        slab = context_slab(table.shape[0], bt)
+        if table.shape[0] % slab:
+            raise ValueError(f"a context table of {table.shape[0]} entries "
+                             f"is no multiple of the slab's {slab}")
+        n = slab * bt
+
+        def trip(i, so_far):
+            entries = jax.lax.dynamic_slice(table, (i * slab,), (slab,))
+            true = start + i * n + jnp.arange(n, dtype=jnp.int32)
+            real = true < ctx_len
+            with jax.named_scope("kv_context"):
+                cached = tuple(jnp.where(real[:, None], a[at, entries]
+                                         .reshape(n, -1), 0) for a in arrays)
+            return merge_attention(*so_far, *part(
+                cached, kv_pos=jnp.where(real, true - ctx_len,
+                                         jnp.int32(1 << 30)),
+                kv_seg=jnp.where(real, 1, -1), return_lse=True, **where))
+
+        o, _ = jax.lax.fori_loop(
+            0, (ctx_len - start + n - 1) // n, trip,
+            part(own, kv_pos=line, kv_seg=seg, return_lse=True, **where))
+        return o.astype(self.dtype)
+
     def _chunk_forward(self, params, tokens, seg, pos, context=None):
         """tokens/seg/pos [T] → (hidden [T, d_model] before the final
         norm, {kind: (K list, V list)} a layer each [T, kv width]; a
@@ -1031,9 +1101,7 @@ class TransformerDecoder:
         Causality inside the packed row is exact under the row's own
         order and segment equality; the context's keys lie before the
         row on that line, at their true distance from segment 1, so the
-        window test holds across the seam. A cached position at or past
-        ctx_len is no key: segment -1, its value zeroed (a freed block
-        keeps its last owner's data)."""
+        window test holds across the seam (`_attend_chunk`)."""
         t = tokens.shape[0]
         line = jnp.arange(t, dtype=jnp.int32)
         valid = seg > 0
@@ -1041,62 +1109,29 @@ class TransformerDecoder:
             x = self._embed(params, tokens, pos)
         new = {k: ([],) if k == "latent" else ([], [])
                for k in set(self.layer_kinds())}
-
-        def cached_line(n, start, ctx_len):
-            """Where n cached positions from `start` lie for the chunk:
-            (which are real, their places on its line, their segment)."""
-            true = start + jnp.arange(n, dtype=jnp.int32)
-            real = true < ctx_len
-            return real, jnp.where(real, true - ctx_len,
-                                   jnp.int32(1 << 30)), jnp.where(real, 1, -1)
-
         for li, lp in enumerate(params["layers"]):
             kind, at = self.kind_of(li), self._slot_in_kind(li)
 
             def attend(q, k, v, kind=kind, at=at):
                 new[kind][0].append(k)
                 new[kind][1].append(v)
-                kv_pos, kv_seg = line, seg
-                if context is not None:
-                    arenas, tables, starts, ctx_len = context
-                    ak, av = arenas[kind]
-                    n = tables[kind].shape[0] * ak.shape[2]
-                    real, at_pos, at_seg = cached_line(
-                        n, starts.get(kind, 0), ctx_len)
-                    with jax.named_scope("kv_context"):
-                        ck = ak[at][tables[kind]].reshape(n, -1)
-                        cv = jnp.where(real[:, None], av[at][
-                            tables[kind]].reshape(n, -1), 0)
-                    k, v = jnp.concatenate([ck, k]), jnp.concatenate([cv, v])
-                    kv_pos = jnp.concatenate([at_pos, line])
-                    kv_seg = jnp.concatenate([at_seg, seg])
                 heads = lambda a: a.reshape(a.shape[0], self.kv_heads,
                                             self.head_dim)
-                return prefill_attention(
-                    q, heads(k), heads(v), q_pos=line, kv_pos=kv_pos,
-                    q_seg=seg, kv_seg=kv_seg,
+                part = lambda entries, **where: prefill_attention(
+                    q, *map(heads, entries),
                     window=self.window if kind == "sliding" else None,
-                    name=f"prefill_attention_{kind}")
+                    name=f"prefill_attention_{kind}", **where)
+                return self._attend_chunk(part, (k, v), kind, at, line, seg,
+                                          context)
 
             def attend_latent(q, entry, lp=lp, at=at):
                 new["latent"][0].append(entry)
-                kv_pos, kv_seg = line, seg
-                if context is not None:
-                    arenas, tables, _, ctx_len = context
-                    (ac,) = arenas["latent"]
-                    n = tables["latent"].shape[0] * ac.shape[2]
-                    real, at_pos, at_seg = cached_line(n, 0, ctx_len)
-                    with jax.named_scope("kv_context"):     # key AND value
-                        # one gather of the arena itself: a layer's
-                        # slice first would be copied out whole
-                        cached = jnp.where(real[:, None], ac[
-                            at, tables["latent"]].reshape(n, -1), 0)
-                    entry = jnp.concatenate([cached, entry])
-                    kv_pos = jnp.concatenate([at_pos, line])
-                    kv_seg = jnp.concatenate([at_seg, seg])
-                return self._attend_expanded(
-                    q, entry, lp, q_pos=line, kv_pos=kv_pos, q_seg=seg,
-                    kv_seg=kv_seg)
+                q = q.transpose(1, 0, 2)
+                part = lambda entries, **where: self._attend_expanded(
+                    q, *entries, lp, heads_first=True, **where)
+                return self._attend_chunk(
+                    part, (entry,), "latent", at, line, seg, context
+                ).transpose(1, 0, 2)
 
             x, _ = self._block(
                 x, lp, li, pos,
@@ -1279,8 +1314,14 @@ class TransformerAdapter:
             else min(self.pack_bucket, next_pow2_bucket(max_rows))
         bt = cache.block_tokens
         self._kv_cap = -(-(model.max_context + 1) // bt) * bt
-        self._ctx_widths = {k: cache.table_width(k, self._kv_cap)
-                            for k in cache.kinds}
+        # a chunk reads its context a slab of table entries at a time:
+        # the table it is handed is whole slabs wide (scratch past the
+        # row's own blocks), so every trip's slice lies inside it
+        widths = {k: cache.table_width(k, self._kv_cap) for k in cache.kinds}
+        slabs = {k: context_slab(w, bt) for k, w in widths.items()}
+        self._ctx_widths = {k: -(-w // slabs[k]) * slabs[k]
+                            for k, w in widths.items()}
+        self._slab_tokens = {k: n * bt for k, n in slabs.items()}
         self._prefilling: Dict[int, int] = {}   # rid -> positions cached
         # Row slots: entry `scratch_slot` of the feed belongs to no one.
         # Twice the rows: a request whose last step is launched keeps its
@@ -1414,6 +1455,9 @@ class TransformerAdapter:
         self._count["chunks"].inc()
         self._count["chunk_tokens"].inc(cur)
         self._count["ctx_tokens"].inc(ctx_len)
+        self._count["ctx_read"].inc(sum(
+            -(-(ctx_len - int(ctx_starts.get(k, 0))) // n) * n
+            for k, n in self._slab_tokens.items()))
         up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last,
                      feed_slots) + 4
         with tracing.span("decode/launch", cat="serve", bytes=up):

@@ -20,8 +20,10 @@ from benchmark.models import seed_key
 from benchmark.reference import kanana2 as ref
 from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import moe
+from deeplearning4j_tpu.serving import decode
 from deeplearning4j_tpu.serving.decode import (DecodeEngine, PagedKVCache,
                                                TransformerAdapter)
+from chunk_context import check_chunk_over_context
 
 PUBLISHED = manifest.data_file("configs", "kanana-2-30b-a3b-instruct-2601")
 CHUNK, BT, PAD = 16, 4, 1024
@@ -85,6 +87,18 @@ def test_chunked_prefill_and_cached_decoding_agree_with_the_reference(
     assert max(c.max() for _, c in gaps) > TOL
     for r, p in prompts.items():
         assert cache.length(r) == len(p) + len(served[r]) - 1
+
+
+# a slab of 32 cached positions (8 table entries): no context, half a
+# slab, exactly one, and two and a half
+@pytest.mark.parametrize("ctx", [0, 16, 32, 80])
+def test_a_chunk_reads_its_cached_latents_a_slab_at_a_time(ctx, monkeypatch):
+    monkeypatch.setattr(decode, "CONTEXT_SLAB", 32)
+    model = builder.build(dict(TINY, max_context=128), 7)
+    ad = check_chunk_over_context(model, _cache(model), CHUNK, ctx,
+                                  TINY["vocab_size"], TOL)
+    # 33 entries cover 128 positions and one more; the table is 5 slabs
+    assert ad._ctx_widths == {"latent": 40}
 
 
 def test_the_plain_forward_gives_the_references_logits():
@@ -297,15 +311,17 @@ def test_the_latent_arena_holds_one_vector_a_token():
         TransformerDecoder(**dict(published, layer_types=("full",)))
 
 
-def test_the_engine_drains_the_latent_cache_and_counts_what_chunks_read():
+def test_the_engine_drains_the_latent_cache_and_counts_what_chunks_read(
+        monkeypatch):
     from deeplearning4j_tpu.optimize.metrics import registry
+    monkeypatch.setattr(decode, "CONTEXT_SLAB", 32)
     model = builder.build(TINY, 2)
     cache = _cache(model)
     ad = TransformerAdapter(model, cache, pack_bucket=CHUNK, max_rows=2)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, 96, n).tolist() for n in (6, 50, 20, 33)]
-    read = ad._count["ctx_tokens"]
-    before = read.value()
+    read, paid = ad._count["ctx_tokens"], ad._count["ctx_read"]
+    before, paid_before = read.value(), paid.value()
     with DecodeEngine(ad, max_decode_batch=2) as eng:
         eng.warmup()
         out = {}
@@ -322,6 +338,12 @@ def test_the_engine_drains_the_latent_cache_and_counts_what_chunks_read():
     # 50 = 16 + 16 + 16 + 2: the later chunks read 16, 32 and 48 cached
     # positions; 20 reads 16, 33 reads 16 and 32
     assert read.value() - before == (16 + 32 + 48) + 16 + (16 + 32)
+    # and gathered them in whole slabs of 32: only the chunk over 48
+    # takes two trips
+    assert paid.value() - paid_before == 32 * ((1 + 1 + 2) + 1 + (1 + 1))
+    assert registry().counter(
+        "serving_decode_prefill_context_read_tokens_total", ""
+    ).value() >= paid.value() >= read.value()
     # the families by kind are there before any traffic, `latent` too
     for name in ("serving_decode_kv_tokens_total",
                  "serving_kv_block_steps_total"):

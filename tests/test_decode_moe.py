@@ -18,9 +18,11 @@ from benchmark.reference import mellum2 as ref
 from deeplearning4j_tpu.ops import flash_attention as fa
 from deeplearning4j_tpu.ops import moe
 from deeplearning4j_tpu.parallel.inference import KVCacheExhaustedError
+from deeplearning4j_tpu.serving import decode
 from deeplearning4j_tpu.serving.decode import (DecodeEngine, PagedKVCache,
                                                TransformerAdapter,
                                                rope_inv_freq)
+from chunk_context import check_chunk_over_context
 
 PUBLISHED = manifest.data_file("configs", "mellum2-12b-a2.5b-instruct")
 WINDOW, CHUNK, BT, PAD = 8, 16, 4, 1024
@@ -82,6 +84,26 @@ def test_chunked_prefill_and_cached_decoding_agree_with_the_reference(
         n = len(p) + len(served[r]) - 1
         assert cache.length(r) == n
         assert cache.held_from(r) == max(0, (n - WINDOW + 1) // BT) * BT
+
+
+# a slab of 32 cached positions (8 table entries) for the full kind: no
+# context, half a slab, exactly one, and two and a half; the sliding
+# kind's whole table (a window's blocks and one) is one slab, read once
+# or not at all
+@pytest.mark.parametrize("ctx", [0, 16, 32, 80])
+def test_a_chunk_reads_both_kinds_of_context_a_slab_at_a_time(
+        ctx, monkeypatch):
+    monkeypatch.setattr(decode, "CONTEXT_SLAB", 32)
+    model = builder.build(dict(TINY, max_context=128), 7)
+    cache = PagedKVCache(
+        layers=model.n_layers, heads=model.kv_heads, head_dim=model.head_dim,
+        dtype=model.dtype, layer_kinds=model.layer_kinds(),
+        window=model.window, block_tokens=BT,
+        max_blocks={"full": 48, "sliding": 12})
+    ad = check_chunk_over_context(model, cache, CHUNK, ctx,
+                                  TINY["vocab_size"], TOL)
+    assert ad._ctx_widths == {"full": 40, "sliding": WINDOW // BT + 1}
+    assert ad._slab_tokens == {"full": 32, "sliding": WINDOW + BT}
 
 
 def test_the_plain_forward_gives_the_references_logits():
@@ -249,9 +271,11 @@ def test_the_paged_decode_kernel_agrees_with_its_dense_arm(window):
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("window", [None, 6])
-def test_the_prefill_kernel_agrees_with_its_dense_arm(window):
-    rng = np.random.default_rng(1)
+def _parts(window, **how):
+    """Sixteen queries (two segments and padding) over sixteen cached
+    positions from 3, of which eight lie below ctx_len, and their own:
+    the operands and `prefill_attention`'s keywords."""
+    rng = np.random.default_rng(2)
     tq, n_ctx, hh, kvh, d, ctx_len, start = 16, 16, 4, 2, 8, 11, 3
     arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     true = start + np.arange(n_ctx)
@@ -263,9 +287,79 @@ def test_the_prefill_kernel_agrees_with_its_dense_arm(window):
         kv_pos=jnp.asarray(np.concatenate([np.where(real, true - ctx_len,
                                                     1 << 30),
                                            np.arange(tq)])),
-        kv_seg=jnp.asarray(np.concatenate([np.where(real, 1, -1), seg])))
-    q, k, v = arr(tq, hh, d), arr(tq + n_ctx, kvh, d), arr(tq + n_ctx, kvh, d)
+        kv_seg=jnp.asarray(np.concatenate([np.where(real, 1, -1), seg])),
+        **how)
+    return (arr(tq, hh, d), arr(tq + n_ctx, kvh, d),
+            arr(tq + n_ctx, kvh, d)), kw, seg
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_the_prefill_kernel_agrees_with_its_dense_arm(window):
+    (q, k, v), kw, _ = _parts(window)
     got = fa.prefill_attention(q, k, v, impl="flash", interpret=True,
                                q_block=8, kv_block=8, **kw)
     want = fa.prefill_attention(q, k, v, impl="dense", **kw)
     np.testing.assert_allclose(got[:-2], want[:-2], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_the_prefill_kernel_hands_back_the_rows_log_sum_exp(window):
+    """Over the cached positions alone: segment 1 sees some of them,
+    the other rows none, and both arms say so alike."""
+    (q, k, v), kw, seg = _parts(window, return_lse=True)
+    kw.update(kv_pos=kw["kv_pos"][:16], kv_seg=kw["kv_seg"][:16])
+    got, got_lse = fa.prefill_attention(q, k[:16], v[:16], impl="flash",
+                                        interpret=True, q_block=8,
+                                        kv_block=8, **kw)
+    want, want_lse = fa.prefill_attention(q, k[:16], v[:16], impl="dense",
+                                          **kw)
+    assert got.dtype == want.dtype == got_lse.dtype == jnp.float32
+    assert got.shape == (16, 4, 8) and got_lse.shape == (16, 4)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_lse, want_lse, atol=1e-5, rtol=1e-5)
+    blind = seg != 1
+    if window:      # the last cached key lies at -1: 6 behind line 5
+        blind = blind | (np.arange(16) >= window - 1)
+    seen = np.asarray(got_lse) > fa.NEG / 2
+    np.testing.assert_array_equal(seen, ~np.broadcast_to(blind[:, None],
+                                                          (16, 4)))
+    assert (np.asarray(got_lse)[blind] == fa.NEG).all()
+    assert (np.asarray(got)[blind] == 0).all()
+    # heads first: the same numbers, turned
+    turn = lambda a: a.transpose(1, 0, 2)
+    first, first_lse = fa.prefill_attention(
+        turn(q), turn(k[:16]), turn(v[:16]), impl="dense", heads_first=True,
+        **kw)
+    np.testing.assert_allclose(turn(first), want, atol=1e-6)
+    np.testing.assert_allclose(first_lse.T, want_lse, atol=1e-6)
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_parts_of_a_key_range_merged_by_lse_are_the_whole(window):
+    (q, k, v), kw, seg = _parts(window)
+    whole = fa.prefill_attention(q, k, v, impl="dense", **kw)
+    cut = lambda lo, hi: fa.prefill_attention(
+        q, k[lo:hi], v[lo:hi], impl="dense", return_lse=True,
+        **dict(kw, kv_pos=kw["kv_pos"][lo:hi], kv_seg=kw["kv_seg"][lo:hi]))
+    own, context = cut(16, 32), cut(0, 16)
+    o, lse = fa.merge_attention(*own, *context)
+    np.testing.assert_allclose(o[:-2], whole[:-2], atol=1e-5, rtol=1e-5)
+    # the rows of segment 2 and the padding see no cached key: the merge
+    # leaves them as they were, bit for bit
+    blind = seg != 1
+    np.testing.assert_array_equal(np.asarray(o)[blind],
+                                  np.asarray(own[0])[blind])
+    np.testing.assert_array_equal(np.asarray(lse)[blind],
+                                  np.asarray(own[1])[blind])
+    # a part that no query sees at all (every key past ctx_len) changes
+    # nothing, whatever its values hold
+    none = fa.prefill_attention(
+        q, k[:16], v[:16], impl="dense", return_lse=True, **dict(
+            kw, kv_pos=jnp.full((16,), 1 << 30), kv_seg=-jnp.ones((16,),
+                                                                  jnp.int32)))
+    again, lse_again = fa.merge_attention(o, lse, *none)
+    np.testing.assert_array_equal(again, o)
+    np.testing.assert_array_equal(lse_again, lse)
+    # and in the other order, from nothing
+    flipped, _ = fa.merge_attention(*none, o, lse)
+    np.testing.assert_array_equal(flipped, o)

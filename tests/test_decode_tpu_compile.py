@@ -3,8 +3,9 @@ v5e at the benchmark's widths (two layers): no chip is attached and
 nothing runs. What only the chip's compiler shows: the donated arenas
 alias their outputs, no executable re-lays an arena out (with the layer
 as a window axis of the scatter, or heads and head_dim as two trailing
-axes, it copied all of it on every step), the Pallas kernels are in and
-Mosaic takes them. Two settings of the one decoder: OPT-1.3B's dense
+axes, it copied all of it on every step; the gather inside the loop over
+a chunk's context reads the arena where it lies too), the Pallas kernels
+are in and Mosaic takes them. Two settings of the one decoder: OPT-1.3B's dense
 float32 block, and a sparse bfloat16 block with grouped KV heads, a
 sliding and a full layer side by side and 64 experts; and a latent
 bfloat16 block (a dense layer and a sparse one with a shared expert)
@@ -45,8 +46,10 @@ SPARSE = dict(
                               "original_max_position_embeddings": 8192}}),
     cache=dict(block_tokens=256, max_blocks={"full": 1056, "sliding": 256}),
     rows=32, kv=8448, pack=2048,
-    # a layer: the attention kernel and the grouped product's three
-    step_kernels=4 * LAYERS, prefill_kernels=4 * LAYERS,
+    # a layer: the attention kernel and the grouped product's three; a
+    # chunk's attention is two calls, over its own keys and over a slab
+    # of its context inside the loop
+    step_kernels=4 * LAYERS, prefill_kernels=5 * LAYERS,
     # a chunk's own temporaries: 2,048 tokens x 8 experts each, their
     # float32 products of width 2,304 gathered back into token order
     prefill_temporaries=2048 * 8 * 2304 * 12)
@@ -63,14 +66,16 @@ LATENT = dict(
     cache=dict(block_tokens=256, max_blocks={"latent": 4160}),
     rows=32, kv=33024, pack=2048,
     # the attention kernel a layer, and the sparse layer's grouped
-    # product's three
-    step_kernels=LAYERS + 3, prefill_kernels=LAYERS + 3,
-    # a chunk's own temporaries: the 33,024 cached and 2,048 own entries
-    # expanded for 32 heads a layer at a time (k_nope 128 and the shared
-    # k_pe 64 broadcast, joined to keys on 256 lanes, values on 128:
+    # product's three; a chunk's attention is two calls (its own keys,
+    # and a slab of its context inside the loop)
+    step_kernels=LAYERS + 3, prefill_kernels=2 * LAYERS + 3,
+    # a chunk's own temporaries: one slab of 4,096 cached entries and
+    # its 2,048 own expanded for 32 heads, a part at a time (k_nope 128
+    # and the shared k_pe 64 broadcast, joined to keys on 256 lanes,
+    # values on 128: 226 MB where the table's whole 33,024 + 2,048 were
     # 1.29 GB), beside the chunk's routed products (2,048 x 6 rows of
     # 2,048 in float32, gathered back: 302 MB)
-    prefill_temporaries=35072 * 32 * (128 + 64 + 256 + 128) * 2
+    prefill_temporaries=(4096 + 2048) * 32 * (128 + 64 + 256 + 128) * 2
     + 2048 * 6 * 2048 * 12 + (64 << 20))
 
 

@@ -523,11 +523,13 @@ def test_the_decoders_programs_name_their_parts_and_decode_attention():
                      "kv_scatter"):
             assert f"/{name}/" in text, name
     # a step reads the cache through the table inside its kernel; a chunk
-    # gathers the slices before it and attends over them and itself
+    # attends over itself and, under a loop, gathers a slab of the slices
+    # before it a trip and attends over that
     assert "/layer_1/attn_full/decode_attention_full/" in step
     assert "/kv_context/" not in step
     assert "/layer_1/attn_full/prefill_attention_full/" in prefill
-    assert "/layer_1/attn_full/kv_context/" in prefill
+    assert "/layer_1/attn_full/while/body/kv_context/" in prefill
+    assert "/layer_1/attn_full/while/body/prefill_attention_full/" in prefill
     assert "/decode_attention_full/" not in prefill
 
 
