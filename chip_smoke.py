@@ -589,17 +589,26 @@ def phase_kernels(cfg, interpret: bool):
     # ---- the serving kernels: the paged decode kernel (the cache read
     # through the block table; grouped KV heads; a window) and the prefill
     # chunk over its cached context, each against its dense arm. On the
-    # chip at the widths of the two served decoders; rehearsed tiny.
+    # chip at the widths of the served decoders that keep K and V per head
+    # (the third has its query heads by kind of layer: 48 on a full layer
+    # and 72 on a sliding one over 8 KV heads, groups of 6 and 9, a
+    # block-diagonal query of [72, 1024]); rehearsed tiny, at a group of 9
+    # too.
     from deeplearning4j_tpu.ops.flash_attention import (
         merge_attention, paged_decode_attention, prefill_attention)
-    serving = [("f32 h2x8 bt8", jnp.float32, 2, 2, 8, 8, 4, None, 16)] \
+    serving = [("f32 h2x8 bt8", jnp.float32, 2, 2, 8, 8, 4, None, 16),
+               ("f32 h18/2x8 bt8", jnp.float32, 18, 2, 8, 8, 4, None, 16)] \
         if interpret else [
         ("f32 h32x64 bt16 (decoder-at-opt-1.3b)", jnp.float32, 32, 32, 64,
          16, 16, None, 128),
         ("bf16 h32/4x128 bt256 full", jnp.bfloat16, 32, 4, 128, 256, 8,
          None, 2048),
         ("bf16 h32/4x128 bt256 window1024", jnp.bfloat16, 32, 4, 128, 256,
-         5, 1024, 2048)]
+         5, 1024, 2048),
+        ("bf16 h48/8x128 bt256 full (laguna-s-2.1)", jnp.bfloat16, 48, 8, 128,
+         256, 8, None, 2048),
+        ("bf16 h72/8x128 bt256 window512 (laguna-s-2.1)", jnp.bfloat16, 72,
+         8, 128, 256, 3, 512, 2048)]
     def chunk_line(chunk, n_ctx, ctx_len):
         """A chunk of two segments and a padded tail after `n_ctx` cached
         positions of which `ctx_len` are real: (the live queries, the

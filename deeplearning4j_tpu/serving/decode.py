@@ -142,6 +142,12 @@ _KV_TOKENS_HELP = ("Keys the rows of the decode steps attended to in one "
 _BLOCK_STEPS_HELP = ("KV blocks the cache held for one layer of the kind, "
                      "summed over decode steps")
 _MOE_HELP = {
+    "routed": ("serving_moe_assignments_routed_total",
+               "Token-to-expert assignments the decode steps' routers "
+               "made, rows x experts_per_token a sparse layer, whether "
+               "the expert is held here or not (under it "
+               "serving_moe_assignments_total: the load of this share "
+               "of the experts)"),
     "assignments": ("serving_moe_assignments_total",
                     "Token-to-expert assignments the decode steps' expert "
                     "layers computed, summed over layers"),
@@ -632,6 +638,19 @@ class TransformerDecoder:
       or an own ``head`` matrix, ``dtype`` of parameters, activations
       and cache (products accumulate in float32; norms, softmax and the
       router run in float32)
+    * ``heads``: one number for every layer | ``{kind: heads}``, the
+      query heads BY KIND of layer (more of them where a window makes
+      keys cheap): ``wq``, ``wo``, the group of a KV head and the gate
+      are then the kind's; ``kv_heads`` and ``head_dim`` stay one
+      number, so the cache's entry is the same in every kind
+    * ``rope[kind]["partial_rotary_factor"]`` (default 1): rotary turns
+      the first ``head_dim × factor`` values of each head of q and k and
+      the rest pass as they are; the kind's table (YaRN's correction
+      dimensions too) is computed over the rotated width
+    * ``gate``: ``None`` | ``"head"``: ``sigmoid(h wgate)`` of the
+      layer's normed input, one scalar a query head a token in float32,
+      multiplied into that head's attention output before ``wo``
+      (head-wise gated attention, arXiv:2505.06708)
     * ``layer_types``: the pattern of kinds, repeated over the layers:
       ``"full"`` attention or ``"sliding"`` (position i sees j <= i with
       i - j < ``window``)
@@ -685,7 +704,7 @@ class TransformerDecoder:
     """
 
     def __init__(self, *, vocab: int = 128, layers: int = 2,
-                 heads: int = 2, head_dim: int = 8, ff: int = 64,
+                 heads=2, head_dim: int = 8, ff: int = 64,
                  max_context: int = 128, seed: int = 0,
                  d_model: Optional[int] = None,
                  kv_heads: Optional[int] = None, norm: str = "layer",
@@ -703,9 +722,20 @@ class TransformerDecoder:
                  mlp_types: Optional[Sequence[str]] = None,
                  dense_ff: int = 0, shared_ff: int = 0,
                  router: str = "softmax", route_scale: float = 1.0,
-                 params=None):
+                 gate: Optional[str] = None, params=None):
         self.vocab, self.n_layers = int(vocab), int(layers)
-        self.heads, self.head_dim = int(heads), int(head_dim)
+        self.layer_types = tuple(layer_types)
+        self.head_dim, self.gate = int(head_dim), gate
+        if isinstance(heads, dict):     # by kind of layer
+            self.heads = {k: int(n) for k, n in heads.items()}
+            if set(self.heads) != set(self.layer_types) \
+                    or attention != "heads" or kv_heads is None \
+                    or d_model is None:
+                raise ValueError(
+                    "heads by kind names every kind of layer_types and "
+                    "needs attention='heads', kv_heads and d_model")
+        else:
+            self.heads = int(heads)
         self.kv_heads = self.heads if kv_heads is None else int(kv_heads)
         self.d_model = self.heads * self.head_dim if d_model is None \
             else int(d_model)
@@ -717,7 +747,6 @@ class TransformerDecoder:
         self.experts_held = tuple(range(self.experts)) \
             if experts_held is None else tuple(experts_held)
         self.tied, self.dtype = bool(tied), jnp.dtype(dtype)
-        self.layer_types = tuple(layer_types)
         self.window = None if window is None else int(window)
         self.init_std, self.row_buckets = float(init_std), row_buckets
         self.attention = attention
@@ -731,6 +760,7 @@ class TransformerDecoder:
                 or row_buckets not in ("pow2", "full") \
                 or attention not in ("heads", "latent") \
                 or router not in ("softmax", "sigmoid") \
+                or gate not in (None, "head") \
                 or {mlp, *(self.mlp_types or ())} - {"relu", "dense", "moe"} \
                 or len(self.mlp_types or ()) not in (0, self.n_layers) \
                 or set(self.layer_types) - set(KINDS):
@@ -745,6 +775,8 @@ class TransformerDecoder:
                                            self.rope_dim, self.v_dim) < 1:
                 raise ValueError("latent attention needs rotary positions, "
                                  "kv_lora_rank and the three head sizes")
+            if gate is not None:
+                raise ValueError("latent attention takes no gate")
             # a query-key head; every head reads the one latent
             self.head_dim, self.kv_heads = self.nope + self.rope_dim, \
                 self.heads
@@ -755,7 +787,7 @@ class TransformerDecoder:
         # around the kernel)
         self.latent_width = -(-(self.rank + self.rope_dim) // 128) * 128
         self.attn_scale = self.head_dim ** -0.5
-        if self.heads % self.kv_heads:
+        if any(self.heads_of(k) % self.kv_heads for k in kinds):
             raise ValueError("heads must be a multiple of kv_heads")
         if position == "sinusoid" and self.d_model % 2:
             raise ValueError("d_model must be even for the sinusoidal "
@@ -767,9 +799,19 @@ class TransformerDecoder:
             raise ValueError("moe needs experts >= experts_per_token > 0")
         if "dense" in ffs and self.dense_ff < 1:
             raise ValueError("a dense SwiGLU layer needs dense_ff")
-        self._rope = {} if position != "rotary" else {
-            k: rope_inv_freq(self.rope_dim, rope[k])
-            for k in set(self.layer_types)}
+        # the width rotary turns, by kind, and its table over that width
+        self._rotated = {} if position != "rotary" else {
+            k: int(self.rope_dim * rope[k].get("partial_rotary_factor", 1))
+            for k in kinds}
+        if any(r < 2 or r % 2 or r > self.rope_dim
+               or (r != self.rope_dim and attention == "latent")
+               for r in self._rotated.values()):
+            raise ValueError(
+                "partial_rotary_factor must leave an even rotated width "
+                "within the head (and latent attention turns its whole "
+                "rope part)")
+        self._rope = {k: rope_inv_freq(r, rope[k])
+                      for k, r in self._rotated.items()}
         # Pool duck-compat: swap/describe read these on every entry.
         self.iteration = 0
         self.epoch = 0
@@ -784,6 +826,11 @@ class TransformerDecoder:
     # ---------------------------------------------------------------- geometry
     def kind_of(self, layer: int) -> str:
         return self.layer_types[layer % len(self.layer_types)]
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a layer of `kind`."""
+        return self.heads[kind] if isinstance(self.heads, dict) \
+            else self.heads
 
     def layer_kinds(self) -> List[str]:
         """Each layer's kind, as the cache wants them."""
@@ -807,7 +854,8 @@ class TransformerDecoder:
     # ----------------------------------------------------------------- weights
     def _leaf_shapes(self, layer: int = 0) -> Dict[str, tuple]:
         d, f = self.d_model, self.ff
-        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        hh = self.heads_of(self.kind_of(layer))
+        q, kv = hh * self.head_dim, self.kv_heads * self.head_dim
         if self.attention == "latent":
             shapes = {"wq": (d, q), "wkv_a": (d, self.rank + self.rope_dim),
                       "wkv_b": (self.rank,
@@ -816,6 +864,8 @@ class TransformerDecoder:
         else:
             shapes = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv),
                       "wo": (q, d)}
+            if self.gate == "head":
+                shapes["wgate"] = (d, hh)
         mlp = self.mlp_of(layer)
         if mlp == "relu":
             shapes.update(w1=(d, f), w2=(f, d))
@@ -886,7 +936,13 @@ class TransformerDecoder:
         return x
 
     def _rotate(self, a, pos, kind):
-        """Rotary position on a [t, heads, rotated width], rotate-half."""
+        """Rotary position on a [t, heads, width], rotate-half over the
+        kind's rotated width: the first values of each head, the rest
+        pass as they are."""
+        r = self._rotated[kind]
+        if r < a.shape[-1]:
+            return jnp.concatenate(
+                [self._rotate(a[..., :r], pos, kind), a[..., r:]], axis=-1)
         inv, factor = self._rope[kind]
         ang = pos[:, None].astype(jnp.float32) * inv
         cos = jnp.tile(jnp.cos(ang), 2)[:, None, :] * factor
@@ -978,7 +1034,7 @@ class TransformerDecoder:
             if self.attention == "latent":
                 a = attend(*self._latent_q_entry(h, lp, pos))
             else:
-                q = self._mm(h, lp["wq"]).reshape(t, self.heads,
+                q = self._mm(h, lp["wq"]).reshape(t, self.heads_of(kind),
                                                   self.head_dim)
                 k, v = self._mm(h, lp["wk"]), self._mm(h, lp["wv"])
                 if self.position == "rotary":
@@ -987,6 +1043,13 @@ class TransformerDecoder:
                         k.reshape(t, self.kv_heads, self.head_dim),
                         pos, kind).reshape(k.shape)
                 a = attend(q, k, v)
+                if self.gate == "head":
+                    with jax.named_scope("attn_gate"):
+                        g = jax.nn.sigmoid(jnp.dot(
+                            h, lp["wgate"],
+                            preferred_element_type=jnp.float32))
+                        a = (a.astype(jnp.float32) * g[..., None]
+                             ).astype(self.dtype)
             x = x + self._mm(a.reshape(t, -1), lp["wo"])
         mlp = self.mlp_of(li)
         if mlp == "relu":
@@ -1333,6 +1396,9 @@ class TransformerAdapter:
         self._free_slots = list(range(self.scratch_slot - 1, -1, -1))
         self._in_flight: Optional[_Launched] = None
         self._launches = 0      # steps launched; `seq` of the spans
+        # what the routers of a step assign a row: every sparse layer's
+        self._routed_a_row = model.top_k * sum(
+            model.mlp_of(li) == "moe" for li in range(model.n_layers))
         self._link = _register_link_metrics()
         self._count = _register_model_metrics()
 
@@ -1590,6 +1656,7 @@ class TransformerAdapter:
             return out, {rid: e for _, rid in rows}
         self._link["d2h", work.phase].inc(down)
         if sums is not None:    # host integers since the fetch above
+            self._count["routed"].inc(len(work.rows) * self._routed_a_row)
             for name, v in zip(("assignments", "touched", "peak"),
                                sums.tolist()):  # jaxlint: disable=JL102
                 self._count[name].inc(v)

@@ -9,7 +9,9 @@ are in and Mosaic takes them. Two settings of the one decoder: OPT-1.3B's dense
 float32 block, and a sparse bfloat16 block with grouped KV heads, a
 sliding and a full layer side by side and 64 experts; and a latent
 bfloat16 block (a dense layer and a sparse one with a shared expert)
-whose one arena a kind holds [latent | k_pe] on 640 lanes. One file, the
+whose one arena a kind holds [latent | k_pe] on 640 lanes; and a bfloat16
+block with query heads by kind of layer (48 full, 72 sliding over 8 KV
+heads), a gate a head, half rotary and a quarter of 256 experts. One file, the
 topology described in a fixture: see the on-chip-measurement guide,
 section 2."""
 import re
@@ -77,6 +79,37 @@ LATENT = dict(
     # 2,048 in float32, gathered back: 302 MB)
     prefill_temporaries=(4096 + 2048) * 32 * (128 + 64 + 256 + 128) * 2
     + 2048 * 6 * 2048 * 12 + (64 << 20))
+LAGUNA = dict(
+    model=dict(vocab=25088, layers=LAYERS,
+               heads={"full": 48, "sliding": 72}, kv_heads=8, head_dim=128,
+               d_model=3072, gate="head", ff=1024, max_context=5376,
+               norm="rms", position="rotary",
+               layer_types=("full", "sliding"), window=512,
+               mlp_types=("dense", "moe"), dense_ff=12288, shared_ff=1024,
+               experts=256, experts_per_token=10,
+               experts_held=tuple(range(64)), route_scale=2.5, tied=False,
+               dtype=jnp.bfloat16, row_buckets="full",
+               rope={"sliding": {"rope_theta": 10000.0,
+                                 "partial_rotary_factor": 1},
+                     "full": {"rope_type": "yarn", "rope_theta": 500000.0,
+                              "factor": 128.0, "beta_fast": 32,
+                              "beta_slow": 1,
+                              "original_max_position_embeddings": 8192,
+                              "attention_factor": 1.4852030263919618,
+                              "partial_rotary_factor": 0.5}}),
+    cache=dict(block_tokens=256, max_blocks={"full": 1344, "sliding": 272}),
+    rows=64, kv=5632, pack=2048,
+    # the attention kernel a layer (48 query heads on the full layer's
+    # 1,024-lane rows, 72 on the sliding one's: a block-diagonal query of
+    # [72, 1024]) and the sparse layer's grouped product's three; a
+    # chunk's attention is two calls a layer, but the sliding layer's
+    # context (3 table entries, 768 keys) is under the kernel's floor of
+    # 1,024 keys and takes the dense arm
+    step_kernels=LAYERS + 3, prefill_kernels=2 * LAYERS - 1 + 3,
+    # a chunk's own temporaries: 2,048 tokens x 10 choices each, gathered
+    # whether their expert is held here or not, their float32 products
+    # of width 3,072 gathered back into token order
+    prefill_temporaries=2048 * 10 * 3072 * 12 + (256 << 20))
 
 
 @pytest.fixture(scope="module")
@@ -151,8 +184,8 @@ def _host_operands(setting, m, which):
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
-@pytest.mark.parametrize("setting", [DENSE, SPARSE, LATENT],
-                         ids=["dense", "sparse", "latent"])
+@pytest.mark.parametrize("setting", [DENSE, SPARSE, LATENT, LAGUNA],
+                         ids=["dense", "sparse", "latent", "heads-by-kind"])
 def test_the_arenas_are_updated_where_they_lie(compiled, setting, which):
     m, params, arenas = _shapes(setting)
     ops = _host_operands(setting, m, which)
