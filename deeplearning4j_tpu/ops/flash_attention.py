@@ -612,6 +612,208 @@ def _visible(q_pos, kv_pos, q_seg, kv_seg, window):
     return ok
 
 
+# a key block's class, known before the block runs (`key_block_classes`)
+KEY_SKIPPED, KEY_EDGE, KEY_WHOLE = 0, 1, 2
+
+# The prefill kernel's key block, what one class covers, is the largest
+# of these that divides the keys, and one grid step takes as many
+# blocks as PREFILL_STEP_KEYS keys hold. Read on the chip (PERF.md, PR
+# 36): the correction of the running max, the accumulator's rescale
+# and a step's fixed cost are paid once a body, so a slab of 4,096
+# whole keys takes 4.4 ms a layer in steps of 256 and 2.0 from 1,024
+# on; an edge block costs 2.2 us a 256 keys at 256, 1.4 at 1,024, which
+# outweighs the hidden pairs a wider edge block computes.
+PREFILL_KEY_BLOCKS = (1024, 512, 256, 128)
+PREFILL_STEP_KEYS = 2048
+# The scoped VMEM the prefill kernel is compiled with (a v5e's default
+# is 16 MiB of its 128), and the share of it the block sizes may plan
+# for: the estimate does not see what Mosaic spills.
+PREFILL_VMEM_LIMIT = 32 * 1024 * 1024
+_PREFILL_VMEM_PLAN = PREFILL_VMEM_LIMIT * 3 // 4
+
+
+def block_ends(a, block: int):
+    """(least, greatest) entry of each `block` entries of `a` [n]."""
+    a = a.reshape(-1, block)
+    return a.min(axis=1), a.max(axis=1)
+
+
+def key_classes_of_ends(q_pos, kv_pos, q_seg, kv_seg, window):
+    """`key_block_classes` from each tile's and each block's (least,
+    greatest) place and segment id, which is all it reads: four pairs
+    of arrays [tiles] / [blocks]."""
+    (qp_lo, qp_hi), (qs_lo, qs_hi) = ((a[:, None] for a in pair)
+                                      for pair in (q_pos, q_seg))
+    (kp_lo, kp_hi), (ks_lo, ks_hi) = ((a[None, :] for a in pair)
+                                      for pair in (kv_pos, kv_seg))
+    run = (kp_lo <= qp_hi) & (ks_lo <= qs_hi) & (ks_hi >= qs_lo)
+    whole = (kp_hi <= qp_lo) & (qs_lo == qs_hi) & (ks_lo == ks_hi) \
+        & (ks_lo == qs_lo)
+    if window is not None:
+        run = run & (qp_lo - kp_hi < window)
+        whole = whole & (qp_hi - kp_lo < window)
+    return run.astype("int32") + (run & whole).astype("int32")
+
+
+def key_block_classes(q_pos, kv_pos, q_seg, kv_seg, window, q_block: int,
+                      kv_block: int):
+    """What a tile of `q_block` queries sees of each block of `kv_block`
+    keys, from the operands `prefill_attention` takes (int arrays [tq] /
+    [tk], NumPy's or jax's): int32 [tq / q_block, tk / kv_block] of
+
+      KEY_SKIPPED  no pair can be visible: the block lies wholly after
+                   the tile, wholly behind its window, or the two
+                   ranges of segment ids cannot meet
+      KEY_WHOLE    every pair is visible: the block's last key is at or
+                   before the tile's first query, tile and block hold
+                   one segment and the same one, and with a window the
+                   tile's last query is less than `window` past the
+                   block's first key
+      KEY_EDGE     all else: the masks decide, pair by pair"""
+    return key_classes_of_ends(
+        block_ends(q_pos, q_block), block_ends(kv_pos, kv_block),
+        block_ends(q_seg, q_block), block_ends(kv_seg, kv_block), window)
+
+
+def _prefill_vmem_bytes(qb, step_keys, dp, dvp, itemsize, out_itemsize):
+    """The prefill kernel's fast memory at these block sizes: the
+    operands' and results' blocks, each twice (the pipeline's two
+    buffers), the running softmax, and a step's scores in float32,
+    their exponentials and those cast for the value product. A [qb, 1]
+    column lies on 128 lanes, as the running max and sum do."""
+    column = qb * _LANE * 4
+    blocks = (qb * dp + step_keys * (dp + dvp)) * itemsize \
+        + qb * dvp * out_itemsize + 3 * column + 8 * step_keys * 4
+    stats = 2 * column + qb * dvp * 4
+    return 2 * blocks + stats + qb * step_keys * (4 + 4 + itemsize)
+
+
+def prefill_kernel_blocks(tq: int, tk: int, head_dim: int, v_dim: int, *,
+                          impl: str = "auto", q_block: int = 0,
+                          kv_block: int = 0, itemsize: int = 2):
+    """`prefill_attention`'s rule for the arm a call takes, from its
+    shapes: None for the dense arm, else ``(q_block, kv_block, parts)``
+    of the kernel: queries a tile, keys a block (what a class of
+    `key_block_classes` covers: the largest of PREFILL_KEY_BLOCKS that
+    divides the keys and that Mosaic can lay out inside the fast memory
+    the kernel may plan for), and the blocks one grid step takes (up to
+    PREFILL_STEP_KEYS keys, as far as they divide the keys and fit).
+    `"auto"` takes the kernel on a TPU from PREFILL_KERNEL_MIN_KEYS
+    keys on; `"flash"` takes it whatever the shapes."""
+    if impl not in ("auto", "flash", "dense"):
+        raise ValueError(f"unknown prefill_attention impl {impl!r}")
+    if impl == "dense" or (impl == "auto" and not (
+            flash_attention_available() and tk >= PREFILL_KERNEL_MIN_KEYS)):
+        return None
+    qb = q_block or pick_kernel_block(tq, 512)
+    pad = lambda n: n + (-n) % _LANE
+    fits = lambda keys: _prefill_vmem_bytes(
+        qb, keys, pad(head_dim), pad(v_dim), itemsize, 4) \
+        <= _PREFILL_VMEM_PLAN
+    for kb in (kv_block,) if kv_block else [
+            b for b in PREFILL_KEY_BLOCKS if tk % b == 0] \
+            or [pick_kernel_block(tk, 256)]:
+        # a block's last two dims: multiples of (8, 128) or the array's
+        tiles = not (tq % qb or tk % kb or (qb % 8 and qb != tq)
+                     or (kb % _LANE and kb != tk))
+        if impl == "flash" or (tiles and fits(kb)):
+            parts = next((n for n in range(PREFILL_STEP_KEYS // kb, 1, -1)
+                          if (tk // kb) % n == 0 and fits(kb * n)), 1)
+            return qb, kb, parts
+    return None
+
+
+def _prefill_kernel(cls_ref, at_ref, qp_ref, qs_ref, kw_ref, q_ref, k_ref,
+                    v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale,
+                    window, nk, parts):
+    """Grid (head, q tile, step of `parts` key blocks). `cls_ref` holds
+    each (tile, key block)'s class, so what a block needs is known
+    before it runs: a skipped one nothing, a whole one the online
+    softmax alone (no position or segment is read, nothing compared or
+    selected), an edge one the masks as well. A step whose blocks are
+    all whole takes them as one block: one correction of the running
+    max, one rescale of the accumulator. The running max and sum lie on
+    `m_ref.shape[1]` lanes, every lane the row's value (128: their
+    arithmetic fills its registers; 1 where a key block is no lane
+    multiple). `at_ref` (the key operands' index maps read it) is the
+    step whose keys a step fetches: its own, or where it runs nothing
+    the nearest that does, so no copy is issued for it."""
+    from jax.experimental import pallas as pl
+
+    j, step = pl.program_id(1), pl.program_id(2)
+    kb = k_ref.shape[1] // parts
+    lanes = m_ref.shape[1]
+    # a row's value on `lanes` lanes -> on n
+    over = lambda a, n: a if lanes == 1 or n == lanes \
+        else jnp.tile(a, (1, n // lanes))
+
+    @pl.when(step == 0)
+    def _():
+        m_ref[:] = jnp.full(m_ref.shape, NEG, m_ref.dtype)
+        l_ref[:] = jnp.zeros(l_ref.shape, l_ref.dtype)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+
+    def add(rows, where=None):
+        """Keys `rows` of the step's block into the running softmax;
+        `where` = their [positions; segments] if a pair may be hidden."""
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0, rows], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if where is not None:
+            kp, ks = where[0:1], where[1:2]
+            s = jnp.where(kp <= qp_ref[:], s, NEG)
+            if window is not None:
+                s = jnp.where(qp_ref[:] - kp < window, s, NEG)
+            s = jnp.where(qs_ref[:] == ks, s, NEG)
+        n = s.shape[1]
+        m_prev = m_ref[:]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - over(m_next, n))
+        if where is not None:
+            # a row that has seen nothing yet: m_next == NEG, and
+            # exp(0) = 1 would count its hidden keys into l
+            p = jnp.where(over(m_next, n) <= NEG / 2, 0.0, p)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:] = m_next
+        acc_ref[:] = acc_ref[:] * over(alpha, acc_ref.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, rows],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    first = (j * nk + step) * parts
+
+    def one(i, rows):
+        kind = cls_ref[first + i]
+        pl.when(kind == KEY_WHOLE)(lambda: add(rows))
+        pl.when(kind == KEY_EDGE)(lambda: add(rows, kw_ref[i]))
+
+    if parts == 1:
+        one(0, slice(None))
+    else:
+        whole = cls_ref[first] == KEY_WHOLE
+        for i in range(1, parts):
+            whole &= cls_ref[first + i] == KEY_WHOLE
+        pl.when(whole)(lambda: add(slice(None)))
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            def body(i, carry):
+                one(i, pl.ds(pl.multiple_of(i * kb, kb), kb))
+                return carry
+            jax.lax.fori_loop(0, parts, body, 0)
+
+    @pl.when(step == nk - 1)
+    def _():
+        l, m = l_ref[:, :1], m_ref[:, :1]
+        safe = jnp.where(l > 0, l, 1.0)
+        # a row that saw no key: zeros, and an lse of NEG so that a
+        # merge weighs the part nothing
+        o_ref[0] = (acc_ref[:] * jnp.where(l > 0, 1.0 / safe, 0.0)).astype(
+            o_ref.dtype)
+        lse_ref[0] = jnp.where(l > 0, m + jnp.log(safe), NEG)
+
+
 def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                       window: Optional[int] = None,
                       scale: Optional[float] = None,
@@ -637,17 +839,18 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
                    (heads first alike), a row that sees no key giving
                    zeros and ``NEG``: one part of a key range, to be
                    joined with the others by :func:`merge_attention`
-      q_pos/kv_pos int32 [tq] / [tk]: places on one line, monotone
-                   inside a block of keys (the skip tests read a block's
-                   ends)
+      q_pos/kv_pos int32 [tq] / [tk]: places on one line
       q_seg/kv_seg int32: a query sees keys of its own segment only
 
     Query i sees key j iff the segments agree, ``kv_pos[j] <=
     q_pos[i]`` and, with `window`, ``q_pos[i] - kv_pos[j] < window``.
-    The kernel skips key blocks wholly above the diagonal, wholly behind
-    the window, or of other segments. `impl="auto"` takes the kernel on
-    a TPU from PREFILL_KERNEL_MIN_KEYS keys on; `"dense"` is the einsum
-    arm. Returns [tq, heads, v_dim]."""
+    The kernel knows each key block's class before it runs it
+    (`key_block_classes`, one table a call for every head): it skips a
+    block wholly above the diagonal, wholly behind the window or of
+    other segments, and masks only in a block where a pair may be
+    hidden. `impl="auto"` takes the kernel on a TPU from
+    PREFILL_KERNEL_MIN_KEYS keys on (`prefill_kernel_blocks`);
+    `"dense"` is the einsum arm. Returns [tq, heads, v_dim]."""
     turn = lambda a: a.transpose(1, 0, 2)
     same = lambda a: a
     # to [t, heads, dim] (the dense arm's order) and to the kernel's
@@ -657,19 +860,12 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
     if hh % kvh:
         raise ValueError(f"{hh} query heads over {kvh} KV heads")
     group = hh // kvh
-    if impl not in ("auto", "flash", "dense"):
-        raise ValueError(f"unknown prefill_attention impl {impl!r}")
-    qb = q_block or pick_kernel_block(tq, 512)
-    kb = kv_block or next((b for b in (256, 128) if tk % b == 0),
-                          pick_kernel_block(tk, 256))   # a lane multiple
-    use_flash = impl == "flash" or (
-        impl == "auto" and flash_attention_available()
-        and tk >= PREFILL_KERNEL_MIN_KEYS
-        and flash_attention_supported(tq, tk, max(d, dv), q_block=qb,
-                                      kv_block=kb))
+    blocks = prefill_kernel_blocks(tq, tk, d, dv, impl=impl, q_block=q_block,
+                                   kv_block=kv_block,
+                                   itemsize=jnp.dtype(q.dtype).itemsize)
     q_pos, kv_pos = q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32)
     q_seg, kv_seg = q_seg.astype(jnp.int32), kv_seg.astype(jnp.int32)
-    if not use_flash:
+    if blocks is None:
         with jax.named_scope(name):
             q, k, v = by_t(q), by_t(k), by_t(v)
             s = jnp.einsum("qkgd,tkd->kgqt", q.reshape(tq, kvh, group, d),
@@ -692,46 +888,67 @@ def prefill_attention(q, k, v, *, q_pos, kv_pos, q_seg, kv_seg,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    qb, kb, parts = blocks
+    if tq % qb or tk % kb:
+        raise ValueError(f"times ({tq}, {tk}) must divide blocks ({qb}, "
+                         f"{kb})")
     q3, k3, v3 = (pad_axis_to(by_head(a), 2, _LANE) for a in (q, k, v))
     dp, dvp = q3.shape[2], v3.shape[2]
-    nk = tk // kb
-    kern = functools.partial(
-        _fwd_kernel, scale=1.0 / math.sqrt(d) if scale is None else scale,
-        causal=True, use_mask=False, use_segs=True, nk=nk, window=window)
-    kv_at = lambda i, j, k_: (i // group, k_, 0)
+    nq, nk, wide = tq // qb, tk // (kb * parts), kb * parts
+    lanes = _LANE if kb % _LANE == 0 else 1
     with jax.named_scope(name):
+        classes = key_block_classes(q_pos, kv_pos, q_seg, kv_seg, window,
+                                    qb, kb)
+        # the step whose keys a step fetches: its own if one of its
+        # blocks runs, else the last before it that ran, else the first
+        # that will
+        runs = (classes.reshape(nq, nk, parts) != KEY_SKIPPED).any(axis=2)
+        step = jnp.arange(nk, dtype=jnp.int32)
+        before = jax.lax.cummax(jnp.where(runs, step, -1), axis=1)
+        at = jnp.where(before >= 0, before,
+                       jnp.argmax(runs, axis=1).astype(jnp.int32)[:, None])
+        key_at = lambda i, j, s, cls, at: at[j * nk + s]
+        kern = functools.partial(
+            _prefill_kernel, window=window, nk=nk, parts=parts,
+            scale=1.0 / math.sqrt(d) if scale is None else scale)
         o3, lse = pl.pallas_call(
             kern,
-            grid=(hh, tq // qb, nk),
-            in_specs=[
-                pl.BlockSpec((qb, 1), lambda i, j, k_: (j, 0)),
-                pl.BlockSpec((1, kb), lambda i, j, k_: (0, k_)),
-                pl.BlockSpec((1, 1, kb), lambda i, j, k_: (0, 0, k_)),
-                pl.BlockSpec((1, qb, 1), lambda i, j, k_: (0, j, 0)),
-                pl.BlockSpec((1, 1, kb), lambda i, j, k_: (0, 0, k_)),
-                pl.BlockSpec((1, qb, dp), lambda i, j, k_: (i, j, 0)),
-                pl.BlockSpec((1, kb, dp), kv_at),
-                pl.BlockSpec((1, kb, dvp), kv_at),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, qb, dvp), lambda i, j, k_: (i, j, 0)),
-                pl.BlockSpec((1, qb, 1), lambda i, j, k_: (i, j, 0)),
-            ],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(hh, nq, nk),
+                in_specs=[
+                    pl.BlockSpec((qb, 1), lambda i, j, s, *_: (j, 0)),
+                    pl.BlockSpec((qb, 1), lambda i, j, s, *_: (j, 0)),
+                    pl.BlockSpec((parts, 2, kb), lambda *a: (key_at(*a), 0,
+                                                            0)),
+                    pl.BlockSpec((1, qb, dp), lambda i, j, s, *_: (i, j, 0)),
+                    pl.BlockSpec((1, wide, dp), lambda i, *a:
+                                 (i // group, key_at(i, *a), 0)),
+                    pl.BlockSpec((1, wide, dvp), lambda i, *a:
+                                 (i // group, key_at(i, *a), 0)),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, qb, dvp), lambda i, j, s, *_: (i, j, 0)),
+                    pl.BlockSpec((1, qb, 1), lambda i, j, s, *_: (i, j, 0)),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((qb, lanes), jnp.float32),   # running max
+                    pltpu.VMEM((qb, lanes), jnp.float32),   # running sum
+                    pltpu.VMEM((qb, dvp), jnp.float32),
+                ]),
             out_shape=[
                 jax.ShapeDtypeStruct((hh, tq, dvp),
                                      jnp.float32 if return_lse else q.dtype),
                 jax.ShapeDtypeStruct((hh, tq, 1), jnp.float32),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((qb, 1), jnp.float32),
-                pltpu.VMEM((qb, 1), jnp.float32),
-                pltpu.VMEM((qb, dvp), jnp.float32),
-            ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=PREFILL_VMEM_LIMIT),
             interpret=interpret,
             name=name,
-        )(q_pos.reshape(tq, 1), kv_pos.reshape(1, tk),
-          jnp.ones((1, 1, tk), jnp.float32), q_seg.reshape(1, tq, 1),
-          kv_seg.reshape(1, 1, tk), q3, k3, v3)
+        )(classes.reshape(-1), at.reshape(-1), q_pos.reshape(tq, 1),
+          q_seg.reshape(tq, 1),
+          jnp.stack([kv_pos.reshape(-1, kb), kv_seg.reshape(-1, kb)], axis=1),
+          q3, k3, v3)
     o = by_head(o3[:, :, :dv])
     return (o, by_head(lse)[..., 0]) if return_lse else o
 

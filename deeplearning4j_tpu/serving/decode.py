@@ -87,8 +87,11 @@ import jax.numpy as jnp
 
 from ..data.padding import next_pow2_bucket
 from ..ops import moe
-from ..ops.flash_attention import (latent_decode_attention, merge_attention,
-                                   paged_decode_attention, prefill_attention)
+from ..ops.flash_attention import (KEY_SKIPPED, KEY_WHOLE, block_ends,
+                                   key_classes_of_ends,
+                                   latent_decode_attention, merge_attention,
+                                   paged_decode_attention, prefill_attention,
+                                   prefill_kernel_blocks)
 from ..ops.pallas_kernels import pad_axis_to
 from ..optimize import tracing
 from ..optimize.metrics import registry
@@ -137,6 +140,14 @@ _CTX_READ_HELP = ("Cached positions the prefill chunks gathered and attended "
                   "in a layer of each kind: whole slabs, as many as the "
                   "context reaches (over ..._context_tokens_total: what a "
                   "chunk pays for what it needs)")
+_KEY_BLOCKS_HELP = ("Key blocks the prefill kernel ran for the chunks' query "
+                    "tiles in a layer, summed over the layers and over a "
+                    "chunk's parts (its own keys, each slab of its "
+                    "context), once for all heads: the blocks its table "
+                    "does not skip; 0 where a part takes the dense arm")
+_KEY_BLOCKS_WHOLE_HELP = ("Of ..._key_blocks_total, the blocks in which "
+                          "every query of the tile sees every key, so the "
+                          "kernel masks nothing in them")
 _KV_TOKENS_HELP = ("Keys the rows of the decode steps attended to in one "
                    "layer of the kind, each row's own token included")
 _BLOCK_STEPS_HELP = ("KV blocks the cache held for one layer of the kind, "
@@ -204,7 +215,12 @@ def _register_model_metrics() -> Dict[Any, Any]:
             "serving_decode_prefill_context_tokens_total", _CTX_TOKENS_HELP),
         "ctx_read": reg.counter(
             "serving_decode_prefill_context_read_tokens_total",
-            _CTX_READ_HELP)}
+            _CTX_READ_HELP),
+        "key_blocks": reg.counter(
+            "serving_decode_prefill_key_blocks_total", _KEY_BLOCKS_HELP),
+        "key_blocks_whole": reg.counter(
+            "serving_decode_prefill_key_blocks_whole_total",
+            _KEY_BLOCKS_WHOLE_HELP)}
     for key, (name, text) in _MOE_HELP.items():
         out[key] = reg.counter(name, text)
     kv = reg.counter("serving_decode_kv_tokens_total", _KV_TOKENS_HELP)
@@ -1385,6 +1401,18 @@ class TransformerAdapter:
         self._ctx_widths = {k: -(-w // slabs[k]) * slabs[k]
                             for k, w in widths.items()}
         self._slab_tokens = {k: n * bt for k, n in slabs.items()}
+        # the block sizes of the prefill kernel for a chunk's own keys
+        # and for a slab of its context, a kind (None: the dense arm),
+        # by `prefill_attention`'s own rule, and the kind's layers
+        sizes = (model.head_dim, model.v_dim if model.attention == "latent"
+                 else model.head_dim)
+        self._kernel_blocks = {k: tuple(
+            prefill_kernel_blocks(self.pack_bucket, tk, *sizes,
+                                  itemsize=model.dtype.itemsize)
+            for tk in (self.pack_bucket, self._slab_tokens[k]))
+            for k in cache.kinds}
+        self._layers_of = {k: model.layer_kinds().count(k)
+                           for k in cache.kinds}
         self._prefilling: Dict[int, int] = {}   # rid -> positions cached
         # Row slots: entry `scratch_slot` of the feed belongs to no one.
         # Twice the rows: a request whose last step is launched keeps its
@@ -1524,6 +1552,9 @@ class TransformerAdapter:
         self._count["ctx_read"].inc(sum(
             -(-(ctx_len - int(ctx_starts.get(k, 0))) // n) * n
             for k, n in self._slab_tokens.items()))
+        ran, whole = self._key_blocks(seg, ctx_len, ctx_starts)
+        self._count["key_blocks"].inc(ran)
+        self._count["key_blocks_whole"].inc(whole)
         up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last,
                      feed_slots) + 4
         with tracing.span("decode/launch", cat="serve", bytes=up):
@@ -1543,6 +1574,58 @@ class TransformerAdapter:
             (picked, finite, None)))
         fails.update(starved)
         return out, fails
+
+    def _key_blocks(self, seg, ctx_len: int, ctx_starts) -> Tuple[int, int]:
+        """Key blocks the prefill kernel runs for a chunk of segments
+        `seg` over `ctx_len` cached positions, and those of them that
+        are whole: `key_block_classes`' rule on what `_attend_chunk`
+        hands the kernel (the chunk's own keys; its context from the
+        kind's start on, whole slabs), for the parts that take the
+        kernel, times the kind's layers. A tile or block goes in as its
+        least and its greatest place and segment, which is all the rule
+        reads: host arithmetic on a few dozen ints."""
+        ends: Dict[int, Any] = {}
+
+        def chunk(n):       # the row's places and segments, n a block
+            if n not in ends:
+                first = np.arange(0, seg.size, n, dtype=np.int32)
+                ends[n] = (first, first + n - 1), block_ends(seg, n)
+            return ends[n]
+
+        none = np.zeros((0,), np.int32)
+        ran = whole = 0
+        for kind, (own, slab) in self._kernel_blocks.items():
+            start = int(ctx_starts.get(kind, 0))
+            if slab is None or ctx_len <= start:
+                slab = None
+            if own is None and slab is None:
+                continue
+            kv_pos, kv_seg = chunk(own[1]) if own is not None \
+                else ((none, none), (none, none))
+            if slab is not None:
+                n, kb = self._slab_tokens[kind], slab[1]
+                first = start + np.arange(
+                    0, -(-(ctx_len - start) // n) * n, kb, dtype=np.int32)
+                # a block's first and last position: before the row at
+                # their true distance in segment 1, or (at or past
+                # ctx_len, the last first) no key
+                there = lambda at: (
+                    np.where(at < ctx_len, at - ctx_len, 1 << 30),
+                    np.where(at < ctx_len, 1, -1))
+                (lo, seg_hi), (hi, seg_lo) = there(first), \
+                    there(first + kb - 1)
+                join = lambda a, b: np.concatenate([a, b])
+                kv_pos = join(kv_pos[0], lo), join(kv_pos[1], hi)
+                kv_seg = join(kv_seg[0], seg_lo), join(kv_seg[1], seg_hi)
+            q_pos, q_seg = chunk((own or slab)[0])
+            table = key_classes_of_ends(
+                q_pos, kv_pos, q_seg, kv_seg,
+                self.model.window if kind == "sliding" else None)
+            ran += self._layers_of[kind] * int(
+                np.count_nonzero(table != KEY_SKIPPED))
+            whole += self._layers_of[kind] * int(
+                np.count_nonzero(table == KEY_WHOLE))
+        return ran, whole
 
     def is_row(self, rid: int) -> bool:
         """Whether `rid`'s whole prompt is prefilled or launched: it

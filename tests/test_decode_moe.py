@@ -363,3 +363,187 @@ def test_parts_of_a_key_range_merged_by_lse_are_the_whole(window):
     # and in the other order, from nothing
     flipped, _ = fa.merge_attention(*none, o, lse)
     np.testing.assert_array_equal(flipped, o)
+
+
+def _context(tk, held):
+    """A slab of `tk` cached positions of which `held` lie below
+    ctx_len, as `_attend_chunk` hands them over: those before the row at
+    their true distance in segment 1, the rest no key."""
+    real = np.arange(tk) < held
+    return (np.where(real, np.arange(tk) - held, 1 << 30),
+            np.where(real, 1, -1))
+
+
+def _block_case(what):
+    """Operands at lane-multiple blocks, one case a kind of key block the
+    kernel tells apart -> (q, k, v, keywords, blocks, rows that see no
+    key)."""
+    rng = np.random.default_rng(36)
+    hh, kvh, d, dv, tq, window = 4, 2, 16, 16, 256, None
+    blocks = dict(q_block=128, kv_block=128)
+    seg = np.ones(tq, np.int64)
+    if what == "whole slab":
+        kp, ks = _context(512, 512)
+    elif what == "last slab":           # the seam at 300 cuts a key block
+        kp, ks = _context(512, 300)
+    elif what == "blind rows":          # padding and segment 2 see no slab
+        seg[150:200], seg[200:] = 2, 0
+        kp, ks = _context(512, 300)
+    elif what == "diagonal":
+        kp, ks = np.arange(tq), seg
+    elif what == "seam":                # cuts a q tile and a key block
+        seg[200:], seg[-20:] = 2, 0
+        kp, ks = np.arange(tq), seg
+    elif what in ("window 512", "window 1024"):
+        window, tq = int(what.split()[1]), 512
+        blocks = dict(q_block=256, kv_block=256)
+        seg = np.ones(tq, np.int64)
+        own = what == "window 512"      # the chunk's own keys / a context
+        kp, ks = (np.arange(tq), seg) if own else _context(1536, 1400)
+    elif what == "keys 192 values 128":
+        hh, kvh, d, dv = 2, 2, 192, 128
+        kp, ks = _context(512, 450)
+    elif what == "72 heads over 8":
+        hh, kvh, tq = 72, 8, 128
+        seg = np.ones(tq, np.int64)
+        kp, ks = np.arange(tq), seg
+    else:
+        raise ValueError(what)
+    tk = kp.size
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    k, v = arr(tk, kvh, d), arr(tk, kvh, dv)
+    if ks.min() < 0:                    # a freed block's entries are zeroed
+        k, v = (jnp.where(jnp.asarray(ks > 0)[:, None, None], a, 0)
+                for a in (k, v))
+    kw = dict(q_pos=jnp.arange(tq), q_seg=jnp.asarray(seg), window=window,
+              kv_pos=jnp.asarray(kp), kv_seg=jnp.asarray(ks))
+    seen = np.asarray(fa._visible(np.arange(tq), kp, seg, ks, window)
+                      ).any(axis=1)
+    return arr(tq, hh, d), k, v, kw, blocks, ~seen
+
+
+BLOCK_CASES = ["whole slab", "last slab", "blind rows", "diagonal", "seam",
+               "window 512", "window 1024", "keys 192 values 128",
+               "72 heads over 8"]
+
+
+@pytest.mark.parametrize("return_lse", [False, True], ids=["o", "o+lse"])
+@pytest.mark.parametrize("what", BLOCK_CASES)
+def test_the_prefill_kernel_by_class_of_key_block(what, return_lse):
+    """The flash arm against the dense arm where the key blocks are
+    skipped, whole and edge ones in turn; a row that sees no key gives
+    zeros and an lse of NEG in both."""
+    q, k, v, kw, blocks, blind = _block_case(what)
+    classes = np.asarray(fa.key_block_classes(
+        *(np.asarray(kw[n]) for n in ("q_pos", "kv_pos", "q_seg", "kv_seg")),
+        kw["window"], blocks["q_block"], blocks["kv_block"]))
+    want_classes = {"whole slab": {fa.KEY_WHOLE},
+                    "72 heads over 8": {fa.KEY_EDGE}}.get(what)
+    if want_classes:
+        assert set(classes.ravel().tolist()) == want_classes
+    elif what not in ("keys 192 values 128",):
+        assert {fa.KEY_SKIPPED, fa.KEY_EDGE} <= set(classes.ravel().tolist())
+    got = fa.prefill_attention(q, k, v, impl="flash", interpret=True,
+                               return_lse=return_lse, **blocks, **kw)
+    want = fa.prefill_attention(q, k, v, impl="dense",
+                                return_lse=return_lse, **kw)
+    if not return_lse:
+        # a blind row's result is the dense arm's even softmax over NEG
+        np.testing.assert_allclose(np.asarray(got)[~blind],
+                                   np.asarray(want)[~blind],
+                                   atol=2e-5, rtol=2e-5)
+        assert (np.asarray(got)[blind] == 0).all()
+        return
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+    assert (np.asarray(got[1])[blind] == fa.NEG).all()
+    assert (np.asarray(got[0])[blind] == 0).all()
+    assert blind.any() == (what == "blind rows")
+
+
+@pytest.mark.parametrize("what", ["last slab", "diagonal", "window 1024"])
+def test_a_key_block_of_1024_gives_what_blocks_of_256_give(what):
+    """One class for 1,024 keys against four for 256 each taken in one
+    step, over 2,048 keys: the same rows, to rounding."""
+    rng = np.random.default_rng(7)
+    tq, tk, hh, d = 256, 2048, 2, 16
+    window = 1024 if what == "window 1024" else None
+    if what == "diagonal":
+        tq = tk
+        kp, ks = np.arange(tk), np.ones(tk, np.int64)
+    else:
+        kp, ks = _context(tk, 1500 if what == "last slab" else tk)
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    q, k, v = arr(tq, hh, d), arr(tk, hh, d), arr(tk, hh, d)
+    kw = dict(q_pos=jnp.arange(tq), q_seg=jnp.ones((tq,), jnp.int32),
+              kv_pos=jnp.asarray(kp), kv_seg=jnp.asarray(ks), window=window,
+              return_lse=True, interpret=True, impl="flash", q_block=256)
+    assert fa.prefill_kernel_blocks(tq, tk, d, d, impl="flash", q_block=256,
+                                    kv_block=256)[2] == 8
+    one = fa.prefill_attention(q, k, v, kv_block=1024, **kw)
+    four = fa.prefill_attention(q, k, v, kv_block=256, **kw)
+    want = fa.prefill_attention(q, k, v, **dict(kw, impl="dense"))
+    for a, b, c in zip(one, four, want):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(b, c, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 192])
+def test_kernel_parts_joined_by_lse_are_the_whole(window):
+    """A chunk's own keys and two slabs of its context, each through the
+    kernel, joined as `_attend_chunk` joins them, against the dense arm
+    over all the keys at once."""
+    rng = np.random.default_rng(11)
+    tq, n, held, hh, kvh, d = 256, 256, 400, 4, 2, 16
+    seg = np.where(np.arange(tq) < 180, 1, 2)
+    kp, ks = _context(2 * n, held)
+    kv_pos, kv_seg = np.concatenate([kp, np.arange(tq)]), \
+        np.concatenate([ks, seg])
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    q, k, v = arr(tq, hh, d), arr(2 * n + tq, kvh, d), arr(2 * n + tq, kvh, d)
+    kw = dict(q_pos=jnp.arange(tq), q_seg=jnp.asarray(seg), window=window)
+    part = lambda lo, hi: fa.prefill_attention(
+        q, k[lo:hi], v[lo:hi], impl="flash", interpret=True, q_block=128,
+        kv_block=128, return_lse=True, kv_pos=jnp.asarray(kv_pos[lo:hi]),
+        kv_seg=jnp.asarray(kv_seg[lo:hi]), **kw)
+    o, _ = fa.merge_attention(*fa.merge_attention(
+        *part(2 * n, 2 * n + tq), *part(0, n)), *part(n, 2 * n))
+    whole = fa.prefill_attention(q, k, v, impl="dense",
+                                 kv_pos=jnp.asarray(kv_pos),
+                                 kv_seg=jnp.asarray(kv_seg), **kw)
+    np.testing.assert_allclose(o, whole, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 5, 40])
+@pytest.mark.parametrize("keys", ["own", "context", "anything"])
+def test_key_block_classes_against_every_pair(keys, window):
+    """skipped => no pair of the block is visible, whole => every pair
+    is; NumPy's and jax's arrays give the same table."""
+    rng = np.random.default_rng(len(keys) + (window or 0))
+    tq, qb, kb = 64, 16, 8
+    q_seg = np.searchsorted([32, 50], np.arange(tq), side="right") + 1
+    q_seg[-5:] = 0
+    q_pos = np.arange(tq)
+    if keys == "own":
+        kv_pos, kv_seg = q_pos, q_seg
+    elif keys == "context":
+        kv_pos, kv_seg = _context(96, 53)
+    else:                       # ids and places in no order at all
+        kv_pos = rng.integers(-40, 80, 96)
+        kv_seg = rng.integers(-1, 4, 96)
+        q_pos, q_seg = rng.integers(0, 64, tq), rng.integers(0, 4, tq)
+    args = (q_pos, kv_pos, q_seg, kv_seg)
+    table = fa.key_block_classes(*args, window, qb, kb)
+    assert isinstance(table, np.ndarray) and table.dtype == np.int32
+    assert table.shape == (tq // qb, kv_pos.size // kb)
+    on_jax = fa.key_block_classes(*map(jnp.asarray, args), window, qb, kb)
+    np.testing.assert_array_equal(table, np.asarray(on_jax))
+    ok = np.asarray(fa._visible(*args, window))
+    tiles = ok.reshape(tq // qb, qb, -1, kb)
+    some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    assert not some[table == fa.KEY_SKIPPED].any()
+    assert every[table == fa.KEY_WHOLE].all()
+    if keys != "anything":      # and on a chunk's operands it is exact
+        np.testing.assert_array_equal(table == fa.KEY_WHOLE, every)
+        assert set(table.ravel().tolist()) == {0, 1, 2} or window == 5

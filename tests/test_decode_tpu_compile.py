@@ -161,15 +161,20 @@ def _shapes(setting):
     return m, params, cache
 
 
-def _host_operands(setting, m, which):
-    """What the adapter hands the executable, from a cache of one block
-    a kind (the host's side is the same at any arena size)."""
+def _adapter(setting, m):
+    """The adapter over a cache of one block a kind (the host's side is
+    the same at any arena size). -> (adapter, that cache)."""
     tiny = PagedKVCache(layers=m.n_layers, heads=1, head_dim=1,
                         layer_kinds=m.layer_kinds(), window=m.window,
                         block_tokens=setting["cache"]["block_tokens"],
                         max_blocks=1)
-    ad = TransformerAdapter(m, tiny, pack_bucket=setting["pack"],
-                            max_rows=setting["rows"])
+    return TransformerAdapter(m, tiny, pack_bucket=setting["pack"],
+                              max_rows=setting["rows"]), tiny
+
+
+def _host_operands(setting, m, which):
+    """What the adapter hands the executable."""
+    ad, tiny = _adapter(setting, m)
     i32 = lambda *shape: np.zeros(shape, np.int32)
     if which == "step":
         tables, starts, lens, _ = tiny.batch_view((), setting["kv"],
@@ -214,3 +219,122 @@ def test_the_arenas_are_updated_where_they_lie(compiled, setting, which):
         layouts = set(re.findall(r"%s\[%s\]\{([\d,]+)" % (name, dims), text))
         assert layouts == {"3,2,1,0"}, f"an arena is re-laid out: {layouts}"
     assert text.count("tpu_custom_call") == setting[which + "_kernels"]
+
+
+KERNEL_SHAPES = {
+    # heads, KV heads, key and value head size, window; each as a chunk
+    # of 2,048 runs it: over its own keys and over a slab of its context
+    "latent 192/128": (32, 32, 192, 128, None, (2048, 4096)),
+    "mellum full": (32, 4, 128, 128, None, (2048, 4096)),
+    "mellum sliding": (32, 4, 128, 128, 1024, (2048, 1280)),
+    "laguna full 48 over 8": (48, 8, 128, 128, None, (2048, 4096)),
+    "laguna sliding 72 over 8": (72, 8, 128, 128, 512, (2048,)),
+}
+
+
+@pytest.mark.parametrize("shape", list(KERNEL_SHAPES))
+def test_mosaic_takes_the_prefill_kernel_inside_its_fast_memory(compiled,
+                                                                shape):
+    """The kernel with its three bodies at the block sizes the wrapper
+    picks from the shapes, compiled under PREFILL_VMEM_LIMIT (Mosaic
+    refuses a kernel that needs more than it is compiled with), and
+    what the wrapper plans for those blocks lies under that limit."""
+    hh, kvh, d, dv, window, keys = KERNEL_SHAPES[shape]
+    tq = 2048
+    for tk in keys:
+        qb, kb, parts = fa.prefill_kernel_blocks(tq, tk, d, dv)
+        # 2,048 keys a step: two blocks of 1,024, or a window's 1,280 whole
+        assert (qb, kb, parts) == ((512, 1024, 2) if tk % 1024 == 0
+                                   else (512, 256, 5))
+        pad = lambda n: -(-n // 128) * 128
+        planned = fa._prefill_vmem_bytes(qb, kb * parts, pad(d), pad(dv), 2,
+                                         4)
+        assert planned <= fa._PREFILL_VMEM_PLAN < fa.PREFILL_VMEM_LIMIT
+        i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)
+        bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        exe = compiled(
+            lambda q, k, v, qp, kp, qs, ks: fa.prefill_attention(
+                q, k, v, q_pos=qp, kv_pos=kp, q_seg=qs, kv_seg=ks,
+                window=window, heads_first=True, return_lse=True,
+                name="prefill_attention_probe"),
+            (), bf(hh, tq, d), bf(kvh, tk, d), bf(kvh, tk, dv), i32(tq),
+            i32(tk), i32(tq), i32(tk))
+        text = exe.as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert "prefill_attention_probe" in text
+        assert str(fa.PREFILL_VMEM_LIMIT) in text, \
+            "the kernel is not compiled under PREFILL_VMEM_LIMIT"
+
+
+@pytest.mark.parametrize("setting,named", [
+    (SPARSE, ("prefill_attention_full", "prefill_attention_sliding")),
+    (LATENT, ("prefill_attention_latent",)),
+    (LAGUNA, ("prefill_attention_full", "prefill_attention_sliding"))],
+    ids=["sparse", "latent", "heads-by-kind"])
+def test_the_chunk_holds_its_kernels_under_their_names(compiled, setting,
+                                                       named):
+    """The readers of `kernels.prefill_attention_*_device_ms` find each
+    kernel by the name its call carries inside `_prefill_pure`."""
+    m, params, arenas = _shapes(setting)
+    ops = _host_operands(setting, m, "prefill")
+    text = compiled(m._prefill_pure, (4, 10), params, *ops[:3], arenas,
+                    *ops[3:]).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name in named:
+        assert any(name in ln for ln in calls), name
+
+
+@pytest.mark.parametrize("setting", [SPARSE, LATENT, LAGUNA, DENSE],
+                         ids=["sparse", "latent", "heads-by-kind", "dense"])
+def test_the_key_block_counters_count_what_the_kernel_is_handed(
+        monkeypatch, setting):
+    """`TransformerAdapter._key_blocks` (host arithmetic on block ends)
+    against `key_block_classes` on the arrays `_attend_chunk` builds,
+    position by position, for the parts that take the kernel."""
+    monkeypatch.setattr(fa, "flash_attention_available", lambda: True)
+    m = TransformerDecoder(params={}, **setting["model"])
+    ad, tiny = _adapter(setting, m)
+    pb = ad.pack_bucket
+    if setting is DENSE:        # prompts under the kernel's floor of keys
+        assert ad._key_blocks(np.ones(pb, np.int32), 0, {}) == (0, 0)
+        return
+    line = np.arange(pb, dtype=np.int32)
+    for seg, ctx_len, start in (
+            (np.ones(pb, np.int32), 0, 0),
+            (np.ones(pb, np.int32), 4096, 0),           # whole slabs
+            (np.ones(pb, np.int32), 6000, 4864),        # a partly real one
+            (np.where(line < 700, 1, np.where(line < 1900, 2, 0)), 0, 0),
+            (np.where(line < 1234, 1, 0).astype(np.int32), 2048, 1024)):
+        starts = {k: np.int32(start) for k in tiny.kinds if k == "sliding"}
+        ran = whole = 0
+        for li in range(m.n_layers):
+            kind = m.kind_of(li)
+            window = m.window if kind == "sliding" else None
+            n, first = ad._slab_tokens[kind], int(starts.get(kind, 0))
+            true = first + np.arange(max(0, -(-(ctx_len - first) // n)) * n)
+            real = true < ctx_len
+            parts = [(line, seg)]
+            if true.size:
+                parts.append((np.where(real, true - ctx_len, 1 << 30),
+                              np.where(real, 1, -1)))
+            for kv_pos, kv_seg in parts:
+                blocks = fa.prefill_kernel_blocks(
+                    pb, min(kv_pos.size, n) if kv_pos is not line else pb,
+                    m.head_dim, m.v_dim if kind == "latent" else m.head_dim)
+                if blocks is None:
+                    continue
+                table = fa.key_block_classes(line, kv_pos, seg, kv_seg,
+                                             window, *blocks[:2])
+                ran += int((table != fa.KEY_SKIPPED).sum())
+                whole += int((table == fa.KEY_WHOLE).sum())
+        assert ad._key_blocks(seg, ctx_len, starts) == (ran, whole)
+        # a chunk of one segment has whole blocks under its diagonal, a
+        # packed one may have none
+        assert whole < ran and (whole > 0 or seg.min() != 1)
+
+
+def test_off_a_tpu_no_part_takes_the_kernel_and_the_counters_stay_0():
+    m = TransformerDecoder(params={}, **LATENT["model"])
+    ad, _ = _adapter(LATENT, m)
+    assert ad._kernel_blocks == {"latent": (None, None)}
+    assert ad._key_blocks(np.ones(2048, np.int32), 8192, {}) == (0, 0)
