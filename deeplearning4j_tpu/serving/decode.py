@@ -148,6 +148,17 @@ _KEY_BLOCKS_HELP = ("Key blocks the prefill kernel ran for the chunks' query "
 _KEY_BLOCKS_WHOLE_HELP = ("Of ..._key_blocks_total, the blocks in which "
                           "every query of the tile sees every key, so the "
                           "kernel masks nothing in them")
+_PAIRS_HELP = ("Query-key pairs of the prefill chunks that the masks leave "
+               "visible in the layers of the kind, summed over those layers "
+               "(a head's: all heads see the same), by the arm that computes "
+               "them: `kernel` where a part takes the prefill kernel, "
+               "`dense` elsewhere")
+_PAIRS_RUN_HELP = ("Query-key pairs inside the tiles and key blocks that the "
+                   "prefill kernel runs in the layers of the kind, visible "
+                   "or not (over ..._pairs_total{arm=kernel}: the kernel's "
+                   "useful share); 0 where a part takes the dense arm")
+# the arms a chunk's attention part takes (`prefill_kernel_blocks`)
+_ARMS = ("kernel", "dense")
 _KV_TOKENS_HELP = ("Keys the rows of the decode steps attended to in one "
                    "layer of the kind, each row's own token included")
 _BLOCK_STEPS_HELP = ("KV blocks the cache held for one layer of the kind, "
@@ -225,9 +236,15 @@ def _register_model_metrics() -> Dict[Any, Any]:
         out[key] = reg.counter(name, text)
     kv = reg.counter("serving_decode_kv_tokens_total", _KV_TOKENS_HELP)
     blocks = reg.counter("serving_kv_block_steps_total", _BLOCK_STEPS_HELP)
+    pairs = reg.counter("serving_decode_prefill_pairs_total", _PAIRS_HELP)
+    pairs_run = reg.counter("serving_decode_prefill_pairs_run_total",
+                            _PAIRS_RUN_HELP)
     for k in KINDS:
         out["kv_tokens", k] = kv.labels(kind=k)
         out["block_steps", k] = blocks.labels(kind=k)
+        out["pairs_run", k] = pairs_run.labels(kind=k)
+        for arm in _ARMS:
+            out["pairs", k, arm] = pairs.labels(kind=k, arm=arm)
     return out
 
 
@@ -1325,13 +1342,32 @@ def naive_generate(model: TransformerDecoder, prompt: Sequence[int],
 
 class _Launched(NamedTuple):
     """One step or prefill chunk on the device whose outputs the host
-    has not fetched: which it is, its place among the adapter's step
-    launches (0 for a chunk), which request's token is at which index,
-    and the device's ``(picked, finite, sums)``."""
+    has not fetched: which it is, its place among the adapter's
+    launches (steps and chunks), which request's token is at which
+    index, and the device's ``(picked, finite, sums)``."""
     phase: str
     seq: int
     rows: List[Tuple[int, int]]
     outputs: tuple
+
+
+class _ChunkWork(NamedTuple):
+    """A chunk's attention work, each kind's times its layers (all heads
+    alike: a head's): the key blocks the prefill kernel runs and those of
+    them that are whole, the visible query-key pairs by ``(kind, arm)``
+    and the pairs inside the blocks the kernel runs, by kind."""
+    blocks: int
+    whole: int
+    pairs: Dict[Tuple[str, str], int]
+    pairs_run: Dict[str, int]
+
+
+def _seen(n: int, window: Optional[int]) -> int:
+    """Keys the queries at places 0..n-1 of one causal line see in all,
+    the query at place p seeing min(p + 1, window)."""
+    if window is None or n <= window:
+        return n * (n + 1) // 2
+    return window * (window + 1) // 2 + (n - window) * window
 
 
 class TransformerAdapter:
@@ -1423,7 +1459,7 @@ class TransformerAdapter:
         self._slot_of: Dict[int, int] = {}
         self._free_slots = list(range(self.scratch_slot - 1, -1, -1))
         self._in_flight: Optional[_Launched] = None
-        self._launches = 0      # steps launched; `seq` of the spans
+        self._launches = 0      # steps and chunks launched: `seq`
         # what the routers of a step assign a row: every sparse layer's
         self._routed_a_row = model.top_k * sum(
             model.mlp_of(li) == "moe" for li in range(model.n_layers))
@@ -1552,16 +1588,25 @@ class TransformerAdapter:
         self._count["ctx_read"].inc(sum(
             -(-(ctx_len - int(ctx_starts.get(k, 0))) // n) * n
             for k, n in self._slab_tokens.items()))
-        ran, whole = self._key_blocks(seg, ctx_len, ctx_starts)
-        self._count["key_blocks"].inc(ran)
-        self._count["key_blocks_whole"].inc(whole)
+        work = self._chunk_work(seg, ctx_len, ctx_starts)
+        self._count["key_blocks"].inc(work.blocks)
+        self._count["key_blocks_whole"].inc(work.whole)
+        pairs: Dict[str, int] = {}      # by kind, both arms
+        for (kind, arm), n in work.pairs.items():
+            self._count["pairs", kind, arm].inc(n)
+            pairs[kind] = pairs.get(kind, 0) + n
+        for kind, n in work.pairs_run.items():
+            self._count["pairs_run", kind].inc(n)
         up = _nbytes(row, seg, pos, slots, ctx_tables, ctx_starts, last,
                      feed_slots) + 4
-        with tracing.span("decode/launch", cat="serve", bytes=up):
+        seq = self._launches + 1
+        with tracing.span("decode/launch", cat="serve", bytes=up, seq=seq,
+                          pairs=pairs, pairs_run=work.pairs_run):
             picked, finite, self._feed = cache.update(
                 lambda a: self.model.prefill(
                     row, seg, pos, a, slots, ctx_tables, ctx_starts,
                     ctx_len, last, self._feed, feed_slots))
+        self._launches = seq
         self._link["h2d", "prefill"].inc(up)
         for rid, n_tok, i in placed:
             cache.advance(rid, n_tok)
@@ -1570,20 +1615,30 @@ class TransformerAdapter:
             else:
                 self._prefilling[rid] = cache.length(rid)
         out, fails = self._launched(_Launched(
-            "prefill", 0, [(i, rid) for rid, _, i in placed if i >= 0],
+            "prefill", seq, [(i, rid) for rid, _, i in placed if i >= 0],
             (picked, finite, None)))
         fails.update(starved)
         return out, fails
 
-    def _key_blocks(self, seg, ctx_len: int, ctx_starts) -> Tuple[int, int]:
-        """Key blocks the prefill kernel runs for a chunk of segments
-        `seg` over `ctx_len` cached positions, and those of them that
-        are whole: `key_block_classes`' rule on what `_attend_chunk`
+    def _chunk_work(self, seg, ctx_len: int, ctx_starts) -> _ChunkWork:
+        """What a chunk of segments `seg` over `ctx_len` cached positions
+        asks of attention, times each kind's layers: host arithmetic, no
+        mask over positions is built.
+
+        Visible pairs in closed form, a segment and a part at a time: a
+        query at place p of a segment sees its segment's keys at places
+        <= p, and the one slice that reads the cache (segment 1) its
+        cached positions from the kind's start on; under a window only
+        keys less than `window` behind it; padding sees nothing. Each
+        part goes under the arm `prefill_kernel_blocks` gives it.
+
+        Key blocks: `key_block_classes`' rule on what `_attend_chunk`
         hands the kernel (the chunk's own keys; its context from the
         kind's start on, whole slabs), for the parts that take the
-        kernel, times the kind's layers. A tile or block goes in as its
-        least and its greatest place and segment, which is all the rule
-        reads: host arithmetic on a few dozen ints."""
+        kernel. A tile or block goes in as its least and its greatest
+        place and segment, which is all the rule reads; the blocks it
+        runs (not skipped) times the tile and the block are the pairs
+        the kernel computes."""
         ends: Dict[int, Any] = {}
 
         def chunk(n):       # the row's places and segments, n a block
@@ -1592,20 +1647,37 @@ class TransformerAdapter:
                 ends[n] = (first, first + n - 1), block_ends(seg, n)
             return ends[n]
 
+        sizes = np.bincount(seg)[1:].tolist()   # padding is segment 0
         none = np.zeros((0,), np.int32)
-        ran = whole = 0
+        blocks = whole = 0
+        pairs: Dict[Tuple[str, str], int] = {}
+        pairs_run: Dict[str, int] = {}
         for kind, (own, slab) in self._kernel_blocks.items():
+            layers = self._layers_of[kind]
+            window = self.model.window if kind == "sliding" else None
             start = int(ctx_starts.get(kind, 0))
-            if slab is None or ctx_len <= start:
+            behind = max(0, ctx_len - start)
+            # each segment over its own keys; segment 1, whose queries
+            # stand at ctx_len + i, over its context too
+            parts = [(own, sum(_seen(n, window) for n in sizes))]
+            if behind:
+                parts.append((slab, _seen(behind + sizes[0], window)
+                              - _seen(behind, window)
+                              - _seen(sizes[0], window)))
+            else:
                 slab = None
+            for tiling, n in parts:
+                key = kind, "dense" if tiling is None else "kernel"
+                pairs[key] = pairs.get(key, 0) + layers * n
             if own is None and slab is None:
                 continue
             kv_pos, kv_seg = chunk(own[1]) if own is not None \
                 else ((none, none), (none, none))
+            n_own = kv_pos[0].size
             if slab is not None:
                 n, kb = self._slab_tokens[kind], slab[1]
                 first = start + np.arange(
-                    0, -(-(ctx_len - start) // n) * n, kb, dtype=np.int32)
+                    0, -(-behind // n) * n, kb, dtype=np.int32)
                 # a block's first and last position: before the row at
                 # their true distance in segment 1, or (at or past
                 # ctx_len, the last first) no key
@@ -1617,15 +1689,19 @@ class TransformerAdapter:
                 join = lambda a, b: np.concatenate([a, b])
                 kv_pos = join(kv_pos[0], lo), join(kv_pos[1], hi)
                 kv_seg = join(kv_seg[0], seg_lo), join(kv_seg[1], seg_hi)
-            q_pos, q_seg = chunk((own or slab)[0])
-            table = key_classes_of_ends(
-                q_pos, kv_pos, q_seg, kv_seg,
-                self.model.window if kind == "sliding" else None)
-            ran += self._layers_of[kind] * int(
-                np.count_nonzero(table != KEY_SKIPPED))
-            whole += self._layers_of[kind] * int(
-                np.count_nonzero(table == KEY_WHOLE))
-        return ran, whole
+            qb = (own or slab)[0]
+            q_pos, q_seg = chunk(qb)
+            table = key_classes_of_ends(q_pos, kv_pos, q_seg, kv_seg,
+                                        window)
+            ran = table != KEY_SKIPPED
+            ran_own = int(np.count_nonzero(ran[:, :n_own]))
+            ran_ctx = int(np.count_nonzero(ran)) - ran_own
+            blocks += layers * (ran_own + ran_ctx)
+            whole += layers * int(np.count_nonzero(table == KEY_WHOLE))
+            pairs_run[kind] = layers * qb * (
+                ran_own * (own[1] if own else 0)
+                + ran_ctx * (slab[1] if slab else 0))
+        return _ChunkWork(blocks, whole, pairs, pairs_run)
 
     def is_row(self, rid: int) -> bool:
         """Whether `rid`'s whole prompt is prefilled or launched: it
@@ -1728,10 +1804,9 @@ class TransformerAdapter:
         fails: Dict[int, BaseException] = {}
         if work is None:
             return out, fails
-        seq = {"seq": work.seq} if work.seq else {}
         rows = [(i, rid) for i, rid in work.rows if rid in self._slot_of]
         try:
-            with tracing.span("decode/fetch", cat="serve", **seq):
+            with tracing.span("decode/fetch", cat="serve", seq=work.seq):
                 picked, finite, sums = jax.device_get(work.outputs)
                 down = _nbytes(picked, finite, sums)
                 tracing.annotate(bytes=down)
@@ -1743,7 +1818,7 @@ class TransformerAdapter:
             for name, v in zip(("assignments", "touched", "peak"),
                                sums.tolist()):  # jaxlint: disable=JL102
                 self._count[name].inc(v)
-        with tracing.span("decode/commit", cat="serve", **seq):
+        with tracing.span("decode/commit", cat="serve", seq=work.seq):
             for i, rid in rows:
                 if self.check_finite and not finite[i]:
                     fails[rid] = NonFiniteOutputError(

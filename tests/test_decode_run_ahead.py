@@ -191,7 +191,7 @@ def test_a_stream_prompt_that_prefills_non_finite_fails_alone():
 def test_a_launch_begins_before_the_fetch_of_the_step_before_it_ends():
     """From the program's own spans, and the counter counts it: a lone
     request's steps are all launched ahead, the first of its own chunk's
-    fetch."""
+    fetch. The chunk is numbered like a step: it is launch 1."""
     tracing.enable(ring_size=1 << 12, fence_every=0)
     try:
         with _engine(name="spans-ahead") as eng:
@@ -205,17 +205,19 @@ def test_a_launch_begins_before_the_fetch_of_the_step_before_it_ends():
         tracing.clear()
     by = {name: {e["args"]["seq"]: e for e in ev if e["name"] == name}
           for name in ("decode/launch", "decode/fetch", "decode/commit")}
+    # the chunk, then 8 steps; the chunk's fetch lies under the first step
     assert sorted(by["decode/launch"]) == sorted(by["decode/fetch"]) == \
-        sorted(by["decode/commit"]) == list(range(1, 9))    # 8 steps
-    for seq in range(1, 8):
+        sorted(by["decode/commit"]) == list(range(1, 10))
+    for seq in range(1, 9):
         nxt, fetch = by["decode/launch"][seq + 1], by["decode/fetch"][seq]
         assert nxt["ts"] + nxt["dur"] <= fetch["ts"] + 1.0   # microseconds
     assert ahead == 8
-    chunk = [e for e in tracing_events if e["name"] == "decode/fetch"
-             and "seq" not in e["args"]]
-    assert len(chunk) == 1      # the chunk's, under the first step
-    first = by["decode/launch"][1]
-    assert first["ts"] + first["dur"] <= chunk[0]["ts"] + 1.0
+    # what the chunk computed: 5 x 6 / 2 visible pairs in each of the two
+    # full layers, and on a CPU no part takes the kernel
+    chunk = by["decode/launch"][1]["args"]
+    assert chunk["pairs"] == {"full": 30} and chunk["pairs_run"] == {}
+    assert all("pairs" not in by["decode/launch"][seq]["args"]
+               for seq in range(2, 10))
 
 
 def test_a_newcomer_takes_the_row_once_its_holders_last_step_is_launched():
@@ -250,8 +252,9 @@ def test_a_newcomer_takes_the_row_once_its_holders_last_step_is_launched():
     prefill = [e for e in ev if e["name"] == "decode/prefill"]
     assert len(prefill) == 2 and prefill[1]["args"]["rids"] == second
     # the newcomer's chunk was launched before the first request's last
-    # step (the third launch) was fetched: under the chunk's own span
-    assert fetch[3]["args"]["parent_id"] == prefill[1]["args"]["span_id"]
+    # step (the fourth launch, after its chunk) was fetched: under the
+    # chunk's own span
+    assert fetch[4]["args"]["parent_id"] == prefill[1]["args"]["span_id"]
 
 
 def _poison(adapter, call, row):
@@ -652,10 +655,10 @@ def test_a_half_open_probe_is_judged_by_its_token_not_by_its_launch(how):
             target=lambda: replies.append(_reply(eng, ([5, 6, 7, 8], 6))))
         probe.start()
         limit = time.monotonic() + 60
-        while eng.adapter._launches == steps and time.monotonic() < limit:
+        while eng.adapter._launches < steps + 2 and time.monotonic() < limit:
             time.sleep(0.002)
         # its chunk and its first step are up; the chunk's fetch waits
-        assert eng.adapter._launches == steps + 1 and eng.adapter.in_flight()
+        assert eng.adapter._launches == steps + 2 and eng.adapter.in_flight()
         time.sleep(0.05)
         assert br.state == HALF_OPEN and closed.value() == before
         gate.set()

@@ -288,7 +288,7 @@ def test_the_chunk_holds_its_kernels_under_their_names(compiled, setting,
                          ids=["sparse", "latent", "heads-by-kind", "dense"])
 def test_the_key_block_counters_count_what_the_kernel_is_handed(
         monkeypatch, setting):
-    """`TransformerAdapter._key_blocks` (host arithmetic on block ends)
+    """`TransformerAdapter._chunk_work` (host arithmetic on block ends)
     against `key_block_classes` on the arrays `_attend_chunk` builds,
     position by position, for the parts that take the kernel."""
     monkeypatch.setattr(fa, "flash_attention_available", lambda: True)
@@ -296,7 +296,7 @@ def test_the_key_block_counters_count_what_the_kernel_is_handed(
     ad, tiny = _adapter(setting, m)
     pb = ad.pack_bucket
     if setting is DENSE:        # prompts under the kernel's floor of keys
-        assert ad._key_blocks(np.ones(pb, np.int32), 0, {}) == (0, 0)
+        assert ad._chunk_work(np.ones(pb, np.int32), 0, {})[:2] == (0, 0)
         return
     line = np.arange(pb, dtype=np.int32)
     for seg, ctx_len, start in (
@@ -327,7 +327,7 @@ def test_the_key_block_counters_count_what_the_kernel_is_handed(
                                              window, *blocks[:2])
                 ran += int((table != fa.KEY_SKIPPED).sum())
                 whole += int((table == fa.KEY_WHOLE).sum())
-        assert ad._key_blocks(seg, ctx_len, starts) == (ran, whole)
+        assert ad._chunk_work(seg, ctx_len, starts)[:2] == (ran, whole)
         # a chunk of one segment has whole blocks under its diagonal, a
         # packed one may have none
         assert whole < ran and (whole > 0 or seg.min() != 1)
@@ -337,4 +337,4 @@ def test_off_a_tpu_no_part_takes_the_kernel_and_the_counters_stay_0():
     m = TransformerDecoder(params={}, **LATENT["model"])
     ad, _ = _adapter(LATENT, m)
     assert ad._kernel_blocks == {"latent": (None, None)}
-    assert ad._key_blocks(np.ones(2048, np.int32), 8192, {}) == (0, 0)
+    assert ad._chunk_work(np.ones(2048, np.int32), 8192, {})[:2] == (0, 0)

@@ -243,8 +243,8 @@ def test_a_step_span_launches_its_step_and_fetches_the_work_before(served):
     """Each `decode/step` gathers and launches its own step and then
     fetches and commits what was launched before it (a step, or the
     chunk that admitted its rows), while its own runs; a step nothing
-    was launched after is fetched alone. Every step is fetched and
-    committed once, under its `seq`."""
+    was launched after is fetched alone. Every step and chunk is fetched
+    and committed once, under its `seq`."""
     ev = served[0]["events"]
     steps = _named(ev, "decode/step")
     assert [s["args"]["step"] for s in steps] == \
@@ -282,17 +282,20 @@ def test_a_step_span_launches_its_step_and_fetches_the_work_before(served):
                 kids[3]["args"].get("seq", seq - 1) == seq - 1
             assert kids[1]["ts"] < kids[2]["ts"] + kids[2]["dur"]
             overlapped += 1
-    assert sorted(rows_of) == list(range(1, len(steps) + 1))
-    fetches = _named(ev, "decode/fetch")
-    by_seq = {f["args"]["seq"]: f for f in fetches if "seq" in f["args"]}
-    assert sorted(by_seq) == sorted(rows_of)        # once each
-    assert sorted(c["args"]["seq"] for c in _named(ev, "decode/commit")
-                  if "seq" in c["args"]) == sorted(rows_of)
-    launches = {e["args"]["seq"]: e for e in _named(ev, "decode/launch")
-                if "seq" in e["args"]}
+    # steps and chunks are numbered by one count, in launch order; a
+    # chunk's launch carries the pairs it computes
+    launches = {e["args"]["seq"]: e for e in _named(ev, "decode/launch")}
+    assert sorted(launches) == list(range(1, len(launches) + 1))
+    chunks = set(launches) - set(rows_of)
+    assert len(chunks) == len(_named(ev, "decode/prefill"))
+    assert all(launches[c]["args"]["pairs"] for c in chunks)
+    by_seq = {f["args"]["seq"]: f for f in _named(ev, "decode/fetch")}
+    assert sorted(by_seq) == sorted(launches)        # once each
+    assert sorted(c["args"]["seq"] for c in _named(ev, "decode/commit")) \
+        == sorted(launches)
     for seq, f in by_seq.items():
         # fetch: an int32 token and a flag a row of the step it fetches
-        assert f["args"]["bytes"] == rows_of[seq] * 5
+        assert seq in chunks or f["args"]["bytes"] == rows_of[seq] * 5
         assert f["ts"] >= launches[seq]["ts"] + launches[seq]["dur"] - 1.0
     # a request alone runs ahead from its first step (launched before its
     # chunk is fetched) to its last
