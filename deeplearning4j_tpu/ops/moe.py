@@ -11,12 +11,30 @@ The product is over the experts held here only. Tokens are grouped by
 expert with a stable sort, with no capacity, so no token is ever
 dropped, and multiplied by a grouped product that reads an expert's
 weights only if the expert has tokens: on a TPU jax's own Pallas
-kernel for it (`megablox.gmm`, `pallas_call` name `gmm`; on a v5e it
-runs a decode step's 256 rows at 87% of the memory's rate and a chunk's
-16,384 at 3.6 times the speed of the compiler's `ragged_dot`: chip
-runs, PR 29), elsewhere `jax.lax.ragged_dot`. What the absent experts would
-have added is left out: the caller's exchange adds it, where there is
-one. On one chip that holds all experts the result is the whole layer.
+kernel for it (`megablox.gmm`, `pallas_call` name `gmm`), elsewhere
+`jax.lax.ragged_dot`. What the absent experts would have added is left
+out: the caller's exchange adds it, where there is one. On one chip
+that holds all experts the result is the whole layer.
+
+The rows are sorted end to end by expert, and gmm is tiled by the
+call's static shape alone, the rows a held expert has on average
+(`m / held`, m = tokens x experts a token):
+  * a decode step's few (the served steps have 1.5-10): `(tm <= 256,
+    <= 1,024, <= 1,024)`; at 256 rows a step it reads the experts at
+    76-87% of the memory's rate;
+  * a prefill chunk's many (96-320 in the served chunks): gmm's grid
+    runs its contraction innermost, so with the contraction split in
+    tiles an expert's weights crossed from memory again on every row
+    tile it ran. A chunk's contraction is whole (`tk = k`, `tn` as wide
+    as gmm's 16 MiB of fast memory allows double-buffered), so a
+    group's consecutive row tiles keep one weight block and its weights
+    cross once a call, in row tiles of 128. On a v5e that is 7% off a
+    Mellum layer's `expert_ffn` at 2,048 x 8 of 64 (4.51 -> 4.18 ms).
+    Laying each expert's rows out in whole tiles of their own, so that
+    no tile runs for two experts, was slower at every served chunk
+    shape (4.76 ms there): the scatter, the padded gather and the
+    elementwise pass over the padded length cost more than the tiles
+    it saved (chip runs, PR 38).
 """
 from __future__ import annotations
 
@@ -30,6 +48,10 @@ import jax.numpy as jnp
 __traced__ = ("route", "expert_ffn")
 
 _LANE = 128
+# a call is a chunk's where a held expert has this many rows on average
+CHUNK_ROWS_PER_EXPERT = 32
+# gmm's `pallas_call` passes no limit: Mosaic's scoped default on a v5e
+GMM_VMEM_BYTES = 16 << 20
 
 
 def grouped_kernel_available() -> bool:
@@ -37,26 +59,72 @@ def grouped_kernel_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _tiles(dim: int, cap: int = 1024) -> list:
+    """The lane multiples that divide `dim` and are at most `cap`,
+    largest first; `[dim]` where there is none."""
+    return [t for t in range(min(dim, cap) // _LANE * _LANE, 0, -_LANE)
+            if dim % t == 0] or [dim]
+
+
 def _tile(dim: int, cap: int = 1024) -> int:
     """The largest lane multiple that divides `dim` and is at most
     `cap`; `dim` itself where there is none."""
-    best = [t for t in range(_LANE, min(dim, cap) + 1, _LANE)
-            if dim % t == 0]
-    return best[-1] if best else dim
+    return _tiles(dim, cap)[0]
 
 
-def grouped_dot(a, w, sizes):
+def chunk_form(m: int, held: int) -> bool:
+    """Whether a product of `m` rows over `held` experts takes the
+    chunk's form: the rows a held expert has on average."""
+    return m >= CHUNK_ROWS_PER_EXPERT * held
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """gmm's fast memory at a tiling: its row, weight and float32 output
+    blocks, each double-buffered, and its float32 accumulator."""
+    return 2 * (tm * tk * itemsize + tk * tn * itemsize + tm * tn * 4) \
+        + tm * tn * 4
+
+
+def grouped_tiling(m: int, held: int, k: int, n: int,
+                   itemsize: int = 2) -> Tuple[int, int, int]:
+    """gmm's `(tm, tk, tn)` for `m` rows of width `k` over `held` experts
+    of [k, n]; tm 0 where no row tile divides `m` (`ragged_dot` then,
+    on any backend). A step: the largest row tile of 256 or less that
+    divides m, then `k` and `n` in lane tiles of 1,024 or less. A chunk
+    (:func:`chunk_form`): the largest row tile of 128 or less, the whole
+    `k` and the widest `n` tile whose blocks fit GMM_VMEM_BYTES, or the
+    step's `k` and `n` tiles where none does."""
+    chunk = chunk_form(m, held)
+    tm = next((t for t in (256, 128, 64, 32, 16, 8)
+               if m % t == 0 and not (chunk and t > 128)), 0)
+    for tn in _tiles(n) if chunk and tm else ():
+        if gmm_vmem_bytes(tm, k, tn, itemsize) <= GMM_VMEM_BYTES:
+            return tm, k, tn
+    return tm, _tile(k), _tile(n)
+
+
+def rows_computed(sizes, tm: int):
+    """Rows gmm's kernel computes at row tile `tm` for groups of `sizes`
+    laid end to end: every tile a group touches, once for each group in
+    it; the rows themselves where tm is 0."""
+    if not tm:
+        return jnp.sum(sizes)
+    ends = jnp.cumsum(sizes)
+    tiles = -(-ends // tm) - (ends - sizes) // tm
+    return jnp.sum(jnp.where(sizes > 0, tiles, 0)) * tm
+
+
+def grouped_dot(a, w, sizes, tiling: Tuple[int, int, int]):
     """``a[rows of group g] @ w[g]`` for each group, rows in group order,
-    float32: a [m, k], w [groups, k, n], sizes int32 [groups]. Rows past
-    the groups' sum hold whatever the product left there."""
-    m = a.shape[0]
-    tm = next((t for t in (256, 128, 64, 32, 16, 8) if m % t == 0), 0)
-    if not (tm and grouped_kernel_available()):
+    float32: a [m, k], w [groups, k, n], sizes int32 [groups], gmm's
+    `tiling` from :func:`grouped_tiling`. Rows past the groups' sum hold
+    whatever the product left there."""
+    if not (tiling[0] and grouped_kernel_available()):
         return jax.lax.ragged_dot(a, w, sizes,
                                   preferred_element_type=jnp.float32)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     return gmm(a, w, sizes, preferred_element_type=jnp.float32,
-               tiling=(tm, _tile(w.shape[1]), _tile(w.shape[2])))
+               tiling=tiling)
 
 
 def route(h, wr, top_k: int, *, scoring: str = "softmax", select_bias=None,
@@ -99,8 +167,9 @@ def expert_ffn(h, weights, experts, wg, wu, wd, *, n_experts: int,
       valid         bool [t]: rows that are real tokens; the others are
                     given to no expert and come back zero
 
-    -> (y [t, d] in h's type, int32 [3]: assignments computed here,
-    experts with at least one token, the fullest expert's tokens)."""
+    -> (y [t, d] in h's type, int32 [4]: assignments computed here,
+    experts with at least one token, the fullest expert's tokens, rows
+    the gate and up products run on a TPU (:func:`rows_computed`))."""
     t, k = experts.shape
     held = tuple(range(n_experts)) if experts_held is None \
         else tuple(int(e) for e in experts_held)
@@ -115,14 +184,18 @@ def expert_ffn(h, weights, experts, wg, wu, wd, *, n_experts: int,
     flat = slot.reshape(-1)
     order = jnp.argsort(flat, stable=True)           # grouped by slot
     sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
+    m, (d, f) = t * k, wg.shape[1:]
+    item = max(h.dtype.itemsize, wg.dtype.itemsize)
+    up = grouped_tiling(m, n, d, f, item)
     xs = h[order // k]                               # [t*k, d]
-    act = (jax.nn.silu(grouped_dot(xs, wg, sizes))
-           * grouped_dot(xs, wu, sizes)).astype(h.dtype)
-    ys = grouped_dot(act, wd, sizes)                 # [t*k, d] float32
+    act = (jax.nn.silu(grouped_dot(xs, wg, sizes, up))
+           * grouped_dot(xs, wu, sizes, up)).astype(h.dtype)
+    ys = grouped_dot(act, wd, sizes, grouped_tiling(m, n, f, d, item))
     back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * k, dtype=order.dtype))
+        jnp.arange(m, dtype=order.dtype))
     y = ys[back].reshape(t, k, -1)
     # rows past the held groups are whatever the product left there
     y = jnp.where((slot < n)[..., None], y * weights[..., None], 0.0)
-    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
+    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes),
+                       rows_computed(sizes, up[0])])
     return jnp.sum(y, axis=1).astype(h.dtype), stats.astype(jnp.int32)

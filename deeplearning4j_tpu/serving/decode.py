@@ -179,6 +179,16 @@ _MOE_HELP = {
     "peak": ("serving_moe_expert_load_peak_total",
              "Tokens of the fullest expert of a layer in a decode step, "
              "summed over layers and steps"),
+    "prefill_assignments": ("serving_moe_prefill_assignments_total",
+                            "Token-to-expert assignments the prefill "
+                            "chunks' expert layers computed, summed over "
+                            "layers"),
+    "prefill_rows": ("serving_moe_prefill_rows_computed_total",
+                     "Rows the prefill chunks' grouped gate and up "
+                     "products run on a TPU, the grouped product's row "
+                     "tiles times their rows, summed over layers (over "
+                     "it serving_moe_prefill_assignments_total is the "
+                     "useful share)"),
 }
 
 
@@ -251,6 +261,13 @@ def _register_model_metrics() -> Dict[Any, Any]:
 def _nbytes(*arrays) -> int:
     """Bytes of the arrays, and of the leaves of any tree among them."""
     return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(arrays))
+
+
+def _summed(sums):
+    """The sparse layers' routing sums added up (a dense layer's are
+    None); None where no layer is sparse."""
+    sums = [s for s in sums if s is not None]
+    return sum(sums[1:], sums[0]) if sums else None
 
 
 # ---------------------------------------------------------------------------
@@ -702,7 +719,7 @@ class TransformerDecoder:
     write the new K/V into them in place, pick the
     greedy token on the device and hand the arenas back — the cache
     rebinds to them (``PagedKVCache.update``), and only int32 tokens,
-    bool flags and three routing sums cross the link. The token a row's
+    bool flags and a few routing sums cross the link. The token a row's
     NEXT step reads stays on the device: ``feed`` is an int32 vector of
     one entry a row slot (and a last one that belongs to no request,
     where pad rows point), donated and handed back like the arenas:
@@ -718,7 +735,8 @@ class TransformerDecoder:
       go to ``slots[kind] = (blk[T], off[T])`` (pads point at the
       scratch block); the pick and the finite flag are taken at the
       positions ``last`` and the picks written to ``feed[feed_slots]``.
-      → ``(token [W], finite [W], feed, arenas)``.
+      → ``(token [W], finite [W], the chunk's routing sums int32 [4] or
+      None, feed, arenas)``.
     * ``step(slot[b], pos[b], arenas, tables, starts, lens[b], feed)`` —
       one token per row, read from ``feed[slot]``, through
       ``paged_decode_attention``, which reads each row's blocks through
@@ -1190,7 +1208,8 @@ class TransformerDecoder:
     def _chunk_forward(self, params, tokens, seg, pos, context=None):
         """tokens/seg/pos [T] → (hidden [T, d_model] before the final
         norm, {kind: (K list, V list)} a layer each [T, kv width]; a
-        latent kind: one list of entries).
+        latent kind: one list of entries; the sparse layers' routing
+        sums int32 [4] summed, or None).
         `context` = (arenas, ctx_tables, ctx_starts, ctx_len): segment
         1 also sees its cached positions below ctx_len.
 
@@ -1205,6 +1224,7 @@ class TransformerDecoder:
             x = self._embed(params, tokens, pos)
         new = {k: ([],) if k == "latent" else ([], [])
                for k in set(self.layer_kinds())}
+        sums = []
         for li, lp in enumerate(params["layers"]):
             kind, at = self.kind_of(li), self._slot_in_kind(li)
 
@@ -1229,10 +1249,11 @@ class TransformerDecoder:
                     part, (entry,), "latent", at, line, seg, context
                 ).transpose(1, 0, 2)
 
-            x, _ = self._block(
+            x, s = self._block(
                 x, lp, li, pos,
                 attend_latent if kind == "latent" else attend, valid)
-        return x, new
+            sums.append(s)
+        return x, new, _summed(sums)
 
     def _logits_pure(self, params, tokens, seg, pos):
         """tokens/seg/pos [1, T] → logits [1, T, vocab]."""
@@ -1243,13 +1264,13 @@ class TransformerDecoder:
                       ctx_tables, ctx_starts, ctx_len, last, feed,
                       feed_slots):
         """One chunk into the cache. → (token int32 [W], finite bool
-        [W], feed, arenas)."""
-        x, new = self._chunk_forward(
+        [W], routing sums int32 [4] or None, feed, arenas)."""
+        x, new, sums = self._chunk_forward(
             params, tokens, seg, pos,
             (arenas, ctx_tables, ctx_starts, ctx_len))
         arenas = self._scatter(arenas, slots, new)
         tok, finite = self._pick(params, x[last])
-        return tok, finite, feed.at[feed_slots].set(tok), arenas
+        return tok, finite, sums, feed.at[feed_slots].set(tok), arenas
 
     def _step_pure(self, params, slot, pos, arenas, tables, starts, lens,
                    feed):
@@ -1294,8 +1315,9 @@ class TransformerDecoder:
             slots[kind] = (tables[kind][jnp.arange(b), at // bt], at % bt)
         arenas = self._scatter(arenas, slots, new)
         picked, finite = self._pick(params, x)
-        sums = [s for s in sums if s is not None]   # the sparse layers'
-        sums = sum(sums[1:], sums[0]) if sums else None
+        sums = _summed(sums)
+        if sums is not None:        # a step's rows computed are not read
+            sums = sums[:3]
         return picked, finite, sums, feed.at[slot].set(picked), arenas
 
     # ------------------------------------------------------------ conveniences
@@ -1383,7 +1405,7 @@ class TransformerAdapter:
     cache's arenas and the feed to the model's executable and rebinds
     to what comes back. What crosses the link is a step's row slots, positions, block tables and
     lengths going up and one picked token and one finite flag a row (and
-    a sparse model's three routing sums) coming back
+    a sparse model's routing sums: three a step, four a chunk) coming back
     (``serving_decode_h2d|d2h_bytes_total`` count exactly those). Every
     served token passes through :meth:`_greedy` on the host.
 
@@ -1602,7 +1624,7 @@ class TransformerAdapter:
         seq = self._launches + 1
         with tracing.span("decode/launch", cat="serve", bytes=up, seq=seq,
                           pairs=pairs, pairs_run=work.pairs_run):
-            picked, finite, self._feed = cache.update(
+            *outputs, self._feed = cache.update(
                 lambda a: self.model.prefill(
                     row, seg, pos, a, slots, ctx_tables, ctx_starts,
                     ctx_len, last, self._feed, feed_slots))
@@ -1616,7 +1638,7 @@ class TransformerAdapter:
                 self._prefilling[rid] = cache.length(rid)
         out, fails = self._launched(_Launched(
             "prefill", seq, [(i, rid) for rid, _, i in placed if i >= 0],
-            (picked, finite, None)))
+            tuple(outputs)))
         fails.update(starved)
         return out, fails
 
@@ -1813,11 +1835,16 @@ class TransformerAdapter:
         except Exception as e:  # noqa: BLE001 — typed by the engine
             return out, {rid: e for _, rid in rows}
         self._link["d2h", work.phase].inc(down)
-        if sums is not None:    # host integers since the fetch above
+        # host integers since the fetch above
+        if sums is not None and work.phase == "step":
             self._count["routed"].inc(len(work.rows) * self._routed_a_row)
             for name, v in zip(("assignments", "touched", "peak"),
                                sums.tolist()):  # jaxlint: disable=JL102
                 self._count[name].inc(v)
+        elif sums is not None:
+            assigned, _, _, run = sums.tolist()  # jaxlint: disable=JL102
+            self._count["prefill_assignments"].inc(assigned)
+            self._count["prefill_rows"].inc(run)
         with tracing.span("decode/commit", cat="serve", seq=work.seq):
             for i, rid in rows:
                 if self.check_finite and not finite[i]:

@@ -17,7 +17,8 @@ def _plain(model, prompt, chunk):
     row, seg, pos = (np.zeros((1, -(-t // chunk) * chunk), np.int32)
                      for _ in range(3))
     row[0, :t], seg[0, :t], pos[0, :t] = prompt, 1, np.arange(t)
-    _, new = model._chunk_forward(model.params_tree, row[0], seg[0], pos[0])
+    _, new, _ = model._chunk_forward(model.params_tree, row[0], seg[0],
+                                     pos[0])
     return (np.asarray(model.logits(row, seg, pos))[0, :t],
             {k: tuple(np.stack(a)[:, :t] for a in arrays)
              for k, arrays in new.items()})
@@ -53,7 +54,7 @@ def check_chunk_over_context(model, cache, chunk, ctx, vocab, tol):
     tokens[:tail], seg[:tail], pos[:tail] = long[ctx:], 1, \
         ctx + np.arange(tail)
     tokens[tail:n], seg[tail:n], pos[tail:n] = short, 2, np.arange(other)
-    x, new = jax.jit(model._chunk_forward)(
+    x, new, _ = jax.jit(model._chunk_forward)(
         model.params_tree, tokens, seg, pos,
         (cache.arenas(), tables, starts, np.int32(ctx)))
     got = np.asarray(model._head(model.params_tree, x))[:n]

@@ -111,7 +111,8 @@ class TestPagedKVCache:
             seg = np.zeros((1, 16), np.int32)
             pos = np.zeros((1, 16), np.int32)
             row[0, :n], seg[0, :n], pos[0, :n] = seq[:n], 1, np.arange(n)
-            _, new = m._chunk_forward(m.params_tree, row[0], seg[0], pos[0])
+            _, new, _ = m._chunk_forward(m.params_tree, row[0], seg[0],
+                                         pos[0])
             ks, vs = (np.stack(a) for a in new["full"])
             tables, lens, starved = _view(c, [r], 16)
             assert lens.tolist() == [n] and not starved
