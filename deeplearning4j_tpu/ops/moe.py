@@ -28,13 +28,18 @@ call's static shape alone, the rows a held expert has on average
     tile it ran. A chunk's contraction is whole (`tk = k`, `tn` as wide
     as gmm's 16 MiB of fast memory allows double-buffered), so a
     group's consecutive row tiles keep one weight block and its weights
-    cross once a call, in row tiles of 128. On a v5e that is 7% off a
-    Mellum layer's `expert_ffn` at 2,048 x 8 of 64 (4.51 -> 4.18 ms).
+    cross once a call. On a v5e that is 4.7% off a layer's `expert_ffn`
+    at each served chunk shape (Mellum 2,048 x 8 of 64: 4.51 -> 4.30
+    ms). Row tiles of 128 were within a call's noise of 256 (4.18 and
+    3.82 against 4.30 and 3.78), so the row tile is the step's rule.
     Laying each expert's rows out in whole tiles of their own, so that
     no tile runs for two experts, was slower at every served chunk
     shape (4.76 ms there): the scatter, the padded gather and the
     elementwise pass over the padded length cost more than the tiles
     it saved (chip runs, PR 38).
+A step keeps its split contraction: a served step's rows lie in one to
+five row tiles, so an expert's weights cross once a visit already, and
+again only where a group straddles a tile's edge.
 """
 from __future__ import annotations
 
@@ -88,16 +93,13 @@ def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
 def grouped_tiling(m: int, held: int, k: int, n: int,
                    itemsize: int = 2) -> Tuple[int, int, int]:
     """gmm's `(tm, tk, tn)` for `m` rows of width `k` over `held` experts
-    of [k, n]; tm 0 where no row tile divides `m` (`ragged_dot` then,
-    on any backend). A step: the largest row tile of 256 or less that
-    divides m, then `k` and `n` in lane tiles of 1,024 or less. A chunk
-    (:func:`chunk_form`): the largest row tile of 128 or less, the whole
-    `k` and the widest `n` tile whose blocks fit GMM_VMEM_BYTES, or the
-    step's `k` and `n` tiles where none does."""
-    chunk = chunk_form(m, held)
-    tm = next((t for t in (256, 128, 64, 32, 16, 8)
-               if m % t == 0 and not (chunk and t > 128)), 0)
-    for tn in _tiles(n) if chunk and tm else ():
+    of [k, n]: the largest row tile of 256 or less that divides m (0
+    where none does: `ragged_dot` then, on any backend). A step: `k` and
+    `n` in lane tiles of 1,024 or less. A chunk (:func:`chunk_form`): the
+    whole `k` and the widest `n` tile whose blocks fit GMM_VMEM_BYTES, or
+    the step's tiles where none does."""
+    tm = next((t for t in (256, 128, 64, 32, 16, 8) if m % t == 0), 0)
+    for tn in _tiles(n) if chunk_form(m, held) and tm else ():
         if gmm_vmem_bytes(tm, k, tn, itemsize) <= GMM_VMEM_BYTES:
             return tm, k, tn
     return tm, _tile(k), _tile(n)

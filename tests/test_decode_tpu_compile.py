@@ -266,6 +266,30 @@ def test_mosaic_takes_the_prefill_kernel_inside_its_fast_memory(compiled,
             "the kernel is not compiled under PREFILL_VMEM_LIMIT"
 
 
+# experts a token, held, hidden and expert width of the served sparse cells
+GMM_SHAPES = {"mellum": (8, 64, 2304, 896), "kanana": (6, 128, 2048, 768),
+              "laguna": (10, 64, 3072, 1024)}
+
+
+@pytest.mark.parametrize("product", ["gate_up", "down"])
+@pytest.mark.parametrize("cell", list(GMM_SHAPES))
+def test_mosaic_takes_the_chunks_grouped_product_at_its_tiling(compiled,
+                                                               cell, product):
+    """gmm at the tiling a served chunk of 2,048 positions takes, the
+    whole contraction: gmm's `pallas_call` passes no limit, so Mosaic
+    holds its blocks to the scoped default and refuses more."""
+    k, held, d, f = GMM_SHAPES[cell]
+    m = 2048 * k
+    kk, n = (d, f) if product == "gate_up" else (f, d)
+    tiling = moe.grouped_tiling(m, held, kk, n)
+    assert moe.chunk_form(m, held) and tiling[1] == kk
+    bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    exe = compiled(lambda a, w, s: moe.grouped_dot(a, w, s, tiling), (),
+                   bf(m, kk), bf(held, kk, n),
+                   jax.ShapeDtypeStruct((held,), jnp.int32))
+    assert exe.as_text().count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("setting,named", [
     (SPARSE, ("prefill_attention_full", "prefill_attention_sliding")),
     (LATENT, ("prefill_attention_latent",)),

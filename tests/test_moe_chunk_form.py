@@ -1,6 +1,6 @@
 """The experts' grouped product in a prefill chunk's form (`ops/moe.py`):
-gmm takes the whole contraction at once, in row tiles of 128, where a
-decode step keeps the tiling it had. On the CPU both run through
+gmm takes the whole contraction at once, where a decode step keeps the
+tiling it had. On the CPU both run through
 `ragged_dot`: the layer must give the plain sum over its experts, the
 rows it reports must be the rows gmm's own metadata runs at the call's
 tiling, the tiling rule must give every served chunk its form and every
@@ -50,7 +50,7 @@ def _case(name, rng):
     if name == "a step's shape":
         t, k = 16, 2
     if name == "an expert over several tiles":
-        t = 512                 # all 512 pick expert 5: four tiles of 128
+        t = 512                 # all 512 pick expert 5: two tiles of 256
     h = jnp.asarray(rng.normal(size=(t, D)), jnp.float32)
     if name.endswith("router"):
         scoring = name.split()[0]
@@ -105,7 +105,7 @@ def test_the_layer_and_its_sums_at_the_calls_form(name):
     # the rows the gate and up products run: gmm's own tiles
     m = idx.size
     tm = moe.grouped_tiling(m, n, D, F)[0]
-    assert tm == (128 if name != "a step's shape" else 32)
+    assert tm == (256 if name != "a step's shape" else 32)
     assert moe.chunk_form(m, n) == (name != "a step's shape")
     assert int(stats[3]) == _tiles_run(sizes, m, tm) * tm
     assert stats[0] <= stats[3] <= stats[0] + (n - 1) * tm + tm
@@ -136,9 +136,9 @@ def test_the_rows_computed_are_the_tiles_gmm_runs(tm):
 # a chunk of 2,048 positions, a step at the cell's rows
 SERVED = {"mellum": (8, 64, 2304, 896, 32), "kanana": (6, 128, 2048, 768, 32),
           "laguna": (10, 64, 3072, 1024, 64)}
-CHUNK_TILING = {"mellum": ((128, 2304, 896), (128, 896, 768)),
-                "kanana": ((128, 2048, 768), (128, 768, 1024)),
-                "laguna": ((128, 3072, 1024), (128, 1024, 1024))}
+CHUNK_TILING = {"mellum": ((256, 2304, 896), (256, 896, 768)),
+                "kanana": ((256, 2048, 768), (256, 768, 1024)),
+                "laguna": ((256, 3072, 512), (256, 1024, 1024))}
 # what every step ran before the chunk had a form of its own
 STEP_TILING = {"mellum": ((256, 768, 896), (256, 896, 768)),
                "kanana": ((64, 1024, 768), (64, 768, 1024)),
